@@ -11,8 +11,8 @@ Arms (per program):
 
 Programs:
   transformer_s2048  composed-attention transformer train graph at
-                     seq 2048 (BENCH_r05's MFU worst case) — the
-                     activation-dominated regime the pass exists for;
+                     seq 2048 — the activation-dominated regime the
+                     pass exists for;
   transformer_s4096  same at seq 4096 (activation bytes scale ~4x);
   decode_step        the decoder-LM single-token decode program
                      (cache-resident regime: persistable KV state
@@ -22,7 +22,7 @@ The static section reports, per arm, the planner's arena peak
 (MemoryReport.peak_bytes with real feed shapes), the ideal-allocator
 bound, and ``peak_reduction_pct`` — the headline the pre-compile OOM
 gate experiences. The optional timing section (skipped by --static-only)
-runs bench.py's marginal-cost protocol per arm with the MFU_BREAKDOWN.md
+runs bench.py's marginal-cost protocol per arm with its
 repeat-and-report-spread convention (median of --repeats marginal
 estimates, spread_pct = 100*(max-min)/median): buffer renaming happens
 before XLA sees the graph, so steps/sec should be flat — the timing arm

@@ -12,12 +12,12 @@ Arms (per model):
 Models:
   transformer  composed-attention transformer (the matmul->softmax->
                matmul chain the fusion outlining exists for) at
-               --seq-len (default 2048 — BENCH_r05's 0.136 MFU_xla
-               worst case); reports tokens/sec (batch * seq).
+               --seq-len (default 2048); reports tokens/sec
+               (batch * seq).
   lstm_lm      the stacked-LSTM language model (ragged feeds); reports
                tokens/sec (fed tokens per step).
 
-Timing is bench.py's marginal-cost protocol with the MFU_BREAKDOWN.md
+Timing is bench.py's marginal-cost protocol with its
 repeat-and-report-spread convention (median of `--repeats` marginal
 estimates, spread_pct = (max-min)/median — estimates whose spread
 swamps the delta are flagged, not trusted). The JSON also reports the

@@ -28,14 +28,10 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..ops.optimizer_ops import SPARSE_HYPER_DEFAULTS, sparse_row_update
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
 
 #: per-kind row slots, in the order the dense op reads them
 ROW_SLOTS = {"sgd": (), "adagrad": ("moment",),

@@ -93,8 +93,7 @@ FLAGS: Dict[str, tuple] = {
         "512", "ops/nn_ops.py",
         "minimum sequence length at which fused attention auto-routes "
         "to the Pallas flash kernel; below it the naive composition "
-        "wins on v5e (measured crossover ~512 — MFU_BREAKDOWN.md "
-        "round 3)"),
+        "wins on v5e (crossover ~512 as measured in round 3)"),
     "PADDLE_TPU_ATTRIBUTION": (
         "1", "observability/attribution.py (published from trainer.py, "
         "serving/engine.py)",
@@ -104,11 +103,12 @@ FLAGS: Dict[str, tuple] = {
         "registry also turns it off; set_attribution_enabled() "
         "overrides the env)"),
     "PADDLE_TPU_PEAK_FLOPS": (
-        "197e12", "observability/attribution.py",
-        "device peak FLOP/s the MFU gauge is normalized against "
-        "(default: v5e bf16 peak, same constant as "
-        "benchmarks/profile_mfu.py); read per step so tests can "
-        "flip it"),
+        "", "observability/attribution.py",
+        "device peak FLOP/s the MFU gauge is normalized against; "
+        "unset, the peak comes from attribution."
+        "PEAK_FLOPS_BY_DEVICE_KIND keyed by the device JAX reports, "
+        "and a device not in that table publishes no paddle_tpu_mfu "
+        "(benchmarks raise). Read per step so tests can flip it"),
     "PADDLE_TPU_FLIGHT_RECORDER": (
         "1", "observability/flight_recorder.py",
         "failure flight recorder: bounded ring of recent profiler "
@@ -226,8 +226,7 @@ FLAGS: Dict[str, tuple] = {
         "0", "ops/nn_ops.py",
         "use the round-2 hand-written BatchNorm backward (custom_vjp) "
         "instead of autodiff; the autodiff default lets XLA fuse the "
-        "backward reductions into conv gradient fusions — see "
-        "MFU_BREAKDOWN.md round 3"),
+        "backward reductions into conv gradient fusions"),
 }
 
 

@@ -3,11 +3,13 @@
 The reference binds its C++ runtime to Python with pybind11
 (reference: paddle/fluid/pybind/pybind.cc:74-185); pybind11 is not in this
 image, so the native layer exposes a C ABI and this module wraps it with
-ctypes. The library is built lazily via `make` on first import if missing.
+ctypes. The library is built lazily via `make` on first use when it is missing
+or older than any native/*.cc.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
 import os
 import subprocess
 import threading
@@ -25,6 +27,16 @@ def _build():
                    capture_output=True)
 
 
+def _stale() -> bool:
+    """True when the library is missing or any native/*.cc is newer
+    than it, so a prebuilt .so can never shadow changed sources."""
+    if not os.path.exists(_LIB_PATH):
+        return True
+    built = os.path.getmtime(_LIB_PATH)
+    return any(os.path.getmtime(src) > built
+               for src in glob.glob(os.path.join(_NATIVE_DIR, "*.cc")))
+
+
 def lib() -> ctypes.CDLL:
     """Load (building if needed) the native library; idempotent."""
     global _lib
@@ -33,7 +45,7 @@ def lib() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
+        if _stale():
             _build()
         l = ctypes.CDLL(_LIB_PATH)
 

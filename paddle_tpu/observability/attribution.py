@@ -33,9 +33,11 @@ a disabled default registry, or ``PADDLE_TPU_ATTRIBUTION=0``.
 
 Boundary (KNOWN_GAPS): the accumulator is process-global, so a serving
 engine co-resident with a training loop folds its dispatch/fetch events
-into the trainer's breakdown. MFU is computed against
-``PADDLE_TPU_PEAK_FLOPS`` (default: v5e bf16 peak, 197e12) — on a CPU
-backend the gauge is self-consistent but not meaningful as an absolute.
+into the trainer's breakdown. MFU is computed against the peak of the
+device JAX reports (``PEAK_FLOPS_BY_DEVICE_KIND``), or
+``PADDLE_TPU_PEAK_FLOPS`` when set; a device that is in neither publishes
+no ``paddle_tpu_mfu`` at all (a CPU backend has no peak worth dividing
+by — tests that read the gauge set the flag).
 """
 from __future__ import annotations
 
@@ -43,27 +45,50 @@ import os
 import threading
 from typing import Dict, Optional
 
+import jax
+
 from .. import profiler
 
-__all__ = ["PHASES", "PHASE_BY_EVENT", "peak_flops",
+__all__ = ["PHASES", "PHASE_BY_EVENT", "PEAK_FLOPS_BY_DEVICE_KIND",
+           "peak_flops", "require_peak_flops",
            "attribution_enabled", "set_attribution_enabled",
            "drain_phases", "mfu_gauge", "model_flops_gauge",
            "phase_histogram"]
 
-#: v5e bf16 peak (benchmarks/profile_mfu.py uses the same constant);
-#: PADDLE_TPU_PEAK_FLOPS overrides for other parts/hosts.
-PEAK_FLOPS_DEFAULT = 197e12
+#: Peak dense bf16 FLOP/s of ONE chip, keyed by
+#: ``jax.devices()[0].device_kind`` exactly as JAX prints it (the first
+#: row is the kind chip_smoke.py printed on the attached chip, PR 22).
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16 per
+#: chip). The one table every MFU in the tree divides by — a device
+#: that is not in it has no default.
+PEAK_FLOPS_BY_DEVICE_KIND = {
+    "TPU v5 lite": 197e12,
+}
 
 
-def peak_flops() -> float:
-    """Device peak FLOP/s the MFU gauge is normalized against (env
-    ``PADDLE_TPU_PEAK_FLOPS``, read per call so tests/benchmarks can
-    flip it)."""
-    try:
-        return float(os.environ.get("PADDLE_TPU_PEAK_FLOPS",
-                                    PEAK_FLOPS_DEFAULT))
-    except ValueError:
-        return PEAK_FLOPS_DEFAULT
+def peak_flops() -> Optional[float]:
+    """Peak FLOP/s the MFU gauge is normalized against:
+    ``PADDLE_TPU_PEAK_FLOPS`` when set (read per call so tests and
+    benchmarks can flip it; a malformed value raises ValueError), else
+    the table row of the device JAX reports, else None — the caller
+    then publishes no MFU."""
+    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
+    if env:
+        return float(env)
+    return PEAK_FLOPS_BY_DEVICE_KIND.get(jax.devices()[0].device_kind)
+
+
+def require_peak_flops() -> float:
+    """peak_flops() for benchmarks: an unknown device is an error, not
+    a default."""
+    peak = peak_flops()
+    if peak is None:
+        raise RuntimeError(
+            f"no peak FLOP/s known for device kind "
+            f"{jax.devices()[0].device_kind!r}: add it to "
+            "observability.attribution.PEAK_FLOPS_BY_DEVICE_KIND with "
+            "its source, or set PADDLE_TPU_PEAK_FLOPS")
+    return peak
 
 
 _enabled_override: Optional[bool] = None
@@ -173,7 +198,8 @@ def drain_phases() -> Dict[str, float]:
 # ---------------------------------------------------------------------------
 _MFU_HELP = ("Model FLOPs utilization of the most recent step/batch: "
              "static cost-model FLOPs / wall time / device peak "
-             "(PADDLE_TPU_PEAK_FLOPS).")
+             "(by device_kind, or PADDLE_TPU_PEAK_FLOPS); absent for a "
+             "device with no known peak.")
 _FLOPS_HELP = ("Static cost-model FLOPs per step of the currently "
                "compiled program for this job.")
 _PHASE_HELP = ("Per-step wall-time breakdown by phase (feed, dispatch, "

@@ -20,7 +20,7 @@ def _mxu_matmul(x, y):
     """matmul that engages the MXU in one pass under AMP: bf16 operands,
     float32 accumulation, and a bf16 RESULT so activations thread
     end-to-end at half width (the f32->bf16 rounding happens in the
-    matmul epilogue, fused — see MFU_BREAKDOWN.md)."""
+    matmul epilogue, fused)."""
     out_dtype = jnp.promote_types(x.dtype, y.dtype)
     x, y = amp_cast(x, y)
     if x.dtype == jnp.bfloat16 == y.dtype and out_dtype == jnp.float32:

@@ -51,9 +51,9 @@ def _conv2d_impl(x, w, strides, paddings, dilations, groups):
     # activations thread end-to-end at half width so every inter-op HBM
     # buffer halves. (Round 1 cast each op's result back to f32; device
     # traces showed the resulting convert_element_type fusions plus the
-    # doubled f32 traffic dominating the HBM-bound step — see
-    # MFU_BREAKDOWN.md. The MXU accumulates in f32 internally either
-    # way; preferred_element_type=f32's conv transpose rule rejects
+    # doubled f32 traffic dominating the HBM-bound step. The MXU
+    # accumulates in f32 internally either way;
+    # preferred_element_type=f32's conv transpose rule rejects
     # mixed-dtype cotangents, so full-bf16 it is.)
     x, w = amp_cast(x, w)
     nhwc = _conv_nhwc()
@@ -327,7 +327,7 @@ def _layer_norm(ctx):
     batch_norm) measured 5-12% SLOWER for the transformer in
     order-controlled same-session A/Bs — LN reduces over the minor
     (d_model) dim where XLA fuses the row-local chain fine, and the
-    coefficient broadcasts only add traffic (MFU_BREAKDOWN.md r3)."""
+    coefficient broadcasts only add traffic."""
     x = ctx.input("X")
     scale = ctx.input("Scale")
     bias = ctx.input("Bias")
@@ -630,6 +630,38 @@ def _unstack(ctx):
     ctx.set_outputs("Y", [p.squeeze(axis) for p in parts])
 
 
+def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,
+                         head_axis):
+    """``attend(q, k, v, mask)`` run per shard of a mesh. GSPMD cannot
+    partition a Mosaic kernel (the TPU lowering raises "Mosaic kernels
+    cannot be automatically partitioned"), so under a ParallelExecutor
+    mesh the flash kernel runs inside shard_map over the batch and head
+    dims — attention is independent across both, so no collective is
+    added. A dim its axis does not divide stays replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    def axis_for(name, dim):
+        ok = name in mesh.axis_names and dim % mesh.shape[name] == 0
+        return name if ok else None
+
+    b_ax = axis_for(batch_axis, q.shape[0])
+    h_ax = axis_for(head_axis, q.shape[1])
+    spec = P(b_ax, h_ax, None, None)
+    args, specs = (q, k, v), (spec,) * 3
+    if mask is not None:
+        if mask.ndim == 2:        # [Sq|1, Sk|1], as flash_attention reads it
+            mask = mask[None, None]
+        elif mask.ndim == 3:      # [B|1, Sq|1, Sk|1]
+            mask = mask[:, None]
+        args += (mask,)
+        specs += (P(b_ax if mask.shape[0] == q.shape[0] else None,
+                    h_ax if mask.shape[1] == q.shape[1] else None,
+                    None, None),)
+    return jax.shard_map(
+        lambda q, k, v, mask=None: attend(q, k, v, mask), mesh=mesh,
+        in_specs=specs, out_specs=spec, check_vma=False)(*args)
+
+
 @register_op("scaled_dot_product_attention", no_grad_slots=["Mask"])
 def _sdpa(ctx):
     """Fused attention (TPU-native addition; the reference composes it from
@@ -687,16 +719,24 @@ def _sdpa(ctx):
         # flash wins 2.5x at S=1024 and 5.6x at S=4096 — the S^2 score
         # materialization only starts to bind around 512. Round 2's
         # threshold of 128 routed the transformer bench's S=256 through
-        # flash and cost it ~35% end-to-end (MFU_BREAKDOWN.md round 3).
+        # flash and cost it ~35% end-to-end. (Round-3 numbers, taken
+        # before this tree's first chip_smoke.py run; not re-measured.)
         min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "512"))
         use_flash = (jax.default_backend() == "tpu" and q.ndim == 4
                      and q.shape[2] >= min_seq
                      and k.shape[2] >= min_seq)
     if use_flash:
         from .pallas import flash_attention
-        ctx.set_output("Out", flash_attention(q, k, v, mask,
-                                              causal=causal,
-                                              sm_scale=sm_scale))
+        attend = functools.partial(flash_attention, causal=causal,
+                                   sm_scale=sm_scale)
+        if mesh is None:
+            out = attend(q, k, v, mask)
+        else:
+            out = _per_shard_attention(
+                attend, mesh, q, k, v, mask,
+                ctx.attr("batch_axis", "data"),
+                ctx.attr("head_axis", "model"))
+        ctx.set_output("Out", out)
         return
     scale = sm_scale if sm_scale is not None \
         else 1.0 / np.sqrt(q.shape[-1])

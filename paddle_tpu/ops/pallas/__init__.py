@@ -13,7 +13,12 @@ import jax
 
 
 def interpret_default() -> bool:
-    """Interpret kernels off-TPU (tests); compile on real hardware."""
+    """Interpret kernels off-TPU (tests); compile on real hardware. On
+    a TPU backend this is False, always: there interpret mode is
+    reachable only through an explicit ``interpret=True`` argument
+    (tests). chip_smoke.py asserts it on the chip and reads the kernels
+    out of the compiled HLO — the proof of which path ran is the
+    ``tpu_custom_call``, not this branch."""
     return jax.default_backend() != "tpu"
 
 

@@ -1,6 +1,6 @@
 """Batch-tiled cross-layer bottleneck megakernel (round-4 campaign).
 
-The round-3 roofline analysis (MFU_BREAKDOWN.md) showed the ResNet-50
+The round-3 roofline analysis showed the ResNet-50
 train step pinned to the HBM roofline at ~40 GB/step vs a ~16 GB hand
 ideal: every conv boundary writes its activation to HBM and the next
 conv reads it back. Whole-block fusion was ruled out there because a

@@ -199,11 +199,7 @@ def _scratch(shape, dtype):
 
 
 def _compiler_params(dimension_semantics):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except (AttributeError, TypeError):  # older jax spelling
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=dimension_semantics)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 # ---------------------------------------------------------------------------

@@ -70,8 +70,12 @@ def parse_collectives(hlo_text: str) -> List[Collective]:
     form replica_groups=[G,S]<=[dims]T(perm)."""
     out = []
     for ln in hlo_text.splitlines():
+        # the shape is everything between "= " and the opcode: a TPU
+        # tuple shape nests parentheses in its tiled layouts
+        # ((bf16[8192,512]{1,0:T(8,128)(2,1)}, ...)), so it cannot be
+        # matched as one balanced group
         m = re.search(
-            r"= ((?:\([^)]*\)|\S+)) (all-reduce|reduce-scatter|all-gather"
+            r"= (.+?) (all-reduce|reduce-scatter|all-gather"
             r"|all-to-all|collective-permute)(?:-start)?\(", ln)
         if not m:
             continue

@@ -205,7 +205,7 @@ def ulysses_attention(q, k, v, kv_mask=None, axis_name: str = "seq",
     if use_flash is None:
         # one routing policy with ops/nn_ops._sdpa: the measured v5e
         # crossover puts flash ahead of the naive composition only
-        # from gathered S ~512 (MFU_BREAKDOWN.md round 3)
+        # from gathered S ~512
         min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "512"))
         use_flash = (jax.default_backend() == "tpu"
                      and qf.shape[2] >= min_seq)

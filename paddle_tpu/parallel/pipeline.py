@@ -29,14 +29,10 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import get_mesh
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, micro_xs,
@@ -83,10 +79,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, micro_xs,
         # the scan carry is device-varying (each stage holds a different
         # activation): mark the initial value accordingly for shard_map's
         # varying-manual-axes type system
-        if hasattr(jax.lax, "pcast"):
-            zero = jax.lax.pcast(zero, (axis,), to="varying")
-        else:  # pragma: no cover — older jax spelling
-            zero = jax.lax.pvary(zero, (axis,))
+        zero = jax.lax.pcast(zero, (axis,), to="varying")
 
         def tick(carry, t):
             state = carry            # activation entering this stage

@@ -184,7 +184,14 @@ def multiprocess_batch_reader(worker_fn: Callable, num_workers: int,
     `tuple(a.copy() for a in batch)`. The views are marked
     non-writeable so accidental in-place mutation raises instead of
     racing the producer. Closing the generator shuts the workers
-    down."""
+    down.
+
+    ONE PROCESS PER CHIP: the workers move numpy arrays through shared
+    memory and never touch a jax array, and `worker_fn` must keep it
+    so. The chip belongs to the process that first initialised a JAX
+    backend (the trainer); a worker that created one jax array on a
+    machine with a chip would hang or die on the TPU library's lock.
+    `import paddle_tpu` initialises no backend."""
     if num_workers < 1:
         raise ValueError("num_workers must be >= 1")
 

@@ -324,7 +324,13 @@ def _service_worker_main(wid, specs, cfg, slots, free_q, out_q, stop_ev,
 
     Each worker OWNS its result queue: a worker SIGKILLed mid-put can
     wedge only its own queue's write lock, never the siblings' — the
-    consumer simply stops reading a retired incarnation's queue."""
+    consumer simply stops reading a retired incarnation's queue.
+
+    numpy only (recordio scan, decode, collate, shm copy): the chip
+    belongs to the consumer process, and a worker that touched jax on
+    a machine with a chip would hang or die on the TPU library's lock
+    — cfg.decode / cfg.collate must keep it so (chip_smoke.py's
+    streaming-fed steps are the standing evidence)."""
     shms: List = []
     layout = None
     try:

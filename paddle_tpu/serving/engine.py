@@ -295,10 +295,10 @@ class ServingEngine:
             # dispatched executable (captured at dispatch — under
             # async overlap executor.last_cost may already belong to
             # the next batch's bucket) / batch wall time / device peak
-            if cost is not None and cost.flops and t1 > t0:
-                self.metrics.set_mfu(
-                    cost.flops / obs_attr.peak_flops() / (t1 - t0),
-                    cost.flops)
+            peak = obs_attr.peak_flops()
+            if cost is not None and cost.flops and t1 > t0 and peak:
+                self.metrics.set_mfu(cost.flops / peak / (t1 - t0),
+                                     cost.flops)
         for req, (i0, i1) in zip(batch.requests, batch.slices):
             out = []
             for f, per_row in zip(fetches, self._per_row_fetch):

@@ -418,10 +418,10 @@ class GenerationEngine:
         self.metrics.step_seconds.record(t1 - t0)
         if obs_attr.attribution_enabled():
             cost = self.model.last_cost()
-            if cost is not None and cost.flops and t1 > t0:
-                self.metrics.set_mfu(
-                    cost.flops / obs_attr.peak_flops() / (t1 - t0),
-                    cost.flops)
+            peak = obs_attr.peak_flops()
+            if cost is not None and cost.flops and t1 > t0 and peak:
+                self.metrics.set_mfu(cost.flops / peak / (t1 - t0),
+                                     cost.flops)
         mem = self.model.last_memory()
         if mem is not None:
             from ...analysis.memory import publish_peak

@@ -318,7 +318,7 @@ class Trainer:
                 "Configured prefetch= depth of the current train() "
                 "call (0 = inline feed assembly).").set(prefetch)
         if attr_on:
-            m_mfu = obs_attr.mfu_gauge(reg, "train")
+            m_mfu = None
             m_flops = obs_attr.model_flops_gauge(reg, "train")
             m_phase = obs_attr.phase_histogram(reg)
             # reset the phase window: events from start()/warmup must
@@ -508,10 +508,15 @@ class Trainer:
                             if cost is not None and cost.flops:
                                 step_s = wall / len(group)
                                 m_flops.set(float(cost.flops))
-                                if step_s > 0:
-                                    m_mfu.set(cost.flops
-                                              / obs_attr.peak_flops()
-                                              / step_s)
+                                peak = obs_attr.peak_flops()
+                                if step_s > 0 and peak:
+                                    # registered on first use: a device
+                                    # with no known peak leaves no
+                                    # zero-valued mfu series behind
+                                    if m_mfu is None:
+                                        m_mfu = obs_attr.mfu_gauge(
+                                            reg, "train")
+                                    m_mfu.set(cost.flops / peak / step_s)
                     dispatch_id += 1
                     if len(group) < k:
                         break

@@ -32,6 +32,13 @@ def fresh_registry():
 
 
 @pytest.fixture
+def peak_flops_flag(monkeypatch):
+    """The CPU backend is in no peak table, so a test that reads
+    paddle_tpu_mfu names the peak it divides by."""
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "197e12")
+
+
+@pytest.fixture
 def fresh_recorder(tmp_path):
     """Point the process-default flight recorder at a private tmp dir
     so this test sees exactly its own dumps."""
@@ -144,7 +151,40 @@ def test_executor_attaches_cost_on_compile_miss():
 # ---------------------------------------------------------------------------
 # live MFU + phase breakdown
 # ---------------------------------------------------------------------------
-def test_trainer_publishes_mfu_and_phase_breakdown(fresh_registry):
+def test_peak_flops_is_keyed_by_device_kind(monkeypatch):
+    """One table, keyed by device_kind; the flag overrides it; a device
+    in neither has NO peak (not the v5e's), and a malformed flag raises
+    instead of quietly becoming the default."""
+    monkeypatch.delenv("PADDLE_TPU_PEAK_FLOPS", raising=False)
+    assert attribution.PEAK_FLOPS_BY_DEVICE_KIND["TPU v5 lite"] == 197e12
+    assert attribution.peak_flops() is None          # device_kind "cpu"
+    with pytest.raises(RuntimeError, match="no peak FLOP/s known"):
+        attribution.require_peak_flops()
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "1e12")
+    assert attribution.peak_flops() == attribution.require_peak_flops() \
+        == 1e12
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "fast")
+    with pytest.raises(ValueError):
+        attribution.peak_flops()
+
+
+def test_trainer_publishes_no_mfu_for_an_unknown_device(fresh_registry,
+                                                        monkeypatch):
+    """No peak, no paddle_tpu_mfu series — absent, not a number
+    computed against some other device's peak. The rest of the
+    attribution (model FLOPs, phases) still publishes."""
+    monkeypatch.delenv("PADDLE_TPU_PEAK_FLOPS", raising=False)
+    main, startup, loss = _build_mlp()
+    Trainer(loss, main_program=main, startup_program=startup).train(
+        num_passes=1, reader=_reader())
+    assert fresh_registry.get("paddle_tpu_mfu") is None
+    assert fresh_registry.get("paddle_tpu_model_flops") \
+        .labels(job="train").value > 0
+    assert fresh_registry.get("paddle_tpu_step_phase_seconds") is not None
+
+
+def test_trainer_publishes_mfu_and_phase_breakdown(fresh_registry,
+                                                   peak_flops_flag):
     """Acceptance: a registry read of a running trainer reports a
     nonzero paddle_tpu_mfu and a phase breakdown whose phase sum equals
     total step wall time (device is the residual, so the identity holds
@@ -215,7 +255,8 @@ def test_attribution_env_flip_reinstalls_listener(monkeypatch):
     assert attribution._phase_listener in profiler._event_listeners
 
 
-def test_serving_engine_publishes_mfu(tmp_path, fresh_registry):
+def test_serving_engine_publishes_mfu(tmp_path, fresh_registry,
+                                      peak_flops_flag):
     from paddle_tpu import serving
 
     main, startup = pt.Program(), pt.Program()

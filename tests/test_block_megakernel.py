@@ -1,6 +1,6 @@
 """Batch-tiled bottleneck megakernel: interpret-mode correctness vs the
 jnp ghost-BN oracle (the on-chip perf A/B lives in
-benchmarks/block_megakernel_ab.py; MFU_BREAKDOWN.md holds results)."""
+benchmarks/block_megakernel_ab.py)."""
 import numpy as np
 import pytest
 

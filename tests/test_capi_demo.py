@@ -1,4 +1,4 @@
-"""C-host inference execution (round-3 VERDICT item 7; reference:
+"""C-host inference execution (reference:
 paddle/capi/main.h:27 + capi/examples/model_inference): a C program
 loads the exported PTIR through the native C ABI, validates it, and
 executes a forward pass through the embedded runtime, returning the

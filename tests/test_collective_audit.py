@@ -27,6 +27,22 @@ def test_parse_literal_and_iota_groups():
     assert cols[2].pairs == [(0, 1), (1, 2), (2, 3), (3, 0)]
 
 
+def test_parse_tpu_tuple_shapes_with_tiled_layouts():
+    """A TPU module prints tiled layouts, so a combined (tuple-shaped)
+    all-reduce nests parentheses inside its shape — taken from the
+    (2,2)-mesh transformer step compiled for v5e in PR 22, where the
+    data-axis gradient sync is exactly such an instruction."""
+    hlo = """
+  %all-reduce.294 = (bf16[8192,512]{1,0:T(8,128)(2,1)S(1)}, bf16[8192,512]{1,0:T(8,128)(2,1)}) all-reduce(%fusion.1234, %fusion.1231), channel_id=39, replica_groups=[2,2]<=[2,2]T(1,0), use_global_device_ids=true, to_apply=%add.36.clone
+  %ag = f32[2048]{0:T(1024)S(1)} all-gather(%p), channel_id=2, replica_groups=[2,2]<=[4], dimensions={0}
+"""
+    cols = ca.parse_collectives(hlo)
+    assert [c.kind for c in cols] == ["all-reduce", "all-gather"]
+    assert cols[0].bytes == 2 * 8192 * 512 * 2
+    assert cols[0].groups == [[0, 2], [1, 3]]
+    assert cols[1].groups == [[0, 1], [2, 3]]
+
+
 def test_classification_against_mesh_axes():
     from paddle_tpu.parallel import make_mesh
     import jax

@@ -1,5 +1,4 @@
-"""Real-archive parse paths for every dataset module (VERDICT r2
-item 7): each test constructs a tiny archive in the REFERENCE's on-disk
+"""Real-archive parse paths for every dataset module: each test constructs a tiny archive in the REFERENCE's on-disk
 format (cifar pickle-tar, aclImdb tar, PTB tgz, ml-1m zip, CoNLL column
 files, VOC tar, flowers mats, WMT dict+bitext, LETOR text) and runs the
 module's real parser over it — the zero-egress environment cannot

@@ -1,7 +1,6 @@
 """Pallas fused conv+BN kernels vs composed-op oracles (interpret mode
 on CPU; the same kernels compile on TPU — see benchmarks/conv_kernel_ab.py
-for the on-chip A/B and MFU_BREAKDOWN.md for the round-3 verdict on
-where they do and do not pay off)."""
+for the on-chip A/B)."""
 import numpy as np
 import jax
 import jax.numpy as jnp

@@ -1,5 +1,4 @@
-"""Quantified memory_optimize benefit (round-3 VERDICT item 8;
-reference motivating case: memory_optimization_transpiler.py:332 +
+"""Quantified memory_optimize benefit (reference motivating case: memory_optimization_transpiler.py:332 +
 tests/book_memory_optimization/test_memopt_machine_translation.py — a
 long unrolled RNN must fit memory).
 
@@ -10,7 +9,7 @@ Two numbers on the same 160-step unrolled RNN:
      WITH or WITHOUT the pass, because XLA's buffer assignment already
      does liveness reuse inside the executable; the measured delta is
      recorded so the "subsumed by XLA" claim is evidence, not
-     assertion (MFU_BREAKDOWN.md §memory_optimize)."""
+     assertion."""
 import numpy as np
 
 import paddle_tpu as pt

@@ -111,7 +111,10 @@ def _smoke_embedding():
         table.lookup(ids)
 
 
-def test_registry_names_and_help_after_smoke_run(tmp_path):
+def test_registry_names_and_help_after_smoke_run(tmp_path, monkeypatch):
+    # the CPU backend is in no peak table: name the peak the
+    # paddle_tpu_mfu family divides by, or it is (rightly) absent
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "197e12")
     host_label = _smoke_train_and_serve(tmp_path)
     reg = default_registry()
     # families() runs the collectors, so pull-model producers (retry

@@ -445,7 +445,12 @@ def test_export_chrome_trace_under_concurrent_emission(tmp_path):
     stop = threading.Event()
 
     def emit():
-        while not stop.is_set():
+        # paced, not a bare spin: every export dumps ALL events so far,
+        # so four unthrottled emitters outran the exporter and the
+        # test's time, memory and /tmp use ran away with the machine
+        # (100 s alone, the suite's whole 1,470 s limit under -n 6,
+        # >10 GB of traces). Emission is still live during every export.
+        while not stop.wait(0.0002):
             with profiler.RecordEvent("spin", cat="test"):
                 pass
 

@@ -1,5 +1,4 @@
-"""Analytic scaling model (parallel/scaling_model.py; round-3 VERDICT
-items 3 & 10): compile-only bench-shape audits feed a stated ICI ring
+"""Analytic scaling model (parallel/scaling_model.py): compile-only bench-shape audits feed a stated ICI ring
 model. The full 8/16/64 x 4-config table lives in SCALING.json (built
 by scaling_model.main in a 64-device process); this test executes the
 machinery end-to-end at the 8-device size the conftest provides."""
@@ -90,7 +89,7 @@ def test_predict_multihost_decomposition():
 def test_sensitivity_band_orders_with_bandwidth():
     """+-2x ICI bandwidth must move efficiency monotonically: half the
     bandwidth can only hurt, double can only help — and the report
-    carries the band (round-5 VERDICT item 9)."""
+    carries the band."""
     from paddle_tpu.parallel.scaling_model import ICI_BW, predict
     inv = {("all-reduce", ("data",)): (4, 40_000_000)}
     sizes = {"data": 8}

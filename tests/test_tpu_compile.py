@@ -1,0 +1,143 @@
+"""The main path's Pallas kernels compile for a TPU v5e — without one.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``). These are
+the kernels chip_smoke.py's train and kernel phases reach, at its
+shapes: what Mosaic refuses (an unaligned slice, too much VMEM) fails
+here at no chip time. Nothing runs, so this says nothing about results
+— chip_smoke.py compares those on the chip. A compile that passes here
+is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file. Keep all such tests in THIS file — a
+second file can land on another worker, where the fixture would skip.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas.flash_attention import flash_attention
+from paddle_tpu.ops.pallas.fused_gru import fused_gru
+from paddle_tpu.ops.pallas.fused_lstm import fused_lstm
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means: no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernels_in(fn, *shapes):
+    """Compile for the described chip; count the Mosaic kernels."""
+    return jax.jit(fn).lower(*shapes).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def _sum_f32(outs):
+    return sum(o.astype(jnp.float32).sum()
+               for o in jax.tree_util.tree_leaves(outs))
+
+
+# [B, H, S, D] of chip_smoke.py's train step (b4 x s2048, 8 heads of
+# 64), and a length that is not a multiple of 128: _clamp_blocks pads
+# it per block, which interpret mode never checks against Mosaic's
+# (8, 128) tiling.
+@pytest.mark.parametrize("seq", [2048, 1100])
+@pytest.mark.parametrize("bias", ["causal", "pad_row", "dense"])
+def test_flash_attention_fwd_bwd_compiles(one_chip, seq, bias):
+    q = jax.ShapeDtypeStruct((4, 8, seq, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    # the masks models/transformer.py builds: a [B,1,1,S] pad-row mask
+    # (encoder and cross attention) and, for decoder self-attention,
+    # pad-row + dense causal broadcast to [B,1,S,S]
+    mshape = {"causal": None, "pad_row": (4, 1, 1, seq),
+              "dense": (4, 1, seq, seq)}[bias]
+
+    def loss(q, k, v, m=None):
+        return _sum_f32(flash_attention(q, k, v, m, causal=mshape is None,
+                                        interpret=False))
+
+    args = (q, q, q) if mshape is None else (
+        q, q, q, jax.ShapeDtypeStruct(mshape, jnp.float32,
+                                      sharding=one_chip))
+    # forward + dq + dkv
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), *args) == 3
+
+
+# bench.py's stacked-LSTM LM shapes (T=64, B=64, H=512): fused_lstm and
+# fused_gru are ON by default on a TPU (ops/sequence_ops.py), so every
+# LSTM/GRU user reaches them; bf16 is what AMP hands them.
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_fused_rnn_fwd_bwd_compiles(one_chip, cell, dtype):
+    t, b, h = 64, 64, 512
+    gates = 4 if cell == "lstm" else 3
+
+    def sds(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    if cell == "lstm":      # x, w, b, h0, c0
+        kernel, args = fused_lstm, (
+            sds(t, b, gates * h), sds(h, gates * h), sds(gates * h),
+            sds(b, h), sds(b, h))
+    else:                   # x, w, h0
+        kernel, args = fused_gru, (
+            sds(t, b, gates * h), sds(h, gates * h), sds(b, h))
+
+    def loss(*a):           # a = (*args, lengths); interpret=False
+        return _sum_f32(kernel(*a, False))
+
+    # forward + backward: one kernel each
+    assert _kernels_in(jax.grad(loss, argnums=tuple(range(len(args)))),
+                       *args, sds(b, dt=jnp.int32)) == 2
+
+
+def test_flash_attention_under_a_mesh_compiles_per_shard(topo):
+    """GSPMD cannot partition a Mosaic kernel: lowering the bare kernel
+    with mesh-sharded operands raises "Mosaic kernels cannot be
+    automatically partitioned" (what ParallelExecutor hit at S >= 512
+    before PR 22). ops/nn_ops.py runs it per shard instead."""
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.nn_ops import _per_shard_attention
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    qs = NamedSharding(mesh, P("data", "model", None, None))
+    q = jax.ShapeDtypeStruct((8, 8, 2048, 64), jnp.bfloat16, sharding=qs)
+    m = jax.ShapeDtypeStruct((8, 1, 1, 2048), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    attend = functools.partial(flash_attention, causal=True,
+                               interpret=False)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(attend).lower(q, q, q, m)
+    sharded = functools.partial(_per_shard_attention, attend, mesh,
+                                batch_axis="data", head_axis="model")
+    text = jax.jit(sharded).lower(q, q, q, m).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    # each device attends its own [4, 4, 2048, 64] shard: no collective
+    assert "all-gather" not in text and "all-reduce" not in text

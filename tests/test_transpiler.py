@@ -150,8 +150,8 @@ def test_memory_optimize_preserves_sub_block_vars():
 
 
 def test_transpiler_pairs_mlp_chains_megatron_style():
-    """VERDICT r3 weak-7: decisions must match the measured-best
-    layout, not just mechanics. The round-4 audit measured naive
+    """Decisions must match the measured-best layout, not just
+    mechanics. The round-4 audit measured naive
     all-column sharding at 7.3 GB/step vs 1.65 GB Megatron-paired
     (SCALING.json); consecutive fc weights must therefore alternate
     col/row so each pair costs one psum."""

@@ -197,7 +197,7 @@ def test_stateful_op_in_probe_prefix_raises():
 
 def test_nested_dynamic_while_gradient_matches_finite_differences():
     """A dynamic-trip-count While NESTED inside another dynamic While
-    trains (VERDICT r3 item 3): the outer loop max-accumulates the
+    trains: the outer loop max-accumulates the
     inner loop's per-iteration trip count into its NestedSteps output,
     the probe reads one bound per nesting level, and the program
     recompiles as nested masked scans (reference: while_op.cc:96-109
