@@ -26,6 +26,7 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
+from .. import profiler
 from ..core import ir
 from .diagnostics import Severity, VerificationError, VerifyReport
 from .passes import (PASS_REGISTRY, AnalysisPass, PassContext,
@@ -159,15 +160,17 @@ def executor_gate(program, block_idx: int,
         if _gate_cache.get(key):
             return
     from .passes import fast_passes
-    report = verify_program(
-        desc, feed_names=feed_key, fetch_names=list(fetch_names),
-        block_idx=block_idx, donate=donate, async_dispatch=not sync,
-        # the hot path runs the shared no-retrace pipeline (build-time
-        # markers only): pure Python, O(ops) — the full
-        # abstract-inference re-trace stays on the cold gates
-        # (serving load, save_inference_model, lint CLI)
-        passes=fast_passes(),
-        program_label=f"program uid={desc.uid} block={block_idx}")
+    with profiler.RecordEvent("compile::verify", cat=profiler.CAT_COMPILE,
+                              args={"uid": desc.uid, "block": block_idx}):
+        report = verify_program(
+            desc, feed_names=feed_key, fetch_names=list(fetch_names),
+            block_idx=block_idx, donate=donate, async_dispatch=not sync,
+            # the hot path runs the shared no-retrace pipeline
+            # (build-time markers only): pure Python, O(ops) — the full
+            # abstract-inference re-trace stays on the cold gates
+            # (serving load, save_inference_model, lint CLI)
+            passes=fast_passes(),
+            program_label=f"program uid={desc.uid} block={block_idx}")
     report.raise_if_errors(context="pre-compile gate")
     with _gate_cache_lock:
         while len(_gate_cache) >= _GATE_CACHE_MAX:

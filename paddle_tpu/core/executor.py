@@ -17,12 +17,15 @@ from __future__ import annotations
 import atexit
 import os
 import threading
+import time
 import warnings
 import weakref
+from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
+import jax.monitoring
 import jax.numpy as jnp
 
 from ..amp import amp_enabled
@@ -324,7 +327,13 @@ def trace_block(block: BlockDesc, env: Dict[str, Any],
     keep = extra.get("keep_vars") or ()
     stats = extra.get("trace_stats")  # optional {.. -> peak_env_bytes}
     for op in block.ops:
-        env.update(run_op(op, env, extra))
+        # the op type (for a grad op, its forward op's too) in the
+        # op_name of every HLO instruction the rule emits: the IR has
+        # no layer scope. Trace time only
+        fwd = op.attrs.get("fwd_op") if op.type == "__vjp__" else None
+        with jax.named_scope(f"__vjp__.{fwd['type']}" if fwd
+                             else op.type):
+            env.update(run_op(op, env, extra))
         dead = op.attrs.get("__dead_vars__")
         if dead:
             for name in dead:
@@ -500,6 +509,123 @@ def _stateful_ops_in(program: Program, ops) -> List[str]:
 # answers "how much compilation is this process paying", which is the
 # capacity question; per-executor splits stay on Executor.cache_stats.
 _obs_cache = None
+_compile_obs_cache = None
+
+
+def _compile_instruments():
+    """(registry, phase-seconds counter family, persistent-cache hits,
+    persistent-cache misses): what a recompile cost and whether JAX's
+    persistent cache answered it, on /metrics."""
+    global _compile_obs_cache
+    reg = default_registry()
+    if _compile_obs_cache is None or _compile_obs_cache[0] is not reg:
+        _compile_obs_cache = (
+            reg,
+            reg.counter(
+                "paddle_tpu_compile_phase_seconds_total",
+                "Host seconds spent compiling, by phase: verify, "
+                "rewrite, memory_plan, cost_model (this program's "
+                "analyses on an executor compile-cache miss) and "
+                "jax_trace, lower, backend, cache_retrieval (JAX's own "
+                "compile events; nested events of one phase are "
+                "counted once).", ("phase",)),
+            reg.counter(
+                "paddle_tpu_persistent_cache_hits_total",
+                "Compiles answered by JAX's persistent compilation "
+                "cache (its cache_hits event)."),
+            reg.counter(
+                "paddle_tpu_persistent_cache_misses_total",
+                "Compiles JAX's persistent compilation cache did not "
+                "hold and wrote (its cache_misses event)."),
+        )
+    return _compile_obs_cache
+
+
+# compile::<phase> of each JAX duration event that is a compile phase
+_JAX_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+    # StableHLO lowering, the Mosaic kernels' included
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # XLA, or the persistent cache's retrieval where it hits
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+# JAX event -> its counter's place in _compile_instruments()
+_JAX_CACHE_COUNTERS = {"/jax/compilation_cache/cache_hits": 2,
+                       "/jax/compilation_cache/cache_misses": 3}
+# {"uid", "block"} of the program whose first dispatch this thread is
+# in (Executor.run), else None
+_compiling = threading.local()
+# per thread, per phase: the (start, seconds) already counted, newest
+# last. Events of one thread arrive ordered by their END, so the ones a
+# new event encloses are at the tail.
+_counted = threading.local()
+_COUNTED_MAX = 4096
+
+
+def _count_compile_event(ev: Dict[str, Any]) -> None:
+    """profiler listener: add to paddle_tpu_compile_phase_seconds_total
+    what a closed compile::<phase> span adds to the union of its
+    thread's spans of that phase (an inner jit fires its own event
+    inside the outer one's, and closes first)."""
+    if ev.get("cat") != profiler.CAT_COMPILE:
+        return
+    phase = ev["name"].partition("::")[2]
+    start, dur = ev["ts"] * 1e-6, ev["dur"] * 1e-6
+    stacks = getattr(_counted, "stacks", None)
+    if stacks is None:
+        stacks = _counted.stacks = {}
+    stack = stacks.get(phase)
+    if stack is None:
+        stack = stacks[phase] = deque(maxlen=_COUNTED_MAX)
+    inside = 0.0
+    while stack and stack[-1][0] >= start:
+        inside += stack.pop()[1]
+    stack.append((start, dur))
+    _compile_instruments()[1].labels(phase=phase).inc(
+        max(0.0, dur - inside))
+
+
+def _compile_span(phase: str, args: Dict[str, Any]):
+    """compile::<phase> around one of this program's own analyses."""
+    return profiler.RecordEvent("compile::" + phase,
+                                cat=profiler.CAT_COMPILE, args=args)
+
+
+def _on_jax_duration(event: str, secs: float, **kw) -> None:
+    """The one jax.monitoring duration listener: each compile phase JAX
+    reports becomes a closed compile::<phase> span that ends now."""
+    phase = _JAX_COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    args = dict(getattr(_compiling, "args", None) or {})
+    if "fun_name" in kw:
+        args["fun_name"] = kw["fun_name"]
+    profiler.emit("compile::" + phase, time.perf_counter() - secs, secs,
+                  profiler.CAT_COMPILE, args)
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    which = _JAX_CACHE_COUNTERS.get(event)
+    if which is not None:
+        _compile_instruments()[which].inc()
+
+
+_listeners_lock = threading.Lock()
+_listening = False
+
+
+def _listen_to_compiles() -> None:
+    """Install the three listeners once a process (jax.monitoring keeps
+    its two for good); called by every Executor construction."""
+    global _listening
+    with _listeners_lock:
+        if not _listening:
+            profiler.add_event_listener(_count_compile_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _listening = True
 
 
 def _obs_instruments():
@@ -552,6 +678,7 @@ class Executor:
         if hasattr(place, "require"):
             place.require()
         place_compile_cache()
+        _listen_to_compiles()
         # donate_state=None reads PADDLE_TPU_DONATE_STATE (default on).
         self.donate_state = DONATE_STATE_DEFAULT if donate_state is None \
             else bool(donate_state)
@@ -808,7 +935,37 @@ class Executor:
         if hasattr(program, "desc"):  # accept the python builder wrapper
             program = program.desc
         scope = global_scope() if scope is None else scope
-        feed = feed or {}
+        with profiler.RecordEvent("pipeline::prepare",
+                                  cat=profiler.CAT_PIPELINE):
+            (compiled, missed, feed_vals, state_vals, step, fetch_names,
+             n_user_fetches) = self._prepare(
+                program, feed or {}, fetch_list, scope, block_idx,
+                iterations, stacked_feed, sync)
+        # a first dispatch traces, lowers and compiles inside the
+        # jitted call: JAX's compile-phase events fired there carry
+        # this program's uid (_on_jax_duration)
+        _compiling.args = {"uid": program.uid, "block": block_idx} \
+            if missed else None
+        try:
+            with profiler.RecordEvent("pipeline::dispatch",
+                                      cat=profiler.CAT_PIPELINE):
+                fetches, new_state = compiled.fn(feed_vals, state_vals,
+                                                 step)
+        finally:
+            _compiling.args = None
+        with profiler.RecordEvent("pipeline::commit",
+                                  cat=profiler.CAT_PIPELINE):
+            result = self._commit(program, scope, compiled, fetches,
+                                  new_state, step, iterations,
+                                  fetch_names, n_user_fetches,
+                                  return_numpy)
+        return result.fetches() if sync else result
+
+    def _prepare(self, program, feed, fetch_list, scope, block_idx,
+                 iterations, stacked_feed, sync):
+        """run()'s host work before the dispatch: the gate look-up, feed
+        conversion, the compile key, the executable (compiled on a
+        miss) and the state arrays it reads."""
         fetch_names = [f if isinstance(f, str) else f.name
                        for f in (fetch_list or [])]
         block = program.block(block_idx)
@@ -903,9 +1060,11 @@ class Executor:
         _, obs_hits, obs_misses, obs_donate = _obs_instruments()
         obs_donate.set(1.0 if self.donate_state else 0.0)
         compiled = self._cache.get(key)
-        if compiled is None:
+        missed = compiled is None
+        if missed:
             self.cache_stats["misses"] += 1
             obs_misses.inc()
+            span_args = {"uid": program.uid, "block": block_idx}
             kw = {} if iterations == 1 else {
                 "iterations": iterations,
                 "or_reduce_tail": len(exhausted),
@@ -922,13 +1081,14 @@ class Executor:
             from ..analysis import rewrite as _rewrite
             if _rewrite.optimize_enabled():
                 try:
-                    rewrite_result = _rewrite.rewrite_program(
-                        program, block_idx, feed_names=feed.keys(),
-                        fetch_names=fetch_names,
-                        donate=self.donate_state,
-                        async_dispatch=not sync,
-                        label=f"program uid={program.uid} "
-                              f"block={block_idx}")
+                    with _compile_span("rewrite", span_args):
+                        rewrite_result = _rewrite.rewrite_program(
+                            program, block_idx, feed_names=feed.keys(),
+                            fetch_names=fetch_names,
+                            donate=self.donate_state,
+                            async_dispatch=not sync,
+                            label=f"program uid={program.uid} "
+                                  f"block={block_idx}")
                 except Exception:
                     rewrite_result = None
                 if rewrite_result is not None and rewrite_result.changed:
@@ -955,11 +1115,12 @@ class Executor:
             mem_report = None
             try:
                 from ..analysis import memory as _memory
-                mem_report = _memory.program_memory(
-                    exec_program, block_idx, feed_shapes=fs,
-                    feed_names=feed.keys(),
-                    label=f"program uid={program.uid} "
-                          f"block={block_idx}")
+                with _compile_span("memory_plan", span_args):
+                    mem_report = _memory.program_memory(
+                        exec_program, block_idx, feed_shapes=fs,
+                        feed_names=feed.keys(),
+                        label=f"program uid={program.uid} "
+                              f"block={block_idx}")
             except Exception:
                 mem_report = None
             if mem_report is not None and _verifier.verify_enabled():
@@ -983,8 +1144,9 @@ class Executor:
             # the cost model must never fail a compile.
             try:
                 from ..analysis import cost_model as _cost_model
-                compiled.cost = _cost_model.program_cost(
-                    exec_program, block_idx, feed_shapes=fs)
+                with _compile_span("cost_model", span_args):
+                    compiled.cost = _cost_model.program_cost(
+                        exec_program, block_idx, feed_shapes=fs)
             except Exception:
                 compiled.cost = None
             self._cache[key] = compiled
@@ -1009,9 +1171,13 @@ class Executor:
         # kept for AOT introspection (profiler cost analysis, the
         # collective audit's HLO re-lowering)
         self._last_feed_vals = feed_vals
-        with profiler.RecordEvent("pipeline::dispatch",
-                                  cat=profiler.CAT_PIPELINE):
-            fetches, new_state = compiled.fn(feed_vals, state_vals, step)
+        return (compiled, missed, feed_vals, state_vals, step,
+                fetch_names, n_user_fetches)
+
+    def _commit(self, program, scope, compiled, fetches, new_state, step,
+                iterations, fetch_names, n_user_fetches, return_numpy):
+        """run()'s host work after the dispatch: the scope repointed at
+        the new state, the While flags, the StepResult."""
         scope.set(STEP_VAR, step + iterations)
         for n, v in new_state.items():
             scope.set(n, v)
@@ -1058,7 +1224,7 @@ class Executor:
         # overwrites
         result.cost = compiled.cost
         result.memory = compiled.memory
-        return result.fetches() if sync else result
+        return result
 
     def cost_for(self, program):
         """The static ProgramCost attached to a compiled executable of

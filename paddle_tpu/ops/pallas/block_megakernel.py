@@ -133,6 +133,7 @@ def bottleneck_block(x, w1, w3, w2, bn1, bn2, bn3, h_img, w_img,
     flops = 2 * n * hw * cm * (cin + 9 * cm + cin)
     out = pl.pallas_call(
         kern,
+        name="block_megakernel",
         grid=(n // tile,),
         in_specs=[
             pl.BlockSpec((m, cin), lambda i: (i, 0),

@@ -183,6 +183,7 @@ def _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret):
     ]
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -371,6 +372,7 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
 
     res_dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(b, h, sq_p // block_q, sk_p // block_k),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -416,6 +418,7 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
 
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(b, h, sk_p // block_k, sq_p // block_q),
         in_specs=in_specs,
         out_specs=[kspec(kq_k), kspec(kq_k)],

@@ -103,6 +103,7 @@ def conv1x1_bn_act(x, w, a=None, b=None, relu=False, stats=True,
     ]
     out, stats_out = pl.pallas_call(
         kernel,
+        name="fused_conv1x1",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, k), lambda i: (i, 0),
@@ -291,6 +292,7 @@ def conv3x3_bn_act(x, w, img_h, img_w, a=None, b=None, relu=False,
         m_total=m)
     out, stats_out = pl.pallas_call(
         kernel,
+        name="fused_conv3x3",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.ANY),       # x stays in HBM

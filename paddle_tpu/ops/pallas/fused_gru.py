@@ -126,6 +126,7 @@ def _run_fwd(x, w, h0, lengths, interpret):
     kernel = functools.partial(_fwd_kernel, hidden=hidden)
     h_all = pl.pallas_call(
         kernel,
+        name="fused_gru_fwd",
         grid=(t_max,),
         in_specs=[
             pl.BlockSpec((bsz, 1), lambda t: (0, 0)),
@@ -170,6 +171,7 @@ def _fused_gru_bwd(interpret, res, grads):
     kernel = functools.partial(_bwd_kernel, hidden=hidden, t_max=t_max)
     dx, dw, dh0 = pl.pallas_call(
         kernel,
+        name="fused_gru_bwd",
         grid=(t_max,),
         in_specs=[
             pl.BlockSpec((bsz, 1), lambda k: (0, 0)),

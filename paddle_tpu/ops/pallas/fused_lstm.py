@@ -149,6 +149,7 @@ def _run_fwd(x, w, b, h0, c0, lengths, interpret):
     kernel = functools.partial(_fwd_kernel, hidden=hidden)
     h_all, c_all = pl.pallas_call(
         kernel,
+        name="fused_lstm_fwd",
         grid=(t_max,),
         in_specs=[
             pl.BlockSpec((bsz, 1), lambda t: (0, 0)),          # lengths
@@ -210,6 +211,7 @@ def _fused_lstm_bwd(interpret, res, grads):
     kernel = functools.partial(_bwd_kernel, hidden=hidden, t_max=t_max)
     dx, dw, db, dh0, dc0 = pl.pallas_call(
         kernel,
+        name="fused_lstm_bwd",
         grid=(t_max,),
         in_specs=[
             pl.BlockSpec((bsz, 1), lambda k: (0, 0)),

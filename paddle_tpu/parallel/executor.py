@@ -17,6 +17,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import profiler
 from ..core.executor import Executor, CompiledProgram, trace_block
 from ..core.lod import RaggedNested, RaggedPair, RaggedTree
 from ..core.scope import Scope, global_scope
@@ -88,9 +89,11 @@ class ParallelExecutor(Executor):
 
     def run(self, program, feed=None, **kw):
         if self._multiprocess and feed:
-            feed = {
-                name: self._globalize_feed(name, v)
-                for name, v in feed.items()}
+            with profiler.RecordEvent("pipeline::globalize_feed",
+                                      cat=profiler.CAT_PIPELINE):
+                feed = {
+                    name: self._globalize_feed(name, v)
+                    for name, v in feed.items()}
         self._pending_ro_globals.clear()
         out = super().run(program, feed=feed, **kw)
         if self._pending_ro_globals:

@@ -31,7 +31,7 @@ def make_mesh(shape: Optional[Sequence[int]] = None,
     return mesh
 
 
-def set_mesh(mesh: Mesh):
+def set_mesh(mesh: Optional[Mesh]):
     global _current_mesh
     _current_mesh = mesh
 

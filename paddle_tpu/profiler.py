@@ -4,7 +4,9 @@ device tracer + tools/timeline.py chrome-trace export).
 TPU-native design: host-side events wrap executor runs; device activity
 comes from jax.profiler (XLA/TPU trace), which natively emits
 chrome://tracing-compatible output — the xprof analog of the reference's
-CUPTI + timeline.py pipeline.
+CUPTI + timeline.py pipeline. While a trace runs every RecordEvent is
+also a jax.profiler.TraceAnnotation, so a device trace holds the host
+spans on its own clock: ``device_profiler`` is the merged timeline.
 """
 from __future__ import annotations
 
@@ -13,6 +15,9 @@ import json
 import threading
 import time
 from typing import Callable, Dict, List, Optional
+
+import jax
+import jax._src.profiler
 
 _events: List[Dict] = []
 _enabled = False
@@ -90,11 +95,32 @@ CAT_RESILIENCE = "resilience"
 #   pipeline::prefetch_fill - producer-thread convert+upload; overlaps
 #                             device compute, so never part of the
 #                             serial step breakdown
+# Around pipeline::dispatch, Executor.run's own host work (not mapped to
+# a phase: it stays in the breakdown's device residual):
+#   pipeline::prepare        - run()'s entry to the dispatch: gate and
+#                              cache look-ups, feed conversion, the
+#                              state arrays read from the scope (and,
+#                              on a miss, the compile::* analyses)
+#   pipeline::commit         - the dispatch's return to run()'s: scope
+#                              repointed at the new state, StepResult
+#   pipeline::globalize_feed - ParallelExecutor lifting a process-local
+#                              feed onto a mesh that spans processes
 CAT_PIPELINE = "pipeline"
 # Per-attempt RPC spans from distributed/jsonrpc.py (rpc::<op>): one
 # event per wire attempt, so retried calls show as distinct spans that
 # share the originating step's trace id.
 CAT_RPC = "rpc"
+# The Trainer loop's own host work around each dispatch (trainer.py):
+# trainer::handler around every call of the caller's event handler,
+# trainer::telemetry around the per-step metrics block.
+CAT_TRAINER = "trainer"
+# What a first dispatch pays before it can run (core/executor.py):
+# compile::verify|rewrite|memory_plan|cost_model around the program's
+# own analyses, and compile::jax_trace|lower|backend|cache_retrieval
+# emitted closed from JAX's compile-phase events. An inner jit fires its
+# own events inside an outer one's: take the UNION of a name's
+# intervals, not their sum.
+CAT_COMPILE = "compile"
 # StepTrace root/child spans (observability/trace.py): trace::step/N
 # covers one dispatched training step; every event closed inside it
 # carries the step's trace_id/span_id in its args.
@@ -113,38 +139,78 @@ class RecordEvent:
         self.cat = cat
         self.args = args
         self.t0 = None
+        self._annotation = None
 
     def __enter__(self):
+        # while a device trace runs (device_profiler, jax.profiler.
+        # start_trace) the span is on the trace's host plane, on this
+        # thread's line and the trace's clock. With no trace running no
+        # annotation is made at all (_tracing)
+        if _tracing():
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        listeners = _event_listeners
-        if not _enabled and not listeners:
-            return False
-        ev = {"name": self.name, "ts": self.t0 * 1e6,
-              "dur": (time.perf_counter() - self.t0) * 1e6,
-              "ph": "X", "pid": 0, "tid": 0}
-        if self.cat:
-            ev["cat"] = self.cat
-        args = dict(self.args) if self.args else {}
-        if _trace_args_provider is not None:
-            targs = _trace_args_provider()
-            if targs:
-                args.update(targs)
-        if args:
-            ev["args"] = args
-        if _enabled:
-            with _events_lock:
-                _events.append(ev)
-        # snapshot: a concurrent remove_event_listener must not skip
-        # another listener mid-iteration
-        for fn in list(listeners):
-            try:
-                fn(ev)
-            except Exception:
-                pass  # a broken listener must never break the hot path
+        dur = time.perf_counter() - self.t0
+        if self._annotation is not None:
+            # callers close a span by hand with no arguments too
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        emit(self.name, self.t0, dur, self.cat, self.args)
         return False
+
+
+# jax.profiler has no public "is a trace running"; its own session
+# state is the one place that knows, however the trace was started
+# (jax 0.9.0, the installation this tree is written for). Without it
+# every span is annotated, which a TraceMe outside a session ignores.
+_jax_profile_state = getattr(jax._src.profiler, "_profile_state", None)
+
+
+def _tracing() -> bool:
+    """Is a jax.profiler trace running in this process? On the chip,
+    annotating every span of an UNtraced run went with rare pauses of
+    1-3 s of the whole process (PERF.md, PR 25: 4 of 19 train-s256
+    runs; 0 of 13 without the annotations), so spans are annotated
+    only while a trace can hold them."""
+    state = _jax_profile_state
+    return state is None or state.profile_session is not None
+
+
+def emit(name: str, start: float, dur: float, cat: Optional[str] = None,
+         args: Optional[Dict] = None) -> None:
+    """Record one CLOSED span (``start``/``dur`` in ``perf_counter``
+    seconds) on the calling thread: what ``RecordEvent.__exit__`` does,
+    and the way in for a duration that is known only once it is over
+    (JAX's compile-phase events, core/executor.py). Such a span is not
+    on a device trace: an annotation cannot be opened in the past."""
+    listeners = _event_listeners
+    if not _enabled and not listeners:
+        return
+    thread = threading.current_thread()
+    ev = {"name": name, "ts": start * 1e6, "dur": dur * 1e6,
+          "ph": "X", "pid": 0, "tid": thread.ident}
+    if cat:
+        ev["cat"] = cat
+    args = dict(args) if args else {}
+    args["thread"] = thread.name
+    if _trace_args_provider is not None:
+        targs = _trace_args_provider()
+        if targs:
+            args.update(targs)
+    ev["args"] = args
+    if _enabled:
+        with _events_lock:
+            _events.append(ev)
+    # snapshot: a concurrent remove_event_listener must not skip
+    # another listener mid-iteration
+    for fn in list(listeners):
+        try:
+            fn(ev)
+        except Exception:
+            pass  # a broken listener must never break the hot path
 
 
 def events(cat: Optional[str] = None) -> List[Dict]:
@@ -203,124 +269,12 @@ def profiler(state: str = "All", sorted_key: Optional[str] = None,
 @contextlib.contextmanager
 def device_profiler(logdir: str):
     """TPU device trace via jax.profiler (xprof); view with tensorboard or
-    Perfetto. Replaces the reference's CUPTI DeviceTracer."""
-    import jax
+    Perfetto. Replaces the reference's CUPTI DeviceTracer. Every
+    RecordEvent open while the trace runs is also a TraceAnnotation, so
+    the trace IS the merged timeline: the program's spans sit on the
+    host plane, each on its own thread's line, on the device's clock."""
     jax.profiler.start_trace(logdir)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def _parse_device_trace(logdir: str) -> List[Dict]:
-    """Newest chrome trace under an xprof logdir -> flat event list
-    (only complete 'X' events, annotated with their process name)."""
-    import glob
-    import gzip
-    import os
-
-    candidates = sorted(
-        glob.glob(os.path.join(logdir, "plugins", "profile", "*",
-                               "*.trace.json.gz")),
-        key=os.path.getmtime)
-    if not candidates:
-        return []
-    with gzip.open(candidates[-1], "rt") as f:
-        tr = json.load(f)
-    raw = tr.get("traceEvents", [])
-    pid_names = {e["pid"]: e["args"].get("name", "")
-                 for e in raw
-                 if e.get("ph") == "M" and e.get("name") == "process_name"}
-    out = []
-    for e in raw:
-        if e.get("ph") != "X":
-            continue
-        out.append({"name": e.get("name", ""), "ts": e.get("ts", 0),
-                    "dur": e.get("dur", 0), "ph": "X",
-                    "pid": e.get("pid", 0), "tid": e.get("tid", 0),
-                    "proc": pid_names.get(e.get("pid"), "")})
-    return out
-
-
-class MergedProfile:
-    """One sorted per-op table + one timeline combining host
-    RecordEvents with device (xprof) activity — the TPU-native analog
-    of the reference's merged profiler output
-    (platform/device_tracer.cc:40-74 + profiler.h:153-158, which fold
-    CUPTI device records into the CPU event table)."""
-
-    def __init__(self):
-        self.host_events: List[Dict] = []
-        self.device_events: List[Dict] = []
-
-    def table(self, limit: Optional[int] = None) -> List[Dict]:
-        agg: Dict = {}
-        for e in self.host_events:
-            a = agg.setdefault(("host", e["name"]),
-                               {"calls": 0, "total_us": 0.0})
-            a["calls"] += 1
-            a["total_us"] += e["dur"]
-        for e in self.device_events:
-            if "device" not in e.get("proc", "").lower() \
-                    and "tpu" not in e.get("proc", "").lower():
-                continue
-            a = agg.setdefault(("device", e["name"]),
-                               {"calls": 0, "total_us": 0.0})
-            a["calls"] += 1
-            a["total_us"] += e["dur"]
-        rows = [{"place": k[0], "name": k[1], **v} for k, v in agg.items()]
-        rows.sort(key=lambda r: -r["total_us"])
-        return rows[:limit] if limit else rows
-
-    def export_chrome_trace(self, path: str):
-        """Host and device events in ONE timeline (host pid 0; device
-        events keep their trace pids, offset to avoid collision)."""
-        events = list(self.host_events)
-        for e in self.device_events:
-            d = dict(e)
-            d.pop("proc", None)
-            d["pid"] = 1000 + int(d.get("pid", 0))
-            events.append(d)
-        with open(path, "w") as f:
-            json.dump({"traceEvents": events}, f)
-
-    def __str__(self):
-        lines = [f"{'place':8s} {'total ms':>10s} {'calls':>7s}  name"]
-        for r in self.table(limit=40):
-            lines.append(f"{r['place']:8s} {r['total_us'] / 1e3:10.3f} "
-                         f"{r['calls']:7d}  {r['name'][:70]}")
-        return "\n".join(lines)
-
-
-@contextlib.contextmanager
-def merged_profile(logdir: str = "/tmp/paddle_tpu_xprof"):
-    """Capture host RecordEvents AND a device trace in one scope; yields
-    a MergedProfile filled on exit.
-
-        with profiler.merged_profile() as prof:
-            train_steps()
-        print(prof)                      # one sorted host+device table
-        prof.export_chrome_trace("t.json")   # one merged timeline
-    """
-    import jax
-
-    global _enabled
-    prof = MergedProfile()
-    with _events_lock:
-        prev_events = list(_events)
-        _events.clear()
-    _enabled = True
-    jax.profiler.start_trace(logdir)
-    try:
-        yield prof
-    finally:
-        jax.profiler.stop_trace()
-        _enabled = False
-        with _events_lock:
-            prof.host_events = list(_events)
-            _events.clear()
-            _events.extend(prev_events)
-        try:
-            prof.device_events = _parse_device_trace(logdir)
-        except Exception:
-            prof.device_events = []

@@ -38,6 +38,7 @@ from typing import List, Optional
 import numpy as np
 
 from ... import profiler
+from ...analysis.memory import publish_peak
 from ...observability import attribution as obs_attr
 from ...resilience import faults
 from ...resilience import health as health_mod
@@ -91,22 +92,36 @@ class GenerationResult:
 class GenerationFuture:
     """Single-resolve handle for one generation request (same contract
     as batcher.ServingFuture: builtins TimeoutError, no cancel state
-    machine)."""
+    machine).
+
+    It also carries the request's life as the engine saw it, four
+    ``time.perf_counter()`` readings (the clock of profiler spans),
+    each None until reached: ``enqueued_at`` (accepted by submit),
+    ``admitted_at`` (a slot taken, before its prefill),
+    ``first_token_at`` (the first generated token delivered) and
+    ``completed_at`` (the future resolved). A request that fails in the
+    queue has only the first and the last."""
 
     def __init__(self):
         self._event = threading.Event()
         self._result: Optional[GenerationResult] = None
         self._exc: Optional[BaseException] = None
+        self.enqueued_at: Optional[float] = None
+        self.admitted_at: Optional[float] = None
+        self.first_token_at: Optional[float] = None
+        self.completed_at: Optional[float] = None
 
     def done(self) -> bool:
         return self._event.is_set()
 
     def set_result(self, result: GenerationResult):
         self._result = result
+        self.completed_at = time.perf_counter()
         self._event.set()
 
     def set_exception(self, exc: BaseException):
         self._exc = exc
+        self.completed_at = time.perf_counter()
         self._event.set()
 
     def result(self, timeout: Optional[float] = None) -> GenerationResult:
@@ -118,15 +133,13 @@ class GenerationFuture:
 
 
 class _Request:
-    __slots__ = ("prompt", "max_new_tokens", "future", "tokens",
-                 "submitted_at")
+    __slots__ = ("prompt", "max_new_tokens", "future", "tokens")
 
     def __init__(self, prompt, max_new_tokens, future):
         self.prompt = list(int(t) for t in prompt)
         self.max_new_tokens = max_new_tokens
         self.future = future
         self.tokens: List[int] = []
-        self.submitted_at = time.monotonic()
 
 
 class GenerationEngine:
@@ -225,6 +238,7 @@ class GenerationEngine:
                         f"generation queue at capacity "
                         f"({self.config.queue_capacity})")
                 self._queue.append(_Request(prompt, budget, fut))
+                fut.enqueued_at = time.perf_counter()
                 self.metrics.requests.inc()
                 self._wake.notify_all()
             return fut
@@ -281,20 +295,26 @@ class GenerationEngine:
                 self._abort_all(ServingStopped(
                     "generation engine stopped without drain"))
                 return
-            try:
-                self._admit(pending)
-                if any(s is not None for s in self._slots):
-                    self._step()
-            except BaseException as e:
-                # device/step failure: the cache state of every active
-                # slot is now suspect — retire them all with the tokens
-                # they already completed, count the failure toward the
-                # breaker, and keep the driver alive (the breaker, not
-                # a dead thread, decides whether to shed)
-                self.health.record_failure(e)
-                self._abort_all(e, reason="error", keep_tokens=True)
-            self.metrics.slots_active.set(
-                sum(1 for s in self._slots if s is not None))
+            # every pass that gets here admits or steps
+            with profiler.RecordEvent("generation::iteration",
+                                      cat=profiler.CAT_SERVING):
+                try:
+                    self._admit(pending)
+                    if any(s is not None for s in self._slots):
+                        self._step()
+                except BaseException as e:
+                    # device/step failure: the cache state of every
+                    # active slot is now suspect — retire them all with
+                    # the tokens they already completed, count the
+                    # failure toward the breaker, and keep the driver
+                    # alive (the breaker, not a dead thread, decides
+                    # whether to shed)
+                    self.health.record_failure(e)
+                    self._abort_all(e, reason="error", keep_tokens=True)
+                with profiler.RecordEvent("generation::telemetry",
+                                          cat=profiler.CAT_SERVING):
+                    self.metrics.slots_active.set(
+                        sum(1 for s in self._slots if s is not None))
 
     def _abort_all(self, exc: BaseException, reason: str = "aborted",
                    keep_tokens: bool = True):
@@ -305,7 +325,7 @@ class GenerationEngine:
                 continue
             self._slots[i] = None
             self._lengths[i] = 0
-            self.metrics.retired(reason)
+            self.metrics.retired(reason, req.future)
             if keep_tokens:
                 req.future.set_result(GenerationResult(
                     req.tokens, "aborted", len(req.prompt)))
@@ -331,18 +351,23 @@ class GenerationEngine:
                 pending.clear()
                 break
             req = pending.popleft()
+            req.future.admitted_at = time.perf_counter()
             if self.mode == "cached":
                 t0 = time.monotonic()
                 with profiler.RecordEvent(
                         f"generation::prefill[{len(req.prompt)}]",
                         cat=profiler.CAT_SERVING):
                     tok = self.model.run_prefill(req.prompt, slot)
-                self.metrics.prefills.inc()
-                self.metrics.prefill_seconds.record(
-                    time.monotonic() - t0)
-                self.health.record_success()
-                self._install(slot, req)
-                self._deliver_token(slot, req, tok)
+                with profiler.RecordEvent("generation::telemetry",
+                                          cat=profiler.CAT_SERVING):
+                    self.metrics.prefills.inc()
+                    self.metrics.prefill_seconds.record(
+                        time.monotonic() - t0)
+                    self.health.record_success()
+                with profiler.RecordEvent("generation::deliver",
+                                          cat=profiler.CAT_SERVING):
+                    self._install(slot, req)
+                    self._deliver_token(slot, req, tok)
             else:
                 self._install(slot, req)
         if requeue:
@@ -373,65 +398,77 @@ class GenerationEngine:
         token at its own cache position; inactive slots ride as padding
         (they write garbage at position 0 of their row, which the next
         prefill into that row overwrites)."""
-        active = self._active()
-        # feed position per slot = index the new token occupies
-        positions = np.zeros(self.spec.slots, np.int64)
-        tokens = np.zeros(self.spec.slots, np.int64)
-        for i in active:
-            positions[i] = self._lengths[i] - 1  # last token's position
-            tokens[i] = self._history[i, self._lengths[i] - 1]
-        depth = int(max(positions[i] for i in active)) + 1
-        bucket = bucket_for(depth, self.spec.cache_buckets)
-        if bucket is None:  # deepest slot exceeded every bucket
-            bucket = self.spec.cache_buckets[-1]
+        with profiler.RecordEvent("generation::build_step",
+                                  cat=profiler.CAT_SERVING):
+            active = self._active()
+            # feed position per slot = index the new token occupies
+            positions = np.zeros(self.spec.slots, np.int64)
+            tokens = np.zeros(self.spec.slots, np.int64)
+            for i in active:
+                positions[i] = self._lengths[i] - 1  # last token's
+                tokens[i] = self._history[i, self._lengths[i] - 1]
+            depth = int(max(positions[i] for i in active)) + 1
+            bucket = bucket_for(depth, self.spec.cache_buckets)
+            if bucket is None:  # deepest slot exceeded every bucket
+                bucket = self.spec.cache_buckets[-1]
         t0 = time.monotonic()
         with profiler.RecordEvent(
                 f"generation::decode_step[{bucket}]",
                 cat=profiler.CAT_SERVING):
             next_tokens = self.model.run_decode(tokens, positions, bucket)
         self._observe_step(t0)
-        for i in active:
-            self._deliver_token(i, self._slots[i], int(next_tokens[i]))
+        self._deliver(active, next_tokens)
 
     def _step_reforward(self):
         """Ablation baseline: full causal forward over every active
         row's whole history — what serving costs without the KV cache."""
-        active = self._active()
-        depth = int(max(self._lengths[i] for i in active))
-        bucket = bucket_for(depth, self.spec.prompt_buckets)
-        if bucket is None:
-            bucket = self.spec.prompt_buckets[-1]
-        matrix = self._history[:, :bucket]
-        lengths = np.maximum(self._lengths, 1)  # inactive rows: dummy 1
+        with profiler.RecordEvent("generation::build_step",
+                                  cat=profiler.CAT_SERVING):
+            active = self._active()
+            depth = int(max(self._lengths[i] for i in active))
+            bucket = bucket_for(depth, self.spec.prompt_buckets)
+            if bucket is None:
+                bucket = self.spec.prompt_buckets[-1]
+            matrix = self._history[:, :bucket]
+            lengths = np.maximum(self._lengths, 1)  # inactive rows: 1
         t0 = time.monotonic()
         with profiler.RecordEvent(
                 f"generation::reforward_step[{bucket}]",
                 cat=profiler.CAT_SERVING):
             next_tokens = self.model.run_full(matrix, lengths, bucket)
         self._observe_step(t0)
-        for i in active:
-            self._deliver_token(i, self._slots[i], int(next_tokens[i]))
+        self._deliver(active, next_tokens)
+
+    def _deliver(self, active, next_tokens):
+        with profiler.RecordEvent("generation::deliver",
+                                  cat=profiler.CAT_SERVING):
+            for i in active:
+                self._deliver_token(i, self._slots[i],
+                                    int(next_tokens[i]))
 
     def _observe_step(self, t0: float):
         t1 = time.monotonic()
-        self.health.record_success()
-        self.metrics.step_seconds.record(t1 - t0)
-        if obs_attr.attribution_enabled():
-            cost = self.model.last_cost()
-            peak = obs_attr.peak_flops()
-            if cost is not None and cost.flops and t1 > t0 and peak:
-                self.metrics.set_mfu(cost.flops / peak / (t1 - t0),
-                                     cost.flops)
-        mem = self.model.last_memory()
-        if mem is not None:
-            from ...analysis.memory import publish_peak
-            publish_peak(self.metrics._attr_job, mem.peak_bytes)
+        with profiler.RecordEvent("generation::telemetry",
+                                  cat=profiler.CAT_SERVING):
+            self.health.record_success()
+            self.metrics.step_seconds.record(t1 - t0)
+            if obs_attr.attribution_enabled():
+                cost = self.model.last_cost()
+                peak = obs_attr.peak_flops()
+                if cost is not None and cost.flops and t1 > t0 and peak:
+                    self.metrics.set_mfu(cost.flops / peak / (t1 - t0),
+                                         cost.flops)
+            mem = self.model.last_memory()
+            if mem is not None:
+                publish_peak(self.metrics._attr_job, mem.peak_bytes)
 
     # -- retire ------------------------------------------------------------
     def _deliver_token(self, slot: int, req: _Request, tok: int):
         """Append one generated token to a slot's stream and retire the
         slot if the request is finished."""
         req.tokens.append(tok)
+        if req.future.first_token_at is None:
+            req.future.first_token_at = time.perf_counter()
         length = int(self._lengths[slot])
         if length < self.spec.max_seq_len:
             self._history[slot, length] = tok
@@ -447,6 +484,6 @@ class GenerationEngine:
         if reason is not None:
             self._slots[slot] = None
             self._lengths[slot] = 0
-            self.metrics.retired(reason)
+            self.metrics.retired(reason, req.future)
             req.future.set_result(GenerationResult(
                 req.tokens, reason, len(req.prompt)))

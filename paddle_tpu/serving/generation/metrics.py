@@ -36,6 +36,9 @@ class GenerationMetrics:
     - shed_total{reason}: every request turned away BEFORE taking a
       slot — circuit_open, queue_full, model_budget (host routing)
     - step_seconds / prefill_seconds: device step wall time
+    - queue_wait_seconds / ttft_seconds: each retired request's wait
+      for a slot and time to first token, from the timestamps on its
+      GenerationFuture
     - slots_active / slots_total: continuous-batching occupancy
     """
 
@@ -98,6 +101,16 @@ class GenerationMetrics:
             "paddle_tpu_decode_prefill_seconds",
             "Wall time of one prefill (full-prompt forward + KV-cache "
             "slot write).")
+        self.queue_wait_seconds = histogram(
+            "paddle_tpu_decode_queue_wait_seconds",
+            "Time a retired request waited for a slot: submit accepted "
+            "it (enqueued_at) until a slot was taken for its prefill "
+            "(admitted_at).")
+        self.ttft_seconds = histogram(
+            "paddle_tpu_decode_ttft_seconds",
+            "Time to first token of a retired request, as the engine "
+            "saw it: submit accepted it (enqueued_at) until its first "
+            "generated token was delivered (first_token_at).")
         self.slots_active = gauge(
             "paddle_tpu_decode_slots_active",
             "In-flight batch slots occupied at the last decode-step "
@@ -110,9 +123,18 @@ class GenerationMetrics:
         self.mfu = None
         self.model_flops = None
 
-    def retired(self, reason: str) -> None:
+    def retired(self, reason: str, future=None) -> None:
+        """One request left the engine. ``future`` (its
+        GenerationFuture, for a request that held a slot) feeds the two
+        latency histograms from the timestamps it carries."""
         self._retired_family.labels(engine=self.engine_label,
                                     reason=reason).inc()
+        if future is not None and future.admitted_at is not None:
+            self.queue_wait_seconds.record(
+                future.admitted_at - future.enqueued_at)
+            if future.first_token_at is not None:
+                self.ttft_seconds.record(
+                    future.first_token_at - future.enqueued_at)
 
     def shed(self, reason: str) -> None:
         self._shed_family.labels(engine=self.engine_label,
@@ -164,6 +186,8 @@ class GenerationMetrics:
             "slots_total": self.slots_total.value,
             "step_seconds": self.step_seconds.snapshot(),
             "prefill_seconds": self.prefill_seconds.snapshot(),
+            "queue_wait_seconds": self.queue_wait_seconds.snapshot(),
+            "ttft_seconds": self.ttft_seconds.snapshot(),
             "retired_by_reason": self._by_reason(self._retired_family),
             "shed_by_reason": self._by_reason(self._shed_family),
             "mfu": self.mfu.value if self.mfu is not None else 0.0,

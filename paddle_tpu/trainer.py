@@ -16,6 +16,9 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
+from . import profiler
+from .analysis.memory import publish_peak
+
 
 class BeginPass:
     def __init__(self, pass_id):
@@ -271,7 +274,14 @@ class Trainer:
             self._service_consumed = 0
         else:
             self._input_service = None
-        handler = event_handler or (lambda e: None)
+        user_handler = event_handler or (lambda e: None)
+
+        def handler(event):
+            # what the caller's own code costs the loop, step by step
+            with profiler.RecordEvent("trainer::handler",
+                                      cat=profiler.CAT_TRAINER):
+                user_handler(event)
+
         fetch_names = list(self.fetch_metrics)
         fetch_list = [self.loss] + [self.fetch_metrics[k]
                                     for k in fetch_names]
@@ -362,8 +372,6 @@ class Trainer:
                                             depth=prefetch)
                 feed_iter = iter(prefetcher)
             else:
-                from . import profiler
-
                 def _inline_feeds():
                     # un-prefetched path: reader + conversion run inline
                     # on the loop thread, so the wait is HOST-BLOCKED
@@ -473,50 +481,53 @@ class Trainer:
                             _drain(log_every)
                         self._maybe_checkpoint(advanced=len(group))
                     if obs_on:
-                        now = time.monotonic()
-                        wall = now - t_prev
-                        m_steps.inc(len(group))
-                        m_step_s.record(wall / len(group))
-                        m_pref.set(prefetcher.occupancy()
-                                   if prefetcher is not None else 0)
-                        t_prev = now
-                        # static peak-HBM plan of THIS dispatch's
-                        # executable (same result-not-executor rule as
-                        # the cost read below)
-                        mem = getattr(res, "memory", None)
-                        if mem is not None:
-                            from .analysis.memory import publish_peak
-                            publish_peak("train", mem.peak_bytes)
-                        if attr_on:
-                            # phase breakdown: measured host phases
-                            # since the last dispatch + the device
-                            # residual — the five phases of one step
-                            # sum to its wall time (device clamps at 0
-                            # when overlapped host work exceeds it)
-                            phases = obs_attr.drain_phases()
-                            host = sum(phases.values())
-                            phases["device"] = max(0.0, wall - host)
-                            for ph in obs_attr.PHASES:
-                                m_phase.labels(phase=ph).record(
-                                    phases.get(ph, 0.0) / len(group))
-                            # the dispatch's OWN cost off the result:
-                            # exe.last_cost may already belong to a
-                            # different program (an event handler
-                            # calling trainer.test() runs the pruned
-                            # eval clone on this same executor)
-                            cost = getattr(res, "cost", None)
-                            if cost is not None and cost.flops:
-                                step_s = wall / len(group)
-                                m_flops.set(float(cost.flops))
-                                peak = obs_attr.peak_flops()
-                                if step_s > 0 and peak:
-                                    # registered on first use: a device
-                                    # with no known peak leaves no
-                                    # zero-valued mfu series behind
-                                    if m_mfu is None:
-                                        m_mfu = obs_attr.mfu_gauge(
-                                            reg, "train")
-                                    m_mfu.set(cost.flops / peak / step_s)
+                        # what publishing the metrics costs a step
+                        with profiler.RecordEvent(
+                                "trainer::telemetry",
+                                cat=profiler.CAT_TRAINER):
+                            now = time.monotonic()
+                            wall = now - t_prev
+                            m_steps.inc(len(group))
+                            m_step_s.record(wall / len(group))
+                            m_pref.set(prefetcher.occupancy()
+                                       if prefetcher is not None else 0)
+                            t_prev = now
+                            # static peak-HBM plan of THIS dispatch's
+                            # executable (same result-not-executor rule as
+                            # the cost read below)
+                            mem = getattr(res, "memory", None)
+                            if mem is not None:
+                                publish_peak("train", mem.peak_bytes)
+                            if attr_on:
+                                # phase breakdown: measured host phases
+                                # since the last dispatch + the device
+                                # residual — the five phases of one step
+                                # sum to its wall time (device clamps at 0
+                                # when overlapped host work exceeds it)
+                                phases = obs_attr.drain_phases()
+                                host = sum(phases.values())
+                                phases["device"] = max(0.0, wall - host)
+                                for ph in obs_attr.PHASES:
+                                    m_phase.labels(phase=ph).record(
+                                        phases.get(ph, 0.0) / len(group))
+                                # the dispatch's OWN cost off the result:
+                                # exe.last_cost may already belong to a
+                                # different program (an event handler
+                                # calling trainer.test() runs the pruned
+                                # eval clone on this same executor)
+                                cost = getattr(res, "cost", None)
+                                if cost is not None and cost.flops:
+                                    step_s = wall / len(group)
+                                    m_flops.set(float(cost.flops))
+                                    peak = obs_attr.peak_flops()
+                                    if step_s > 0 and peak:
+                                        # registered on first use: a device
+                                        # with no known peak leaves no
+                                        # zero-valued mfu series behind
+                                        if m_mfu is None:
+                                            m_mfu = obs_attr.mfu_gauge(
+                                                reg, "train")
+                                        m_mfu.set(cost.flops / peak / step_s)
                     dispatch_id += 1
                     if len(group) < k:
                         break

@@ -46,10 +46,16 @@ def pytest_configure(config):
 
 @pytest.fixture(autouse=True)
 def fresh_programs():
-    """Each test builds graphs into fresh default programs and scope."""
+    """Each test builds graphs into fresh default programs and scope,
+    under no ambient mesh: ``make_mesh`` sets a process-wide mesh, and
+    one left by an earlier test file of the same xdist worker (which
+    files share a worker changes from run to run) sharded whatever the
+    next test built with ``mesh=None``."""
     import paddle_tpu as pt
+    from paddle_tpu.parallel.mesh import set_mesh
     pt.reset_default_programs()
     pt.reset_global_scope()
+    set_mesh(None)
     yield
 
 

@@ -345,3 +345,118 @@ def test_new_decode_flags_registered():
                  "PADDLE_TPU_DECODE_MODEL_BUDGET"):
         assert name in flags.FLAGS
         assert flags.get(name)
+
+
+# ---------------------------------------------------------------------------
+# spans and each request's life (ISSUE 25)
+# ---------------------------------------------------------------------------
+def _serve_and_listen(model, prompts, mode, max_new_tokens=6):
+    """(futures, engine stats, closed profiler events) of one engine
+    run over more prompts than slots."""
+    from paddle_tpu import profiler
+    events = []
+    profiler.add_event_listener(events.append)
+    eng = model.serve(config=GenerationConfig(max_new_tokens=max_new_tokens),
+                      mode=mode).start()
+    try:
+        futs = [eng.submit(p) for p in prompts]
+        for f in futs:
+            f.result(timeout=120)
+    finally:
+        eng.stop(drain=True, timeout=120)
+        profiler.remove_event_listener(events.append)
+    return futs, eng.stats(), events
+
+
+PROMPTS = [[3, 4, 5], [6, 7], [8, 9, 10, 11], [12], [13, 14, 15]]
+
+
+@pytest.mark.parametrize("mode", ["cached", "reforward"])
+def test_each_request_carries_its_four_timestamps(model, mode):
+    import time
+    t_before = time.perf_counter()
+    futs, stats, _events = _serve_and_listen(model, PROMPTS, mode)
+    t_after = time.perf_counter()
+    for f in futs:
+        life = [f.enqueued_at, f.admitted_at, f.first_token_at,
+                f.completed_at]
+        assert None not in life
+        assert life == sorted(life)
+        assert t_before <= life[0] and life[-1] <= t_after
+    # two slots, five requests: some waited for a slot behind others
+    waits = sorted(f.admitted_at - f.enqueued_at for f in futs)
+    assert waits[-1] > waits[0]
+    # FIFO admission: admitted in the order they were enqueued
+    order = sorted(futs, key=lambda f: f.enqueued_at)
+    assert [f.admitted_at for f in order] == \
+        sorted(f.admitted_at for f in futs)
+    # both histograms took one sample a retired request
+    for key in ("queue_wait_seconds", "ttft_seconds"):
+        assert stats[key]["count"] == len(futs), stats[key]
+    assert stats["ttft_seconds"]["mean"] >= \
+        stats["queue_wait_seconds"]["mean"] > 0
+
+
+def test_a_request_failed_in_the_queue_has_no_slot_timestamps(model):
+    from paddle_tpu.serving.batcher import ServingStopped
+    eng = model.serve(config=GenerationConfig(max_new_tokens=16),
+                      mode="cached").start()
+    futs = [eng.submit([3 + i, 4]) for i in range(8)]
+    eng.stop(drain=False, timeout=120)
+    dropped = []
+    for f in futs:
+        try:
+            f.result(timeout=30)
+        except ServingStopped:
+            dropped.append(f)
+    assert dropped, "eight requests on two slots: some never got one"
+    for f in dropped:
+        assert f.admitted_at is None and f.first_token_at is None
+        assert f.enqueued_at <= f.completed_at
+    stats = eng.stats()
+    assert stats["queue_wait_seconds"]["count"] == \
+        len(futs) - len(dropped)
+
+
+@pytest.mark.parametrize("mode, step", [
+    ("cached", "generation::decode_step["),
+    ("reforward", "generation::reforward_step[")])
+def test_iteration_span_and_its_children(model, mode, step):
+    futs, stats, events = _serve_and_listen(model, PROMPTS, mode)
+    driver = [e for e in events
+              if e["args"]["thread"] == "generation-driver"]
+
+    def named(prefix):
+        return [e for e in driver if e["name"].startswith(prefix)]
+
+    def inside(child, parent):
+        return parent["ts"] <= child["ts"] and child["ts"] + \
+            child["dur"] <= parent["ts"] + parent["dur"]
+
+    iterations, steps = named("generation::iteration"), named(step)
+    prefills = named("generation::prefill[")
+    # the counts the benchmark's driver holds the engine to
+    assert len(steps) == stats["steps"]
+    assert len(prefills) == stats["prefills"] == \
+        (len(PROMPTS) if mode == "cached" else 0)
+    assert sorted(e["name"] for e in prefills) == sorted(
+        f"generation::prefill[{len(p)}]" for p in PROMPTS)[:len(prefills)]
+    # one iteration a step: each step in exactly one iteration, no
+    # iteration with two, and every iteration admitted or stepped
+    for it in iterations:
+        assert len([s for s in steps if inside(s, it)]) <= 1
+        assert [e for e in steps + prefills + named(
+            "generation::deliver") if inside(e, it)]
+    for child in steps + prefills + named("generation::build_step") + \
+            named("generation::deliver") + named("generation::telemetry"):
+        assert len([it for it in iterations if inside(child, it)]) == 1
+    assert len(named("generation::build_step")) == len(steps)
+    # a deliver after every step, and in cached mode after every prefill
+    assert len(named("generation::deliver")) == len(steps) + len(prefills)
+    # the executor's spans fall inside the step spans by themselves
+    for s in steps + prefills:
+        kinds = {e["name"] for e in named("pipeline::") if inside(e, s)}
+        assert {"pipeline::prepare", "pipeline::dispatch",
+                "pipeline::commit", "pipeline::fetch_sync"} <= kinds
+    assert {e["tid"] for e in driver} == {driver[0]["tid"]}
+    assert all(e["tid"] != threading.get_ident() for e in driver)

@@ -166,7 +166,14 @@ def test_registry_names_and_help_after_smoke_run(tmp_path, monkeypatch):
                      "paddle_tpu_embed_table_rows",
                      # ISSUE 20: memory-planner families
                      "paddle_tpu_memory_peak_bytes",
-                     "paddle_tpu_memory_reuse_bytes_total"):
+                     "paddle_tpu_memory_reuse_bytes_total",
+                     # ISSUE 25: what a compile cost, each request's
+                     # wait for a slot and time to first token
+                     "paddle_tpu_compile_phase_seconds_total",
+                     "paddle_tpu_persistent_cache_hits_total",
+                     "paddle_tpu_persistent_cache_misses_total",
+                     "paddle_tpu_decode_queue_wait_seconds",
+                     "paddle_tpu_decode_ttft_seconds"):
         assert expected in names, f"smoke run did not publish {expected}"
     # the generation smoke shed exactly through the host budget path
     gen_shed = {key for key, _ in
