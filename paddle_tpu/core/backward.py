@@ -6,8 +6,12 @@ ops are walked in reverse, a grad-op description is appended per forward op,
 and duplicate gradient contributions are summed. Ops may register an explicit
 grad maker; every op without one gets the generic `__vjp__` grad op, whose
 compute rule calls jax.vjp on the forward compute rule — exact gradients with
-no per-op adjoint code, and XLA's CSE dedups the recomputed forward values
-against the original forward ops after fusion.
+no per-op adjoint code. The rule is linearised ONCE where the grad op reads
+the very values its forward op read: the executor runs that forward op under
+jax.vjp and the grad op applies the pullback (ops/core_ops.py). A grad op fed
+a @PRE. snapshot or a re-named value replays the rule; XLA's CSE merges a
+replayed rule's HLO with the forward op's, but not a custom call, so a Pallas
+kernel in a replayed rule runs twice.
 """
 from __future__ import annotations
 

@@ -314,6 +314,39 @@ class StepResult:
         return iter(self.fetches())
 
 
+def _wiring_key(type: str, inputs: Dict, outputs: Dict):
+    """What a forward op and the copy its grad op embeds share: the
+    type, the input wiring and the output slots. Output NAMES are left
+    out: the rewrite's inplace_reuse pass re-names a dead output on the
+    forward op alone, and the outputs are read by position."""
+    return (type,
+            tuple(sorted((s, tuple(ns)) for s, ns in inputs.items())),
+            tuple(sorted((s, len(ns)) for s, ns in outputs.items())))
+
+
+def _vjp_sites(ops) -> Optional[Dict[Any, OpDesc]]:
+    """{_wiring_key of a forward op: the __vjp__ op of `ops` that embeds
+    it} for the grad ops that may reuse their forward op's pullback (as
+    analysis/rewrite.py::_vjp_of matches, attr drift tolerated, less the
+    output names). A grad op fed another name than its forward op read
+    (backward.py's @PRE. snapshots) is no site, nor are two grad ops of
+    one key. None where `ops` holds no __vjp__."""
+    sites: Optional[Dict[Any, Optional[OpDesc]]] = None
+    for op in ops:
+        if op.type != "__vjp__":
+            continue
+        if sites is None:
+            sites = {}
+        fwd = op.attrs["fwd_op"]
+        read = [n for names in fwd["inputs"].values() for n in names]
+        read += op.attrs.get("closure_names") or []
+        if op.inputs.get("FwdIn") != read:
+            continue
+        key = _wiring_key(fwd["type"], fwd["inputs"], fwd["outputs"])
+        sites[key] = None if key in sites else op
+    return sites
+
+
 def trace_block(block: BlockDesc, env: Dict[str, Any],
                 extra: Dict[str, Any]) -> Dict[str, Any]:
     """Run every op's compute rule under trace, mutating env. Returns env.
@@ -323,7 +356,32 @@ def trace_block(block: BlockDesc, env: Dict[str, Any],
     those tracers are dropped from env right after the op, shortening
     tracer lifetimes (XLA does in-executable buffer reuse on its own;
     this keeps the lowering from pinning dead values). Vars in
-    extra["keep_vars"] (fetches + state writes) always survive."""
+    extra["keep_vars"] (fetches + state writes) always survive.
+
+    A forward op whose __vjp__ op comes later in THIS block runs once,
+    under jax.vjp, and its pullback waits for that grad op in a dict this
+    call puts under extra[VJP_PULLBACKS] and takes away again on its way
+    out (an exception's too): a pullback never outlives the call that made
+    it, and a sub-block or scan-body trace, a call of its own, sees only
+    its own. A block without a __vjp__ op is traced with no such dict."""
+    sites = _vjp_sites(block.ops)
+    if not sites:
+        return _trace_ops(block, env, extra, None)
+    from ..ops.core_ops import VJP_PULLBACKS
+    outer = extra.get(VJP_PULLBACKS)
+    extra[VJP_PULLBACKS] = {}
+    try:
+        return _trace_ops(block, env, extra, sites)
+    finally:
+        if outer is None:
+            del extra[VJP_PULLBACKS]
+        else:
+            extra[VJP_PULLBACKS] = outer
+
+
+def _trace_ops(block, env, extra, sites):
+    if sites:
+        from ..ops.core_ops import run_op_keeping_pullback
     keep = extra.get("keep_vars") or ()
     stats = extra.get("trace_stats")  # optional {.. -> peak_env_bytes}
     for op in block.ops:
@@ -333,7 +391,10 @@ def trace_block(block: BlockDesc, env: Dict[str, Any],
         fwd = op.attrs.get("fwd_op") if op.type == "__vjp__" else None
         with jax.named_scope(f"__vjp__.{fwd['type']}" if fwd
                              else op.type):
-            env.update(run_op(op, env, extra))
+            gop = sites and sites.get(
+                _wiring_key(op.type, op.inputs, op.outputs))
+            outs = gop and run_op_keeping_pullback(op, gop, env, extra)
+            env.update(run_op(op, env, extra) if outs is None else outs)
         dead = op.attrs.get("__dead_vars__")
         if dead:
             for name in dead:

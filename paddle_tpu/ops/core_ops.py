@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from ..core.ir import OpDesc
 from ..core.lod import RaggedNested, RaggedPair, RaggedTree
-from ..core.registry import ExecutionContext, OpRegistry, register_op
+from ..core.registry import ExecutionContext, register_op
 
 _JNP_DTYPE = {
     "float32": jnp.float32, "float64": jnp.float64, "float16": jnp.float16,
@@ -234,49 +234,113 @@ def _shape(ctx):
 
 # -- the generic grad op ----------------------------------------------------
 
+_RAGGED = (RaggedPair, RaggedNested, RaggedTree)
+# extra[VJP_PULLBACKS]: {id(__vjp__ op): (pullback, FwdIn values)} of the
+# trace_block call that is running; that call owns the dict and removes it
+VJP_PULLBACKS = "vjp_pullbacks"
+
+
+def _dense(v):
+    return v.data if isinstance(v, _RAGGED) else v
+
+
+def _grad_out_names(fwd, out_has_grad):
+    return [n for n, h in zip(fwd.output_names(), out_has_grad) if h]
+
+
+def _forward_fn(fwd, replay_names, grad_out_names, env, extra,
+                keep_outs=False):
+    """The function a __vjp__ site differentiates: `fwd`'s rule with
+    `vals` bound to `replay_names`. Only grad-receiving outputs go through
+    vjp (others contribute nothing), and ragged values pass as their dense
+    data (lengths are non-diff ints). With `keep_outs`, every output rides
+    along as aux."""
+    from ..core.registry import run_op
+
+    def f(vals):
+        local = dict(env)
+        local.update(zip(replay_names, vals))
+        outs = run_op(fwd, local, extra)
+        res = tuple(_dense(outs[n]) for n in grad_out_names)
+        return (res, outs) if keep_outs else res
+    return f
+
+
+def run_op_keeping_pullback(op, gop, env, extra):
+    """The forward half of a __vjp__ site (core/executor.py trace_block):
+    run forward op `op` ONCE, under jax.vjp with the function its grad op
+    `gop` would replay, and leave the pullback with the values it was
+    linearised at in extra[VJP_PULLBACKS] for `gop` to pop. Returns the
+    op's outputs, or None (run it plainly) where an input is not in env
+    or `op` is not the op `gop` embeds: same wiring, but an attr of the
+    embedded copy has another value here (names are re-used by the
+    rewrite passes; markers stamped on `op` alone are fine)."""
+    replay_names = gop.inputs["FwdIn"]
+    try:
+        in_vals = tuple(env[n] for n in replay_names)
+    except KeyError:
+        return None
+    embedded = gop.attrs["fwd_op"]["attrs"]
+    if embedded is not op.attrs:
+        try:
+            if not all(k in op.attrs and bool(op.attrs[k] == v)
+                       for k, v in embedded.items()):
+                return None
+        except ValueError:   # an array-valued attr: no plain truth value
+            return None
+    f = _forward_fn(op, replay_names,
+                    _grad_out_names(op, gop.attrs["out_has_grad"]),
+                    env, extra, keep_outs=True)
+    _, pullback, outs = jax.vjp(f, in_vals, has_aux=True)
+    extra[VJP_PULLBACKS][id(gop)] = (pullback, in_vals)
+    return outs
+
+
+def _count_grad_site(served, fwd_type):
+    from ..observability.registry import default_registry
+    default_registry().counter(
+        "paddle_tpu_grad_sites_total",
+        "Grad sites (__vjp__ ops) traced, by forward op type and by how "
+        "they were served: reused (the pullback the forward op's own "
+        "trace made) or replayed (the forward rule traced a second time "
+        "under jax.vjp).", ("served", "op")).labels(
+            served=served, op=fwd_type).inc()
+
+
 @register_op("__vjp__", ragged_aware=True)
 def _vjp(ctx):
     """Gradient of an arbitrary forward op via jax.vjp on its compute rule.
 
-    See core/backward.py for how this op is constructed. XLA CSE merges the
-    re-traced forward values with the original forward ops post-fusion.
+    See core/backward.py for how this op is constructed. Where the
+    trace_block call that runs this op kept the forward op's pullback
+    (run_op_keeping_pullback) AND every FwdIn value is the very object
+    that pullback was linearised at, the pullback is applied and the
+    forward rule is not run again. Otherwise (a @PRE. snapshot, a value
+    renamed or dropped in between, a forward op in another block) the
+    rule is replayed under jax.vjp: XLA's CSE merges replayed HLO with
+    the forward op's, but NOT a custom call — a Pallas kernel in a
+    replayed rule runs twice a step.
     """
     fwd = OpDesc.from_dict(ctx.attr("fwd_op"))
-    fwd_def = OpRegistry.get(fwd.type)
-    fwd_in_names = fwd.input_names()
-    fwd_out_names = fwd.output_names()
     in_vals = ctx.inputs("FwdIn")
     out_grads = ctx.inputs("OutGrad")
-    out_has_grad = ctx.attr("out_has_grad")
     in_need_grad = ctx.attr("in_need_grad")
-    # Sub-block ops read outer vars via closure (see backward.py
-    # _sub_block_free_vars); those ride along as extra FwdIn entries so
-    # jax.vjp sees them as arguments and produces their gradients.
-    closure_names = ctx.attr("closure_names", []) or []
-    grad_out_names = [n for n, h in zip(fwd_out_names, out_has_grad) if h]
-    replay_names = fwd_in_names + list(closure_names)
 
-    # Only grad-receiving outputs go through vjp (others contribute nothing),
-    # and ragged values pass as their dense data (lengths are non-diff ints).
-    from ..core.registry import run_op
-
-    def f(vals):
-        env = dict(ctx.env)
-        for n, v in zip(replay_names, vals):
-            env[n] = v
-        outs = run_op(fwd, env, ctx.extra)
-        res = []
-        for n in grad_out_names:
-            v = outs[n]
-            res.append(v.data if isinstance(
-                v, (RaggedPair, RaggedNested, RaggedTree)) else v)
-        return tuple(res)
-
-    _, vjp_fn = jax.vjp(f, tuple(in_vals))
-    cts = tuple(g.data if isinstance(
-        g, (RaggedPair, RaggedNested, RaggedTree)) else g
-        for g in out_grads)
-    (in_grads,) = vjp_fn(cts)
+    kept = (ctx.extra.get(VJP_PULLBACKS) or {}).pop(id(ctx.op), None)
+    if kept is not None and all(a is b for a, b in zip(kept[1], in_vals)):
+        vjp_fn = kept[0]
+        _count_grad_site("reused", fwd.type)
+    else:
+        # Sub-block ops read outer vars via closure (see backward.py
+        # _sub_block_free_vars); those ride along as extra FwdIn entries
+        # so jax.vjp sees them as arguments and produces their gradients.
+        closure_names = ctx.attr("closure_names", []) or []
+        f = _forward_fn(fwd, fwd.input_names() + list(closure_names),
+                        _grad_out_names(fwd, ctx.attr("out_has_grad")),
+                        ctx.env, ctx.extra)
+        _, vjp_fn = jax.vjp(f, tuple(in_vals))
+        _count_grad_site("replayed", fwd.type)
+    (in_grads,) = vjp_fn(tuple(_dense(g) for g in out_grads))
 
     idx = 0
     for need, g, v in zip(in_need_grad, in_grads, in_vals):
