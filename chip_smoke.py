@@ -491,37 +491,71 @@ def phase_serve(sm, cfg, device):
              finish=deep.finish_reason)
 
     # PR 16 called KV-cache donation "a TPU win" that CPU copies: read
-    # the compiled decode step. The cache vars are the only parameters
+    # the compiled decode steps. The cache vars are the only parameters
     # of shape [slots, heads, max_seq, d_key]; each must be aliased to
-    # an output in the module's input_output_alias table.
-    top = cfg["cache_buckets"][-1]
-    lm = model.programs["decode"][top]
-    model.run_decode(np.ones(spec.slots, np.int64),
-                     np.zeros(spec.slots, np.int64), top)
-    compiled = aot_compiled_for(model.executor, lm.main, scope=model.scope)
+    # an output in the module's input_output_alias table, and nothing
+    # of that size may be a copy. On the chip the appends are Pallas
+    # calls, one a cache (ops/pallas/kv_cache_append.py): the batched
+    # scatter they replace compiles to a `while` over the slots.
     import re
-    text = compiled.as_text()
-    header = text[:text.find("\n\n")] if "\n\n" in text else text[:20000]
-    aliased = {int(m) for m in re.findall(
-        r"\(\s*(\d+)\s*,\s*\{[^}]*\}\s*,\s*(?:may|must)-alias\)", header)}
+    from paddle_tpu.observability import default_registry
+    on_tpu = device.platform == "tpu"
     d_key = cfg["d_model"] // cfg["n_head"]
-    shape = f"f32[{spec.slots},{cfg['n_head']},{top},{d_key}]"
-    entry = text[text.find("\nENTRY "):]      # fusions number their own
-    cache_params = {int(n) for n in re.findall(
-        re.escape(shape) + r"[^\n]*? parameter\((\d+)\)", entry)}
-    ma = compiled.memory_analysis()
-    emit(kv_cache=dict(
-        cache_vars=len(model.cache_names), cache_shape=shape,
-        cache_parameters=len(cache_params),
-        aliased_to_output=len(cache_params & aliased),
-        alias_bytes=ma.alias_size_in_bytes,
-        argument_bytes=ma.argument_size_in_bytes,
-        temp_bytes=ma.temp_size_in_bytes, **mem_stats(device)))
-    sm.check(len(cache_params) == len(model.cache_names)
-             and cache_params <= aliased,
-             "serve: every KV-cache argument of the decode step is "
-             "aliased to its output", cache=len(cache_params),
-             aliased=len(cache_params & aliased))
+    top = cfg["cache_buckets"][-1]
+    dims = (spec.slots, cfg["n_head"], top, d_key)
+    shape = "f32[%d,%d,%d,%d]" % dims
+    swapped = "f32[%d,%d,%d,%d]" % (dims[0], dims[1], dims[3], dims[2])
+    for bucket in cfg["cache_buckets"]:
+        lm = model.programs["decode"][bucket]
+        model.run_decode(np.ones(spec.slots, np.int64),
+                         np.zeros(spec.slots, np.int64), bucket)
+        compiled = aot_compiled_for(model.executor, lm.main,
+                                    scope=model.scope)
+        text = compiled.as_text()
+        header = text[:text.find("\n\n")] if "\n\n" in text \
+            else text[:20000]
+        aliased = {int(m) for m in re.findall(
+            r"\(\s*(\d+)\s*,\s*\{[^}]*\}\s*,\s*(?:may|must)-alias\)",
+            header)}
+        entry = text[text.find("\nENTRY "):]  # fusions number their own
+        cache_params = {int(n) for n in re.findall(
+            re.escape(shape) + r"[^\n]*? parameter\((\d+)\)", entry)}
+        cache_copies = len(re.findall(
+            "= (?:" + re.escape(shape) + "|" + re.escape(swapped)
+            + r")\S* copy\(", text))
+        whiles = len(re.findall(r"= \S.* while\(", text))
+        custom_calls = text.count('custom_call_target="tpu_custom_call"')
+        ma = compiled.memory_analysis()
+        emit(kv_cache=dict(
+            bucket=bucket, cache_vars=len(model.cache_names),
+            cache_shape=shape, cache_parameters=len(cache_params),
+            aliased_to_output=len(cache_params & aliased),
+            cache_sized_copies=cache_copies, whiles=whiles,
+            custom_calls=custom_calls,
+            alias_bytes=ma.alias_size_in_bytes,
+            argument_bytes=ma.argument_size_in_bytes,
+            temp_bytes=ma.temp_size_in_bytes, **mem_stats(device)))
+        sm.check(len(cache_params) == len(model.cache_names)
+                 and cache_params <= aliased and cache_copies == 0,
+                 f"serve: every KV-cache argument of the decode step "
+                 f"[{bucket}] is aliased to its output and nothing of "
+                 f"its size is copied", cache=len(cache_params),
+                 aliased=len(cache_params & aliased), copies=cache_copies)
+        if on_tpu:
+            sm.check(custom_calls == len(model.cache_names)
+                     and whiles == 0,
+                     f"serve: the decode step [{bucket}] appends to its "
+                     f"caches in Pallas calls, one a cache, and holds no "
+                     f"while loop", custom_calls=custom_calls,
+                     whiles=whiles)
+    sites = {labels[0]: child.value for labels, child in
+             default_registry().get(
+                 "paddle_tpu_kv_append_sites_total").samples()}
+    sm.check(sites.get("kernel" if on_tpu else "scatter", 0) > 0
+             and sites.get("scatter" if on_tpu else "kernel", 0) == 0,
+             "serve: every kv_cache_append site traced took the "
+             + ("kernel" if on_tpu else "scatter (no TPU here)"),
+             sites=sites)
     model.executor.close()
 
 

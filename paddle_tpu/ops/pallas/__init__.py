@@ -3,7 +3,14 @@
 The reference hand-writes CUDA for its fused hot ops (fused LSTM
 paddle/cuda/src/hl_cuda_lstm.cu, top-k cuda/src/hl_top_k.cu, attention-era
 compositions in nets.py). The TPU-native analogue is a small library of
-Pallas kernels; everything else rides XLA fusion.
+Pallas kernels; everything else rides XLA fusion:
+
+  flash_attention    tiled attention, forward and backward
+  fused_lstm / gru   the recurrent time loop, state kept in VMEM
+  fused_conv         conv with BatchNorm's sweeps folded in (ResNet)
+  block_megakernel   a whole ResNet bottleneck block, tiled by batch
+  kv_cache_append    the decode step's cache append: every slot's new
+                     K or V row in one call, the cache updated in place
 
 All kernels run in interpret mode on CPU (tests) and compiled on TPU.
 """

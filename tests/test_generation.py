@@ -90,6 +90,56 @@ def test_mid_generation_admit_retire_bit_identity(model):
         assert got.finish_reason == solo.finish_reason
 
 
+def _append_sites():
+    from paddle_tpu.observability import default_registry
+    fam = default_registry().get("paddle_tpu_kv_append_sites_total")
+    return {labels[0]: child.value for labels, child in fam.samples()} \
+        if fam is not None else {}
+
+
+@pytest.mark.parametrize("lane_axis,max_seq_len,buckets", [
+    (3, 24, (8, 16, 24)),      # d_key on the lanes: 8-row tiles
+    (2, 128, (8, 16, 128)),    # positions on the lanes: 128-lane blocks
+], ids=["row_form", "lane_form"])
+def test_decode_through_append_kernel_matches_scatter_path(
+        monkeypatch, lane_axis, max_seq_len, buckets):
+    """The decode programs with kv_cache_append steered to its Pallas
+    kernel (interpret mode here; on a TPU the rule picks it by itself)
+    emit the token streams of today's scatter path and of the full
+    re-forward, and every append site is counted on the path taken."""
+    from paddle_tpu.ops import cache_ops
+    kw = dict(SPEC_KW, max_seq_len=max_seq_len, prompt_buckets=buckets,
+              cache_buckets=buckets)
+    prompts = [[5, 9, 3, 2], [7, 3, 2, 4]]
+
+    def streams(mode):
+        before = _append_sites()
+        model = GenerationModel.build(GenerationSpec(**kw))
+        out = _generate_all(model, prompts, mode, max_new_tokens=18)
+        after = _append_sites()
+        return out, {k: after[k] - before.get(k, 0) for k in after
+                     if after[k] != before.get(k, 0)}, model.spec
+
+    scatter, scatter_sites, spec = streams("cached")
+    reforward, no_sites, _ = streams("reforward")
+    monkeypatch.setattr(cache_ops, "_append_kernel_lane_axis",
+                        lambda ctx, cache: lane_axis)
+    kernel, kernel_sites, _ = streams("cached")
+    for k, s, r in zip(kernel, scatter, reforward):
+        assert k.tokens == s.tokens == r.tokens
+        assert k.finish_reason == s.finish_reason == r.finish_reason
+    # K and V of every layer, once a decode program traced (one a cache
+    # bucket the generation entered), all on the one path
+    final_len = len(prompts[0]) + len(kernel[0].tokens)
+    entered = {bucket_for(n, spec.cache_buckets)
+               for n in range(len(prompts[0]) + 1, final_len + 1)}
+    sites = 2 * spec.n_layer * len(entered)
+    assert len(entered) >= 2
+    assert scatter_sites == {"scatter": sites}
+    assert kernel_sites == {"kernel": sites}
+    assert no_sites == {}
+
+
 # ---------------------------------------------------------------------------
 # donation non-interference
 # ---------------------------------------------------------------------------

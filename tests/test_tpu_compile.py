@@ -141,3 +141,37 @@ def test_flash_attention_under_a_mesh_compiles_per_shard(topo):
     assert text.count("tpu_custom_call") == 1
     # each device attends its own [4, 4, 2048, 64] shard: no collective
     assert "all-gather" not in text and "all-reduce" not in text
+
+
+# serve-chat's caches (128 slots x 8 heads x 2048 positions, chipbench/
+# configs/decoder-lm-base.json) and the shapes the next serving
+# configuration may store: d_key 128, bf16. A v5e holds a d_key of 64
+# with the POSITIONS on its lanes, a d_key of 128 row-major; the op's
+# rule reads that from the backend (ops/cache_ops.py device_lane_axis)
+# and hands the kernel the cache in the device's own order, where the
+# transposes around the call are bitcasts. Handed the other order, XLA
+# copies the whole cache in and out (1 GB of temporaries at f32 d64).
+@pytest.mark.parametrize("slots", [128, 4])
+@pytest.mark.parametrize("d_key,lane_axis", [(64, 2), (128, 3)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_kv_cache_append_compiles_in_place(topo, one_chip, dtype, d_key,
+                                           lane_axis, slots):
+    from paddle_tpu.ops.cache_ops import device_lane_axis
+    from paddle_tpu.ops.pallas.kv_cache_append import kv_cache_append
+    shape = (slots, 8, 2048, d_key)
+    assert device_lane_axis(shape, dtype, topo.devices[0]) == lane_axis
+    cache = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    new = jax.ShapeDtypeStruct((slots, 8, 1, d_key), dtype,
+                               sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda c, n, p: kv_cache_append(c, n, p, lane_axis=lane_axis,
+                                        interpret=False),
+        donate_argnums=0).lower(cache, new, pos).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " while(" not in text
+    assert "may-alias" in text[:text.find("\n\n")] or \
+        "must-alias" in text[:text.find("\n\n")]
+    # nothing of the cache's size beside the cache: a copy would show
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
