@@ -20,9 +20,6 @@ from .cost_model import (CostModelPass, OpCost, ProgramCost,  # noqa
                          program_cost)
 from .memory import (MemoryPass, MemoryReport, VarInterval,  # noqa
                      check_budget, hbm_budget_bytes, program_memory)
-from .rewrite import (RewritePass, RewriteResult,  # noqa
-                      REWRITE_PASS_REGISTRY, default_rewrite_passes,
-                      optimize_enabled, rewrite_program)
 
 __all__ = [
     "Diagnostic", "Severity", "VerificationError", "VerifyReport",
@@ -32,6 +29,4 @@ __all__ = [
     "CostModelPass", "OpCost", "ProgramCost", "program_cost",
     "MemoryPass", "MemoryReport", "VarInterval", "check_budget",
     "hbm_budget_bytes", "program_memory",
-    "RewritePass", "RewriteResult", "REWRITE_PASS_REGISTRY",
-    "default_rewrite_passes", "optimize_enabled", "rewrite_program",
 ]

@@ -297,10 +297,10 @@ def _flops_for(op: ir.OpDesc,
             (8 * x.numel, False, None)
 
     if t == "scaled_dot_product_attention":
-        # the outlined attention mega-op (analysis/rewrite.py): two
+        # the attention op the models place (ops/nn_ops.py): two
         # seq^2 contractions plus the online softmax. Without this rule
         # the generic 1-flop/elem fallback would book ~Sq*d instead of
-        # ~4*Sq*Sk*d and silently crater reported MFU post-rewrite.
+        # ~4*Sq*Sk*d and silently crater reported MFU.
         q, k = first("Q"), first("K")
         if q is None or k is None or len(q.shape) < 3:
             return None, False, None
@@ -327,18 +327,6 @@ def _flops_for(op: ir.OpDesc,
         h = w.shape[0]
         gates = 4 if t == "lstm" else 3
         return 2 * nt * h * gates * h + 12 * nt * h, True, None
-
-    if t == "se_block":
-        # outlined squeeze-excitation gate (ops/fusion_ops.py): global
-        # pool + gate multiply sweep the activation twice; the two
-        # bottleneck FCs are 2*MAC each
-        x, w1 = first("X"), first("W1")
-        if x is None or w1 is None or len(x.shape) != 4 \
-                or len(w1.shape) != 2:
-            return None, False, None
-        n, c = x.shape[0], x.shape[1]
-        r = w1.shape[1]
-        return 2 * x.numel + 4 * n * c * r, True, None
 
     if t in _OPTIMIZER_FLOPS:
         p = first("Param")

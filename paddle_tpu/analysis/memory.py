@@ -9,24 +9,24 @@ binds to the fed batch, other dynamic dims bind to 1.
 
 Two numbers come out of the same walk:
 
-- ``peak_bytes`` — the planner's headline estimate, under the
-  *arena* model the executor actually implements: one buffer per
-  distinct var name, allocated at its first reference and held to the
-  end of the step (the trace env never frees mid-step; legacy Fluid
-  freed only at scope exit). Persistable vars (params, optimizer
-  state, KV caches) are resident for the whole step. This is an upper
-  bound that the ``inplace_reuse`` rewrite pass genuinely tightens:
-  renaming a dead buffer's successor onto it removes one arena slot.
+- ``peak_bytes`` — the *arena* figure of the trace env: one buffer
+  per distinct var name, allocated at its first reference and held to
+  the end of the step (the trace env never frees mid-step; legacy
+  Fluid freed only at scope exit). Persistable vars (params, optimizer
+  state, KV caches) are resident for the whole step. It depends on how
+  the builder NAMED its temporaries, which XLA never sees.
 - ``ideal_peak_bytes`` — the free-at-last-use interval sweep: what a
   perfect allocator (XLA's, roughly) could reach on the un-fused
-  graph. The true device footprint lies between the two; see
-  KNOWN_GAPS "Memory planning boundaries".
+  graph, whatever the vars are called. This is the number the gate
+  judges; it still over-states what XLA allocates (fusion keeps most
+  temporaries out of HBM; KNOWN_GAPS "Memory planning boundaries").
 
 The ``memory`` analysis pass attaches a :class:`MemoryReport` to the
-verify report; :func:`check_budget` turns an over-budget report into a
-structured ``hbm-oom`` diagnostic that the Executor raises BEFORE the
-program ever reaches XLA (``PADDLE_TPU_HBM_BYTES``, default one v5e
-core's 16 GiB, 0 disables).
+verify report; :func:`check_budget` turns a report whose
+``ideal_peak_bytes`` is over the budget into a structured ``hbm-oom``
+diagnostic that the Executor raises BEFORE the program ever reaches
+XLA (``PADDLE_TPU_HBM_BYTES``, default one v5e core's 16 GiB, 0
+disables).
 """
 from __future__ import annotations
 
@@ -326,7 +326,8 @@ def program_memory(program, block_idx: int = 0,
 # ---------------------------------------------------------------------------
 def check_budget(report: MemoryReport, budget: Optional[int] = None,
                  top_k: int = 5) -> VerifyReport:
-    """Diagnose ``report.peak_bytes`` against the HBM budget.
+    """Diagnose ``report.ideal_peak_bytes`` (the free-at-last-use
+    peak, which does not depend on var names) against the HBM budget.
 
     Returns a :class:`VerifyReport` that is clean when the plan fits
     (or the gate is disabled with budget 0) and carries one structured
@@ -337,7 +338,7 @@ def check_budget(report: MemoryReport, budget: Optional[int] = None,
         budget = hbm_budget_bytes()
     vr = VerifyReport(program_label=report.label)
     vr.memory = report
-    if budget <= 0 or report.peak_bytes <= budget:
+    if budget <= 0 or report.ideal_peak_bytes <= budget:
         return vr
     offenders = ", ".join(
         f"{v.name} {_fmt_bytes(v.bytes)} ({v.kind})"
@@ -345,16 +346,19 @@ def check_budget(report: MemoryReport, budget: Optional[int] = None,
     hw = report.high_water or {}
     vr.add(Diagnostic(
         Severity.ERROR, "hbm-oom",
-        f"static peak-HBM estimate {_fmt_bytes(report.peak_bytes)} "
+        f"static peak-HBM estimate "
+        f"{_fmt_bytes(report.ideal_peak_bytes)} "
         f"({_fmt_bytes(report.resident_bytes)} resident + "
-        f"{_fmt_bytes(report.activation_bytes)} activations) exceeds "
+        f"{_fmt_bytes(report.ideal_peak_bytes - report.resident_bytes)}"
+        f" activations live at once, freed at last use; "
+        f"{_fmt_bytes(report.peak_bytes)} if none were freed) exceeds "
         f"the {_fmt_bytes(budget)} budget; top buffers: {offenders}",
         block_path=hw.get("block_path") or (report.block_idx,),
         op_index=hw.get("op_index"), op_type=hw.get("op_type"),
-        hint="reduce batch/sequence length or cache buckets, keep "
-             "PADDLE_TPU_INPLACE_REUSE=1, or raise PADDLE_TPU_HBM_BYTES "
-             "(0 disables this gate); the estimate is the pre-XLA "
-             "no-reuse upper bound — see the `memory` analysis pass"))
+        hint="reduce batch/sequence length or cache buckets, or raise "
+             "PADDLE_TPU_HBM_BYTES (0 disables this gate); the "
+             "estimate is made before XLA fuses anything — see the "
+             "`memory` analysis pass"))
     return vr
 
 
@@ -385,8 +389,8 @@ _obs_cache = None
 
 def publish_peak(job: str, peak_bytes: int) -> None:
     """Best-effort gauge of the most recent compile's static peak
-    (``paddle_tpu_memory_peak_bytes{job}``) — same registry-identity
-    caching as the rewrite pipeline's publisher."""
+    (``paddle_tpu_memory_peak_bytes{job}``), cached by registry
+    identity as the verifier's and the executor's instruments are."""
     global _obs_cache
     try:
         from ..observability import default_registry

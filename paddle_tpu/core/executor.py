@@ -316,19 +316,17 @@ class StepResult:
 
 def _wiring_key(type: str, inputs: Dict, outputs: Dict):
     """What a forward op and the copy its grad op embeds share: the
-    type, the input wiring and the output slots. Output NAMES are left
-    out: the rewrite's inplace_reuse pass re-names a dead output on the
-    forward op alone, and the outputs are read by position."""
+    type and the input and output wiring."""
     return (type,
             tuple(sorted((s, tuple(ns)) for s, ns in inputs.items())),
-            tuple(sorted((s, len(ns)) for s, ns in outputs.items())))
+            tuple(sorted((s, tuple(ns)) for s, ns in outputs.items())))
 
 
 def _vjp_sites(ops) -> Optional[Dict[Any, OpDesc]]:
     """{_wiring_key of a forward op: the __vjp__ op of `ops` that embeds
-    it} for the grad ops that may reuse their forward op's pullback (as
-    analysis/rewrite.py::_vjp_of matches, attr drift tolerated, less the
-    output names). A grad op fed another name than its forward op read
+    it} for the grad ops that may reuse their forward op's pullback
+    (attr drift tolerated here; run_op_keeping_pullback checks the
+    embedded attrs). A grad op fed another name than its forward op read
     (backward.py's @PRE. snapshots) is no site, nor are two grad ops of
     one key. None where `ops` holds no __vjp__."""
     sites: Optional[Dict[Any, Optional[OpDesc]]] = None
@@ -461,10 +459,9 @@ class CompiledProgram:
         # Executor.run at the compile-cache miss that built this
         # executable (None when the cost model could not run)
         self.cost = None
-        # RewriteResult of the optimizer pipeline that produced the
-        # program this executable traced (None: rewrite disabled,
-        # failed, or changed nothing)
-        self.rewrite = None
+        # static MemoryReport of the traced program, attached next to
+        # the cost (None when the planner could not run)
+        self.memory = None
 
 
 class _BlockPrefix:
@@ -585,8 +582,8 @@ def _compile_instruments():
             reg.counter(
                 "paddle_tpu_compile_phase_seconds_total",
                 "Host seconds spent compiling, by phase: verify, "
-                "rewrite, memory_plan, cost_model (this program's "
-                "analyses on an executor compile-cache miss) and "
+                "memory_plan, cost_model (this program's analyses on "
+                "an executor compile-cache miss) and "
                 "jax_trace, lower, backend, cache_retrieval (JAX's own "
                 "compile events; nested events of one phase are "
                 "counted once).", ("phase",)),
@@ -1130,31 +1127,6 @@ class Executor:
                 "iterations": iterations,
                 "or_reduce_tail": len(exhausted),
                 "stacked_feed": stacked_feed}
-            # Rewrite pipeline (analysis/rewrite.py): DCE/CSE/constant
-            # folding + fusion outlining onto the Pallas kernels, run
-            # once per compile-cache miss on a CLONE (the caller's
-            # program object is never mutated). Every pass is verified
-            # by fast_passes() post-rewrite; a failed verification
-            # discards that pass, and any unexpected error falls back
-            # to compiling the program exactly as built.
-            exec_program, exec_block = program, block
-            rewrite_result = None
-            from ..analysis import rewrite as _rewrite
-            if _rewrite.optimize_enabled():
-                try:
-                    with _compile_span("rewrite", span_args):
-                        rewrite_result = _rewrite.rewrite_program(
-                            program, block_idx, feed_names=feed.keys(),
-                            fetch_names=fetch_names,
-                            donate=self.donate_state,
-                            async_dispatch=not sync,
-                            label=f"program uid={program.uid} "
-                                  f"block={block_idx}")
-                except Exception:
-                    rewrite_result = None
-                if rewrite_result is not None and rewrite_result.changed:
-                    exec_program = rewrite_result.program
-                    exec_block = exec_program.block(block_idx)
             # feed shapes of THIS dispatch, for the -1-dim binding of
             # the memory plan and the cost model below (stacked feeds
             # strip the leading K axis — both analyses are per traced
@@ -1165,8 +1137,8 @@ class Executor:
                 if isinstance(shp, tuple):
                     fs[fk] = shp[1:] if stacked_feed else shp
             # Pre-compile OOM gate (analysis/memory.py): the static
-            # peak-HBM plan of the program ABOUT to be compiled — the
-            # rewritten graph, post buffer-reuse. An over-budget
+            # peak-HBM plan of the program about to be compiled, its
+            # free-at-last-use peak against the budget. An over-budget
             # program (PADDLE_TPU_HBM_BYTES, 0 disables) raises a
             # structured VerificationError naming the top offenders
             # and the high-water op BEFORE XLA ever sees it, instead
@@ -1178,36 +1150,29 @@ class Executor:
                 from ..analysis import memory as _memory
                 with _compile_span("memory_plan", span_args):
                     mem_report = _memory.program_memory(
-                        exec_program, block_idx, feed_shapes=fs,
+                        program, block_idx, feed_shapes=fs,
                         feed_names=feed.keys(),
                         label=f"program uid={program.uid} "
                               f"block={block_idx}")
             except Exception:
                 mem_report = None
             if mem_report is not None and _verifier.verify_enabled():
-                budget = _memory.hbm_budget_bytes()
-                if budget > 0 and mem_report.peak_bytes > budget:
-                    _memory.check_budget(
-                        mem_report, budget).raise_if_errors(
-                        context="pre-compile memory gate")
-            compiled = self._compile(exec_program, exec_block, feed_sig,
+                _memory.check_budget(mem_report).raise_if_errors(
+                    context="pre-compile memory gate")
+            compiled = self._compile(program, block, feed_sig,
                                      fetch_names, scope,
                                      while_bounds=while_bounds,
                                      donate=self.donate_state, **kw)
-            # introspection: which rewrite produced this executable
-            compiled.rewrite = rewrite_result
             compiled.memory = mem_report
             # static cost attribution, attached once per compiled
             # executable: per-op FLOPs/bytes with the dynamic batch dim
-            # bound from THIS dispatch's feed shapes. Computed on the
-            # REWRITTEN program — the graph that actually runs — so
-            # MFU attribution stays correct post-rewrite. Best-effort:
-            # the cost model must never fail a compile.
+            # bound from THIS dispatch's feed shapes. Best-effort: the
+            # cost model must never fail a compile.
             try:
                 from ..analysis import cost_model as _cost_model
                 with _compile_span("cost_model", span_args):
                     compiled.cost = _cost_model.program_cost(
-                        exec_program, block_idx, feed_shapes=fs)
+                        program, block_idx, feed_shapes=fs)
             except Exception:
                 compiled.cost = None
             self._cache[key] = compiled

@@ -121,36 +121,23 @@ FLAGS: Dict[str, tuple] = {
         "directory flight-recorder dump bundles are written to "
         "(flightrec_<ms>_<pid>_<seq>_<reason>/, pruned to this "
         "process's newest 8)"),
-    "PADDLE_TPU_OPTIMIZE": (
-        "1", "analysis/rewrite.py (gate in core/executor.py)",
-        "ProgramDesc rewrite pipeline on every compile-cache miss: "
-        "dead-op elimination, CSE, constant folding, fusion outlining "
-        "onto the Pallas kernels, and kernel-dispatch annotation — "
-        "each pass verified by fast_passes() and discarded on failure; "
-        "0 compiles every program exactly as built"),
-    "PADDLE_TPU_INPLACE_REUSE": (
-        "1", "analysis/rewrite.py (inplace_reuse pass)",
-        "liveness-driven buffer reuse during rewrite: rename an op's "
-        "output onto a dead same-signature buffer so the arena holds "
-        "one allocation instead of two (value-preserving, root block "
-        "only, never touches persistable/donated/fetched names); "
-        "0 keeps every var its own buffer"),
     "PADDLE_TPU_HBM_BYTES": (
         str(16 * 1024 ** 3), "analysis/memory.py (gate in "
         "core/executor.py)",
         "per-core HBM budget for the pre-compile OOM gate: a program "
-        "whose static peak-memory estimate exceeds this raises a "
-        "structured VerificationError (top offenders + high-water op) "
-        "before XLA compiles it. Default one v5e core (16 GiB); "
+        "whose static free-at-last-use peak (MemoryReport."
+        "ideal_peak_bytes) exceeds this raises a structured "
+        "VerificationError (top offenders + high-water op) before XLA "
+        "compiles it. Default one v5e core (16 GiB); "
         "0 disables the gate (the MemoryReport is still attached)"),
     "PADDLE_TPU_PALLAS_SDPA": (
-        "1", "analysis/rewrite.py (kernel_dispatch pass)",
-        "flash-kernel dispatch annotation for "
-        "scaled_dot_product_attention ops during rewrite: '1' leaves "
-        "the op's measured min-seq auto policy in charge "
-        "(PADDLE_TPU_FLASH_MIN_SEQ), 'force' stamps use_flash=True "
-        "(interpret mode off-TPU — test coverage), '0' pins the naive "
-        "composition"),
+        "1", "ops/nn_ops.py (scaled_dot_product_attention rule)",
+        "flash-kernel choice for scaled_dot_product_attention ops "
+        "that carry no use_flash attr of their own, read when the op "
+        "(and its grad op) is traced: '1' leaves the measured min-seq "
+        "policy in charge on a TPU (PADDLE_TPU_FLASH_MIN_SEQ), "
+        "'force' engages the kernel anywhere (interpret mode off-TPU "
+        "— test coverage), '0' pins the naive composition"),
     "PADDLE_TPU_INPUT_WORKERS": (
         "2", "reader/streaming.py",
         "initial worker-process count of a StreamingInputService "

@@ -13,12 +13,7 @@ from ..layer_helper import LayerHelper
 
 def multi_head_attention(q_in, k_in, v_in, d_model, n_head, mask=None,
                          dropout_rate=0.0, causal=False, seq_axis=None,
-                         seq_impl="ring", attention_impl="fused"):
-    """attention_impl="fused" appends the single
-    scaled_dot_product_attention op; "composed" builds the user-level
-    matmul -> (+mask) -> softmax -> matmul chain instead — the program
-    shape the rewrite layer's fusion outlining (analysis/rewrite.py)
-    exists for, used by benchmarks/rewrite_ab.py as the off-arm."""
+                         seq_impl="ring"):
     d_key = d_model // n_head
     # "tp_col_*"/"tp_row_*" name prefixes mark the Megatron pairing for
     # tensor parallelism (tp_param_specs below): qkv projections are
@@ -41,32 +36,9 @@ def multi_head_attention(q_in, k_in, v_in, d_model, n_head, mask=None,
         return layers.transpose(reshaped, [0, 2, 1, 3])
 
     qh, kh, vh = split_heads(q), split_heads(k), split_heads(v)
-    if attention_impl == "composed":
-        if causal or seq_axis:
-            raise ValueError(
-                "attention_impl='composed' expresses causality and "
-                "padding through the additive mask only; use the fused "
-                "impl for the causal-attr / context-parallel paths")
-        scores = layers.matmul(qh, kh, transpose_y=True,
-                               alpha=float(1.0 / np.sqrt(d_key)))
-        if mask is not None:
-            scores = layers.elementwise_add(scores, mask)
-        probs = layers.softmax(scores)
-        ctx_v = layers.matmul(probs, vh)
-    else:
-        helper = LayerHelper("mha")
-        ctx_v = helper.create_tmp_variable(q.dtype)
-        inputs = {"Q": qh, "K": kh, "V": vh}
-        if mask is not None:
-            inputs["Mask"] = mask
-        attrs = {"causal": causal}
-        if seq_axis:
-            # context parallelism over the named mesh axis (ring/ulysses)
-            attrs["seq_axis"] = seq_axis
-            attrs["seq_impl"] = seq_impl
-        helper.append_op(type="scaled_dot_product_attention",
-                         inputs=inputs, outputs={"Out": ctx_v},
-                         attrs=attrs)
+    # context parallelism over the named mesh axis (ring/ulysses)
+    cp = {"seq_axis": seq_axis, "seq_impl": seq_impl} if seq_axis else {}
+    ctx_v = _sdpa_op(qh, kh, vh, mask, causal, **cp)
     merged = layers.transpose(ctx_v, [0, 2, 1, 3])
     merged = layers.reshape(merged, [0, 0, d_model])
     out = layers.fc(merged, size=d_model, num_flatten_dims=2,
@@ -91,10 +63,9 @@ def _add_norm(x, y, d_model):
 
 
 def encoder_layer(x, d_model, n_head, d_inner, mask=None, dropout=0.0,
-                  seq_axis=None, seq_impl="ring", attention_impl="fused"):
+                  seq_axis=None, seq_impl="ring"):
     attn = multi_head_attention(x, x, x, d_model, n_head, mask, dropout,
-                                seq_axis=seq_axis, seq_impl=seq_impl,
-                                attention_impl=attention_impl)
+                                seq_axis=seq_axis, seq_impl=seq_impl)
     x = _add_norm(x, attn, d_model)
     f = ffn(x, d_model, d_inner, dropout)
     return _add_norm(x, f, d_model)
@@ -102,15 +73,13 @@ def encoder_layer(x, d_model, n_head, d_inner, mask=None, dropout=0.0,
 
 def decoder_layer(x, enc_out, d_model, n_head, d_inner, self_mask=None,
                   cross_mask=None, dropout=0.0, self_causal=False,
-                  seq_axis=None, seq_impl="ring", attention_impl="fused"):
+                  seq_axis=None, seq_impl="ring"):
     self_attn = multi_head_attention(x, x, x, d_model, n_head, self_mask,
                                      dropout, causal=self_causal,
-                                     seq_axis=seq_axis, seq_impl=seq_impl,
-                                     attention_impl=attention_impl)
+                                     seq_axis=seq_axis, seq_impl=seq_impl)
     x = _add_norm(x, self_attn, d_model)
     cross = multi_head_attention(x, enc_out, enc_out, d_model, n_head,
-                                 cross_mask, dropout,
-                                 attention_impl=attention_impl)
+                                 cross_mask, dropout)
     x = _add_norm(x, cross, d_model)
     f = ffn(x, d_model, d_inner, dropout)
     return _add_norm(x, f, d_model)
@@ -148,15 +117,13 @@ def transformer(src_ids, trg_ids, trg_labels, pos_src, pos_trg,
                 src_vocab=10000, trg_vocab=10000, max_len=64, n_layer=2,
                 n_head=8, d_model=512, d_inner=2048, dropout=0.0,
                 causal_mask=None, pad_id=0, seq_axis=None,
-                seq_impl="ring", dist_embedding=False,
-                attention_impl="fused"):
+                seq_impl="ring", dist_embedding=False):
     src_mask = _pad_attn_mask(src_ids, pad_id)
     enc = embed(src_ids, src_vocab, d_model, max_len, pos_src,
                 dist_embedding=dist_embedding)
     for _ in range(n_layer):
         enc = encoder_layer(enc, d_model, n_head, d_inner, src_mask,
-                            dropout, seq_axis=seq_axis, seq_impl=seq_impl,
-                            attention_impl=attention_impl)
+                            dropout, seq_axis=seq_axis, seq_impl=seq_impl)
     dec = embed(trg_ids, trg_vocab, d_model, max_len, pos_trg,
                 dist_embedding=dist_embedding)
     if seq_axis:
@@ -179,8 +146,7 @@ def transformer(src_ids, trg_ids, trg_labels, pos_src, pos_trg,
         dec = decoder_layer(dec, enc, d_model, n_head, d_inner,
                             self_mask, src_mask, dropout,
                             self_causal=self_causal, seq_axis=seq_axis,
-                            seq_impl=seq_impl,
-                            attention_impl=attention_impl)
+                            seq_impl=seq_impl)
     logits = layers.fc(dec, size=trg_vocab, num_flatten_dims=2)
     tok_loss = layers.softmax_with_cross_entropy(logits, trg_labels)
     # Average only over non-pad target positions.
@@ -330,14 +296,14 @@ def _lm_blocks(x, n_layer, d_model, n_head, d_inner, attn_fn):
     return x
 
 
-def _sdpa_op(qh, kh, vh, mask, causal):
+def _sdpa_op(qh, kh, vh, mask, causal, **attrs):
     helper = LayerHelper("mha")
     out = helper.create_tmp_variable(qh.dtype)
     inputs = {"Q": qh, "K": kh, "V": vh}
     if mask is not None:
         inputs["Mask"] = mask
     helper.append_op(type="scaled_dot_product_attention", inputs=inputs,
-                     outputs={"Out": out}, attrs={"causal": causal})
+                     outputs={"Out": out}, attrs=dict(attrs, causal=causal))
     return out
 
 
@@ -495,8 +461,7 @@ def build_decoder_lm(vocab_size=1000, max_seq_len=64, slots=4,
 
 def build_train(src_vocab=10000, trg_vocab=10000, max_len=64, n_layer=2,
                 n_head=8, d_model=512, d_inner=2048, lr=1e-3,
-                seq_axis=None, seq_impl="ring", dist_embedding=False,
-                attention_impl="fused"):
+                seq_axis=None, seq_impl="ring", dist_embedding=False):
     import paddle_tpu as pt
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
@@ -515,7 +480,6 @@ def build_train(src_vocab=10000, trg_vocab=10000, max_len=64, n_layer=2,
                                    d_model, d_inner,
                                    causal_mask=causal, seq_axis=seq_axis,
                                    seq_impl=seq_impl,
-                                   dist_embedding=dist_embedding,
-                                   attention_impl=attention_impl)
+                                   dist_embedding=dist_embedding)
         opt.AdamOptimizer(learning_rate=lr).minimize(loss)
     return main, startup, {"loss": loss}

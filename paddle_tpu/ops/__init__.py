@@ -23,6 +23,5 @@ def all_ops():
     return OpRegistry.all_ops()
 from . import csp_ops  # noqa: F401
 from . import reader_ops  # noqa: F401
-from . import fusion_ops  # noqa: F401
 from . import augment_ops  # noqa: F401
 from . import cache_ops  # noqa: F401

@@ -273,8 +273,8 @@ def run_op_keeping_pullback(op, gop, env, extra):
     linearised at in extra[VJP_PULLBACKS] for `gop` to pop. Returns the
     op's outputs, or None (run it plainly) where an input is not in env
     or `op` is not the op `gop` embeds: same wiring, but an attr of the
-    embedded copy has another value here (names are re-used by the
-    rewrite passes; markers stamped on `op` alone are fine)."""
+    embedded copy has another value here (a name can be written twice;
+    markers stamped on `op` alone are fine)."""
     replay_names = gop.inputs["FwdIn"]
     try:
         in_vals = tuple(env[n] for n in replay_names)

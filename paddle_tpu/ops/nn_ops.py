@@ -702,33 +702,28 @@ def _sdpa(ctx):
             head_axis=ctx.attr("head_axis", "model")))
         return
 
-    # Explicit softmax scale (attr "scale"): stamped by the rewrite
-    # layer when it outlines a composed attention chain, preserving the
-    # user's exact scaling; None keeps the standard 1/sqrt(d_key).
-    sm_scale = ctx.attr("scale", None)
-    sm_scale = None if sm_scale is None else float(sm_scale)
-
     use_flash = ctx.attr("use_flash", None)
-    if use_flash and q.ndim != 4:
-        # the flash kernel's layout is [B, H, S, D]; an outlined 3-D
-        # attention keeps the (identical-math) naive composition
-        use_flash = False
     if use_flash is None:
-        # measured crossover on v5e (bf16, h8 d64, fwd+bwd, marginal
-        # protocol): naive/XLA wins 1.56x at S=256, parity at S=512,
-        # flash wins 2.5x at S=1024 and 5.6x at S=4096 — the S^2 score
-        # materialization only starts to bind around 512. Round 2's
-        # threshold of 128 routed the transformer bench's S=256 through
-        # flash and cost it ~35% end-to-end. (Round-3 numbers, taken
-        # before this tree's first chip_smoke.py run; not re-measured.)
+        # the op leaves the choice open: PADDLE_TPU_PALLAS_SDPA decides.
+        # "force" engages the kernel anywhere, "0" pins the composition,
+        # "1" (a TPU only) leaves it to the measured crossover on v5e
+        # (bf16, h8 d64, fwd+bwd, marginal protocol): naive/XLA wins
+        # 1.56x at S=256, parity at S=512, flash wins 2.5x at S=1024 and
+        # 5.6x at S=4096 — the S^2 score materialization only starts to
+        # bind around 512. Round 2's threshold of 128 routed the
+        # transformer bench's S=256 through flash and cost it ~35%
+        # end-to-end. (Round-3 numbers, taken before this tree's first
+        # chip_smoke.py run; not re-measured.)
+        from .pallas import pallas_dispatch
+        enabled, interp = pallas_dispatch("PADDLE_TPU_PALLAS_SDPA", "1")
+        forced = interp is None
         min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "512"))
-        use_flash = (jax.default_backend() == "tpu" and q.ndim == 4
-                     and q.shape[2] >= min_seq
-                     and k.shape[2] >= min_seq)
+        use_flash = (enabled and q.ndim == 4
+                     and (forced or (q.shape[2] >= min_seq
+                                     and k.shape[2] >= min_seq)))
     if use_flash:
         from .pallas import flash_attention
-        attend = functools.partial(flash_attention, causal=causal,
-                                   sm_scale=sm_scale)
+        attend = functools.partial(flash_attention, causal=causal)
         if mesh is None:
             out = attend(q, k, v, mask)
         else:
@@ -738,8 +733,7 @@ def _sdpa(ctx):
                 ctx.attr("head_axis", "model"))
         ctx.set_output("Out", out)
         return
-    scale = sm_scale if sm_scale is not None \
-        else 1.0 / np.sqrt(q.shape[-1])
+    scale = 1.0 / np.sqrt(q.shape[-1])
     scores = jnp.einsum("...qd,...kd->...qk", q, k) * scale
     if mask is not None:
         scores = scores + mask

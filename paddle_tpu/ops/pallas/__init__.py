@@ -29,17 +29,14 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def pallas_dispatch(knob_env: str, default: str, attr=None):
-    """Shared policy for op-level kernel dispatch: returns
-    (enabled, interpret). "1" enables on TPU only, "force" enables
-    anywhere via interpret mode (test coverage), "0" disables.
-
-    ``attr`` is a program-level override stamped onto the op by the
-    rewrite layer's kernel_dispatch pass (analysis/rewrite.py): when
-    present it replaces the env read, making the dispatch decision part
-    of the IR instead of trace-time environment sniffing.
+def pallas_dispatch(knob_env: str, default: str):
+    """The one policy for op-level kernel dispatch, read when the op's
+    rule is traced (and again by its grad op, which differentiates the
+    same rule): returns (enabled, interpret). "1" enables on TPU only,
+    "force" enables anywhere via interpret mode (test coverage), "0"
+    disables.
     """
-    knob = attr if attr is not None else os.environ.get(knob_env, default)
+    knob = os.environ.get(knob_env, default)
     if knob == "force":
         return True, None          # None -> interpret_default() inside
     return (knob == "1" and jax.default_backend() == "tpu"), False

@@ -370,8 +370,7 @@ def _lstm(ctx):
     # fwd+bwd than the unrolled scan on chip. Standard gates only;
     # PADDLE_TPU_PALLAS_LSTM=0 disables.
     from .pallas import pallas_dispatch
-    enabled, interp = pallas_dispatch("PADDLE_TPU_PALLAS_LSTM", "1",
-                                      attr=ctx.attr("__pallas__"))
+    enabled, interp = pallas_dispatch("PADDLE_TPU_PALLAS_LSTM", "1")
     eligible = (
         not use_peepholes
         and ctx.attr("gate_activation", "sigmoid") == "sigmoid"
@@ -446,8 +445,7 @@ def _gru(ctx):
     # default ON: measured ~1.8x over the scan path on v5e (20-layer
     # stacked GRU, b64 t100 h512, marginal-cost protocol, 2 runs each)
     from .pallas import pallas_dispatch
-    enabled, interp = pallas_dispatch("PADDLE_TPU_PALLAS_GRU", "1",
-                                      attr=ctx.attr("__pallas__"))
+    enabled, interp = pallas_dispatch("PADDLE_TPU_PALLAS_GRU", "1")
     eligible = (ctx.attr("gate_activation", "sigmoid") == "sigmoid"
                 and ctx.attr("activation", "tanh") == "tanh")
     if enabled and eligible:

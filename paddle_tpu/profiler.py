@@ -115,7 +115,7 @@ CAT_RPC = "rpc"
 # trainer::telemetry around the per-step metrics block.
 CAT_TRAINER = "trainer"
 # What a first dispatch pays before it can run (core/executor.py):
-# compile::verify|rewrite|memory_plan|cost_model around the program's
+# compile::verify|memory_plan|cost_model around the program's
 # own analyses, and compile::jax_trace|lower|backend|cache_retrieval
 # emitted closed from JAX's compile-phase events. An inner jit fires its
 # own events inside an outer one's: take the UNION of a name's

@@ -20,24 +20,14 @@ therefore:
 release_memory() is the reference's lighter sibling: annotation only, no
 reordering (here they share the implementation).
 
-Successor note (ISSUE 8): `paddle_tpu.analysis.rewrite` is this
-transpiler's successor — a verified rewrite pipeline on the analysis
-pass framework (DCE/CSE/constant folding/fusion outlining) that runs
-automatically on every executor compile-cache miss instead of as a
-user-invoked program mutation, with every pass gated by the static
-verifier. This module stays for the `__dead_vars__` trace-time
-annotation (which the rewrite layer respects and scrubs where its
-renames would invalidate them) and for reference API parity.
-
-Successor note (ISSUE 20): the reference's headline behavior — actual
-in-place var reuse driven by liveness — now lives in the verified
-pipeline too: `analysis/memory.py` is the planner (per-var live
-intervals, arena + ideal peak-HBM estimates, the executor's
-pre-compile `hbm-oom` gate) and the `inplace_reuse` rewrite pass is
-the reuse transform (dead-interval buffer renaming, adopted only when
-the post-pass verifier is clean, gated by the bit-exact loss-identity
-test). New code should call `analysis.memory.program_memory` /
-rely on the default rewrite pipeline rather than `memory_optimize()`.
+Successor note: the planner half of this lives in `analysis/memory.py`
+(per-var live intervals, arena + free-at-last-use peak-HBM estimates,
+the executor's pre-compile `hbm-oom` gate); new code should call
+`analysis.memory.program_memory`. The in-place renaming half had a
+successor too (PRs 8 and 20, a rewrite pipeline on every compile-cache
+miss); PR 31 deleted it, because XLA's buffer assignment repeats every
+renaming (PERF.md section 6). This module stays for the `__dead_vars__`
+trace-time annotation and for reference API parity.
 """
 from __future__ import annotations
 

@@ -120,6 +120,30 @@ def test_cost_model_vjp_doubles_forward():
                 and c.note and "mul" in c.note]
     assert mul_vjps and mul_vjps[0].flops == 2 * mul.flops
 
+    # the attention op has its own rule (two seq^2 contractions and the
+    # softmax), and its grad op books twice that
+    from paddle_tpu.layer_helper import LayerHelper
+    B, H, S, D = 2, 2, 8, 4
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        q = layers.fc(layers.data("q", [H, S, D]), size=D,
+                      num_flatten_dims=3, bias_attr=False)
+        helper = LayerHelper("sdpa")
+        out = helper.create_tmp_variable("float32")
+        helper.append_op(type="scaled_dot_product_attention",
+                         inputs={"Q": q, "K": q, "V": q},
+                         outputs={"Out": out})
+        pt.optimizer.SGDOptimizer(learning_rate=0.01).minimize(
+            layers.mean(out))
+    cost = cost_model.program_cost(main, feed_shapes={"q": (B, H, S, D)})
+    (sdpa,) = [c for c in cost.ops
+               if c.op_type == "scaled_dot_product_attention"]
+    assert sdpa.exact
+    assert sdpa.flops == 4 * B * H * S * S * D + 5 * B * H * S * S
+    (sdpa_vjp,) = [c for c in cost.ops if c.op_type == "__vjp__"
+                   and c.note and "scaled_dot" in c.note]
+    assert sdpa_vjp.flops == 2 * sdpa.flops
+
 
 def test_cost_model_pass_attaches_report_cost():
     main, startup, loss = _build_mlp()

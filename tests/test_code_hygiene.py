@@ -18,9 +18,14 @@ metric-name lint from the observability PR (tests/test_metric_names.py):
   stateful constructor (``threading.Lock()``, optimizer-state
   materialization) runs and is thrown away, and the discarded object's
   side effects already happened.
+
+And two checks of the flag table (paddle_tpu/flags.py) against the
+code: a flag whose reader was deleted must leave the table with it, and
+an environment variable the package reads must be in the table.
 """
 import ast
 import os
+import re
 
 import pytest
 
@@ -212,3 +217,56 @@ def test_lint_rules_allow_benign_forms(snippet):
     tree = ast.parse(snippet)
     assert not list(_thread_without_daemon(tree))
     assert not list(_setdefault_with_side_effectful_default(tree))
+
+
+# ---------------------------------------------------------------------------
+# the flag table against the code
+# ---------------------------------------------------------------------------
+_ROOT = os.path.dirname(_PKG)
+_FLAG_NAME = re.compile(r"(PADDLE_TPU|BENCH)_[A-Z0-9_]+")
+
+
+def _string_constants(paths):
+    """Every whole string literal of `paths` (a docstring that merely
+    mentions a name is a longer string and does not count)."""
+    for path in paths:
+        for node in ast.walk(_parse(path)):
+            if isinstance(node, ast.Constant) \
+                    and isinstance(node.value, str):
+                yield node.value, path
+
+
+def _package_code():
+    return [p for p in _py_files()
+            if os.path.basename(p) != "flags.py"]
+
+
+def test_every_registered_flag_is_read():
+    """A name in flags.FLAGS is a string some code hands to an
+    environment read: in the package or, for the BENCH_* rows, in
+    bench.py / benchmarks/. A flag left in the table after its reader
+    went fails here."""
+    from paddle_tpu import flags
+    harness = [os.path.join(_ROOT, "bench.py")] + [
+        os.path.join(_ROOT, "benchmarks", f)
+        for f in sorted(os.listdir(os.path.join(_ROOT, "benchmarks")))
+        if f.endswith(".py")]
+    named = {s for s, _ in _string_constants(_package_code() + harness)}
+    unread = sorted(n for n in flags.FLAGS if n not in named)
+    assert not unread, (
+        "flags.FLAGS rows nothing reads (delete the row with its "
+        f"reader): {unread}")
+
+
+def test_every_env_read_is_registered():
+    """Every PADDLE_TPU_* / BENCH_* name the package uses as a string
+    (os.environ.get and the helpers that wrap it) has its row in
+    flags.FLAGS."""
+    from paddle_tpu import flags
+    missing = sorted({
+        f"{s} ({_rel(path)})"
+        for s, path in _string_constants(_package_code())
+        if _FLAG_NAME.fullmatch(s) and s not in flags.FLAGS})
+    assert not missing, (
+        "environment variables read but not in paddle_tpu/flags.py: "
+        f"{missing}")

@@ -9,7 +9,7 @@ from paddle_tpu import layers, profiler
 from paddle_tpu.observability import default_registry
 from paddle_tpu.trainer import Trainer
 
-OWN_PHASES = ("verify", "rewrite", "memory_plan", "cost_model")
+OWN_PHASES = ("verify", "memory_plan", "cost_model")
 JAX_PHASES = ("jax_trace", "lower", "backend")
 
 
@@ -104,7 +104,7 @@ def test_jax_phases_lie_inside_the_first_dispatch(started):
             # an emitted span ends "now" on the listener's clock
             assert lo - 1e3 <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e3
     (prepare,) = h.named("pipeline::prepare")
-    for phase in ("rewrite", "memory_plan", "cost_model"):
+    for phase in ("memory_plan", "cost_model"):
         (e,) = h.named("compile::" + phase)
         assert prepare["ts"] <= e["ts"] and \
             e["ts"] + e["dur"] <= prepare["ts"] + prepare["dur"]

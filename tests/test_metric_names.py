@@ -19,10 +19,6 @@ def _smoke_train_and_serve(tmp_path):
         x = layers.data("x", [4])
         label = layers.data("label", [1])
         pred = layers.fc(x, size=2)
-        # dead op: guarantees the rewrite pipeline (ISSUE 8) records a
-        # dce action on this smoke program, so the rewrite families
-        # below are populated
-        layers.scale(x, 2.0)
         loss = layers.mean(layers.square(pred - label))
         pt.optimizer.SGDOptimizer(learning_rate=0.05).minimize(loss)
     trainer = Trainer(loss, main_program=main, startup_program=startup)
@@ -136,9 +132,6 @@ def test_registry_names_and_help_after_smoke_run(tmp_path, monkeypatch):
                      "paddle_tpu_serving_shed_total",
                      "paddle_tpu_serving_model_version",
                      "paddle_tpu_serving_canary_requests_total",
-                     # ISSUE 8: rewrite-pipeline families
-                     "paddle_tpu_rewrite_seconds",
-                     "paddle_tpu_rewrite_ops_total",
                      # ISSUE 16: token-serving families
                      "paddle_tpu_decode_requests_total",
                      "paddle_tpu_decode_tokens_total",
@@ -164,9 +157,8 @@ def test_registry_names_and_help_after_smoke_run(tmp_path, monkeypatch):
                      "paddle_tpu_embed_cache_refreshes_total",
                      "paddle_tpu_embed_cache_staleness_steps",
                      "paddle_tpu_embed_table_rows",
-                     # ISSUE 20: memory-planner families
+                     # ISSUE 20: memory-planner family
                      "paddle_tpu_memory_peak_bytes",
-                     "paddle_tpu_memory_reuse_bytes_total",
                      # ISSUE 25: what a compile cost, each request's
                      # wait for a slot and time to first token
                      "paddle_tpu_compile_phase_seconds_total",
@@ -179,11 +171,6 @@ def test_registry_names_and_help_after_smoke_run(tmp_path, monkeypatch):
     gen_shed = {key for key, _ in
                 reg.get("paddle_tpu_decode_shed_total").samples()}
     assert any(k[1] == "model_budget" for k in gen_shed), gen_shed
-    # the smoke program carries a deliberately-dead op: the rewrite
-    # ledger must book its removal under {pass="dce", action="remove_op"}
-    rw = {key for key, _ in
-          reg.get("paddle_tpu_rewrite_ops_total").samples()}
-    assert ("dce", "remove_op") in rw, rw
     # the hot-swap left exactly one live version series (v2=1, v1=0)
     # for THIS host — other tests' hosts share the global registry, so
     # scope by the host label instead of asserting across the process

@@ -133,28 +133,6 @@ def _build_sdpa_hand():
     return main, startup, loss, _att_feed(), {"flash": 2}
 
 
-def _build_sdpa_outlined():
-    """A composed matmul-softmax-matmul chain the rewrite layer outlines
-    into one SDPA mega-op with one merged `__vjp__`."""
-    H, S, D = _ATT["H"], _ATT["S"], _ATT["D"]
-    main, startup = pt.Program(), pt.Program()
-    with pt.program_guard(main, startup):
-        q = layers.data("q", [H, S, D])
-        k = layers.data("k", [H, S, D])
-        v = layers.data("v", [H, S, D])
-        label = layers.data("label", [H, S, D])
-        qp, kp, vp = [layers.fc(t, size=D, num_flatten_dims=3,
-                                bias_attr=False) for t in (q, k, v)]
-        scores = layers.matmul(qp, kp, transpose_y=True,
-                               alpha=float(1.0 / np.sqrt(D)))
-        mask = layers.assign(
-            np.triu(np.full((S, S), -1e9, np.float32), k=1))
-        probs = layers.softmax(layers.elementwise_add(scores, mask))
-        loss = layers.mean(layers.square(layers.matmul(probs, vp) - label))
-        pt.optimizer.SGDOptimizer(learning_rate=0.1).minimize(loss)
-    return main, startup, loss, _att_feed(), {"flash": 1}
-
-
 def _ragged_ids(seed=1, vocab=50):
     rng = np.random.RandomState(seed)
     data = rng.randint(0, vocab, size=(10, 1)).astype(np.int64)
@@ -211,12 +189,11 @@ def _mesh_executor():
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("builder,exe_factory", [
     (_build_sdpa_hand, pt.Executor),
-    (_build_sdpa_outlined, pt.Executor),
     (_build_sdpa_hand, _mesh_executor),
     (_build_lstm, pt.Executor),
     (_build_gru, pt.Executor),
-], ids=["sdpa-hand-placed", "sdpa-outlined-by-rewrite",
-        "sdpa-under-shard_map", "fused_lstm", "fused_gru"])
+], ids=["sdpa-hand-placed", "sdpa-under-shard_map", "fused_lstm",
+        "fused_gru"])
 def test_one_forward_kernel_call_a_site(builder, exe_factory, monkeypatch):
     main, exe, sites, delta = _run_once(builder, monkeypatch, exe_factory)
     calls = _kernel_calls(_step_jaxpr(exe, main))
@@ -608,9 +585,10 @@ def test_counter_reads_the_attention_sites_of_a_transformer(monkeypatch):
     calls = _kernel_calls(_step_jaxpr(exe, main))
     assert dict(calls) == dict(flash_fwd=6, flash_bwd_dq=6,
                                flash_bwd_dkv=6)
-    # every other site is reused too, but the two whose grad op the
-    # rewrite layer wired to another name than the forward op reads
-    assert _served(c.delta, "replayed") <= 2
+    # and so is every other site: the program is traced as built, so
+    # each grad op embeds exactly the wiring its forward op has (output
+    # names included) and no site of this model replays
+    assert _served(c.delta, "replayed") == 0
     assert _served(c.delta, "reused") > 100
 
 
@@ -656,3 +634,63 @@ def test_counter_replays_snapshots_and_counts_no_probe(monkeypatch):
     replayed = {op: v for (s_, op), v in c.delta.items() if s_ == "replayed"}
     assert replayed == {"while": 1}
     assert _served(c.delta, "reused") >= 3
+
+
+# ---------------------------------------------------------------------------
+# (6) the knob that chooses the kernel where the op leaves it open
+# ---------------------------------------------------------------------------
+def _knob_step(monkeypatch, knob, attrs, grad):
+    """(kernel calls of the step, its loss) of one attention op carrying
+    `attrs`, traced under PADDLE_TPU_PALLAS_SDPA=`knob`."""
+    from paddle_tpu.layer_helper import LayerHelper
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", knob)
+    pt.reset_default_programs()
+    pt.reset_global_scope()
+    H, S, D = _ATT["H"], _ATT["S"], _ATT["D"]
+    main, startup = pt.Program(), pt.Program()
+    main.random_seed = startup.random_seed = 3
+    with pt.program_guard(main, startup):
+        q, k, v, label = (layers.data(n, [H, S, D])
+                          for n in ("q", "k", "v", "label"))
+        proj = [layers.fc(t, size=D, num_flatten_dims=3, bias_attr=False)
+                for t in (q, k, v)]
+        helper = LayerHelper("sdpa")
+        out = helper.create_tmp_variable("float32")
+        helper.append_op(type=SDPA, inputs=dict(zip("QKV", proj)),
+                         outputs={"Out": out},
+                         attrs=dict(attrs, causal=True))
+        loss = layers.mean(layers.square(out - label))
+        if grad:
+            pt.optimizer.SGDOptimizer(learning_rate=0.1).minimize(loss)
+    exe = pt.Executor()
+    exe.run(startup)
+    (lv,) = exe.run(main, feed=_att_feed(), fetch_list=[loss])
+    return dict(_kernel_calls(_step_jaxpr(exe, main))), float(lv)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "minimize"])
+@pytest.mark.parametrize("attrs", [{}, {"use_flash": False},
+                                   {"use_flash": True}],
+                         ids=["attr-absent", "attr-False", "attr-True"])
+@pytest.mark.parametrize("knob", ["1", "0", "force"])
+def test_sdpa_knob_chooses_the_kernel(knob, attrs, grad, monkeypatch):
+    """The step holds the flash kernels exactly when the op asks for
+    them itself, or leaves the choice open and the knob says `force`
+    (off the TPU `1` keeps the composition whatever the length); the
+    op's own attr wins over the knob either way; and with a grad op the
+    backward kernels come with the forward one, because the grad op
+    differentiates the same rule."""
+    calls, loss = _knob_step(monkeypatch, knob, attrs, grad)
+    want = {}
+    if attrs.get("use_flash", knob == "force"):
+        want = {"flash_fwd": 1}
+        if grad:
+            want.update(flash_bwd_dq=1, flash_bwd_dkv=1)
+    assert calls == want
+    composed_calls, composed = _knob_step(
+        monkeypatch, "0", {"use_flash": False}, grad)
+    assert composed_calls == {}
+    if want:
+        np.testing.assert_allclose(loss, composed, rtol=1e-5)
+    else:
+        assert loss == composed

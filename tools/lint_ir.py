@@ -117,8 +117,8 @@ def _build_static_rnn():
     with pt.program_guard(main, startup):
         # [T, B, D]: the executable StaticRNN shape regime (a 1-D [D]
         # step input would make fc size its weight [1, D] at build
-        # time, so the network could verify but never run — the
-        # rewrite layer's loss-identity gate executes every network)
+        # time, so the network could verify but never run —
+        # tests/test_compile_path.py executes every network)
         x = layers.data("x", [5, 4, 8], append_batch_size=False)
         rnn = layers.StaticRNN()
         with rnn.step():
@@ -248,75 +248,6 @@ def lint_model_dir(dirname: str):
         program_label=f"model dir {dirname!r}")
 
 
-def optimize_report(network: str = None, model_dir: str = None,
-                    batch: int = 1, train_fetch: bool = False):
-    """Run the rewrite pipeline (analysis/rewrite.py) offline over the
-    target program and return a JSON-able summary: per-pass action
-    counts, op counts before/after, and the static FLOPs/bytes delta
-    from the cost model. ``train_fetch=True`` restricts the fetch set
-    to the first declared fetch (the training loop's loss-only stance —
-    auxiliary metric heads then count as dead)."""
-    from paddle_tpu.analysis import cost_model, rewrite
-    if network:
-        main, _startup, feeds, fetches = NETWORKS[network]()
-        label = f"network {network!r}"
-    else:
-        main, feeds, fetches = _load_model_dir(model_dir)
-        label = f"model dir {model_dir!r}"
-    if train_fetch and fetches:
-        fetches = fetches[:1]
-    desc = main.desc if hasattr(main, "desc") else main
-    before = cost_model.program_cost(desc, batch=batch, label=label)
-    res = rewrite.rewrite_program(desc, feed_names=feeds,
-                                  fetch_names=fetches, label=label)
-    after = cost_model.program_cost(res.program, batch=batch,
-                                    label=label)
-    n_before = sum(len(b.ops) for b in desc.blocks)
-    n_after = sum(len(b.ops) for b in res.program.blocks)
-    summary = res.summary()
-    summary.update({
-        "target": label,
-        "fetches": list(fetches),
-        "ops_before": n_before, "ops_after": n_after,
-        "ops_removed": summary["passes"].get("dce", {})
-        .get("remove_op", 0) + summary["passes"].get("cse", {})
-        .get("merge_op", 0),
-        "outlined": sum(v.get("outline", 0)
-                        for v in summary["passes"].values()),
-        "flops_before": before.flops, "flops_after": after.flops,
-        "bytes_before": before.bytes_accessed,
-        "bytes_after": after.bytes_accessed,
-        "flops_delta_pct": round(
-            100.0 * (after.flops - before.flops) / before.flops, 2)
-        if before.flops else 0.0,
-        "bytes_delta_pct": round(
-            100.0 * (after.bytes_accessed - before.bytes_accessed)
-            / before.bytes_accessed, 2) if before.bytes_accessed
-        else 0.0,
-    })
-    return summary
-
-
-def render_optimize_summary(s: dict) -> str:
-    lines = [f"optimize {s['target']}: {s['ops_before']} -> "
-             f"{s['ops_after']} ops in {s['seconds'] * 1e3:.1f} ms "
-             f"({'changed' if s['changed'] else 'no change'})"]
-    for pname, acts in sorted(s["passes"].items()):
-        acc = ", ".join(f"{a}={c}" for a, c in sorted(acts.items()))
-        lines.append(f"  pass {pname:16s} {acc}")
-    for pname in s["aborted"]:
-        lines.append(f"  pass {pname:16s} ABORTED (post-rewrite "
-                     f"verification failed; changes discarded)")
-    lines.append(
-        f"  static cost: {s['flops_before'] / 1e6:.3f} -> "
-        f"{s['flops_after'] / 1e6:.3f} MFLOP "
-        f"({s['flops_delta_pct']:+.2f}%), "
-        f"{s['bytes_before'] / 1e6:.2f} -> "
-        f"{s['bytes_after'] / 1e6:.2f} MB accessed "
-        f"({s['bytes_delta_pct']:+.2f}%)")
-    return "\n".join(lines)
-
-
 def cost_report(network: str = None, model_dir: str = None,
                 batch: int = 1):
     """Build/load the target program and return its ProgramCost."""
@@ -378,16 +309,6 @@ def main(argv=None) -> int:
                          "(analysis/memory.py: peak bytes, high-water "
                          "op, top live tensors) instead of running "
                          "the verifier")
-    ap.add_argument("--optimize", action="store_true",
-                    help="run the rewrite pipeline "
-                         "(analysis/rewrite.py) offline and print the "
-                         "per-pass summary: ops removed/merged/folded, "
-                         "subgraphs outlined, static FLOPs/bytes delta")
-    ap.add_argument("--train-fetch", action="store_true",
-                    help="--optimize: restrict the fetch set to the "
-                         "first declared fetch (the training loop's "
-                         "loss-only stance; auxiliary metric heads "
-                         "then count as dead)")
     ap.add_argument("--batch", type=int, default=1,
                     help="--cost/--memory: batch size bound to "
                          "dynamic (-1) dims (default 1)")
@@ -417,16 +338,6 @@ def main(argv=None) -> int:
         limit = min(args.limit, 10) if args.limit == 20 else args.limit
         print(mem.to_json(indent=2) if args.json
               else mem.table(limit=limit))
-        return 0
-
-    if args.optimize:
-        import json
-        summary = optimize_report(network=args.network,
-                                  model_dir=args.model_dir,
-                                  batch=args.batch,
-                                  train_fetch=args.train_fetch)
-        print(json.dumps(summary, indent=2) if args.json
-              else render_optimize_summary(summary))
         return 0
 
     if args.network:
