@@ -35,6 +35,7 @@ uses the chip: the streaming workers are numpy-only children.
 from __future__ import annotations
 
 import argparse
+import collections
 import contextlib
 import gc
 import json
@@ -305,6 +306,14 @@ def write_shards(workdir, rng, cfg, n_shards=2):
     return paths
 
 
+def sdpa_sites():
+    """Counter of "path/mask/causal": attention sites traced so far."""
+    from paddle_tpu.observability import default_registry
+    fam = default_registry().get("paddle_tpu_sdpa_sites_total")
+    return collections.Counter() if fam is None else collections.Counter(
+        {"/".join(labels): child.value for labels, child in fam.samples()})
+
+
 def build_transformer(cfg):
     from paddle_tpu.models import transformer
     return transformer.build_train(
@@ -356,9 +365,11 @@ def phase_train(sm, cfg, device, workdir):
     # off-TPU the op keeps the naive path unless forced; the rehearsal
     # forces the kernels (interpret mode) so the same code is traced
     force = {} if on_tpu else {"PADDLE_TPU_PALLAS_SDPA": "force"}
+    sites_before = sdpa_sites()
     with env(**force):
         trainer.train(1, lambda: [batch0] * cfg["steps"],
                       event_handler=handler)
+    sites = dict(sdpa_sites() - sites_before)
     entry = next(v for k, v in exe._cache.items() if k[0] == main.desc.uid)
     compiled = aot_compiled_for(exe, main)
     flash_calls = compiled.as_text().count("tpu_custom_call")
@@ -390,6 +401,7 @@ def phase_train(sm, cfg, device, workdir):
                             built_by_this_run=not cfg["lib_existed"]),
         tpu_custom_calls=dict(train_step=flash_calls,
                               naive_eval=naive_calls),
+        sdpa_sites=sites,
         compile_cache=dict(exe.cache_stats),
         memory=dict(planner_peak_bytes=entry.memory.peak_bytes
                     if entry.memory else None,
@@ -416,11 +428,20 @@ def phase_train(sm, cfg, device, workdir):
              "(no second compile)")
     sm.check(stats.get("delivered") == cfg["stream_steps"],
              "train: the input service delivered every streaming batch")
+    # encoder self, decoder self and cross attention in every layer;
+    # only the decoder's own is causal, and no site is handed a mask
+    # with a query axis (paddle_tpu_sdpa_sites_total{path,mask,causal})
+    n_sites = 3 * cfg["n_layer"]
+    sm.check(sites == {"flash/key_row/0": n_sites - cfg["n_layer"],
+                       "flash/key_row/1": cfg["n_layer"]},
+             "train: every attention site of the step took the flash "
+             "kernels with a key-row mask, the decoder's own with the "
+             "causal flag, none with a dense mask", sites=sites)
     if on_tpu:
-        sm.check(flash_calls >= 3 and naive_calls == 0,
-                 "train: flash kernel in the train step's HLO, none in "
-                 "the naive program", train_step=flash_calls,
-                 naive_eval=naive_calls)
+        sm.check(flash_calls == 3 * n_sites and naive_calls == 0,
+                 "train: forward, dq and dkv kernel of every attention "
+                 "site in the train step's HLO, none in the naive "
+                 "program", train_step=flash_calls, naive_eval=naive_calls)
     exe.close()
 
 

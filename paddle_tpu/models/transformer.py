@@ -2,7 +2,14 @@
 python/paddle/fluid/tests/unittests/transformer_model.py, used by
 test_parallel_executor.py:419). Multi-head attention runs through the
 fused scaled_dot_product_attention op; everything is dense [batch, len]
-with padding masks, the TPU-native shape regime."""
+with padding masks, the TPU-native shape regime.
+
+Masks reach the op as structure, never as a [Sq, Sk] array: padding is
+a key-row mask [b, 1, 1, Sk] (-1e9 at pad keys, _pad_attn_mask) and
+causality is the op's 'causal' attr, on the decoder's self-attention of
+every program built here. The flash kernels then fetch one mask row a
+key block and skip the tiles above the diagonal, and the ring of the
+context-parallel path rotates the row with its K/V block."""
 from __future__ import annotations
 
 import numpy as np
@@ -72,10 +79,11 @@ def encoder_layer(x, d_model, n_head, d_inner, mask=None, dropout=0.0,
 
 
 def decoder_layer(x, enc_out, d_model, n_head, d_inner, self_mask=None,
-                  cross_mask=None, dropout=0.0, self_causal=False,
-                  seq_axis=None, seq_impl="ring"):
+                  cross_mask=None, dropout=0.0, seq_axis=None,
+                  seq_impl="ring"):
+    # self_mask is the target's key-row pad mask: causality is the attr
     self_attn = multi_head_attention(x, x, x, d_model, n_head, self_mask,
-                                     dropout, causal=self_causal,
+                                     dropout, causal=True,
                                      seq_axis=seq_axis, seq_impl=seq_impl)
     x = _add_norm(x, self_attn, d_model)
     cross = multi_head_attention(x, enc_out, enc_out, d_model, n_head,
@@ -116,8 +124,8 @@ def _pad_attn_mask(ids, pad_id=0):
 def transformer(src_ids, trg_ids, trg_labels, pos_src, pos_trg,
                 src_vocab=10000, trg_vocab=10000, max_len=64, n_layer=2,
                 n_head=8, d_model=512, d_inner=2048, dropout=0.0,
-                causal_mask=None, pad_id=0, seq_axis=None,
-                seq_impl="ring", dist_embedding=False):
+                pad_id=0, seq_axis=None, seq_impl="ring",
+                dist_embedding=False):
     src_mask = _pad_attn_mask(src_ids, pad_id)
     enc = embed(src_ids, src_vocab, d_model, max_len, pos_src,
                 dist_embedding=dist_embedding)
@@ -126,27 +134,11 @@ def transformer(src_ids, trg_ids, trg_labels, pos_src, pos_trg,
                             dropout, seq_axis=seq_axis, seq_impl=seq_impl)
     dec = embed(trg_ids, trg_vocab, d_model, max_len, pos_trg,
                 dist_embedding=dist_embedding)
-    if seq_axis:
-        if causal_mask is not None:
-            raise ValueError(
-                "seq_axis and causal_mask are mutually exclusive: ring "
-                "attention cannot consume a dense [Sq,Sk] bias; causality "
-                "is expressed via the op's 'causal' attr on the CP path")
-        # CP path: causality is an attr (ring-compatible); the pad mask
-        # stays a key-row mask that rotates with its K/V block.
-        self_mask = _pad_attn_mask(trg_ids, pad_id)
-        self_causal = True
-    else:
-        self_causal = False
-        self_mask = causal_mask
-        if causal_mask is not None:
-            trg_mask = _pad_attn_mask(trg_ids, pad_id)
-            self_mask = layers.elementwise_add(trg_mask, causal_mask)
+    trg_mask = _pad_attn_mask(trg_ids, pad_id)
     for _ in range(n_layer):
         dec = decoder_layer(dec, enc, d_model, n_head, d_inner,
-                            self_mask, src_mask, dropout,
-                            self_causal=self_causal, seq_axis=seq_axis,
-                            seq_impl=seq_impl)
+                            trg_mask, src_mask, dropout,
+                            seq_axis=seq_axis, seq_impl=seq_impl)
     logits = layers.fc(dec, size=trg_vocab, num_flatten_dims=2)
     tok_loss = layers.softmax_with_cross_entropy(logits, trg_labels)
     # Average only over non-pad target positions.
@@ -470,15 +462,9 @@ def build_train(src_vocab=10000, trg_vocab=10000, max_len=64, n_layer=2,
         lbl = layers.data("trg_labels", [max_len, 1], dtype="int64")
         pos = layers.data("pos_ids", [max_len], dtype="int64",
                           append_batch_size=False)
-        causal = None
-        if not seq_axis:
-            causal = layers.assign(
-                np.triu(np.full((max_len, max_len), -1e9, np.float32),
-                        k=1))
         loss, logits = transformer(src, trg, lbl, pos, pos, src_vocab,
                                    trg_vocab, max_len, n_layer, n_head,
-                                   d_model, d_inner,
-                                   causal_mask=causal, seq_axis=seq_axis,
+                                   d_model, d_inner, seq_axis=seq_axis,
                                    seq_impl=seq_impl,
                                    dist_embedding=dist_embedding)
         opt.AdamOptimizer(learning_rate=lr).minimize(loss)

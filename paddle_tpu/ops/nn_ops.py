@@ -662,6 +662,28 @@ def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,
         in_specs=specs, out_specs=spec, check_vma=False)(*args)
 
 
+def _count_sdpa_site(ctx, path, mask, causal):
+    """One count an attention site traced into a step program, in the
+    idiom of ops/cache_ops.py _count_append_site (the build-time shape
+    inference carries no program and is no site). A grad site that
+    replays its forward rule traces it, and counts, again."""
+    if "program" not in ctx.extra:
+        return
+    from ..observability.registry import default_registry
+    default_registry().counter(
+        "paddle_tpu_sdpa_sites_total",
+        "scaled_dot_product_attention sites traced into a step program, "
+        "by the path taken (flash: the Pallas kernels; composed: "
+        "matmul-softmax-matmul left to XLA; sequence_parallel: ring or "
+        "ulysses over a mesh axis), by the mask handed in (none; "
+        "key_row: one value a key, [b,1,1,Sk]; dense: a query axis "
+        "longer than 1, which the kernels read a score-sized block of "
+        "per tile) and by the causal attr (1 lets the kernels skip the "
+        "tiles above the diagonal).",
+        ("path", "mask", "causal")).labels(
+            path=path, mask=mask, causal=str(int(causal))).inc()
+
+
 @register_op("scaled_dot_product_attention", no_grad_slots=["Mask"])
 def _sdpa(ctx):
     """Fused attention (TPU-native addition; the reference composes it from
@@ -694,6 +716,8 @@ def _sdpa(ctx):
                     "sequence-parallel attention supports key-row masks "
                     "([b,1,1,Sk]); express causality via attr 'causal', "
                     f"got mask shape {mask.shape}")
+        _count_sdpa_site(ctx, "sequence_parallel",
+                         "none" if mask is None else "key_row", causal)
         ctx.set_output("Out", sequence_parallel_attention(
             q, k, v, mesh, axis=seq_axis,
             impl=ctx.attr("seq_impl", "ring"), causal=causal,
@@ -721,6 +745,10 @@ def _sdpa(ctx):
         use_flash = (enabled and q.ndim == 4
                      and (forced or (q.shape[2] >= min_seq
                                      and k.shape[2] >= min_seq)))
+    mask_kind = "none" if mask is None else (
+        "dense" if mask.ndim >= 2 and mask.shape[-2] > 1 else "key_row")
+    _count_sdpa_site(ctx, "flash" if use_flash else "composed", mask_kind,
+                     causal)
     if use_flash:
         from .pallas import flash_attention
         attend = functools.partial(flash_attention, causal=causal)

@@ -93,6 +93,34 @@ def test_trainable_bias_broadcast_grad(bias_shape):
                                atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("S", [80, 100])
+def test_causal_flag_and_key_row_bias_equal_the_dense_bias(S):
+    """What models/transformer.py hands the decoder's self-attention
+    (causal=True and a [b,1,1,S] pad mask) against the [b,1,S,S] sum of
+    triangle and pad mask it handed before ISSUE 32: forward, dq, dk,
+    dv, over 3 or 4 tiles each way of a length the tile does not
+    divide, one row half pads."""
+    B, H, D = 2, 2, 16
+    q, k, v, w = (_rand((B, H, S, D), i) for i in range(4))
+    pad = np.zeros((B, 1, 1, S), np.float32)
+    pad[0, ..., S - 5:] = -1e9
+    pad[1, ..., S // 2:] = -1e9
+    tri = np.triu(np.full((S, S), -1e9, np.float32), k=1)
+
+    def run(bias, causal):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, jnp.asarray(bias), causal=causal,
+                                block_q=32, block_k=32, interpret=True)
+            return jnp.sum(o * w), o
+        grads, o = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (o, *grads)
+
+    for name, a, b in zip(("out", "dq", "dk", "dv"), run(pad, True),
+                          run(pad + tri, False)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-6, rtol=1e-6, err_msg=name)
+
+
 def test_flash_uneven_kv_len():
     # Sq != Sk and not multiples of the block size: padding must be masked.
     B, H, Sq, Sk, D = 1, 1, 40, 72, 16

@@ -65,18 +65,22 @@ def _sum_f32(outs):
 # it per block, which interpret mode never checks against Mosaic's
 # (8, 128) tiling.
 @pytest.mark.parametrize("seq", [2048, 1100])
-@pytest.mark.parametrize("bias", ["causal", "pad_row", "dense"])
+@pytest.mark.parametrize("bias", ["causal", "pad_row", "causal_pad_row",
+                                  "dense"])
 def test_flash_attention_fwd_bwd_compiles(one_chip, seq, bias):
     q = jax.ShapeDtypeStruct((4, 8, seq, 64), jnp.bfloat16,
                              sharding=one_chip)
     # the masks models/transformer.py builds: a [B,1,1,S] pad-row mask
-    # (encoder and cross attention) and, for decoder self-attention,
-    # pad-row + dense causal broadcast to [B,1,S,S]
+    # (encoder and cross attention), the same with causal=True (decoder
+    # self-attention); a [B,1,S,S] bias is what an op handed a dense
+    # mask still reaches the kernels with
     mshape = {"causal": None, "pad_row": (4, 1, 1, seq),
+              "causal_pad_row": (4, 1, 1, seq),
               "dense": (4, 1, seq, seq)}[bias]
 
     def loss(q, k, v, m=None):
-        return _sum_f32(flash_attention(q, k, v, m, causal=mshape is None,
+        return _sum_f32(flash_attention(q, k, v, m,
+                                        causal=bias.startswith("causal"),
                                         interpret=False))
 
     args = (q, q, q) if mshape is None else (
@@ -84,6 +88,63 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, seq, bias):
                                       sharding=one_chip))
     # forward + dq + dkv
     assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), *args) == 3
+
+
+def test_train_step_holds_54_kernels_and_no_score_sized_mask(
+        one_chip, monkeypatch):
+    """The step of transformer-base.train-s2048 (chipbench/configs,
+    batch 8 x sequence 2048, AMP bf16), compiled whole for the described
+    chip: 18 attention sites x (forward, dq, dkv), and causality reaches
+    them as a flag, so nothing of [.., 2048, 2048] f32 is an operand or
+    a constant (ISSUE 32; until then the decoder's self-attention was
+    handed an f32[8,1,2048,2048] sum of triangle and pad mask)."""
+    import importlib
+    import re
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer
+
+    batch, seq, vocab = 8, 2048, 32000
+    # the rule asks jax.default_backend(), which is still the CPU here
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", "force")
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.pallas.flash_attention"),
+        "_interpret_default", lambda: False)
+    pt.reset_default_programs()
+    pt.reset_global_scope()
+    try:
+        with pt.amp.amp_guard(True):
+            main, startup, fetch = transformer.build_train(
+                src_vocab=vocab, trg_vocab=vocab, max_len=seq, n_layer=6,
+                n_head=8, d_model=512, d_inner=2048)
+            exe = pt.Executor()
+            exe.run(startup)
+            scope = pt.global_scope()
+            step = exe._compile(main.desc, main.desc.block(0), None,
+                                [fetch["loss"].name], scope)
+
+            def sds(shape, dtype):
+                return jax.ShapeDtypeStruct(shape, dtype,
+                                            sharding=one_chip)
+
+            def state(names):
+                return {n: sds(scope.get(n).shape, scope.get(n).dtype)
+                        for n in names}
+
+            feed = {n: sds((batch, seq, 1), jnp.int32)
+                    for n in ("src_ids", "trg_ids", "trg_labels")}
+            feed["pos_ids"] = sds((seq,), jnp.int32)
+            text = step.jitted.lower(
+                feed, state(step.ro_names), state(step.rw_names),
+                sds((), jnp.int32)).compile().as_text()
+    finally:
+        pt.reset_global_scope()       # a gigabyte of weights and moments
+    assert text.count("tpu_custom_call") == 54
+    score_sized = re.findall(
+        rf"f32\[(?:\d+,)*{seq},{seq}\]", text)
+    # [8, 2048, d_inner = 2048] activations are the only such shape
+    assert set(score_sized) <= {f"f32[{batch},{seq},{seq}]"}, \
+        sorted(set(score_sized))
 
 
 # bench.py's stacked-LSTM LM shapes (T=64, B=64, H=512): fused_lstm and
