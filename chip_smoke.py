@@ -497,18 +497,50 @@ def phase_serve(sm, cfg, device):
 
     # one request deep enough to leave the first cache bucket: the
     # decode step at the largest bucket runs on the device too
-    engine = model.serve(config=gcfg, mode="cached").start()
-    try:
-        deep = engine.generate(
-            rng.randint(1, cfg["vocab"], cfg["deep_prompt"]).tolist(),
-            timeout=900)
-    finally:
-        engine.stop(drain=False, timeout=60)
+    deep_prompt = rng.randint(1, cfg["vocab"], cfg["deep_prompt"]).tolist()
+
+    def generate_deep(model):
+        engine = model.serve(config=gcfg, mode="cached").start()
+        try:
+            return engine.generate(deep_prompt, timeout=900)
+        finally:
+            engine.stop(drain=False, timeout=60)
+
+    deep = generate_deep(model)
     sm.check(len(deep.tokens) == cfg["new_tokens"]
              or deep.finish_reason == "eos",
              "serve: a request crossing into the largest cache bucket "
              "completes", tokens=len(deep.tokens),
              finish=deep.finish_reason)
+    # the same request with the decode steps' attention held to the
+    # composed path (the same weights: the spec's seed draws them). On
+    # the chip the first run read the caches through the Pallas kernel
+    # (ops/pallas/decode_attention.py), so this compares the two there.
+    from paddle_tpu.observability import default_registry
+    from paddle_tpu.ops import nn_ops
+
+    def decode_attention_sites():
+        """By path, the attention sites traced that were handed a
+        KvLen: the decode programs' (mask label kv_len)."""
+        fam = default_registry().get("paddle_tpu_sdpa_sites_total")
+        return collections.Counter(
+            {labels[0]: child.value for labels, child in fam.samples()
+             if labels[1] == "kv_len"})
+
+    choose = nn_ops._decode_kernel_lane_axis
+    nn_ops._decode_kernel_lane_axis = lambda ctx, q, cache, bound: None
+    sites_before = decode_attention_sites()
+    try:
+        composed_model = GenerationModel.build(spec)
+        composed = generate_deep(composed_model)
+        composed_model.executor.close()
+    finally:
+        nn_ops._decode_kernel_lane_axis = choose
+    held = decode_attention_sites() - sites_before
+    sm.check(deep.tokens == composed.tokens,
+             "serve: the deep request's tokens through the decode "
+             "attention kernel equal the composed path's",
+             kernel=deep.tokens, composed=composed.tokens)
 
     # PR 16 called KV-cache donation "a TPU win" that CPU copies: read
     # the compiled decode steps. The cache vars are the only parameters
@@ -516,9 +548,11 @@ def phase_serve(sm, cfg, device):
     # an output in the module's input_output_alias table, and nothing
     # of that size may be a copy. On the chip the appends are Pallas
     # calls, one a cache (ops/pallas/kv_cache_append.py): the batched
-    # scatter they replace compiles to a `while` over the slots.
+    # scatter they replace compiles to a `while` over the slots. And
+    # the attention reads the caches in one Pallas call a layer
+    # (ops/pallas/decode_attention.py), where composed over a slice it
+    # was two multiply-reduce fusions a layer over every slot's bucket.
     import re
-    from paddle_tpu.observability import default_registry
     on_tpu = device.platform == "tpu"
     d_key = cfg["d_model"] // cfg["n_head"]
     top = cfg["cache_buckets"][-1]
@@ -563,11 +597,11 @@ def phase_serve(sm, cfg, device):
                  aliased=len(cache_params & aliased), copies=cache_copies)
         if on_tpu:
             sm.check(custom_calls == len(model.cache_names)
-                     and whiles == 0,
+                     + cfg["n_layer"] and whiles == 0,
                      f"serve: the decode step [{bucket}] appends to its "
-                     f"caches in Pallas calls, one a cache, and holds no "
-                     f"while loop", custom_calls=custom_calls,
-                     whiles=whiles)
+                     f"caches in Pallas calls, one a cache, attends over "
+                     f"them in one a layer, and holds no while loop",
+                     custom_calls=custom_calls, whiles=whiles)
     sites = {labels[0]: child.value for labels, child in
              default_registry().get(
                  "paddle_tpu_kv_append_sites_total").samples()}
@@ -576,6 +610,16 @@ def phase_serve(sm, cfg, device):
              "serve: every kv_cache_append site traced took the "
              + ("kernel" if on_tpu else "scatter (no TPU here)"),
              sites=sites)
+    # the decode programs' attention sites: on the chip all through
+    # the length-bounded kernel, except the model held to composing
+    own = decode_attention_sites() - held
+    path = "decode_kernel" if on_tpu else "composed"
+    sm.check(set(held) == {"composed"} and set(own) == {path},
+             "serve: every attention site of the decode programs took "
+             + ("the length-bounded kernel" if on_tpu
+                else "the composed path (no TPU here)")
+             + ", and the composed path where held to it",
+             sites=dict(own), held=dict(held))
     model.executor.close()
 
 

@@ -218,6 +218,14 @@ class ProgramCost:
 # ---------------------------------------------------------------------------
 # FLOP rules
 # ---------------------------------------------------------------------------
+def _attended_rows(op: ir.OpDesc, kv: _VarInfo) -> int:
+    """Key rows an attention site reads of ``kv``: all of them, or for
+    a cached-decode site (KvLen), which is handed the whole cache, at
+    most attr kv_bound — the static worst case, the live lengths being
+    run-time data."""
+    return min(kv.shape[-2], int(op.attrs.get("kv_bound", kv.shape[-2])))
+
+
 def _flops_for(op: ir.OpDesc,
                lookup: Callable[[str], Optional[_VarInfo]]
                ) -> Tuple[Optional[int], bool, Optional[str]]:
@@ -306,7 +314,7 @@ def _flops_for(op: ir.OpDesc,
             return None, False, None
         lead = _prod(q.shape[:-2])
         sq, d = q.shape[-2], q.shape[-1]
-        sk = k.shape[-2]
+        sk = _attended_rows(op, k)
         return (4 * lead * sq * sk * d + 5 * lead * sq * sk,
                 True, None)
 
@@ -387,8 +395,8 @@ def _bytes_override(op: ir.OpDesc,
         # cache as read+written per decoded token would overstate
         # decode-step traffic by max_seq/1 and crater reported
         # arithmetic intensity. The cache-READ traffic of attention is
-        # booked on the consumer (slice + scaled_dot_product_attention
-        # operands), not here.
+        # booked on the consumer (scaled_dot_product_attention with a
+        # KvLen, at its bound), not here.
         new_b = 0
         names = op.input("New")
         if names:
@@ -427,11 +435,26 @@ def _bytes_override(op: ir.OpDesc,
                 ids = v.bytes
         return ((3 + 2 * n_slots) * touched + ids,
                 "sparse apply: touched rows + slots only")
+    if op.type == "scaled_dot_product_attention" and op.input("KvLen"):
+        # cached decode: K and V are whole [slots, h, max_seq, d]
+        # caches of which at most kv_bound rows a slot are read — the
+        # cache read is booked here, at the bound (the slice that used
+        # to carry it is gone), plus Q, the lengths and Out
+        total = 0
+        for slot in ("K", "V"):
+            v = lookup(op.input(slot)[0])
+            if v is None:
+                return None
+            total += v.bytes // v.shape[-2] * _attended_rows(op, v)
+        for name in (op.input("Q") + op.input("KvLen")
+                     + op.output("Out")):
+            v = lookup(name)
+            if v is not None:
+                total += v.bytes
+        return total, "cached attention: rows under the bound only"
     if op.type == "slice":
-        # a slice reads exactly the rows it keeps — the decode step
-        # slices the first L rows out of a [slots, h, max_seq, d]
-        # cache, and charging the full cache read here would double the
-        # whole point of cache-length bucketing
+        # a slice reads exactly the rows it keeps, not the array it
+        # cuts them from
         out_b = 0
         for names in op.outputs.values():
             for n in names:

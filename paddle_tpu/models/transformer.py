@@ -188,8 +188,9 @@ def tp_param_specs(main, vocab_sizes=(), tp_axis="model"):
 #   "prefill"  [1, S]  same forward for one request, but every layer
 #              also writes its K/V rows into that request's cache slot
 #   "decode"   [slots, 1]  one-token step: append K/V at each slot's
-#              position, attend over the first L cached rows (L = the
-#              cache-length bucket), emit the greedy next token
+#              position, attend over each slot's live rows (the fed
+#              `lengths`, at most L = the cache-length bucket; the op
+#              gets the whole caches), emit the greedy next token
 #
 # Weight sharing works by name: each program is built under
 # framework.isolated_name_scope() and makes the IDENTICAL sequence of
@@ -288,12 +289,18 @@ def _lm_blocks(x, n_layer, d_model, n_head, d_inner, attn_fn):
     return x
 
 
-def _sdpa_op(qh, kh, vh, mask, causal, **attrs):
+def _sdpa_op(qh, kh, vh, mask, causal, kv_len=None, **attrs):
+    """``kv_len`` [b] int: K and V are whole KV caches and row b's live
+    keys are the prefix [0, kv_len[b]) — the op is told the structure
+    (attr ``kv_bound`` is the most a row holds) where a mask over a
+    slice would hide it."""
     helper = LayerHelper("mha")
     out = helper.create_tmp_variable(qh.dtype)
     inputs = {"Q": qh, "K": kh, "V": vh}
     if mask is not None:
         inputs["Mask"] = mask
+    if kv_len is not None:
+        inputs["KvLen"] = kv_len
     helper.append_op(type="scaled_dot_product_attention", inputs=inputs,
                      outputs={"Out": out}, attrs=dict(attrs, causal=causal))
     return out
@@ -345,7 +352,11 @@ def _build_lm_program(mode, seq_len, vocab_size, max_seq_len, slots,
                               append_batch_size=False)
             positions = layers.data("positions", [slots], dtype="int64",
                                     append_batch_size=False)
-            feeds = ["token_ids", "positions"]
+            # live rows of each slot's cache, this step's token counted:
+            # positions + 1 for a slot in flight, 0 for an idle one
+            lengths = layers.data("lengths", [slots], dtype="int64",
+                                  append_batch_size=False)
+            feeds = ["token_ids", "positions", "lengths"]
         else:
             b = 1 if mode == "prefill" else slots
             ids = layers.data("token_ids", [b, seq_len, 1], dtype="int64",
@@ -365,20 +376,15 @@ def _build_lm_program(mode, seq_len, vocab_size, max_seq_len, slots,
         if mode == "decode":
             # embed the single new token at each slot's own position
             x = _lm_embed(ids, positions, vocab_size, d_model, max_seq_len)
-            ar = layers.unsqueeze(layers.range(0, seq_len, 1, "int64"),
-                                  [0])                       # [1, L]
-            pos2 = layers.unsqueeze(positions, [1])          # [slots, 1]
-            mask = _key_row_mask(layers.less_equal(ar, pos2))
 
             def attn(i, qh, kh, vh):
+                # the WHOLE caches and each slot's live length reach the
+                # op; the bucket is only the bound
                 kc, vc = caches[i]
                 _cache_update("kv_cache_append", kc, kh, positions, "Pos")
                 _cache_update("kv_cache_append", vc, vh, positions, "Pos")
-                k_l = layers.slice(kc, axes=[2], starts=[0],
-                                   ends=[seq_len])
-                v_l = layers.slice(vc, axes=[2], starts=[0],
-                                   ends=[seq_len])
-                return _sdpa_op(qh, k_l, v_l, mask, causal=False)
+                return _sdpa_op(qh, kc, vc, None, causal=False,
+                                kv_len=lengths, kv_bound=seq_len)
         else:
             pos_ids = layers.assign(
                 np.arange(seq_len).astype(np.int64))

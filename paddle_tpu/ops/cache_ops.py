@@ -58,7 +58,8 @@ def _kv_cache_write(ctx):
     Cache: [slots, h, max_seq, d]; New: [1, h, S, d] (S <= max_seq);
     Slot: [1] int — the in-flight batch slot index. Rows [0, S) of the
     slot are overwritten; rows beyond S keep whatever the previous
-    occupant left (masked out by the decode-step attention mask).
+    occupant left (beyond the length the decode step's attention is
+    handed, so masked or never read).
     """
     cache = ctx.input("Cache")
     new = ctx.input("New").astype(cache.dtype)
@@ -119,7 +120,7 @@ def _kv_cache_append(ctx):
     Cache: [slots, h, max_seq, d]; New: [slots, h, 1, d]; Pos: [slots]
     int — per-slot write position. Inactive slots point Pos at 0; the
     garbage row is overwritten by that slot's next prefill and is never
-    attended to meanwhile (the additive mask covers only live rows).
+    attended to meanwhile (an idle slot's live length is 0).
 
     On a TPU all slots' rows reach the cache in one Pallas call that
     touches one tile a slot (_append_kernel_lane_axis says when);

@@ -675,16 +675,40 @@ def _count_sdpa_site(ctx, path, mask, causal):
         "scaled_dot_product_attention sites traced into a step program, "
         "by the path taken (flash: the Pallas kernels; composed: "
         "matmul-softmax-matmul left to XLA; sequence_parallel: ring or "
-        "ulysses over a mesh axis), by the mask handed in (none; "
+        "ulysses over a mesh axis; decode_kernel: the Pallas read of a "
+        "KV cache bounded by each slot's length), by the mask handed in "
+        "(none; "
         "key_row: one value a key, [b,1,1,Sk]; dense: a query axis "
         "longer than 1, which the kernels read a score-sized block of "
-        "per tile) and by the causal attr (1 lets the kernels skip the "
-        "tiles above the diagonal).",
+        "per tile; kv_len: no mask but each row's live length over a "
+        "KV cache, which path decode_kernel reads only the live blocks "
+        "of and path composed slices to the bound and masks) and by the "
+        "causal attr (1 lets the kernels skip the tiles above the "
+        "diagonal).",
         ("path", "mask", "causal")).labels(
             path=path, mask=mask, causal=str(int(causal))).inc()
 
 
-@register_op("scaled_dot_product_attention", no_grad_slots=["Mask"])
+def _decode_kernel_lane_axis(ctx, q, cache, bound):
+    """Which path a cached-decode attention site takes, decided on what
+    the trace can observe, in the idiom of ops/cache_ops.py
+    _append_kernel_lane_axis: the lane axis to hand the Pallas kernel
+    (ops/pallas/decode_attention.py), or None for the composition over
+    a slice. The kernel runs on a TPU backend, outside a mesh, for one
+    query row a slot and a cache it can serve as the device holds it;
+    there is no knob."""
+    if jax.default_backend() != "tpu" or ctx.extra.get("mesh") is not None \
+            or q.ndim != 4 or q.shape[2] != 1:
+        return None
+    from .cache_ops import device_lane_axis
+    from .pallas.decode_attention import fits
+    lane_axis = device_lane_axis(cache.shape, cache.dtype)
+    return lane_axis if fits(cache.shape, cache.dtype, lane_axis,
+                             bound) else None
+
+
+@register_op("scaled_dot_product_attention",
+             no_grad_slots=["Mask", "KvLen"])
 def _sdpa(ctx):
     """Fused attention (TPU-native addition; the reference composes it from
     matmul/softmax in python/paddle/fluid/nets.py:312).
@@ -698,6 +722,29 @@ def _sdpa(ctx):
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     mask = ctx.input("Mask")
     causal = bool(ctx.attr("causal", False))
+
+    # Cached decode: K and V are whole KV caches [slots, h, max_seq, d]
+    # and KvLen [slots] says how many of each slot's rows are live;
+    # attr kv_bound (static) is the most any slot holds this step.
+    kv_len = ctx.input("KvLen")
+    if kv_len is not None:
+        if mask is not None or causal:
+            raise ValueError("scaled_dot_product_attention: KvLen "
+                             "stands in for the mask and for causality")
+        bound = int(ctx.attr("kv_bound", k.shape[2]))
+        lane_axis = _decode_kernel_lane_axis(ctx, q, k, bound)
+        if lane_axis is not None:
+            from .pallas.decode_attention import decode_attention
+            _count_sdpa_site(ctx, "decode_kernel", "kv_len", causal)
+            ctx.set_output("Out", decode_attention(
+                q, k, v, kv_len, bound=bound, lane_axis=lane_axis))
+            return
+        # the rows under the bound, the dead ones masked: the bits of
+        # the [slots,1,1,L] additive mask over a slice this replaces
+        k, v = k[:, :, :bound], v[:, :, :bound]
+        live = jnp.arange(bound)[None, :] < kv_len[:, None]
+        mask = jnp.where(live, 0.0, -1e9).astype(
+            jnp.float32)[:, None, None, :]
 
     # Sequence/context parallelism: attr seq_axis names a mesh axis the
     # sequence dim is sharded over (parallel/context_parallel.py).
@@ -745,8 +792,10 @@ def _sdpa(ctx):
         use_flash = (enabled and q.ndim == 4
                      and (forced or (q.shape[2] >= min_seq
                                      and k.shape[2] >= min_seq)))
-    mask_kind = "none" if mask is None else (
-        "dense" if mask.ndim >= 2 and mask.shape[-2] > 1 else "key_row")
+    mask_kind = "kv_len" if kv_len is not None else (
+        "none" if mask is None else (
+            "dense" if mask.ndim >= 2 and mask.shape[-2] > 1
+            else "key_row"))
     _count_sdpa_site(ctx, "flash" if use_flash else "composed", mask_kind,
                      causal)
     if use_flash:

@@ -11,6 +11,9 @@ Pallas kernels; everything else rides XLA fusion:
   block_megakernel   a whole ResNet bottleneck block, tiled by batch
   kv_cache_append    the decode step's cache append: every slot's new
                      K or V row in one call, the cache updated in place
+  decode_attention   the decode step's attention over the KV cache: one
+                     query row a slot against the blocks of that slot's
+                     cache that hold live keys, and no others
 
 All kernels run in interpret mode on CPU (tests) and compiled on TPU.
 """

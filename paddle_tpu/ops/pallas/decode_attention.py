@@ -1,0 +1,226 @@
+"""Decode-step attention over the KV cache as one Pallas TPU kernel.
+
+A decode step attends ONE query row a slot to that slot's live prefix
+of the cache: keys ``[0, kv_len[s])`` of a ``[slots, heads, max_seq,
+d_key]`` cache whose every slot is reserved to ``max_seq``. Composed
+from einsum-softmax-einsum over a slice to the step's bucket, XLA reads
+the whole slice of every slot — the deepest slot picks the bucket, so a
+server whose slots are mostly short reads mostly padding. Here the
+device reads, for each slot, only the blocks of ``block_rows`` positions
+that hold a live key: the work is ONE flat list of (slot, block) pairs,
+built from the lengths before the call, and the kernel is one loop over
+that list whose trip count is the number of live blocks. A block past a
+slot's length, and every block of a slot of length 0, costs nothing: no
+DMA, no loop iteration. Each iteration waits for its K and V block,
+has already started the copy of the next pair's (across slots too), and
+folds the block into an online softmax; the slot's last block writes
+its context.
+
+Everything is f32: the blocks are read in the cache's dtype and widened,
+scores are a multiply and a sublane reduction on the VPU (what XLA's
+fusion of a one-row product does), softmax and the context accumulate
+in f32. Nothing goes through the MXU, so no product is rounded to bf16.
+
+The cache is taken as the DEVICE holds it (ops/cache_ops.py
+``device_lane_axis``). A d_key of 64 is held with the positions on the
+128 lanes and d_key on the sublanes, and the kernel is handed the
+``[slots, heads, d_key, max_seq]`` view of the same bytes, exactly as
+kv_cache_append.py is: a block is ``(heads, d_key, block_rows)``, the
+query and the context of slot ``s`` are one lane of a ``[heads, d_key,
+slots]`` array. A cache held row-major (``lane_axis`` 3, d_key 128) is
+left to the composed path (``fits`` says no).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_default as _interpret_default  # shared policy
+from .kv_cache_append import LANES, fits as _append_fits, sublane_tile
+
+_NEG = -1e30
+
+
+def block_rows(bound: int) -> int:
+    """Positions a block: the DMA's grain. 256 keeps a 1-MB K+V pair of
+    copies in flight (8 heads x 64 x 256 x 4 bytes each) while a short
+    slot reads little past its length; a bound that 256 does not divide
+    moves 128-lane blocks."""
+    return 256 if bound % 256 == 0 else LANES
+
+
+def fits(cache_shape, dtype, lane_axis, bound) -> bool:
+    """Whether the kernel serves this cache under this bound: one the
+    append kernel serves with its POSITIONS on the lanes (4-D, a 32- or
+    16-bit float, max_seq a whole number of 128-lane blocks), d_key a
+    whole number of sublane tiles, and the bound a whole number of
+    128-lane blocks inside the cache."""
+    return (lane_axis == 2 and _append_fits(cache_shape, dtype, 2)
+            and 0 < bound <= cache_shape[2] and bound % LANES == 0
+            and cache_shape[3] % sublane_tile(dtype) == 0)
+
+
+def kv_blocks(lengths, bound):
+    """(read, under_bound): the cache blocks one call of the kernel
+    reads for these per-slot live lengths, and the blocks a read of
+    every slot to the bound would take. One count serves K and V and
+    every layer alike. Pure numpy: the engine counts with it."""
+    rows = block_rows(bound)
+    lengths = np.clip(np.asarray(lengths, np.int64), 0, bound)
+    return int((-(-lengths // rows)).sum()), lengths.size * -(-bound // rows)
+
+
+def _work_list(kv_len, bound, rows):
+    """The live (slot, block) pairs in slot order, padded to the static
+    worst case, and how many there are."""
+    slots = kv_len.shape[0]
+    blocks = -(-kv_len // rows)                     # [slots]
+    ends = jnp.cumsum(blocks)
+    item = jnp.arange(slots * (bound // rows), dtype=jnp.int32)
+    # the slot of pair i: how many slots end at or before it (a
+    # compare and a sum; searchsorted would loop on the device)
+    slot = jnp.minimum(jnp.sum(ends[None, :] <= item[:, None], axis=1),
+                       slots - 1).astype(jnp.int32)
+    block = item - (ends - blocks)[slot]
+    return ends[-1:].astype(jnp.int32), slot, block.astype(jnp.int32)
+
+
+def _kernel(n_ref, slot_ref, block_ref, len_ref,      # scalar prefetch
+            q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *, rows, scale):
+    n_items = n_ref[0]
+
+    def copies(item, buf):
+        at = (slot_ref[item], slice(None), slice(None),
+              pl.ds(pl.multiple_of(block_ref[item] * rows, rows), rows))
+        return (pltpu.make_async_copy(k_hbm.at[at], k_buf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[at], v_buf.at[buf],
+                                      sem.at[1, buf]))
+
+    def start(item, buf):
+        for c in copies(item, buf):
+            c.start()
+
+    # a slot of length 0 has no pair in the list: its context is 0
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_items > 0)
+    def _():
+        start(0, 0)
+
+    def body(item, carry):
+        buf = item % 2
+
+        @pl.when(item + 1 < n_items)
+        def _():
+            start(item + 1, 1 - buf)
+
+        s, j = slot_ref[item], block_ref[item]
+        kv_len = len_ref[s]
+        # slot s's query is lane s % 128 of its 128-lane block
+        base = pl.multiple_of(s // LANES * LANES, LANES)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, LANES), 2)
+        mine = lane == s % LANES
+        q = jnp.sum(jnp.where(mine, q_ref[:, :, pl.ds(base, LANES)], 0.0),
+                    axis=2, keepdims=True)            # [h, d, 1]
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        for c in copies(item, buf):
+            c.wait()
+        k = k_buf[buf].astype(jnp.float32)            # [h, d, rows]
+        v = v_buf[buf].astype(jnp.float32)
+        scores = jnp.sum(q * k, axis=1, keepdims=True) * scale
+        pos = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, rows), 2)
+        scores = jnp.where(pos < kv_len, scores, _NEG)  # [h, 1, rows]
+        m_prev = m_ref[...]                           # [h, 1, LANES]
+        m_new = jnp.maximum(
+            m_prev, jnp.max(scores, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new[:, :, :1])
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
+        m_ref[...] = m_new
+        pv = p * v                                    # [h, d, rows]
+        # the lanes are summed once a slot, not once a block
+        part = pv[:, :, :LANES]
+        for c in range(1, rows // LANES):
+            part = part + pv[:, :, c * LANES:(c + 1) * LANES]
+        acc_ref[...] = alpha * acc_ref[...] + part
+
+        @pl.when((j + 1) * rows >= kv_len)
+        def _():
+            ctx = jnp.sum(acc_ref[...], axis=2, keepdims=True) \
+                / l_ref[:, :, :1]                     # [h, d, 1]
+            at = (slice(None), slice(None), pl.ds(base, LANES))
+            o_ref[at] = jnp.where(mine, ctx, o_ref[at])
+
+        return carry
+
+    jax.lax.fori_loop(0, n_items, body, 0)
+
+
+# jitted: the six sites of a decode program trace and lower ONE kernel
+# between them, as kv_cache_append's do
+@functools.partial(jax.jit, static_argnames=("bound", "rows", "interpret"))
+def _attend(q, k_cache, v_cache, kv_len, *, bound, rows, interpret):
+    slots, heads, _, d_key = k_cache.shape
+    kv_len = jnp.clip(kv_len.astype(jnp.int32), 0, bound)
+    n_items, item_slot, item_block = _work_list(kv_len, bound, rows)
+    # the query rows as the cache holds its rows: d_key on the
+    # sublanes, one slot a lane
+    q_t = jnp.transpose(q[:, :, 0, :].astype(jnp.float32), (1, 2, 0))
+    q_t = jnp.pad(q_t, ((0, 0), (0, 0), (0, -slots % LANES)))
+    whole = pl.BlockSpec(q_t.shape, lambda i, *_: (0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_kernel, rows=rows,
+                          scale=float(1.0 / np.sqrt(d_key))),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, heads, d_key, rows), k_cache.dtype),
+                pltpu.VMEM((2, heads, d_key, rows), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((heads, 1, LANES), jnp.float32),
+                pltpu.VMEM((heads, 1, LANES), jnp.float32),
+                pltpu.VMEM((heads, d_key, LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q_t.shape, jnp.float32),
+        name="decode_attention",
+        interpret=interpret,
+    )(n_items, item_slot, item_block, kv_len, q_t,
+      jnp.swapaxes(k_cache, 2, 3), jnp.swapaxes(v_cache, 2, 3))
+    return jnp.transpose(out[:, :, :slots], (2, 0, 1))[:, :, None, :] \
+        .astype(q.dtype)
+
+
+def decode_attention(q, k_cache, v_cache, kv_len, *, bound, lane_axis=2,
+                     interpret=None):
+    """softmax(q k^T / sqrt(d_key)) v over keys ``[0, kv_len[s])`` of
+    every slot ``s``: ``q`` [slots, heads, 1, d_key], the caches
+    [slots, heads, max_seq, d_key], ``kv_len`` [slots] int, clipped to
+    ``[0, bound]``; a slot of length 0 gets zeros. ``bound`` (static)
+    is the most any slot may hold this step, ``lane_axis`` the axis of
+    the caches the device holds on its lanes. Must satisfy ``fits``."""
+    if k_cache.shape != v_cache.shape or k_cache.dtype != v_cache.dtype \
+            or not fits(k_cache.shape, k_cache.dtype, lane_axis, bound):
+        raise ValueError(
+            f"decode_attention kernel cannot serve caches {k_cache.shape} "
+            f"{k_cache.dtype} / {v_cache.shape} {v_cache.dtype} with axis "
+            f"{lane_axis} on the lanes under a bound of {bound}")
+    if interpret is None:
+        interpret = _interpret_default()
+    return _attend(q, k_cache, v_cache, kv_len, bound=int(bound),
+                   rows=block_rows(int(bound)), interpret=bool(interpret))

@@ -398,6 +398,8 @@ class GenerationEngine:
         token at its own cache position; inactive slots ride as padding
         (they write garbage at position 0 of their row, which the next
         prefill into that row overwrites)."""
+        # here and not at the top: importing paddle_tpu imports no Pallas
+        from ...ops.pallas.decode_attention import kv_blocks
         with profiler.RecordEvent("generation::build_step",
                                   cat=profiler.CAT_SERVING):
             active = self._active()
@@ -411,12 +413,16 @@ class GenerationEngine:
             bucket = bucket_for(depth, self.spec.cache_buckets)
             if bucket is None:  # deepest slot exceeded every bucket
                 bucket = self.spec.cache_buckets[-1]
+            # live cache rows per slot, this token counted; 0 where no
+            # request is: attention reads nothing of an idle slot
+            lengths = self._lengths
         t0 = time.monotonic()
         with profiler.RecordEvent(
                 f"generation::decode_step[{bucket}]",
                 cat=profiler.CAT_SERVING):
-            next_tokens = self.model.run_decode(tokens, positions, bucket)
-        self._observe_step(t0)
+            next_tokens = self.model.run_decode(tokens, positions, bucket,
+                                                lengths)
+        self._observe_step(t0, kv_blocks(lengths, bucket))
         self._deliver(active, next_tokens)
 
     def _step_reforward(self):
@@ -446,12 +452,16 @@ class GenerationEngine:
                 self._deliver_token(i, self._slots[i],
                                     int(next_tokens[i]))
 
-    def _observe_step(self, t0: float):
+    def _observe_step(self, t0: float, blocks=None):
+        """``blocks``: a cached step's (read, under the bound) cache
+        blocks, by ops/pallas/decode_attention.py kv_blocks."""
         t1 = time.monotonic()
         with profiler.RecordEvent("generation::telemetry",
                                   cat=profiler.CAT_SERVING):
             self.health.record_success()
             self.metrics.step_seconds.record(t1 - t0)
+            if blocks is not None:
+                self.metrics.kv_blocks(*blocks)
             if obs_attr.attribution_enabled():
                 cost = self.model.last_cost()
                 peak = obs_attr.peak_flops()

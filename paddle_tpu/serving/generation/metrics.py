@@ -35,6 +35,8 @@ class GenerationMetrics:
       aborted (breaker trip / non-drain stop), error
     - shed_total{reason}: every request turned away BEFORE taking a
       slot — circuit_open, queue_full, model_budget (host routing)
+    - kv_blocks_total{state}: cache blocks a cached step's attention
+      reads (a live row in them) and skips (the rest under the bucket)
     - step_seconds / prefill_seconds: device step wall time
     - queue_wait_seconds / ttft_seconds: each retired request's wait
       for a slot and time to first token, from the timestamps on its
@@ -93,6 +95,15 @@ class GenerationMetrics:
             "circuit_open (breaker), queue_full (engine queue "
             "capacity), model_budget (per-model host admission).",
             ("engine", "reason"))
+        self._kv_blocks_family = reg.counter(
+            "paddle_tpu_decode_kv_blocks_total",
+            "KV-cache blocks of the cached decode steps' attention, a "
+            "block count a step (one count serves K, V and every "
+            "layer), from the slots' live lengths alone: read (blocks "
+            "that hold a live row, which the length-bounded kernel "
+            "reads) and skipped (the rest of the blocks under the "
+            "step's bucket, which it leaves; attention composed over a "
+            "slice reads those too).", ("engine", "state"))
         self.step_seconds = histogram(
             "paddle_tpu_decode_step_seconds",
             "Wall time of one decode step (dispatch to materialized "
@@ -140,6 +151,13 @@ class GenerationMetrics:
         self._shed_family.labels(engine=self.engine_label,
                                  reason=reason).inc()
 
+    def kv_blocks(self, read: int, under_bound: int) -> None:
+        """One cached decode step's attention: blocks with a live row
+        in them, of the blocks under the step's bucket."""
+        for state, n in (("read", read), ("skipped", under_bound - read)):
+            self._kv_blocks_family.labels(engine=self.engine_label,
+                                          state=state).inc(n)
+
     def _by_reason(self, family) -> Dict[str, float]:
         out = {}
         for key, child in family.samples():
@@ -166,7 +184,8 @@ class GenerationMetrics:
         key = (self.engine_label,)
         for fam in self._owned_families:
             fam.discard(key)
-        for family in (self._retired_family, self._shed_family):
+        for family in (self._retired_family, self._shed_family,
+                       self._kv_blocks_family):
             for k, _ in family.samples():
                 if k[0] == self.engine_label:
                     family.discard(k)
@@ -190,6 +209,7 @@ class GenerationMetrics:
             "ttft_seconds": self.ttft_seconds.snapshot(),
             "retired_by_reason": self._by_reason(self._retired_family),
             "shed_by_reason": self._by_reason(self._shed_family),
+            "kv_blocks_by_state": self._by_reason(self._kv_blocks_family),
             "mfu": self.mfu.value if self.mfu is not None else 0.0,
         }
         if executor is not None:

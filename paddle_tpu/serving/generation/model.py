@@ -250,16 +250,22 @@ class GenerationModel:
         return int(out.reshape(-1)[0])
 
     def run_decode(self, tokens: np.ndarray, positions: np.ndarray,
-                   bucket: int) -> np.ndarray:
+                   bucket: int, lengths: Optional[np.ndarray] = None
+                   ) -> np.ndarray:
         """One decode step over the whole slot array. tokens:
         [slots] int64 (last emitted token per slot), positions: [slots]
-        int64 (cache write/attend position per slot). Returns [slots]
-        next tokens."""
+        int64 (cache write position per slot), lengths: [slots] int64
+        (live cache rows per slot counting this token: positions + 1,
+        the default, or 0 for a slot with no request in it, whose row
+        of the result means nothing). Returns [slots] next tokens."""
         lm = self.programs["decode"][int(bucket)]
+        positions = positions.astype(np.int64)
         out = self._run(lm, {
             "token_ids": tokens.reshape(self.spec.slots, 1, 1)
             .astype(np.int64),
-            "positions": positions.astype(np.int64)})
+            "positions": positions,
+            "lengths": positions + 1 if lengths is None
+            else lengths.astype(np.int64)})
         return out.reshape(-1)
 
     def run_full(self, token_matrix: np.ndarray, lengths: np.ndarray,

@@ -305,7 +305,8 @@ def test_decode_cost_hand_counts(model):
     lm = model.programs["decode"][L]
     cost = cost_model.program_cost(
         lm.main, feed_shapes={"token_ids": (spec.slots, 1, 1),
-                              "positions": (spec.slots,)})
+                              "positions": (spec.slots,),
+                              "lengths": (spec.slots,)})
     slots, h = spec.slots, spec.n_head
     d_key = spec.d_model // spec.n_head
     # SDPA mega-op: q len 1 against the L cached rows, per layer.
@@ -314,8 +315,18 @@ def test_decode_cost_hand_counts(model):
             if c.op_type == "scaled_dot_product_attention"]
     assert len(sdpa) == spec.n_layer
     expect_sdpa = 4 * (slots * h) * 1 * L * d_key + 5 * (slots * h) * 1 * L
+    # its bytes: the op is handed the WHOLE [slots, h, max_seq, d]
+    # caches and reads at most the bucket's L rows of each (booked at
+    # the bound; the slice that carried this read before is gone),
+    # plus q, the int64 lengths and the context
+    kept = slots * h * L * d_key * 4
+    q_bytes = slots * h * 1 * d_key * 4
+    expect_bytes = 2 * kept + 2 * q_bytes + slots * 8
+    assert L < spec.max_seq_len
     for c in sdpa:
         assert c.exact and c.flops == expect_sdpa, (c.flops, expect_sdpa)
+        assert c.bytes_accessed == expect_bytes, (c.bytes_accessed,
+                                                  expect_bytes)
     # kv_cache_append: zero flops; bytes = 2 * new rows + index — the
     # whole [slots, h, max_seq, d] cache must NOT be charged per token
     appends = [c for c in cost.ops if c.op_type == "kv_cache_append"]
@@ -326,12 +337,8 @@ def test_decode_cost_hand_counts(model):
         assert c.flops == 0
         assert c.bytes_accessed == 2 * new_bytes + pos_bytes, \
             (c.bytes_accessed, 2 * new_bytes + pos_bytes)
-    # slice reads only the kept L rows, not the max_seq cache
-    slices = [c for c in cost.ops if c.op_type == "slice"]
-    assert len(slices) == 2 * spec.n_layer
-    kept = slots * h * L * d_key * 4
-    for c in slices:
-        assert c.bytes_accessed == 2 * kept, (c.bytes_accessed, 2 * kept)
+    # no slice stands between a cache and its attention any more
+    assert not [c for c in cost.ops if c.op_type == "slice"]
     assert cost.unresolved == 0
 
 

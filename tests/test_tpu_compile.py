@@ -236,3 +236,101 @@ def test_kv_cache_append_compiles_in_place(topo, one_chip, dtype, d_key,
         "must-alias" in text[:text.find("\n\n")]
     # nothing of the cache's size beside the cache: a copy would show
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+# serve-chat's decode step (chipbench/configs/decoder-lm-base.json: 128
+# slots, 6 layers, 8 heads of 64, 2048 positions, f32), both cache
+# buckets, compiled whole for the described chip. The appends and the
+# attention read the SAME cache buffers: the append kernel hands its
+# output back in the model's axis order, the attention kernel takes the
+# device's order again, and XLA must make nothing of the two transposes.
+@pytest.mark.parametrize("bucket", [512, 2048])
+@pytest.mark.parametrize("whole_step", [False, True],
+                         ids=["kernel_alone", "whole_step"])
+def test_decode_step_reads_its_caches_in_place(topo, one_chip,
+                                               monkeypatch, whole_step,
+                                               bucket):
+    """12 appends + 6 length-bounded attention reads as custom calls,
+    every cache aliased to its output, nothing cache-sized copied, no
+    loop over slots, and no multiply-reduce fusion over a cache (ISSUE
+    35; until then twelve of them read all 6.44 GB a step). And the
+    same of the one kernel alone, where a fault is cheaper to read."""
+    import re
+
+    from paddle_tpu.ops import cache_ops
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+
+    slots, heads, max_seq, d_key = 128, 8, 2048, 64
+    shape = (slots, heads, max_seq, d_key)
+    cache_re = r"f32\[%d,%d,(?:%d,%d|%d,%d)\]" % (
+        slots, heads, max_seq, d_key, d_key, max_seq)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def checks(compiled, calls, caches):
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == calls
+        assert not re.findall(r"= \S.* while\(", text)
+        assert not re.findall("= " + cache_re + r"\S* copy\(", text)
+        # XLA's own temporaries: activations and the work list, not a
+        # 537-MB cache (a copy of one would show here first)
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+        entry = text[text.find("\nENTRY "):]
+        params = {int(n) for n in re.findall(
+            cache_re + r"[^\n]*? parameter\((\d+)\)", entry)}
+        assert len(params) == caches
+        return text, params
+
+    if not whole_step:
+        compiled = jax.jit(
+            lambda q, k, v, n: decode_attention(q, k, v, n, bound=bucket,
+                                                interpret=False)).lower(
+            sds((slots, heads, 1, d_key), jnp.float32),
+            sds(shape, jnp.float32), sds(shape, jnp.float32),
+            sds((slots,), jnp.int32)).compile()
+        checks(compiled, 1, 2)
+        return
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer
+
+    # the rules ask the backend, which is still the CPU here, how it
+    # holds a cache: answer for the described chip
+    real = cache_ops.device_lane_axis
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        cache_ops, "device_lane_axis",
+        lambda shape, dtype: real(shape, dtype, topo.devices[0]))
+    lm = transformer._build_lm_program(
+        "decode", bucket, 32000, max_seq, slots, 6, heads, 512, 2048, 0)
+    block = lm.main.desc.block(0)
+
+    class EveryVarThere:         # shapes come from the program, not
+        def has(self, name):     # from 6.44 GB of caches in a scope
+            return True
+
+    step = pt.Executor()._compile(lm.main.desc, block, None,
+                                  [lm.fetch_name], EveryVarThere())
+
+    def state(names):
+        return {n: sds(block.find_var_recursive(n).shape, jnp.float32)
+                for n in names}
+
+    feed = {"token_ids": sds((slots, 1, 1), jnp.int32),
+            "positions": sds((slots,), jnp.int32),
+            "lengths": sds((slots,), jnp.int32)}
+    compiled = step.jitted.lower(
+        feed, state(step.ro_names), state(step.rw_names),
+        sds((), jnp.int32)).compile()
+    text, cache_params = checks(compiled, 12 + 6, 12)
+    assert len(step.rw_names) == 12
+    header = text[:text.find("\n\n")]
+    aliased = {int(m) for m in re.findall(
+        r"\(\s*(\d+)\s*,\s*\{[^}]*\}\s*,\s*(?:may|must)-alias\)", header)}
+    assert cache_params <= aliased
+    # what is left of that name reduces [128] rows of a layer norm
+    over_a_cache = [ln for ln in text.splitlines()
+                    if "multiply_reduce" in ln and " fusion(" in ln
+                    and re.search(cache_re, ln)]
+    assert not over_a_cache, over_a_cache[:2]
