@@ -304,18 +304,49 @@ def _flops_for(op: ir.OpDesc,
         return (None, False, None) if x is None else \
             (8 * x.numel, False, None)
 
+    if t == "rms_norm":
+        x = first("X")
+        return (None, False, None) if x is None else \
+            (4 * x.numel, False, None)
+
+    if t == "rotary_embedding":
+        x = first("X")
+        return (None, False, None) if x is None else \
+            (3 * x.numel, False, None)
+
+    if t == "moe_router":
+        x, w = first("X"), first("W")
+        if x is None or w is None or len(w.shape) != 2:
+            return None, False, None
+        return 2 * x.numel * w.shape[1], True, None
+
+    if t == "moe_experts":
+        # three grouped products over the rows routed to HELD experts.
+        # How many that is is run-time data: booked at its expectation
+        # under uniform routing, tokens * top_k * held / total
+        x, w = first("X"), first("WGate")
+        if x is None or w is None or len(w.shape) != 2:
+            return None, False, None
+        tokens = x.numel // x.shape[-1]
+        rows = (tokens * int(op.attrs["top_k"])
+                * int(op.attrs["experts_held"])
+                // int(op.attrs["experts_total"]))
+        return (6 * rows * x.shape[-1] * w.shape[1], False,
+                "routed rows at their expectation under uniform routing")
+
     if t == "scaled_dot_product_attention":
         # the attention op the models place (ops/nn_ops.py): two
         # seq^2 contractions plus the online softmax. Without this rule
         # the generic 1-flop/elem fallback would book ~Sq*d instead of
         # ~4*Sq*Sk*d and silently crater reported MFU.
-        q, k = first("Q"), first("K")
+        q, k, v = first("Q"), first("K"), first("V")
         if q is None or k is None or len(q.shape) < 3:
             return None, False, None
         lead = _prod(q.shape[:-2])
         sq, d = q.shape[-2], q.shape[-1]
+        d_v = v.shape[-1] if v is not None and v.shape else d
         sk = _attended_rows(op, k)
-        return (4 * lead * sq * sk * d + 5 * lead * sq * sk,
+        return (2 * lead * sq * sk * (d + d_v) + 5 * lead * sq * sk,
                 True, None)
 
     if t in ("lstm", "gru"):

@@ -13,12 +13,13 @@ from typing import List, Optional, Sequence, Union
 from ..framework import Variable
 from ..layer_helper import LayerHelper, ParamAttr
 from ..initializer import ConstantInitializer, NormalInitializer, \
-    XavierInitializer
+    UniformInitializer, XavierInitializer
 
 __all__ = [
     "fc", "embedding", "dynamic_lstm", "dynamic_gru", "conv2d",
     "depthwise_conv2d", "conv2d_transpose", "pool2d", "batch_norm",
-    "layer_norm", "dropout", "cross_entropy", "softmax_with_cross_entropy",
+    "layer_norm", "rms_norm", "rotary_embedding", "moe_router",
+    "moe_experts", "dropout", "cross_entropy", "softmax_with_cross_entropy",
     "sigmoid_cross_entropy_with_logits", "square_error_cost", "accuracy",
     "topk", "sequence_pool", "sequence_conv", "sequence_softmax",
     "sequence_expand", "sequence_first_step", "sequence_last_step",
@@ -343,6 +344,105 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
                      attrs={"epsilon": epsilon,
                             "begin_norm_axis": begin_norm_axis})
     return helper.append_activation(out)
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """x / sqrt(mean(x^2) + epsilon) * scale over the last axis, scale
+    starting at 1 (ops/nn_ops.py rms_norm)."""
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    scale = helper.create_parameter(
+        helper.param_attr, shape=[int(input.shape[-1])], dtype=input.dtype,
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable(input.dtype)
+    helper.append_op(type="rms_norm", inputs={"X": input, "Scale": scale},
+                     outputs={"Y": out}, attrs={"epsilon": epsilon})
+    return out
+
+
+def rotary_embedding(x, positions, theta=10000.0, name=None):
+    """Rotary position embedding of x [..., S, r] at the fed positions
+    [S], on neighbouring pairs (ops/nn_ops.py rotary_embedding)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_tmp_variable(x.dtype)
+    helper.append_op(type="rotary_embedding",
+                     inputs={"X": x, "Positions": positions},
+                     outputs={"Out": out},
+                     attrs={"theta": float(theta)})
+    return out
+
+
+def moe_router(input, experts_total, top_k, routed_scaling_factor=1.0,
+               selection_bias=None, param_attr=None, name=None):
+    """Route input [..., d] over ``experts_total`` experts
+    (ops/moe_ops.py moe_router): returns (expert ids [..., top_k] int32,
+    weights [..., top_k] float32). ``selection_bias`` (an Initializer)
+    adds a NON-trainable per-expert bias that moves the selection and
+    never the weights; nothing in the program updates it."""
+    helper = LayerHelper("moe_router", param_attr=param_attr, name=name)
+    w = helper.create_parameter(
+        helper.param_attr, shape=[int(input.shape[-1]), experts_total],
+        dtype="float32")
+    inputs = {"X": input, "W": w}
+    if selection_bias is not None:
+        inputs["Bias"] = helper.create_parameter(
+            ParamAttr(initializer=selection_bias, trainable=False),
+            shape=[experts_total], dtype="float32", is_bias=True)
+    idx = helper.create_tmp_variable("int32")
+    weights = helper.create_tmp_variable("float32")
+    helper.append_op(type="moe_router", inputs=inputs,
+                     outputs={"TopIdx": idx, "TopW": weights},
+                     attrs={"top_k": int(top_k),
+                            "routed_scaling_factor":
+                                float(routed_scaling_factor)})
+    return idx, weights
+
+
+def moe_experts(input, top_idx, top_w, d_inner, experts_total,
+                experts_held=None, expert_offset=0, down_init_scale=1.0,
+                name=None):
+    """The gated-FFN experts [expert_offset, expert_offset +
+    experts_held) of a layer of ``experts_total``, applied to the tokens
+    the routing (top_idx, top_w) sends them (ops/moe_ops.py
+    moe_experts); every expert where ``experts_held`` is None. The held
+    experts' matrices are stacked by rows ([held * d, f] gate and up,
+    [held * f, d] down) and drawn as ``held`` separate Xavier layers,
+    the down projections times ``down_init_scale``. The layer keeps a
+    persistable ``<name>.live_rows`` [3] that no optimizer touches: the
+    rows the routing sent its experts, summed over the steps run, the
+    steps, and the last step's rows (what the grouped products' time
+    follows, and nothing else in the program shows)."""
+    helper = LayerHelper("moe_experts", name=name)
+    held = experts_total if experts_held is None else int(experts_held)
+    d, f = int(input.shape[-1]), int(d_inner)
+
+    def stacked(rows, cols, scale=1.0):
+        limit = scale * (6.0 / (rows + cols)) ** 0.5
+        return helper.create_parameter(
+            ParamAttr(initializer=UniformInitializer(-limit, limit)),
+            shape=[held * rows, cols], dtype=input.dtype)
+
+    w_gate, w_up = stacked(d, f), stacked(d, f)
+    w_down = stacked(f, d, down_init_scale)
+    out = helper.create_tmp_variable(input.dtype)
+    live = helper.create_tmp_variable("float32")
+    live.stop_gradient = True
+    helper.append_op(type="moe_experts",
+                     inputs={"X": input, "TopIdx": top_idx, "TopW": top_w,
+                             "WGate": w_gate, "WUp": w_up,
+                             "WDown": w_down},
+                     outputs={"Out": out, "LiveRows": live},
+                     attrs={"experts_total": int(experts_total),
+                            "experts_held": held,
+                            "expert_offset": int(expert_offset),
+                            "top_k": int(top_idx.shape[-1])})
+    tally = helper.create_global_variable(
+        shape=[3], dtype="float32", persistable=True,
+        name=helper.name + ".live_rows")
+    helper.set_variable_initializer(tally, ConstantInitializer(0.0))
+    helper.append_op(type="moe_rows_tally",
+                     inputs={"Tally": tally, "LiveRows": live},
+                     outputs={"TallyOut": tally})
+    return out
 
 
 def dropout(x, dropout_prob, is_test=False, seed=None, name=None,

@@ -210,7 +210,10 @@ def split(input, num_or_sections, dim=-1, name=None):
     else:
         num = len(num_or_sections)
         sections = list(num_or_sections)
-    outs = [helper.create_tmp_variable(input.dtype) for _ in range(num)]
+    # builtins.range: this module's `range` layer shadows the builtin
+    import builtins
+    outs = [helper.create_tmp_variable(input.dtype)
+            for _ in builtins.range(num)]
     helper.append_op(type="split", inputs={"X": input},
                      outputs={"Out": outs},
                      attrs={"num": num if sections is None else 0,

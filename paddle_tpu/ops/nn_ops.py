@@ -348,6 +348,22 @@ def _layer_norm(ctx):
     ctx.set_output("Variance", var.reshape(x.shape[:begin]))
 
 
+@register_op("rms_norm")
+def _rms_norm(ctx):
+    """Root-mean-square norm over the last axis (Zhang & Sennrich,
+    arXiv:1910.07467): x / sqrt(mean(x^2) + eps) * Scale, no centring
+    and no bias. Statistics in float32 whatever X's width, the result
+    at X's width, as layer_norm above."""
+    x = ctx.input("X")
+    scale = ctx.input("Scale")
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    y = xf * jax.lax.rsqrt(ms + ctx.attr("epsilon", 1e-6))
+    if scale is not None:
+        y = y * scale
+    ctx.set_output("Y", y.astype(x.dtype))
+
+
 @register_op("lrn")
 def _lrn(ctx):
     x = ctx.input("X")  # NCHW
@@ -628,6 +644,27 @@ def _unstack(ctx):
     num = x.shape[axis]
     parts = jnp.split(x, num, axis=axis)
     ctx.set_outputs("Y", [p.squeeze(axis) for p in parts])
+
+
+@register_op("rotary_embedding", no_grad_slots=["Positions"])
+def _rotary_embedding(ctx):
+    """Rotary position embedding (Su et al., arXiv:2104.09864) on
+    X [..., S, r] with Positions [S]: the neighbours (x[2i], x[2i+1])
+    of a row at position p — the complex-number form — are turned by
+    the angle p * theta^(-2i/r). Angles and the rotation in float32,
+    the result at X's width."""
+    x = ctx.input("X")
+    pos = ctx.input("Positions").reshape(-1).astype(jnp.float32)
+    r = x.shape[-1]
+    inv_freq = 1.0 / (float(ctx.attr("theta", 10000.0)) ** (
+        jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    angle = pos[:, None] * inv_freq[None, :]              # [S, r/2]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                    axis=-1).reshape(x.shape)
+    ctx.set_output("Out", out.astype(x.dtype))
 
 
 def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,

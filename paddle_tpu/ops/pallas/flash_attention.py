@@ -6,7 +6,9 @@ matmul/softmax in python/paddle/fluid/nets.py:312; its hand-fused CUDA
 analogue for recurrent hot loops is paddle/cuda/src/hl_cuda_lstm.cu —
 Pallas is the TPU-native equivalent of that hand-fusion layer).
 
-Layout: q [B, H, Sq, D], k/v [B, H, Sk, D], optional additive bias/mask
+Layout: q [B, H, Sq, D], k [B, H, Sk, D], v [B, H, Sk, Dv] (Dv may differ
+from D: latent attention keeps 192-wide keys beside 128-wide values; the
+scale stays Q's), out [B, H, Sq, Dv], optional additive bias/mask
 broadcastable as [B, {1|H}, Sq, Sk]. The grid iterates
 (batch, head, q-block, k-block) with the k-block axis innermost ("arbitrary"
 semantics) so VMEM scratch accumulators carry across k-blocks while Mosaic
@@ -79,7 +81,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     def _step():
         q = q_ref[0, 0]                       # [bq, d]
         k = k_ref[0, 0]                       # [bk, d]
-        v = v_ref[0, 0]                       # [bk, d]
+        v = v_ref[0, 0]                       # [bk, dv]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
@@ -104,7 +106,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, d]
+            preferred_element_type=jnp.float32)               # [bq, dv]
         acc_scr[:] = acc_scr[:] * alpha + pv
 
     @pl.when(ik == nk - 1)
@@ -139,7 +141,7 @@ def _bias_spec(bias, sq_p, sk_p, block_q, block_k, order):
 
 def _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k, interpret)
     sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_k)
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
@@ -150,7 +152,7 @@ def _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret):
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d), lambda b, h, iq, ik: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_k, d), lambda b, h, iq, ik: (b, h, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b, h, iq, ik: (b, h, ik, 0)),
+        pl.BlockSpec((1, 1, block_k, dv), lambda b, h, iq, ik: (b, h, ik, 0)),
     ]
     args = [qp, kp, vp]
     if bias is not None:
@@ -170,14 +172,14 @@ def _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret):
     scratch = [
         _scratch((block_q, 128), jnp.float32),
         _scratch((block_q, 128), jnp.float32),
-        _scratch((block_q, d), jnp.float32),
+        _scratch((block_q, dv), jnp.float32),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
+        jax.ShapeDtypeStruct((b, h, sq_p, dv), q.dtype),
         jax.ShapeDtypeStruct((b, h, sq_p, 128), jnp.float32),
     ]
     out_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda b, h, iq, ik: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, block_q, dv), lambda b, h, iq, ik: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_q, 128),
                      lambda b, h, iq, ik: (b, h, iq, 0)),
     ]
@@ -224,7 +226,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     @pl.when(run)
     def _step():
         q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
-        do = do_ref[0, 0]                                    # [bq, d]
+        do = do_ref[0, 0]                                    # [bq, dv]
         lse = lse_ref[0, 0][:, :1]                           # [bq, 1]
         delta = delta_ref[0, 0][:, :1]                       # [bq, 1]
         s = jax.lax.dot_general(
@@ -294,7 +296,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
         p = jnp.exp(s - lse)                                 # [bq, bk]
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bk, d]
+            preferred_element_type=jnp.float32)              # [bk, dv]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -314,7 +316,7 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     q, k, v, bias, o, lse = res
     do = g
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k, interpret)
     sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_k)
 
@@ -328,11 +330,11 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     lsep = jnp.pad(jnp.broadcast_to(lse, (b, h, sq, 128)), pad_q)
     deltap = jnp.pad(jnp.broadcast_to(delta, (b, h, sq, 128)), pad_q)
 
-    def qspec(im):
-        return pl.BlockSpec((1, 1, block_q, d), im)
+    def qspec(im, width=d):
+        return pl.BlockSpec((1, 1, block_q, width), im)
 
-    def kspec(im):
-        return pl.BlockSpec((1, 1, block_k, d), im)
+    def kspec(im, width=d):
+        return pl.BlockSpec((1, 1, block_k, width), im)
 
     def rspec(im):  # row stats [.., 128]
         return pl.BlockSpec((1, 1, block_q, 128), im)
@@ -340,14 +342,14 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     # ---- dq (+ dbias) over grid (b, h, iq, ik), k innermost ----
     qk_q = lambda b, h, iq, ik: (b, h, iq, 0)
     qk_k = lambda b, h, iq, ik: (b, h, ik, 0)
-    in_specs = [qspec(qk_q), kspec(qk_k), kspec(qk_k)]
+    in_specs = [qspec(qk_q), kspec(qk_k), kspec(qk_k, dv)]
     args = [qp, kp, vp]
     has_bias = bias is not None
     if has_bias:
         biasp, bspec = _bias_spec(bias, sq_p, sk_p, block_q, block_k, "qk")
         in_specs.append(bspec)
         args.append(biasp)
-    in_specs += [qspec(qk_q), rspec(qk_q), rspec(qk_q)]
+    in_specs += [qspec(qk_q, dv), rspec(qk_q), rspec(qk_q)]
     args += [dop, lsep, deltap]
 
     out_shape = [jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype)]
@@ -397,13 +399,13 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     # ---- dk/dv over grid (b, h, ik, iq), q innermost ----
     kq_q = lambda b, h, ik, iq: (b, h, iq, 0)
     kq_k = lambda b, h, ik, iq: (b, h, ik, 0)
-    in_specs = [qspec(kq_q), kspec(kq_k), kspec(kq_k)]
+    in_specs = [qspec(kq_q), kspec(kq_k), kspec(kq_k, dv)]
     args2 = [qp, kp, vp]
     if has_bias:
         biasp, bspec = _bias_spec(bias, sq_p, sk_p, block_q, block_k, "kq")
         in_specs.append(bspec)
         args2.append(biasp)
-    in_specs += [qspec(kq_q), rspec(kq_q), rspec(kq_q)]
+    in_specs += [qspec(kq_q, dv), rspec(kq_q), rspec(kq_q)]
     args2 += [dop, lsep, deltap]
 
     def dkv_kernel(*refs):
@@ -421,11 +423,11 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
         name="flash_bwd_dkv",
         grid=(b, h, sk_p // block_k, sq_p // block_q),
         in_specs=in_specs,
-        out_specs=[kspec(kq_k), kspec(kq_k)],
+        out_specs=[kspec(kq_k), kspec(kq_k, dv)],
         out_shape=[jax.ShapeDtypeStruct((b, h, sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, h, sk_p, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b, h, sk_p, dv), v.dtype)],
         scratch_shapes=[_scratch((block_k, d), jnp.float32),
-                        _scratch((block_k, d), jnp.float32)],
+                        _scratch((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(("parallel",) * 3 + ("arbitrary",)),
         interpret=interpret,
     )(*args2)
@@ -470,8 +472,9 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     bias_grad: bool = False) -> jax.Array:
     """Tiled online-softmax attention.
 
-    q: [B, H, Sq, D]; k, v: [B, H, Sk, D]; bias additive with any of the
-    four dims broadcast (size 1). Returns [B, H, Sq, D].
+    q: [B, H, Sq, D]; k: [B, H, Sk, D]; v: [B, H, Sk, Dv]; bias additive
+    with any of the four dims broadcast (size 1). Returns [B, H, Sq, Dv].
+    sm_scale defaults to 1/sqrt(D), Q's width, whatever Dv is.
 
     bias_grad=False (default) treats bias as a constant mask: backward
     returns zeros for it without materializing the O(Sq*Sk) dbias buffer.

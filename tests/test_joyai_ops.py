@@ -1,0 +1,362 @@
+"""The ops a latent-attention, sparse-expert decoder adds (rms_norm,
+rotary_embedding, moe_router, moe_experts), each with its gradient,
+against the functions of the plain reference
+(chipbench/reference_joyai.py: no sort, no grouped product — every held
+expert runs on every token and the routing masks the result); and the
+expert layer's contract with the deployment: the parts that all the
+shares of an expert-parallel group give, with the shared expert counted
+once, add up to the uncut layer, at ANY routing."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from chipbench import reference_joyai as ref
+from paddle_tpu import layers
+from paddle_tpu.core.registry import grad_var_name
+from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.ops.moe_ops import held_experts_ffn
+
+T, D, F, E, K = 24, 16, 12, 16, 4
+ROUTING = dict(num_experts_per_tok=K,
+               routed_scaling_factor=2.5)
+
+
+def _run_op(op_type, feeds, attrs, outs, wrt, out_dtypes=None):
+    """(program, output vars, feed) of one op over fed inputs, with the
+    backward pass of sum(last output * cot) for the inputs in ``wrt``."""
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        ins = {slot: layers.data(name, list(a.shape), dtype=str(a.dtype),
+                                 append_batch_size=False,
+                                 stop_gradient=name not in wrt)
+               for slot, (name, a) in feeds.items()}
+        helper = LayerHelper(op_type)
+        out_vars = {s: helper.create_tmp_variable(
+            (out_dtypes or {}).get(s, "float32")) for s in outs}
+        helper.append_op(type=op_type, inputs=ins, outputs=out_vars,
+                         attrs=attrs)
+        first = out_vars[outs[-1]]
+        cot = layers.data("cot", [-1], append_batch_size=False)
+        loss = layers.reduce_sum(layers.elementwise_mul(
+            layers.reshape(first, [-1]), cot))
+        pt.append_backward(loss, program=main)
+    return main, out_vars, {name: a for name, a in feeds.values()}
+
+
+def _fetch(main, out_vars, feed, outs, wrt, cot):
+    got = pt.Executor().run(
+        main, feed=dict(feed, cot=cot.reshape(-1)),
+        fetch_list=[out_vars[s] for s in outs]
+        + [grad_var_name(n) for n in wrt])
+    return got[:len(outs)], got[len(outs):]
+
+
+def _close(got, want, what, rtol=2e-5, atol=2e-6):
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=rtol, atol=atol, err_msg=what)
+
+
+def test_rms_norm_and_its_gradients():
+    rng = np.random.RandomState(0)
+    x = rng.randn(3, 5, D).astype(np.float32)
+    scale = rng.uniform(0.5, 1.5, D).astype(np.float32)
+    cot = rng.randn(3, 5, D).astype(np.float32)
+    main, ov, feed = _run_op(
+        "rms_norm", {"X": ("x", x), "Scale": ("scale", scale)},
+        {"epsilon": 1e-6}, ["Y"], ("x", "scale"))
+    (y,), grads = _fetch(main, ov, feed, ["Y"], ("x", "scale"), cot)
+    _close(y, ref.rms_norm(x, scale, 1e-6), "y")
+    want = jax.grad(lambda x, s: jnp.sum(ref.rms_norm(x, s, 1e-6) * cot),
+                    (0, 1))(x, scale)
+    for name, g, w in zip(("dx", "dscale"), grads, want):
+        _close(g, w, name, rtol=2e-4, atol=2e-5)
+
+
+def test_rms_norm_keeps_bf16_in_and_f32_statistics():
+    """Statistics in float32 whatever the input's width: a bf16 input
+    of large entries must not overflow or lose the mean square."""
+    from paddle_tpu.core.registry import OpRegistry
+    x = (np.random.RandomState(1).randn(4, 256) * 300).astype(np.float32)
+
+    class Ctx:
+        extra = {}
+        outputs = {}
+
+        def input(self, slot):
+            return {"X": jnp.asarray(x, jnp.bfloat16),
+                    "Scale": jnp.ones(256)}[slot]
+
+        def attr(self, name, default=None):
+            return default
+
+        def set_output(self, slot, value):
+            self.outputs[slot] = value
+
+    ctx = Ctx()
+    OpRegistry.get("rms_norm").compute(ctx)
+    y = ctx.outputs["Y"]
+    assert y.dtype == jnp.bfloat16
+    want = ref.rms_norm(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32),
+                        1.0, 1e-6)
+    _close(y.astype(jnp.float32), want, "y", rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("shape", [(2, 3, 10, 8), (2, 1, 10, 8)])
+def test_rotary_embedding_on_interleaved_pairs(shape):
+    rng = np.random.RandomState(2)
+    x = rng.randn(*shape).astype(np.float32)
+    pos = (np.arange(10) * 37 + 5).astype(np.int64)   # fed, not 0..S-1
+    cot = rng.randn(*shape).astype(np.float32)
+    main, ov, feed = _run_op(
+        "rotary_embedding", {"X": ("x", x), "Positions": ("pos", pos)},
+        {"theta": 32000000.0}, ["Out"], ("x",))
+    (y,), (dx,) = _fetch(main, ov, feed, ["Out"], ("x",), cot)
+    _close(y, ref.rope(jnp.asarray(x), jnp.asarray(pos), 32000000.0), "y")
+    want = jax.grad(lambda x: jnp.sum(ref.rope(
+        x, jnp.asarray(pos), 32000000.0) * cot))(jnp.asarray(x))
+    _close(dx, want, "dx", rtol=2e-4, atol=2e-5)
+    # a rotation: norms of pairs survive, position 0 is the identity
+    y0 = pt.Executor().run(main, feed=dict(
+        feed, pos=np.zeros(10, np.int64), cot=cot.reshape(-1)),
+        fetch_list=[ov["Out"]])[0]
+    _close(y0, x, "position 0")
+    _close(np.square(y).reshape(*shape[:-1], -1, 2).sum(-1),
+           np.square(x).reshape(*shape[:-1], -1, 2).sum(-1), "norms",
+           rtol=1e-4, atol=1e-5)
+
+
+def _router_inputs(seed):
+    rng = np.random.RandomState(seed)
+    x = rng.randn(T, D).astype(np.float32)
+    w = (rng.randn(D, E) * 0.5).astype(np.float32)
+    return x, w, rng
+
+
+def _route(x, w, bias, wrt=("x", "w")):
+    attrs = dict(top_k=K, routed_scaling_factor=2.5)
+    feeds = {"X": ("x", x), "W": ("w", w)}
+    if bias is not None:
+        feeds["Bias"] = ("bias", bias)
+    return _run_op("moe_router", feeds, attrs, ["TopIdx", "TopW"], wrt,
+                   out_dtypes={"TopIdx": "int32"})
+
+
+def test_moe_router_and_its_gradients():
+    x, w, rng = _router_inputs(3)
+    bias = rng.uniform(-0.1, 0.1, E).astype(np.float32)
+    cot = rng.randn(T, K).astype(np.float32)
+    main, ov, feed = _route(x, w, bias)
+    (idx, weights), grads = _fetch(main, ov, feed, ["TopIdx", "TopW"],
+                                   ("x", "w"), cot)
+    want_idx, want_w = ref.route(x, w, bias, ROUTING)
+    np.testing.assert_array_equal(idx, want_idx)
+    assert idx.dtype == np.int32
+    _close(weights, want_w, "weights")
+    _close(weights.sum(-1), np.full(T, 2.5), "normalised and scaled",
+           rtol=1e-5)
+    want = jax.grad(lambda x, w: jnp.sum(
+        ref.route(x, w, bias, ROUTING)[1] * cot), (0, 1))(x, w)
+    for name, g, wg in zip(("dx", "dw"), grads, want):
+        _close(g, wg, name, rtol=2e-4, atol=2e-5)
+
+
+def test_selection_bias_chooses_and_does_not_weigh():
+    """A bias that lifts two experts into every token's selection: the
+    picks change, and the weights are still the picked experts' own
+    scores, normalised — the bias appears in no weight."""
+    x, w, _ = _router_inputs(4)
+    bias = np.zeros(E, np.float32)
+    bias[[5, 11]] = 10.0
+    (plain_idx, _), _ = _fetch(*_route(x, w, None, wrt=()),
+                               ["TopIdx", "TopW"], (), np.zeros((T, K)))
+    (idx, weights), _ = _fetch(*_route(x, w, bias, wrt=()),
+                               ["TopIdx", "TopW"], (), np.zeros((T, K)))
+    assert all({5, 11} <= set(row) for row in idx.tolist())
+    assert not np.array_equal(np.sort(idx, -1), np.sort(plain_idx, -1))
+    scores = np.asarray(jax.nn.sigmoid(x @ w))
+    picked = np.take_along_axis(scores, idx, -1)
+    _close(weights, picked / picked.sum(-1, keepdims=True) * 2.5,
+           "weights from the scores alone")
+
+
+def _expert_weights(rng, held):
+    return [(rng.randn(held * D, F) * 0.3).astype(np.float32),
+            (rng.randn(held * D, F) * 0.3).astype(np.float32),
+            (rng.randn(held * F, D) * 0.3).astype(np.float32)]
+
+
+def _experts_op(x, idx, weights, mats, held, offset, total=E):
+    names = ("x", "topw", "w_gate", "w_up", "w_down")
+    feeds = {"X": ("x", x), "TopIdx": ("idx", idx), "TopW": ("topw", weights),
+             "WGate": ("w_gate", mats[0]), "WUp": ("w_up", mats[1]),
+             "WDown": ("w_down", mats[2])}
+    attrs = dict(experts_total=total, experts_held=held,
+                 expert_offset=offset, top_k=idx.shape[-1])
+    return _run_op("moe_experts", feeds, attrs, ["Out"], names), names
+
+
+@pytest.mark.parametrize("held,offset", [(4, 0), (4, 8), (16, 0), (3, 13)])
+def test_moe_experts_and_its_gradients(held, offset):
+    rng = np.random.RandomState(5 + held + offset)
+    x = rng.randn(T, D).astype(np.float32)
+    idx, weights = (np.asarray(a) for a in ref.route(
+        x, (rng.randn(D, E) * 0.5).astype(np.float32),
+        np.zeros(E, np.float32), ROUTING))
+    mats = _expert_weights(rng, held)
+    cot = rng.randn(T, D).astype(np.float32)
+    (main, ov, feed), names = _experts_op(x, idx.astype(np.int32), weights,
+                                          mats, held, offset)
+    (out,), grads = _fetch(main, ov, feed, ["Out"], names, cot)
+    _close(out, ref.routed_experts(x, idx, weights, *mats, held, offset),
+           "out", rtol=2e-4, atol=2e-5)
+    want = jax.grad(lambda x, tw, a, b, c: jnp.sum(ref.routed_experts(
+        x, idx, tw, a, b, c, held, offset) * cot), (0, 1, 2, 3, 4))(
+            x, weights, *mats)
+    for name, g, w in zip(names, grads, want):
+        _close(g, w, "d" + name, rtol=1e-3, atol=1e-4)
+
+
+def test_moe_experts_counts_its_live_rows_and_the_tally_keeps_them():
+    """LiveRows is the number of assignments to held experts (4..6 of
+    16 here); ``moe_rows_tally`` folds a step's count into (sum over
+    the steps, steps, last)."""
+    rng = np.random.RandomState(9)
+    x = rng.randn(T, D).astype(np.float32)
+    idx = np.stack([rng.permutation(E)[:K] for _ in range(T)])
+    weights = rng.uniform(0.1, 1.0, (T, K)).astype(np.float32)
+    feeds = {"X": ("x", x), "TopIdx": ("idx", idx.astype(np.int32)),
+             "TopW": ("topw", weights)}
+    feeds.update(zip(("WGate", "WUp", "WDown"), zip(
+        ("w_gate", "w_up", "w_down"), _expert_weights(rng, 3))))
+    main, ov, feed = _run_op(
+        "moe_experts", feeds, dict(experts_total=E, experts_held=3,
+                                   expert_offset=4, top_k=K),
+        ["LiveRows", "Out"], ())
+    live = pt.Executor().run(main, feed=dict(
+        feed, cot=np.zeros(T * D, np.float32)),
+        fetch_list=[ov["LiveRows"]])[0]
+    want = int(np.sum((idx >= 4) & (idx < 7)))
+    assert 0 < want < T * 3 and float(live) == want
+    main, ov, feed = _run_op(
+        "moe_rows_tally",
+        {"Tally": ("tally", np.array([40., 3., 9.], np.float32)),
+         "LiveRows": ("live", np.float32(want))}, {}, ["TallyOut"], ())
+    out = pt.Executor().run(main, feed=dict(feed, cot=np.zeros(3, np.float32)),
+                            fetch_list=[ov["TallyOut"]])[0]
+    np.testing.assert_array_equal(out, [40 + want, 4, want])
+
+
+def _ffn_over(x, idx, weights, mats, held, offset):
+    d, f = x.shape[-1], mats[0].shape[-1]
+    return held_experts_ffn(
+        jnp.asarray(x), jnp.asarray(idx, jnp.int32), jnp.asarray(weights),
+        mats[0].reshape(held, d, f), mats[1].reshape(held, d, f),
+        mats[2].reshape(held, f, d), expert_offset=offset)
+
+
+def test_shares_of_a_group_add_up_to_the_uncut_layer():
+    """16 experts in 4 shares of 4: the four shares' routed parts plus
+    the shared expert ONCE equal the uncut reference layer (router,
+    all 16 experts, shared expert)."""
+    rng = np.random.RandomState(6)
+    x = rng.randn(T, D).astype(np.float32)
+    router = (rng.randn(D, E) * 0.5).astype(np.float32)
+    bias = rng.uniform(-0.1, 0.1, E).astype(np.float32)
+    mats = _expert_weights(rng, E)
+    shared = [(rng.randn(D, F) * 0.3).astype(np.float32),
+              (rng.randn(D, F) * 0.3).astype(np.float32),
+              (rng.randn(F, D) * 0.3).astype(np.float32)]
+    m = dict(ROUTING, n_routed_experts=E, experts_held=E, expert_offset=0)
+    uncut = ref.moe_ffn(jnp.asarray(x), [router, bias] + mats + shared, m)
+    idx, weights = ref.route(x, router, bias, ROUTING)
+    total = ref.gated_ffn(jnp.asarray(x), *shared)
+    for share in range(4):
+        rows = slice(share * 4 * D, (share + 1) * 4 * D)
+        down = slice(share * 4 * F, (share + 1) * 4 * F)
+        part = [mats[0][rows], mats[1][rows], mats[2][down]]
+        total = total + _ffn_over(x, idx, weights, part, 4, share * 4)
+    _close(total, uncut, "sum of the shares", rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["every_pick_held", "no_pick_held"])
+def test_no_token_is_dropped_at_the_extreme_routings(case):
+    """Held experts 4..7 of 16, top-4. Worst case: EVERY token routes
+    all four picks to the held experts (rows = tokens x 4, the whole
+    buffer live). Best case: no token routes to them (no live row, the
+    part is exactly zero). Both exact against the reference."""
+    rng = np.random.RandomState(7)
+    x = rng.randn(T, D).astype(np.float32)
+    mats = _expert_weights(rng, 4)
+    if case == "every_pick_held":
+        idx = np.stack([rng.permutation(4) + 4 for _ in range(T)])
+    else:
+        idx = np.stack([rng.permutation(12)[:4] for _ in range(T)])
+        idx = np.where(idx >= 4, idx + 4, idx)       # skips 4..7
+    weights = rng.uniform(0.1, 1.0, (T, K)).astype(np.float32)
+    got = _ffn_over(x, idx, weights, mats, 4, 4)
+    want = ref.routed_experts(x, idx, weights, *mats, 4, 4)
+    _close(got, want, case, rtol=2e-4, atol=2e-5)
+    if case == "no_pick_held":
+        assert not np.asarray(got).any()
+    else:
+        assert np.abs(np.asarray(got)).min(axis=-1).max() > 0
+    grads = jax.grad(lambda x: jnp.sum(_ffn_over(
+        x, idx, weights, mats, 4, 4) ** 2))(jnp.asarray(x))
+    want_g = jax.grad(lambda x: jnp.sum(ref.routed_experts(
+        x, idx, weights, *mats, 4, 4) ** 2))(jnp.asarray(x))
+    _close(grads, want_g, case + " dx", rtol=1e-3, atol=1e-4)
+
+
+def test_fewer_held_experts_than_picks_shrinks_the_buffer():
+    """2 held experts under top-4: at most 2 of a token's distinct picks
+    are held, so tokens x 2 rows hold the worst case — and do."""
+    rng = np.random.RandomState(8)
+    x = rng.randn(T, D).astype(np.float32)
+    mats = _expert_weights(rng, 2)
+    idx = np.stack([np.concatenate([[6, 7], rng.permutation(6)[:2]])
+                    [rng.permutation(4)] for _ in range(T)])
+    weights = rng.uniform(0.1, 1.0, (T, K)).astype(np.float32)
+    got = _ffn_over(x, idx, weights, mats, 2, 6)
+    _close(got, ref.routed_experts(x, idx, weights, *mats, 2, 6),
+           "two held under top-4", rtol=2e-4, atol=2e-5)
+
+
+def test_moe_experts_refuses_a_range_outside_the_layer():
+    x = np.zeros((T, D), np.float32)
+    idx = np.zeros((T, K), np.int32)
+    (main, ov, feed), _ = _experts_op(
+        x, idx, np.zeros((T, K), np.float32),
+        _expert_weights(np.random.RandomState(0), 4), 4, 14)
+    with pytest.raises(Exception, match="are not among"):
+        _fetch(main, ov, feed, ["Out"], (), np.zeros((T, D), np.float32))
+
+
+def test_cost_model_books_the_new_ops():
+    from paddle_tpu.models import decoder_moe
+    main, startup, fetch = decoder_moe.build_train(
+        trg_vocab=96, max_len=16, hidden_size=32, num_attention_heads=2,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=64,
+        moe_intermediate_size=24, n_routed_experts=8, experts_held=2,
+        num_experts_per_tok=2, num_hidden_layers=2)
+    from paddle_tpu.analysis.cost_model import program_cost
+    cost = program_cost(main.desc, feed_shapes={
+        "src_ids": [2, 16, 1], "trg_ids": [2, 16, 1],
+        "trg_labels": [2, 16, 1], "pos_ids": [16]})
+    by_type = {}
+    for c in cost.ops:
+        by_type.setdefault(c.op_type, []).append(c)
+    tokens = 32
+    assert [c.flops for c in by_type["moe_router"]] == [2 * tokens * 32 * 8] * 2
+    # expectation under uniform routing: tokens * top_k * held / total
+    assert [c.flops for c in by_type["moe_experts"]] == \
+        [6 * (tokens * 2 * 2 // 8) * 32 * 24] * 2
+    # attention: QK^T at the key width (24), PV at the value width (16)
+    assert all(c.flops == 2 * 2 * 2 * 16 * 16 * (24 + 16)
+               + 5 * 2 * 2 * 16 * 16
+               for c in by_type["scaled_dot_product_attention"])
+    assert all(c.flops > 0 for t in ("rms_norm", "rotary_embedding")
+               for c in by_type[t])
+    assert not cost.unresolved
