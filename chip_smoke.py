@@ -53,13 +53,16 @@ FULL = dict(vocab=32000, n_layer=6, n_head=8, d_model=512, d_inner=2048,
             prompt_buckets=[128, 512], cache_buckets=[512, 2048],
             prompt_lens=[37, 120, 300, 480], deep_prompt=500,
             new_tokens=32, rnn=(64, 64, 512),       # bench.py LSTM-LM T,B,H
-            flash_odd_seq=1100, mesh_batch=8, mesh_steps=3)
+            flash_odd_seq=1100, mesh_batch=8, mesh_steps=3,
+            # joyai-llm-flash.train-ep32's attention site: B,H,S,d,d_v
+            latent_site=(1, 32, 4096, 192, 128))
 TINY = dict(vocab=96, n_layer=1, n_head=2, d_model=32, d_inner=64,
             batch=2, seq=16, steps=4, stream_steps=2,
             prompt_buckets=[8, 16], cache_buckets=[16, 32],
             prompt_lens=[3, 5, 9, 12], deep_prompt=14,
             new_tokens=4, rnn=(6, 4, 8),
-            flash_odd_seq=20, mesh_batch=4, mesh_steps=2)
+            flash_odd_seq=20, mesh_batch=4, mesh_steps=2,
+            latent_site=(1, 2, 24, 24, 16))
 
 # Stated tolerances. Losses are means over thousands of tokens, so bf16
 # rounding (eps 2^-8) mostly averages out. Kernel outputs and gradients
@@ -191,13 +194,21 @@ def phase_kernels(sm, cfg, interpret):
 
     # flash attention at the train step's shapes: causal + pad-row bias
     # (what models/transformer.py builds), and a length that is not a
-    # multiple of 128 (the _clamp_blocks padding path)
+    # multiple of 128 (the _clamp_blocks padding path); then the
+    # latent-attention site of models/decoder_moe.py: keys wider than
+    # values, causal, no bias
     b, h, d = cfg["batch"], cfg["n_head"], cfg["d_model"] // cfg["n_head"]
-    for s in (cfg["seq"], cfg["flash_odd_seq"]):
-        q, k, v = (jnp.asarray(rng.randn(b, h, s, d) * 0.5, jnp.bfloat16)
-                   for _ in range(3))
+    bwd_before = flash_bwd_sites()
+    shapes = [(b, h, s, d, d, True)
+              for s in (cfg["seq"], cfg["flash_odd_seq"])]
+    shapes.append(cfg["latent_site"] + (False,))
+    for b, h, s, d, d_v, masked in shapes:
+        q, k = (jnp.asarray(rng.randn(b, h, s, d) * 0.5, jnp.bfloat16)
+                for _ in range(2))
+        v = jnp.asarray(rng.randn(b, h, s, d_v) * 0.5, jnp.bfloat16)
         pad = np.zeros((b, 1, 1, s), np.float32)
-        pad[:, :, :, s - s // 8:] = -1e9
+        if masked:
+            pad[:, :, :, s - s // 8:] = -1e9
         bias = jnp.asarray(pad)
 
         def naive(q, k, v, bias):
@@ -209,14 +220,20 @@ def phase_kernels(sm, cfg, interpret):
             return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
         def flash(q, k, v, bias):
-            return flash_attention(q, k, v, bias, causal=True,
-                                   interpret=interpret)
+            return flash_attention(q, k, v, bias if masked else None,
+                                   causal=True, interpret=interpret)
 
         fwd, bwd = both(flash, naive, (q, k, v, bias), (0, 1, 2))
         sm.check(fwd < FLASH_RTOL and bwd < FLASH_RTOL,
-                 f"kernel flash_attention [{b},{h},{s},{d}] bf16 "
-                 "causal+bias agrees with the naive composition",
+                 f"kernel flash_attention [{b},{h},{s},{d}/{d_v}] bf16 "
+                 f"causal{'+bias' if masked else ''} agrees with the "
+                 "naive composition",
                  fwd_reldiff=fwd, grad_reldiff=bwd, rtol=FLASH_RTOL)
+    bwd_sites = dict(flash_bwd_sites() - bwd_before)
+    sm.check(bwd_sites == {"resident": len(shapes)},
+             "kernel flash_attention: each backward kept its head's K "
+             "and V resident (one kernel, dQ finished in it)",
+             flash_bwd_sites=bwd_sites)
 
     t_max, bsz, hid = cfg["rnn"]
     lens = jnp.asarray(rng.randint(t_max // 2, t_max + 1, bsz), jnp.int32)
@@ -306,12 +323,22 @@ def write_shards(workdir, rng, cfg, n_shards=2):
     return paths
 
 
-def sdpa_sites():
-    """Counter of "path/mask/causal": attention sites traced so far."""
+def _site_counts(family):
     from paddle_tpu.observability import default_registry
-    fam = default_registry().get("paddle_tpu_sdpa_sites_total")
+    fam = default_registry().get(family)
     return collections.Counter() if fam is None else collections.Counter(
         {"/".join(labels): child.value for labels, child in fam.samples()})
+
+
+def sdpa_sites():
+    """Counter of "path/mask/causal": attention sites traced so far."""
+    return _site_counts("paddle_tpu_sdpa_sites_total")
+
+
+def flash_bwd_sites():
+    """Counter of "path": flash backward calls traced so far, by what
+    their byte count let them keep in VMEM."""
+    return _site_counts("paddle_tpu_flash_bwd_sites_total")
 
 
 def build_transformer(cfg):
@@ -365,11 +392,12 @@ def phase_train(sm, cfg, device, workdir):
     # off-TPU the op keeps the naive path unless forced; the rehearsal
     # forces the kernels (interpret mode) so the same code is traced
     force = {} if on_tpu else {"PADDLE_TPU_PALLAS_SDPA": "force"}
-    sites_before = sdpa_sites()
+    sites_before, bwd_before = sdpa_sites(), flash_bwd_sites()
     with env(**force):
         trainer.train(1, lambda: [batch0] * cfg["steps"],
                       event_handler=handler)
     sites = dict(sdpa_sites() - sites_before)
+    bwd_sites = dict(flash_bwd_sites() - bwd_before)
     entry = next(v for k, v in exe._cache.items() if k[0] == main.desc.uid)
     compiled = aot_compiled_for(exe, main)
     flash_calls = compiled.as_text().count("tpu_custom_call")
@@ -401,7 +429,7 @@ def phase_train(sm, cfg, device, workdir):
                             built_by_this_run=not cfg["lib_existed"]),
         tpu_custom_calls=dict(train_step=flash_calls,
                               naive_eval=naive_calls),
-        sdpa_sites=sites,
+        sdpa_sites=sites, flash_bwd_sites=bwd_sites,
         compile_cache=dict(exe.cache_stats),
         memory=dict(planner_peak_bytes=entry.memory.peak_bytes
                     if entry.memory else None,
@@ -437,11 +465,16 @@ def phase_train(sm, cfg, device, workdir):
              "train: every attention site of the step took the flash "
              "kernels with a key-row mask, the decoder's own with the "
              "causal flag, none with a dense mask", sites=sites)
+    sm.check(bwd_sites == {"resident": n_sites},
+             "train: every site's backward is the one kernel with its "
+             "head's K and V resident, none walks them in segments",
+             flash_bwd_sites=bwd_sites)
     if on_tpu:
-        sm.check(flash_calls == 3 * n_sites and naive_calls == 0,
-                 "train: forward, dq and dkv kernel of every attention "
-                 "site in the train step's HLO, none in the naive "
-                 "program", train_step=flash_calls, naive_eval=naive_calls)
+        sm.check(flash_calls == 2 * n_sites and naive_calls == 0,
+                 "train: the forward and the one backward kernel of every "
+                 "attention site in the train step's HLO, none in the "
+                 "naive program", train_step=flash_calls,
+                 naive_eval=naive_calls)
     exe.close()
 
 

@@ -14,9 +14,17 @@ broadcastable as [B, {1|H}, Sq, Sk]. The grid iterates
 semantics) so VMEM scratch accumulators carry across k-blocks while Mosaic
 pipelines the HBM->VMEM block copies.
 
-The backward pass is two more Pallas kernels (dq and dkv) using the
-logsumexp residual, plus an exact additive-bias gradient emitted from the
-dq kernel — the standard flash-attention-2 recurrence.
+The backward pass is ONE more Pallas kernel using the logsumexp
+residual — the flash-attention-2 recurrence with each score tile's s, p,
+dp and ds computed once and feeding dV, dK and dQ (a dq and a dkv kernel
+would each recompute them). Its grid is (batch, head, k-segment, q-block):
+a head's K and V, with f32 dK and dV accumulators beside them, stay
+resident in VMEM while the q-blocks stream, an in-kernel loop walks the
+k-blocks (up to the diagonal on a causal site), and dQ leaves finished.
+How much of K and V stays resident is a byte count against _VMEM_BUDGET:
+where a whole head does not fit, the same kernel takes them a segment at
+a time and dQ leaves as an f32 partial a segment for XLA to sum. An
+exact additive-bias gradient is emitted from the same tiles on request.
 """
 from __future__ import annotations
 
@@ -201,70 +209,66 @@ def _scratch(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def _compiler_params(dimension_semantics):
-    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
+def _compiler_params(dimension_semantics, **more):
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                **more)
 
 
 # ---------------------------------------------------------------------------
-# backward kernels
+# backward kernel
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dbias_ref, dq_scr, *, sm_scale, causal, block_q,
-               block_k, kv_len):
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    run = True
-    if causal:
-        run = iq * block_q + block_q - 1 >= ik * block_k
-
-    @pl.when(run)
-    def _step():
-        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
-        do = do_ref[0, 0]                                    # [bq, dv]
-        lse = lse_ref[0, 0][:, :1]                           # [bq, 1]
-        delta = delta_ref[0, 0][:, :1]                       # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
-        if causal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse)                                 # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq, bk]
-        ds = p * (dp - delta)                                # [bq, bk]
-        if dbias_ref is not None:
-            dbias_ref[0, 0] = ds.astype(dbias_ref.dtype)
-        dq_scr[:] += sm_scale * jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if dbias_ref is not None:
-        @pl.when(jnp.logical_not(run))
-        def _zero_bias():
-            dbias_ref[0, 0] = jnp.zeros_like(dbias_ref[0, 0])
-
-    @pl.when(ik == nk - 1)
-    def _fin():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+# What one backward call may plan to keep in VMEM: half of a v5e core's
+# 128 MiB, the rest being Mosaic's own. The call asks for what it
+# counted (vmem_limit_bytes), not for the default scoped 16 MiB.
+_VMEM_BUDGET = 64 << 20
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                block_q, block_k, kv_len):
-    ik, iq = pl.program_id(2), pl.program_id(3)
+def _bwd_blocks(sq, sk, block_q, block_k, interpret):
+    """The backward's tiles under the caps: each axis split into the
+    fewest tiles that respect its cap, the tile shrunk to fit. A score
+    tile is held keys-down [block_k, block_q] and K, V, dK, dV are
+    sliced by rows of block_k, so compiled tiles are whole 128-lane
+    columns one way and whole packed sublane tiles the other."""
+    unit = 8 if interpret else 128
+
+    def fit(s, cap):
+        n = -(-s // max(cap, unit))
+        return _ceil_to(-(-s // n), unit)
+
+    return fit(sq, block_q), fit(sk, block_k)
+
+
+def _bwd_vmem_bytes(chunks, block_q, block_k, d, dv, itemsize, bias_lanes,
+                    emit_dbias):
+    """Bytes a backward call keeps in VMEM with `chunks` k-blocks of one
+    head resident: K, V and the dK, dV blocks double-buffered by the
+    pipeline, their f32 accumulators, the bias (and dbias) rows beside
+    them; one q-block's operands and dQ; the f32 score-sized values of
+    a tile in flight."""
+    per_key = (4 * itemsize + 4) * (d + dv) + 8 * bias_lanes
+    if emit_dbias:
+        per_key += 8 * block_q
+    per_step = block_q * (2 * itemsize * (d + dv) + 12 * d + 256)
+    return (chunks * block_k * per_key + per_step
+            + 6 * 4 * block_q * block_k)
+
+
+def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, kv_len, chunks,
+                bias_kind, emit_dbias):
+    """One q-block against the `chunks` k-blocks of one resident
+    k-segment, scores held keys-down ([block_k, block_q]: the row
+    statistics are rows, and only dQ's product contracts over a
+    transposed operand). Each tile's s, p, dp, ds are computed once and
+    feed dV, dK and dQ."""
+    n_in = 6 + (bias_kind is not None)
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if bias_kind is not None else None
+    do_ref, lse_ref, delta_ref = refs[n_in - 3:n_in]
+    dq_ref, dk_ref, dv_ref = refs[n_in:n_in + 3]
+    dbias_ref = refs[n_in + 3] if emit_dbias else None
+    dq_scr, dk_scr, dv_scr = refs[-3:]
+    ks, iq = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
 
     @pl.when(iq == 0)
@@ -272,43 +276,76 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = True
-    if causal:
-        run = iq * block_q + block_q - 1 >= ik * block_k
+    dq_scr[:] = jnp.zeros_like(dq_scr)
+    if emit_dbias:        # the tiles that do not run still own a block
+        dbias_ref[0, 0] = jnp.zeros_like(dbias_ref[0, 0])
 
-    @pl.when(run)
-    def _step():
-        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
+    q, do = q_ref[0, 0], do_ref[0, 0]          # [bq, d], [bq, dv]
+    lse, delta = lse_ref[0, 0, 0], delta_ref[0, 0, 0]       # [1, bq]
+    first = ks * chunks
+    # k-blocks past the last key, or wholly above the causal diagonal:
+    # nothing to do
+    stop = jnp.minimum(chunks, -(-kv_len // block_k) - first)
+    if causal:
+        stop = jnp.minimum(
+            stop, (iq * block_q + block_q - 1) // block_k + 1 - first)
+
+    def _tile(c, carry):
+        rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+        k, v = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [bk, bq]
+        if bias_kind == "key":      # one value a key, on every lane
+            s = s + bias_ref[0, 0, rows, :][:, :1].astype(jnp.float32)
+        elif bias_kind == "score":
+            s = s + bias_ref[0, 0, rows, :].astype(jnp.float32)
+        kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        if kv_len % block_k:        # mask seq padding
+            s = jnp.where(kpos < kv_len, s, NEG_INF)
         if causal:
             qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
+                jnp.int32, s.shape, 1)
             s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse)                                 # [bq, bk]
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        p = jnp.exp(s - lse)                                 # [bk, bq]
+        dv_scr[rows, :] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # [bk, dv]
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [bk, bq]
         ds = p * (dp - delta)
-        dk_scr[:] += sm_scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        if emit_dbias:
+            dbias_ref[0, 0, rows, :] = ds
+        ds = ds.astype(q.dtype)
+        dk_scr[rows, :] += sm_scale * jax.lax.dot_general(
+            ds, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # [bk, d]
+        dq_scr[:] += sm_scale * jax.lax.dot_general(
+            ds, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [bq, d]
+        return carry
+
+    jax.lax.fori_loop(0, stop, _tile, None)
+    dq_ref[0, 0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
     @pl.when(iq == nq - 1)
     def _fin():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _count_bwd_site(path):
+    from ...observability.registry import default_registry
+    default_registry().counter(
+        "paddle_tpu_flash_bwd_sites_total",
+        "flash-attention backward calls traced, by what the call's byte "
+        "count against the VMEM budget let it keep resident (resident: "
+        "a head's whole K and V, so dK, dV and dQ leave the kernel "
+        "finished; partial: K and V a segment at a time, dQ written "
+        "once a segment in f32 and summed by XLA).",
+        ("path",)).labels(path=path).inc()
 
 
 def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
@@ -317,140 +354,166 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     do = g
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k, interpret)
-    sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_k)
+    bias_kind = None if bias is None else (
+        "key" if bias.shape[2] == 1 else "score")
+    emit_dbias = bias is not None and bias_needs_grad
+    # Tile caps the caller left open: a score-sized bias or dbias block
+    # rides with every tile, so those keep the forward's caps; a
+    # key-row mask is one column of a tile and caps nothing. Half of a
+    # tile on the causal diagonal is masked work, so a causal site
+    # takes the smaller tile (v5e, PR 38: 1.81 against 1.99 ms a site
+    # of 64 heads x 2048 x 64; not causal 2.50 against 2.42).
+    if emit_dbias:
+        cap = 128
+    elif bias_kind == "score" or causal:
+        cap = 512
+    else:
+        cap = 1024
+    block_q, block_k = _bwd_blocks(
+        sq, sk, cap if block_q is None else block_q,
+        cap if block_k is None else block_k, interpret)
+    nq, nk = -(-sq // block_q), -(-sk // block_k)
+    # How much of a head's K and V stays resident is a byte count: all
+    # of it where that fits, else the fewest equal segments that do.
+    bias_lanes = {None: 0, "key": 128, "score": block_q}[bias_kind]
+
+    def vmem_bytes(chunks):
+        return _bwd_vmem_bytes(chunks, block_q, block_k, d, dv,
+                               q.dtype.itemsize, bias_lanes, emit_dbias)
+
+    chunks = nk
+    while chunks > 1 and vmem_bytes(chunks) > _VMEM_BUDGET:
+        chunks -= 1
+    nseg = -(-nk // chunks)
+    chunks = -(-nk // nseg)
+    seg = chunks * block_k
+    sq_p, sk_p = nq * block_q, nseg * seg
+    _count_bwd_site("resident" if nseg == 1 else "partial")
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)                 # [B,H,Sq,1]
+                    axis=-1)                                # [B,H,Sq]
     pad_q = ((0, 0), (0, 0), (0, sq_p - sq), (0, 0))
     pad_k = ((0, 0), (0, 0), (0, sk_p - sk), (0, 0))
     qp, dop = jnp.pad(q, pad_q), jnp.pad(do, pad_q)
     kp, vp = jnp.pad(k, pad_k), jnp.pad(v, pad_k)
-    # lse rows for padded q positions must not produce NaN in exp(s - lse):
-    lsep = jnp.pad(jnp.broadcast_to(lse, (b, h, sq, 128)), pad_q)
-    deltap = jnp.pad(jnp.broadcast_to(delta, (b, h, sq, 128)), pad_q)
 
-    def qspec(im, width=d):
-        return pl.BlockSpec((1, 1, block_q, width), im)
+    def rows(x):        # a row a q-block; pads read lse 0: exp stays finite
+        return jnp.pad(x, pad_q[:3]).reshape(b, h, nq, 1, block_q)
 
-    def kspec(im, width=d):
-        return pl.BlockSpec((1, 1, block_k, width), im)
+    def qspec(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, ks, iq: (b, h, iq, 0))
 
-    def rspec(im):  # row stats [.., 128]
-        return pl.BlockSpec((1, 1, block_q, 128), im)
+    def kspec(width):
+        return pl.BlockSpec((1, 1, seg, width),
+                            lambda b, h, ks, iq: (b, h, ks, 0))
 
-    # ---- dq (+ dbias) over grid (b, h, iq, ik), k innermost ----
-    qk_q = lambda b, h, iq, ik: (b, h, iq, 0)
-    qk_k = lambda b, h, iq, ik: (b, h, ik, 0)
-    in_specs = [qspec(qk_q), kspec(qk_k), kspec(qk_k, dv)]
+    rspec = pl.BlockSpec((1, 1, 1, 1, block_q),
+                         lambda b, h, ks, iq: (b, h, iq, 0, 0))
+    in_specs = [qspec(d), kspec(d), kspec(dv)]
     args = [qp, kp, vp]
-    has_bias = bias is not None
-    if has_bias:
-        biasp, bspec = _bias_spec(bias, sq_p, sk_p, block_q, block_k, "qk")
-        in_specs.append(bspec)
+    if bias is not None:
+        bb, bh = bias.shape[:2]
+        if bias_kind == "key":      # [.., 1, Sk|1] -> [.., sk_p, 128]
+            biasp = jnp.broadcast_to(
+                jnp.pad(jnp.broadcast_to(bias[:, :, 0], (bb, bh, sk)),
+                        pad_k[:3])[..., None], (bb, bh, sk_p, 128))
+        else:                       # [.., Sq, Sk|1] -> [.., sk_p, sq_p]
+            biasp = jnp.pad(
+                jnp.swapaxes(jnp.broadcast_to(bias, (bb, bh, sq, sk)), 2, 3),
+                ((0, 0), (0, 0), (0, sk_p - sk), (0, sq_p - sq)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, seg, bias_lanes),
+            lambda b, h, ks, iq: (0 if bb == 1 else b, 0 if bh == 1 else h,
+                                  ks, iq if bias_kind == "score" else 0)))
         args.append(biasp)
-    in_specs += [qspec(qk_q, dv), rspec(qk_q), rspec(qk_q)]
-    args += [dop, lsep, deltap]
+    in_specs += [qspec(dv), rspec, rspec]
+    args += [dop, rows(lse[..., 0]), rows(delta)]
 
-    out_shape = [jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype)]
-    out_specs = [qspec(qk_q)]
-    emit_dbias = has_bias and bias_needs_grad
+    # dQ a k-segment: finished where there is one, else an f32 partial
+    out_shape = [
+        jax.ShapeDtypeStruct((nseg, b, h, sq_p, d),
+                             q.dtype if nseg == 1 else jnp.float32),
+        jax.ShapeDtypeStruct((b, h, sk_p, d), k.dtype),
+        jax.ShapeDtypeStruct((b, h, sk_p, dv), v.dtype)]
+    out_specs = [
+        pl.BlockSpec((1, 1, 1, block_q, d),
+                     lambda b, h, ks, iq: (ks, b, h, iq, 0)),
+        kspec(d), kspec(dv)]
     if emit_dbias:
         out_shape.append(jax.ShapeDtypeStruct(
-            (b, h, sq_p, sk_p), jnp.float32))
+            (b, h, sk_p, sq_p), jnp.float32))
         out_specs.append(pl.BlockSpec(
-            (1, 1, block_q, block_k), lambda b, h, iq, ik: (b, h, iq, ik)))
+            (1, 1, seg, block_q), lambda b, h, ks, iq: (b, h, ks, iq)))
 
-    def dq_kernel(*refs):
-        n_in = len(args)
-        ins, outs, scr = refs[:n_in], refs[n_in:-1], refs[-1]
-        bias_ref = ins[3] if has_bias else None
-        rest = ins[3 + int(has_bias):]
-        _dq_kernel(ins[0], ins[1], ins[2], bias_ref, rest[0], rest[1],
-                   rest[2], outs[0],
-                   outs[1] if emit_dbias else None, scr,
-                   sm_scale=sm_scale, causal=causal, block_q=block_q,
-                   block_k=block_k, kv_len=sk)
-
-    res_dq = pl.pallas_call(
-        dq_kernel,
-        name="flash_bwd_dq",
-        grid=(b, h, sq_p // block_q, sk_p // block_k),
+    outs = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_k=block_k, kv_len=sk, chunks=chunks, bias_kind=bias_kind,
+            emit_dbias=emit_dbias),
+        # the benchmark's readers find the backward by flash_bwd_(dq|dkv)
+        name="flash_bwd_dkv_dq",
+        grid=(b, h, nseg, nq),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[_scratch((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(("parallel",) * 3 + ("arbitrary",)),
+        scratch_shapes=[_scratch((block_q, d), jnp.float32),
+                        _scratch((seg, d), jnp.float32),
+                        _scratch((seg, dv), jnp.float32)],
+        compiler_params=_compiler_params(
+            ("parallel",) * 3 + ("arbitrary",),
+            vmem_limit_bytes=max(32 << 20, 5 * vmem_bytes(chunks) // 4)),
         interpret=interpret,
     )(*args)
+    dq, dk, dv_ = outs[:3]
+    dq = dq[0] if nseg == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
     if emit_dbias:
-        dq, dbias_full = res_dq
-        dbias_full = dbias_full[:, :, :sq, :sk]
+        dbias = jnp.swapaxes(outs[3], 2, 3)[:, :, :sq, :sk]
         # reduce over every broadcast dim of the original bias
         for ax in range(4):
-            if bias.shape[ax] == 1 and dbias_full.shape[ax] != 1:
-                dbias_full = jnp.sum(dbias_full, axis=ax, keepdims=True)
-        dbias = dbias_full.astype(bias.dtype)
+            if bias.shape[ax] == 1 and dbias.shape[ax] != 1:
+                dbias = jnp.sum(dbias, axis=ax, keepdims=True)
+        dbias = dbias.astype(bias.dtype)
     else:
-        dq = res_dq[0]
         dbias = jnp.zeros_like(bias) if bias is not None else None
-    dq = dq[:, :, :sq]
-
-    # ---- dk/dv over grid (b, h, ik, iq), q innermost ----
-    kq_q = lambda b, h, ik, iq: (b, h, iq, 0)
-    kq_k = lambda b, h, ik, iq: (b, h, ik, 0)
-    in_specs = [qspec(kq_q), kspec(kq_k), kspec(kq_k, dv)]
-    args2 = [qp, kp, vp]
-    if has_bias:
-        biasp, bspec = _bias_spec(bias, sq_p, sk_p, block_q, block_k, "kq")
-        in_specs.append(bspec)
-        args2.append(biasp)
-    in_specs += [qspec(kq_q, dv), rspec(kq_q), rspec(kq_q)]
-    args2 += [dop, lsep, deltap]
-
-    def dkv_kernel(*refs):
-        n_in = len(args2)
-        ins, outs, scr = refs[:n_in], refs[n_in:n_in + 2], refs[n_in + 2:]
-        bias_ref = ins[3] if has_bias else None
-        rest = ins[3 + int(has_bias):]
-        _dkv_kernel(ins[0], ins[1], ins[2], bias_ref, rest[0], rest[1],
-                    rest[2], outs[0], outs[1], scr[0], scr[1],
-                    sm_scale=sm_scale, causal=causal, block_q=block_q,
-                    block_k=block_k, kv_len=sk)
-
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        name="flash_bwd_dkv",
-        grid=(b, h, sk_p // block_k, sq_p // block_q),
-        in_specs=in_specs,
-        out_specs=[kspec(kq_k), kspec(kq_k, dv)],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, h, sk_p, dv), v.dtype)],
-        scratch_shapes=[_scratch((block_k, d), jnp.float32),
-                        _scratch((block_k, dv), jnp.float32)],
-        compiler_params=_compiler_params(("parallel",) * 3 + ("arbitrary",)),
-        interpret=interpret,
-    )(*args2)
-    dk, dv = dk[:, :, :sk], dv[:, :, :sk]
-    return dq, dk, dv, dbias
+    return dq[:, :, :sq], dk[:, :, :sk], dv_[:, :, :sk], dbias
 
 
 # ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
 
+def _fwd_caps(bias, bias_grad, block_q, block_k):
+    """The forward's tile caps (explicit block_q/block_k always win):
+    1024 for bias-free attention; a bias hands every tile a block of
+    its own, score-sized where it has a query axis, so mask-bias
+    defaults to 512 (~5 score-sized fp32 buffers = 5MB, well under the
+    16MB scoped-vmem limit). Trainable-bias grads show larger fp32
+    reassociation drift at big tiles (~4e-3 rel between 128 and 512 at
+    S=1024 on v5e) — they default to the original 128 tiling for
+    bit-stable gradients."""
+    if bias is None:
+        default_blk = 1024
+    elif bias_grad:
+        default_blk = 128
+    else:
+        default_blk = 512
+    return (default_blk if block_q is None else block_q,
+            default_blk if block_k is None else block_k)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret,
            bias_grad):
-    o, _ = _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
-                interpret)
+    o, _ = _fwd(q, k, v, bias, sm_scale, causal,
+                *_fwd_caps(bias, bias_grad, block_q, block_k), interpret)
     return o
 
 
 def _flash_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
                interpret, bias_grad):
-    o, lse = _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
-                  interpret)
+    o, lse = _fwd(q, k, v, bias, sm_scale, causal,
+                  *_fwd_caps(bias, bias_grad, block_q, block_k), interpret)
     return o, (q, k, v, bias, o, lse)
 
 
@@ -479,41 +542,28 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     bias_grad=False (default) treats bias as a constant mask: backward
     returns zeros for it without materializing the O(Sq*Sk) dbias buffer.
     Set bias_grad=True for trainable biases (e.g. relative-position bias);
-    the gradient is then emitted from the dq kernel and summed over any
-    broadcast dims.
+    the gradient is then emitted from the backward kernel a score tile at
+    a time and summed over any broadcast dims.
 
     block_q/block_k act as CAPS on the tile size: the sequence is split
     into the fewest cap-respecting tiles and the tile shrinks to fit
     (minimizing padding), so an explicit 256 with sq=900 runs 4 tiles
-    of 232. None selects the per-path default cap below, swept on v5e
-    with stacked-layer fwd+bwd marginal timing: 1024x1024 beat 128x128
-    by 1.4x at seq 256, 2.7x at 1024, and was still fastest at 4096.
+    of 232 forward (of 256 backward, whose compiled tiles are whole
+    128-lane columns). None lets each pass choose from what it is
+    handed: the forward by _fwd_caps, swept on v5e with stacked-layer
+    fwd+bwd marginal timing (1024x1024 beat 128x128 by 1.4x at seq 256,
+    2.7x at 1024, and was still fastest at 4096); the backward by _bwd.
     """
     if interpret is None:
         interpret = _interpret_default()
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    # Default tile caps (explicit block_q/block_k always win): 1024 for
-    # bias-free attention; a materialized bias adds score-sized blocks
-    # to every kernel's VMEM footprint, so mask-bias defaults to 512
-    # (~5 score-sized fp32 buffers = 5MB, well under the 16MB
-    # scoped-vmem limit). Trainable-bias grads additionally accumulate
-    # dbias tiles and show larger fp32 reassociation drift at big tiles
-    # (~4e-3 rel between 128 and 512 at S=1024 on v5e) — they default
-    # to the original 128 tiling for bit-stable gradients.
-    if bias is None:
-        default_blk = 1024
-    elif bias_grad:
-        default_blk = 128
-    else:
-        default_blk = 512
-    block_q = default_blk if block_q is None else block_q
-    block_k = default_blk if block_k is None else block_k
     if bias is not None:
         if bias.ndim == 2:        # [Sq|1, Sk|1]
             bias = bias[None, None]
         elif bias.ndim == 3:      # [B|1, Sq|1, Sk|1]
             bias = bias[:, None]
     return _flash(q, k, v, bias, float(sm_scale), bool(causal),
-                  int(block_q), int(block_k), bool(interpret),
-                  bool(bias_grad))
+                  None if block_q is None else int(block_q),
+                  None if block_k is None else int(block_k),
+                  bool(interpret), bool(bias_grad))

@@ -121,6 +121,83 @@ def test_causal_flag_and_key_row_bias_equal_the_dense_bias(S):
                                    atol=1e-6, rtol=1e-6, err_msg=name)
 
 
+def _bwd_paths():
+    import collections
+
+    from paddle_tpu.observability import default_registry
+    fam = default_registry().get("paddle_tpu_flash_bwd_sites_total")
+    return collections.Counter() if fam is None else collections.Counter(
+        {labels[0]: child.value for labels, child in fam.samples()})
+
+
+# (sq, sk, d, d_v, tile cap): lengths the tiles do not divide, a value
+# head of another width, more keys than queries and fewer, and five
+# tiles each way of 1100
+_FUSED_DIMS = [(80, 80, 16, 16, 32), (100, 100, 16, 24, 32),
+               (40, 72, 16, 16, 32), (72, 40, 24, 16, 32),
+               (1100, 1100, 16, 16, 256)]
+
+
+@pytest.mark.parametrize("budget", [None, 0], ids=["resident", "partial"])
+@pytest.mark.parametrize("dims", _FUSED_DIMS,
+                         ids=lambda d: "x".join(map(str, d)))
+@pytest.mark.parametrize("bias_kind", ["none", "key_row", "dense",
+                                       "trainable_head_key",
+                                       "trainable_query_key"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_backward_matches_naive(causal, bias_kind, dims, budget,
+                                      monkeypatch):
+    """The one backward kernel (ISSUE 38) against jax.grad of the
+    composition: dq, dk, dv, and dbias where the bias is trained, with a
+    head's K and V resident and — the budget taken away — a k-block at a
+    time, where every q-block writes an f32 partial dQ a k-block and a
+    causal site's skipped tiles must write their zeros."""
+    import importlib
+    sq, sk, d, dv, cap = dims
+    B, H = (1, 2) if sq > 1000 else (2, 2)
+    if sq > 1000 and bias_kind not in ("none", "key_row"):
+        pytest.skip("the long case runs the cells' two mask kinds")
+    if budget is not None:
+        monkeypatch.setattr(importlib.import_module(
+            "paddle_tpu.ops.pallas.flash_attention"), "_VMEM_BUDGET",
+            budget)
+    q, k = _rand((B, H, sq, d), 0), _rand((B, H, sk, d), 1)
+    v, w = _rand((B, H, sk, dv), 2), _rand((B, H, sq, dv), 3)
+    rng = np.random.RandomState(4)
+    bias = {
+        "none": None,
+        "key_row": np.where(rng.rand(B, 1, 1, sk) < 0.2, -1e9, 0.0),
+        "dense": np.where(rng.rand(B, 1, sq, sk) < 0.1, -1e9, 0.0),
+        "trainable_head_key": rng.randn(1, H, 1, sk) * 0.1,
+        "trainable_query_key": rng.randn(1, 1, sq, sk) * 0.1,
+    }[bias_kind]
+    if bias is not None:
+        bias = np.asarray(bias, np.float32)
+        if not bias_kind.startswith("trainable"):
+            bias[..., 0] = 0.0      # no row without a key
+    trained = bias_kind.startswith("trainable")
+    argnums = (0, 1, 2, 3) if trained else (0, 1, 2)
+
+    def loss_flash(q, k, v, b):
+        return jnp.sum(w * flash_attention(
+            q, k, v, b, causal=causal, block_q=cap, block_k=cap,
+            interpret=True, bias_grad=trained))
+
+    def loss_naive(q, k, v, b):
+        return jnp.sum(w * naive(q, k, v, b, causal=causal))
+
+    paths = _bwd_paths()
+    got = jax.grad(loss_flash, argnums)(q, k, v, bias)
+    segments = -(-sk // cap) if budget == 0 else 1
+    assert _bwd_paths() - paths == {
+        "resident" if segments == 1 else "partial": 1}
+    want = jax.grad(loss_naive, argnums)(q, k, v, bias)
+    for name, a, b in zip(("dq", "dk", "dv", "dbias"), got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+
+
 def test_flash_uneven_kv_len():
     # Sq != Sk and not multiples of the block size: padding must be masked.
     B, H, Sq, Sk, D = 1, 1, 40, 72, 16

@@ -60,41 +60,79 @@ def _sum_f32(outs):
                for o in jax.tree_util.tree_leaves(outs))
 
 
+def _bwd_sites():
+    """Counter of path: flash backward calls traced so far."""
+    import collections
+
+    from paddle_tpu.observability import default_registry
+    fam = default_registry().get("paddle_tpu_flash_bwd_sites_total")
+    return collections.Counter() if fam is None else collections.Counter(
+        {labels[0]: child.value for labels, child in fam.samples()})
+
+
 # [B, H, S, D] of chip_smoke.py's train step (b4 x s2048, 8 heads of
 # 64), and a length that is not a multiple of 128: _clamp_blocks pads
 # it per block, which interpret mode never checks against Mosaic's
-# (8, 128) tiling.
-@pytest.mark.parametrize("seq", [2048, 1100])
-@pytest.mark.parametrize("bias", ["causal", "pad_row", "causal_pad_row",
-                                  "dense"])
-def test_flash_attention_fwd_bwd_compiles(one_chip, seq, bias):
-    q = jax.ShapeDtypeStruct((4, 8, seq, 64), jnp.bfloat16,
+# (8, 128) tiling. Then train-ep32's site (chipbench/configs/
+# joyai-llm-flash.json: 1 x 32 x 4096, 192-wide keys, 128-wide values,
+# causal, no bias), whose resident K and V with their f32 accumulators
+# pass the default scoped VMEM: the call asks for what it counted.
+@pytest.mark.parametrize("shape,bias", [
+    ((4, 8, s, 64, 64), m) for s in (2048, 1100)
+    for m in ("causal", "pad_row", "causal_pad_row", "dense")
+] + [((1, 32, 4096, 192, 128), "causal")],
+    ids=lambda v: v if isinstance(v, str) else "x".join(map(str, v)))
+def test_flash_attention_fwd_bwd_compiles(one_chip, shape, bias):
+    b, h, seq, d, d_v = shape
+    q = jax.ShapeDtypeStruct((b, h, seq, d), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, h, seq, d_v), jnp.bfloat16,
                              sharding=one_chip)
     # the masks models/transformer.py builds: a [B,1,1,S] pad-row mask
     # (encoder and cross attention), the same with causal=True (decoder
     # self-attention); a [B,1,S,S] bias is what an op handed a dense
     # mask still reaches the kernels with
-    mshape = {"causal": None, "pad_row": (4, 1, 1, seq),
-              "causal_pad_row": (4, 1, 1, seq),
-              "dense": (4, 1, seq, seq)}[bias]
+    mshape = {"causal": None, "pad_row": (b, 1, 1, seq),
+              "causal_pad_row": (b, 1, 1, seq),
+              "dense": (b, 1, seq, seq)}[bias]
 
     def loss(q, k, v, m=None):
         return _sum_f32(flash_attention(q, k, v, m,
                                         causal=bias.startswith("causal"),
                                         interpret=False))
 
-    args = (q, q, q) if mshape is None else (
-        q, q, q, jax.ShapeDtypeStruct(mshape, jnp.float32,
+    args = (q, q, v) if mshape is None else (
+        q, q, v, jax.ShapeDtypeStruct(mshape, jnp.float32,
                                       sharding=one_chip))
-    # forward + dq + dkv
-    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), *args) == 3
+    sites = _bwd_sites()
+    # forward + the one backward kernel, a head's K and V resident
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), *args) == 2
+    assert _bwd_sites() - sites == {"resident": 1}
 
 
-def test_train_step_holds_54_kernels_and_no_score_sized_mask(
+def test_flash_backward_beyond_the_vmem_budget_compiles(one_chip):
+    """A head whose K and V with their accumulators pass the budget
+    (32,768 keys of 128: 100 MB) is walked a segment at a time, dQ an
+    f32 partial a segment: the same kernel, and it compiles."""
+    q = jax.ShapeDtypeStruct((1, 2, 32768, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return _sum_f32(flash_attention(q, k, v, causal=True,
+                                        interpret=False))
+
+    sites = _bwd_sites()
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 2
+    assert _bwd_sites() - sites == {"partial": 1}
+
+
+def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_mask(
         one_chip, monkeypatch):
     """The step of transformer-base.train-s2048 (chipbench/configs,
     batch 8 x sequence 2048, AMP bf16), compiled whole for the described
-    chip: 18 attention sites x (forward, dq, dkv), and causality reaches
+    chip: 18 attention sites x (forward, one backward kernel that forms
+    each score tile once: ISSUE 38; the dq / dkv pair made it 54), every
+    backward with its head's K and V resident, and causality reaches
     them as a flag, so nothing of [.., 2048, 2048] f32 is an operand or
     a constant (ISSUE 32; until then the decoder's self-attention was
     handed an f32[8,1,2048,2048] sum of triangle and pad mask)."""
@@ -105,6 +143,7 @@ def test_train_step_holds_54_kernels_and_no_score_sized_mask(
     from paddle_tpu.models import transformer
 
     batch, seq, vocab = 8, 2048, 32000
+    sites = _bwd_sites()
     # the rule asks jax.default_backend(), which is still the CPU here
     monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", "force")
     monkeypatch.setattr(
@@ -139,7 +178,12 @@ def test_train_step_holds_54_kernels_and_no_score_sized_mask(
                 sds((), jnp.int32)).compile().as_text()
     finally:
         pt.reset_global_scope()       # a gigabyte of weights and moments
-    assert text.count("tpu_custom_call") == 54
+    assert text.count("tpu_custom_call") == 36
+    calls = re.findall(
+        r"%\S*(flash_[a-z_]*?)_*\.\d+ = [^\n]*tpu_custom_call", text)
+    assert sorted(set(calls)) == ["flash_bwd_dkv_dq", "flash_fwd"]
+    assert calls.count("flash_fwd") == 18
+    assert _bwd_sites() - sites == {"resident": 18}
     score_sized = re.findall(
         rf"f32\[(?:\d+,)*{seq},{seq}\]", text)
     # [8, 2048, d_inner = 2048] activations are the only such shape
@@ -201,6 +245,13 @@ def test_flash_attention_under_a_mesh_compiles_per_shard(topo):
     text = jax.jit(sharded).lower(q, q, q, m).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     # each device attends its own [4, 4, 2048, 64] shard: no collective
+    assert "all-gather" not in text and "all-reduce" not in text
+    # and differentiates it there: the forward and the one backward
+    # kernel a shard, what train-mesh-dp2tp2 runs 18 times a step
+    text = jax.jit(jax.grad(
+        lambda q, k, v, m: _sum_f32(sharded(q, k, v, m)),
+        argnums=(0, 1, 2))).lower(q, q, q, m).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
     assert "all-gather" not in text and "all-reduce" not in text
 
 

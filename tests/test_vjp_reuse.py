@@ -200,7 +200,7 @@ def test_one_forward_kernel_call_a_site(builder, exe_factory, monkeypatch):
     want = {}
     for kind, n in sites.items():
         if kind == "flash":
-            want.update(flash_fwd=n, flash_bwd_dq=n, flash_bwd_dkv=n)
+            want.update(flash_fwd=n, flash_bwd_dkv_dq=n)
         else:
             want.update({kind + "_fwd": n, kind + "_bwd": n})
     assert dict(calls) == want
@@ -217,7 +217,7 @@ def test_replay_runs_the_forward_kernel_twice_a_site(monkeypatch):
     main, exe, sites, delta = _run_once(_build_sdpa_hand, monkeypatch)
     calls = _kernel_calls(_step_jaxpr(exe, main))
     assert calls["flash_fwd"] == 2 * sites["flash"]
-    assert calls["flash_bwd_dq"] == sites["flash"]
+    assert calls["flash_bwd_dkv_dq"] == sites["flash"]
     assert _served(delta, "reused") == 0
     assert _served(delta, "replayed", SDPA) == sites["flash"]
 
@@ -583,8 +583,7 @@ def test_counter_reads_the_attention_sites_of_a_transformer(monkeypatch):
     assert _served(c.delta, "reused", SDPA) == 6
     assert _served(c.delta, "replayed", SDPA) == 0
     calls = _kernel_calls(_step_jaxpr(exe, main))
-    assert dict(calls) == dict(flash_fwd=6, flash_bwd_dq=6,
-                               flash_bwd_dkv=6)
+    assert dict(calls) == dict(flash_fwd=6, flash_bwd_dkv_dq=6)
     # and so is every other site: the program is traced as built, so
     # each grad op embeds exactly the wiring its forward op has (output
     # names included) and no site of this model replays
@@ -678,14 +677,14 @@ def test_sdpa_knob_chooses_the_kernel(knob, attrs, grad, monkeypatch):
     them itself, or leaves the choice open and the knob says `force`
     (off the TPU `1` keeps the composition whatever the length); the
     op's own attr wins over the knob either way; and with a grad op the
-    backward kernels come with the forward one, because the grad op
+    backward kernel comes with the forward one, because the grad op
     differentiates the same rule."""
     calls, loss = _knob_step(monkeypatch, knob, attrs, grad)
     want = {}
     if attrs.get("use_flash", knob == "force"):
         want = {"flash_fwd": 1}
         if grad:
-            want.update(flash_bwd_dq=1, flash_bwd_dkv=1)
+            want.update(flash_bwd_dkv_dq=1)
     assert calls == want
     composed_calls, composed = _knob_step(
         monkeypatch, "0", {"use_flash": False}, grad)
