@@ -40,6 +40,7 @@ import contextlib
 import gc
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -351,7 +352,7 @@ def build_transformer(cfg):
 
 def phase_train(sm, cfg, device, workdir):
     import paddle_tpu as pt
-    from paddle_tpu import native
+    from paddle_tpu import native, profiler
     from paddle_tpu.parallel.collective_audit import aot_compiled_for
     from paddle_tpu.reader import (RawDecoder, StreamingConfig,
                                    StreamingInputService)
@@ -401,6 +402,21 @@ def phase_train(sm, cfg, device, workdir):
     entry = next(v for k, v in exe._cache.items() if k[0] == main.desc.uid)
     compiled = aot_compiled_for(exe, main)
     flash_calls = compiled.as_text().count("tpu_custom_call")
+    # the op table of the same entry (core/op_table.py): which program
+    # op each instruction of the compiled step belongs to
+    table = entry.op_table()
+    kernel_ops = collections.Counter(
+        table.ops[n].op_type if n in table.ops else None
+        for n in re.findall(r"^\s+(?:ROOT )?%?(\S+) = .*tpu_custom_call",
+                            compiled.as_text(), re.M))
+    # and two more steps under a device trace, reduced through it: the
+    # share of the device's busy time that lands on a program op
+    trace_dir = os.path.join(workdir, "op_trace")
+    with env(**force), profiler.device_profiler(trace_dir):
+        trainer.train(1, lambda: [batch0] * 2)
+    traced = profiler.device_op_times(trace_dir).get(0)
+    op_seconds = sum(r["seconds"] for r in traced["rows"]
+                     if r["role"] != "ambiguous") if traced else None
     misses_before = exe.cache_stats["misses"]
     n_mem = len(losses)
 
@@ -430,6 +446,11 @@ def phase_train(sm, cfg, device, workdir):
         tpu_custom_calls=dict(train_step=flash_calls,
                               naive_eval=naive_calls),
         sdpa_sites=sites, flash_bwd_sites=bwd_sites,
+        op_table=dict(instructions_with_a_program_op=len(table.ops),
+                      kernels={str(k): v for k, v in kernel_ops.items()},
+                      traced_busy_s=traced and traced["busy_s"],
+                      traced_on_a_program_op_s=op_seconds,
+                      without_by_time=traced and traced["unmapped_top"][:5]),
         compile_cache=dict(exe.cache_stats),
         memory=dict(planner_peak_bytes=entry.memory.peak_bytes
                     if entry.memory else None,
@@ -469,7 +490,24 @@ def phase_train(sm, cfg, device, workdir):
              "train: every site's backward is the one kernel with its "
              "head's K and V resident, none walks them in segments",
              flash_bwd_sites=bwd_sites)
+    # a JAX or libtpu that empties the HLO metadata fails here, on the
+    # chip, and not silently in a per-layer metric
+    roles = {r.role for r in table.ops.values()}
+    sm.check({"forward", "backward", "optimizer"} <= roles,
+             "train: the op table of the step holds forward, grad and "
+             "optimizer ops", roles=sorted(roles))
     if on_tpu:
+        sm.check(traced is not None
+                 and op_seconds >= 0.9 * traced["busy_s"],
+                 "train: at least 90 % of the device time a trace of the "
+                 "step shows is of instructions that carry a program op",
+                 on_a_program_op_s=op_seconds,
+                 busy_s=traced and traced["busy_s"])
+        sm.check(kernel_ops == {
+            "scaled_dot_product_attention": n_sites,
+            "__vjp__.scaled_dot_product_attention": n_sites},
+            "train: the op table puts every Mosaic call of the step under "
+            "its attention op, forward or grad", kernels=dict(kernel_ops))
         sm.check(flash_calls == 2 * n_sites and naive_calls == 0,
                  "train: the forward and the one backward kernel of every "
                  "attention site in the train step's HLO, none in the "

@@ -32,6 +32,7 @@ from ..amp import amp_enabled
 from .. import profiler
 from ..observability import trace as obs_trace
 from ..observability.registry import default_registry
+from . import op_table
 from .ir import Program, BlockDesc, OpDesc, SUB_BLOCK_ATTRS
 from .lod import LoDTensor, RaggedNested, RaggedPair, RaggedTree
 from .registry import OpRegistry, run_op
@@ -377,18 +378,39 @@ def trace_block(block: BlockDesc, env: Dict[str, Any],
             extra[VJP_PULLBACKS] = outer
 
 
+def _block_path(block) -> Tuple[int, ...]:
+    """A block's indices from its root down to itself: the `block_path`
+    of the cost model's rows (analysis/passes.py iter_blocks)."""
+    path = [block.idx]
+    while block.parent_idx >= 0:
+        block = block.program.blocks[block.parent_idx]
+        path.append(block.idx)
+    return tuple(reversed(path))
+
+
 def _trace_ops(block, env, extra, sites):
     if sites:
         from ..ops.core_ops import run_op_keeping_pullback
     keep = extra.get("keep_vars") or ()
     stats = extra.get("trace_stats")  # optional {.. -> peak_env_bytes}
-    for op in block.ops:
-        # the op type (for a grad op, its forward op's too) in the
-        # op_name of every HLO instruction the rule emits: the IR has
-        # no layer scope. Trace time only
+    path = _block_path(block)
+    for index, op in enumerate(block.ops):
+        # the op type (for a grad op, its forward op's too) and then
+        # the op itself, as cost_model.OpCost names it (block path and
+        # index), in the op_name of every HLO instruction the rule
+        # emits: "jit(step_fn)/matmul/b0.412/dot_general", which
+        # core/op_table.py reads back out of the compiled module. Trace
+        # time only, and a function of the program's content alone: a
+        # uid or a counter here would change the HLO from process to
+        # process and the persistent compile cache would never hit. An
+        # inner jax.jit that several sites share (the flash kernels,
+        # the twelve kv_cache_append sites) is lowered once and keeps
+        # its FIRST site's index: rows by type are exact, rows by op
+        # put every site's time on the first
         fwd = op.attrs.get("fwd_op") if op.type == "__vjp__" else None
         with jax.named_scope(f"__vjp__.{fwd['type']}" if fwd
-                             else op.type):
+                             else op.type), \
+                jax.named_scope(op_table.scope(path, index)):
             gop = sites and sites.get(
                 _wiring_key(op.type, op.inputs, op.outputs))
             outs = gop and run_op_keeping_pullback(op, gop, env, extra)
@@ -444,10 +466,12 @@ class CompiledProgram:
     """A jitted artifact for (program, feed signature, fetch list).
 
     `jitted`/`ro_names`/`rw_names` expose the underlying jax.jit stage for
-    AOT introspection (profiler.cost_analysis, HLO dumps)."""
+    AOT introspection (profiler.cost_analysis, HLO dumps); `lower_again`
+    is the one way to it, and `op_table` what is read from it most."""
 
     def __init__(self, fn, read_names, write_names, fetch_names,
-                 jitted=None, ro_names=(), rw_names=()):
+                 jitted=None, ro_names=(), rw_names=(), block=None,
+                 arg_shardings=None):
         self.fn = fn
         self.read_names = read_names
         self.write_names = write_names
@@ -455,6 +479,10 @@ class CompiledProgram:
         self.jitted = jitted
         self.ro_names = list(ro_names)
         self.rw_names = list(rw_names)
+        # the block `jitted` traces (its closure holds it anyway)
+        self.block = block
+        # the mesh executor's in_shardings, as (feed, ro, rw, step)
+        self.arg_shardings = arg_shardings
         # static ProgramCost of ONE traced iteration, attached by
         # Executor.run at the compile-cache miss that built this
         # executable (None when the cost model could not run)
@@ -462,6 +490,79 @@ class CompiledProgram:
         # static MemoryReport of the traced program, attached next to
         # the cost (None when the planner could not run)
         self.memory = None
+        # abstract values of `jitted`'s arguments (ShapeDtypeStructs
+        # with shardings: no device memory), recorded at that miss
+        self.avals = None
+        self._op_table = None
+
+    def record_avals(self, feed_vals, state_vals, step) -> None:
+        """Keep the abstract form of the arguments of the call about to
+        be made, so that `lower_again` needs neither a scope nor an
+        executor nor a feed. An array keeps the sharding it is committed
+        to (the mesh executor's declared ones where it compiled)."""
+        args = (feed_vals, {n: state_vals[n] for n in self.ro_names},
+                {n: state_vals[n] for n in self.rw_names}, step)
+        shardings = () if self.arg_shardings is None \
+            else (self.arg_shardings,)
+        self.avals = jax.tree_util.tree_map(_aval, args, *shardings)
+
+    def lower_again(self):
+        """The compiled executable of this entry, lowered again from the
+        recorded abstract values: THE implementation of "lower a cache
+        entry again" (`parallel.collective_audit.aot_compiled_for` is a
+        look-up in front of it). JAX re-uses the jaxpr it traced for the
+        same abstract values, so no op rule runs again, and the
+        persistent compile cache answers the compile where it is on."""
+        if self.avals is None:
+            raise RuntimeError(
+                "no recorded arguments for AOT lowering: this entry was "
+                "not compiled through Executor.run")
+        return self.jitted.lower(*self.avals).compile()
+
+    def op_table(self):
+        """{instruction name: (op type, role, block path, op index)} of
+        the compiled step and what goes with it (core/op_table.py).
+        Built on the first ask, never before, and kept as plain data."""
+        if self._op_table is None:
+            self._op_table = op_table.parse(
+                self.lower_again().as_text(), self.program_ops())
+        return self._op_table
+
+    @property
+    def uid(self) -> Optional[int]:
+        """The uid of the Program traced (this process's own number for
+        it: it names rows for a reader and never reaches the HLO)."""
+        return None if self.block is None else self.block.program.uid
+
+    def program_ops(self):
+        """{(block idx, op index): (role, scope type)} of the program
+        this entry traces (`op_table.program_ops`)."""
+        return op_table.program_ops(self.block)
+
+
+def _aval(x, sharding=None):
+    if sharding is None and isinstance(x, jax.Array) and x.committed:
+        sharding = x.sharding
+    return jax.ShapeDtypeStruct(
+        np.shape(x), jax.dtypes.canonicalize_dtype(np.result_type(x)),
+        sharding=sharding, weak_type=getattr(x, "weak_type", False))
+
+
+# Every step program compiled in this process, newest last: a reader
+# that holds no executor (a telemetry thread, the benchmark after its
+# driver has closed its executor) asks here. Entries hold the jitted
+# stage and plain data, never state arrays; the oldest fall out.
+_COMPILED_MAX = 16
+_compiled_programs: "deque[CompiledProgram]" = deque(
+    maxlen=_COMPILED_MAX)
+_compiled_lock = threading.Lock()
+
+
+def compiled_programs() -> List[CompiledProgram]:
+    """The cache entries of every Executor of this process (the last
+    16), whether or not that executor is still open."""
+    with _compiled_lock:
+        return list(_compiled_programs)
 
 
 class _BlockPrefix:
@@ -952,7 +1053,7 @@ class Executor:
 
         return CompiledProgram(call, read_names, write_names, fetch_names,
                                jitted=jitted, ro_names=ro_names,
-                               rw_names=rw_names)
+                               rw_names=rw_names, block=block)
 
     # ------------------------------------------------------------------
     def run(self, program: Program, feed: Optional[Dict[str, Any]] = None,
@@ -1176,6 +1277,8 @@ class Executor:
             except Exception:
                 compiled.cost = None
             self._cache[key] = compiled
+            with _compiled_lock:
+                _compiled_programs.append(compiled)
         else:
             self.cache_stats["hits"] += 1
             obs_hits.inc()
@@ -1194,8 +1297,10 @@ class Executor:
                     "donate_state=False.")
 
         state_vals = {n: scope.get(n) for n in compiled.read_names}
-        # kept for AOT introspection (profiler cost analysis, the
-        # collective audit's HLO re-lowering)
+        if missed:
+            compiled.record_avals(feed_vals, state_vals, step)
+        # the arrays of the last feed, for a caller that traces the
+        # jitted stage itself (AOT lowering reads compiled.avals)
         self._last_feed_vals = feed_vals
         return (compiled, missed, feed_vals, state_vals, step,
                 fetch_names, n_user_fetches)
@@ -1268,10 +1373,20 @@ class Executor:
                 return compiled.cost
         return None
 
-    def cost_table(self, program=None, limit: int = 20) -> Optional[str]:
+    def cost_table(self, program=None, limit: int = 20,
+                   measured=None) -> Optional[str]:
         """Rendered per-op cost table for ``program`` (default: the
         most recently dispatched executable) — the Executor-level view
-        of the always-on attribution."""
+        of the always-on attribution. Handed ``measured``, one device's
+        entry of `profiler.device_op_times`, the rows are the ops the
+        device spent time on, heaviest first, each static count beside
+        the seconds measured, the share of busy time and the FLOP/s and
+        GB/s they make (`profiler.op_time_table`)."""
+        if measured is not None:
+            desc = getattr(program, "desc", program)
+            return profiler.op_time_table(
+                measured, by="op", limit=limit,
+                uid=None if desc is None else desc.uid)
         cost = self.cost_for(program) if program is not None \
             else self.last_cost
         return None if cost is None else cost.table(limit=limit)

@@ -235,30 +235,15 @@ def assert_collectives(inv, expectations, forbid=()) -> None:
 
 def aot_compiled_for(exe, program, scope=None):
     """AOT re-lower + compile the cached executable for `program` in
-    executor `exe`, with the same abstract state the last run used.
-    The one shared implementation of the cache-lookup-by-uid +
-    ro/rw-from-scope + jitted.lower(...).compile() dance (used by the
-    collective audit AND bench.py cost analysis)."""
-    import jax.numpy as jnp
-    import paddle_tpu as pt
-    scope = pt.global_scope() if scope is None else scope
+    executor `exe`, with the abstract arguments of the run that compiled
+    it: the look-up by uid in front of `CompiledProgram.lower_again`,
+    the one implementation of lowering a cache entry again (used by the
+    collective audit, bench.py's cost analysis, the benchmark's memory
+    reading and the op table). `scope` is accepted and not read: the
+    entry keeps its own abstract values since PR 39."""
     uid = program.desc.uid if hasattr(program, "desc") else program.uid
     entry = next(v for k, v in exe._cache.items() if k[0] == uid)
-    raise_if = [n for n in entry.ro_names + entry.rw_names
-                if scope.find(n) is None]
-    if raise_if:
-        raise RuntimeError(f"state missing from scope: {raise_if[:5]}")
-    ro = {n: scope.get(n) for n in entry.ro_names}
-    rw = {n: scope.get(n) for n in entry.rw_names}
-    feed_vals = getattr(exe, "_last_feed_vals", None)
-    if feed_vals is None:
-        raise RuntimeError(
-            "no recorded feed for AOT lowering — run the program once "
-            "before aot_compiled_for (the executor records the last "
-            "feed values)")
-    lowered = entry.jitted.lower(feed_vals, ro, rw,
-                                 jnp.zeros((), jnp.int32))
-    return lowered.compile()
+    return entry.lower_again()
 
 
 def compiled_hlo_for(exe, program, scope=None) -> str:

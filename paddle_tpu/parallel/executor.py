@@ -142,6 +142,9 @@ class ParallelExecutor(Executor):
         read_names, write_names = \
             self._state_names(program, block, scope)
         mesh = self.mesh
+        # a local, not self: the process-wide list of compiled programs
+        # (core/executor.py) keeps `fn` and must not keep this executor
+        feed_axis = self.sharding.feed_axis
         fetch_names = list(fetch_names)
         rw_names = [n for n in read_names if n in set(write_names)]
         ro_names = [n for n in read_names if n not in set(write_names)]
@@ -155,7 +158,7 @@ class ParallelExecutor(Executor):
                 "program": program,
                 "step": step,
                 "mesh": mesh,
-                "feed_axis": self.sharding.feed_axis,
+                "feed_axis": feed_axis,
                 "keep_vars": set(fetch_names) | set(write_names),
                 "prng": lambda seed: jax.random.fold_in(
                     jax.random.PRNGKey(seed), step),
@@ -296,7 +299,10 @@ class ParallelExecutor(Executor):
 
         return CompiledProgram(call, read_names, write_names,
                                fetch_names, jitted=jitted,
-                               ro_names=ro_names, rw_names=rw_names)
+                               ro_names=ro_names, rw_names=rw_names,
+                               block=block,
+                               arg_shardings=(feed_shardings, ro_shardings,
+                                              rw_shardings, step_sh))
 
     @staticmethod
     def _state_names(program, block, scope):

@@ -58,7 +58,7 @@ DCN_BW = 3.125e9     # bytes/s per chip (25 Gbit/s/chip host NIC share)
 DCN_LAT = 10e-6      # s per DCN hop
 # FLOP/s — this model is OF a v5e pod whatever device runs it, so it
 # reads the v5e row of the one peak table (shared with the live-MFU
-# gauge and profile_mfu so the three can't drift)
+# gauge so the two can't drift)
 from ..observability.attribution import PEAK_FLOPS_BY_DEVICE_KIND
 PEAK_BF16 = PEAK_FLOPS_BY_DEVICE_KIND["TPU v5 lite"]
 
