@@ -195,11 +195,11 @@ def phase_kernels(sm, cfg, interpret):
 
     # flash attention at the train step's shapes: causal + pad-row bias
     # (what models/transformer.py builds), and a length that is not a
-    # multiple of 128 (the _clamp_blocks padding path); then the
+    # multiple of 128 (the tiles shrink to it, padded); then the
     # latent-attention site of models/decoder_moe.py: keys wider than
     # values, causal, no bias
     b, h, d = cfg["batch"], cfg["n_head"], cfg["d_model"] // cfg["n_head"]
-    bwd_before = flash_bwd_sites()
+    fwd_before, bwd_before = flash_fwd_sites(), flash_bwd_sites()
     shapes = [(b, h, s, d, d, True)
               for s in (cfg["seq"], cfg["flash_odd_seq"])]
     shapes.append(cfg["latent_site"] + (False,))
@@ -230,11 +230,15 @@ def phase_kernels(sm, cfg, interpret):
                  f"causal{'+bias' if masked else ''} agrees with the "
                  "naive composition",
                  fwd_reldiff=fwd, grad_reldiff=bwd, rtol=FLASH_RTOL)
+    fwd_sites = dict(flash_fwd_sites() - fwd_before)
     bwd_sites = dict(flash_bwd_sites() - bwd_before)
-    sm.check(bwd_sites == {"resident": len(shapes)},
-             "kernel flash_attention: each backward kept its head's K "
-             "and V resident (one kernel, dQ finished in it)",
-             flash_bwd_sites=bwd_sites)
+    # `both` traces the forward alone and again under jax.grad
+    sm.check(fwd_sites == {"resident": 2 * len(shapes)}
+             and bwd_sites == {"resident": len(shapes)},
+             "kernel flash_attention: each forward and each backward kept "
+             "its head's K and V resident (one grid step a q-block; dQ "
+             "finished in the one backward kernel)",
+             flash_fwd_sites=fwd_sites, flash_bwd_sites=bwd_sites)
 
     t_max, bsz, hid = cfg["rnn"]
     lens = jnp.asarray(rng.randint(t_max // 2, t_max + 1, bsz), jnp.int32)
@@ -342,6 +346,11 @@ def flash_bwd_sites():
     return _site_counts("paddle_tpu_flash_bwd_sites_total")
 
 
+def flash_fwd_sites():
+    """The same of the forward calls."""
+    return _site_counts("paddle_tpu_flash_fwd_sites_total")
+
+
 def build_transformer(cfg):
     from paddle_tpu.models import transformer
     return transformer.build_train(
@@ -394,21 +403,29 @@ def phase_train(sm, cfg, device, workdir):
     # forces the kernels (interpret mode) so the same code is traced
     force = {} if on_tpu else {"PADDLE_TPU_PALLAS_SDPA": "force"}
     sites_before, bwd_before = sdpa_sites(), flash_bwd_sites()
+    fwd_before = flash_fwd_sites()
     with env(**force):
         trainer.train(1, lambda: [batch0] * cfg["steps"],
                       event_handler=handler)
     sites = dict(sdpa_sites() - sites_before)
     bwd_sites = dict(flash_bwd_sites() - bwd_before)
+    fwd_sites = dict(flash_fwd_sites() - fwd_before)
     entry = next(v for k, v in exe._cache.items() if k[0] == main.desc.uid)
     compiled = aot_compiled_for(exe, main)
-    flash_calls = compiled.as_text().count("tpu_custom_call")
+    text = compiled.as_text()
+    flash_calls = text.count("tpu_custom_call")
+    # the logsumexp leaves the forward [B, H, S] f32: a residual 128
+    # lanes wide (67 MB a site at 8 x 8 x 2048) would show as this
+    lane_wide = re.findall(
+        r"f32\[%d,%d,%d,128\]" % (cfg["batch"], cfg["n_head"], cfg["seq"]),
+        text)
     # the op table of the same entry (core/op_table.py): which program
     # op each instruction of the compiled step belongs to
     table = entry.op_table()
     kernel_ops = collections.Counter(
         table.ops[n].op_type if n in table.ops else None
         for n in re.findall(r"^\s+(?:ROOT )?%?(\S+) = .*tpu_custom_call",
-                            compiled.as_text(), re.M))
+                            text, re.M))
     # and two more steps under a device trace, reduced through it: the
     # share of the device's busy time that lands on a program op
     trace_dir = os.path.join(workdir, "op_trace")
@@ -445,7 +462,8 @@ def phase_train(sm, cfg, device, workdir):
                             built_by_this_run=not cfg["lib_existed"]),
         tpu_custom_calls=dict(train_step=flash_calls,
                               naive_eval=naive_calls),
-        sdpa_sites=sites, flash_bwd_sites=bwd_sites,
+        sdpa_sites=sites, flash_fwd_sites=fwd_sites,
+        flash_bwd_sites=bwd_sites,
         op_table=dict(instructions_with_a_program_op=len(table.ops),
                       kernels={str(k): v for k, v in kernel_ops.items()},
                       traced_busy_s=traced and traced["busy_s"],
@@ -486,10 +504,11 @@ def phase_train(sm, cfg, device, workdir):
              "train: every attention site of the step took the flash "
              "kernels with a key-row mask, the decoder's own with the "
              "causal flag, none with a dense mask", sites=sites)
-    sm.check(bwd_sites == {"resident": n_sites},
-             "train: every site's backward is the one kernel with its "
-             "head's K and V resident, none walks them in segments",
-             flash_bwd_sites=bwd_sites)
+    sm.check(fwd_sites == {"resident": n_sites}
+             and bwd_sites == {"resident": n_sites},
+             "train: every site's forward and backward is one kernel with "
+             "its head's K and V resident, none walks them in segments",
+             flash_fwd_sites=fwd_sites, flash_bwd_sites=bwd_sites)
     # a JAX or libtpu that empties the HLO metadata fails here, on the
     # chip, and not silently in a per-layer metric
     roles = {r.role for r in table.ops.values()}
@@ -513,6 +532,10 @@ def phase_train(sm, cfg, device, workdir):
                  "attention site in the train step's HLO, none in the "
                  "naive program", train_step=flash_calls,
                  naive_eval=naive_calls)
+        sm.check(not lane_wide,
+                 "train: no f32 buffer of a site's rows x 128 lanes in the "
+                 "step's HLO (the logsumexp leaves the forward compact)",
+                 found=lane_wide[:2])
     exe.close()
 
 

@@ -9,26 +9,37 @@ Pallas is the TPU-native equivalent of that hand-fusion layer).
 Layout: q [B, H, Sq, D], k [B, H, Sk, D], v [B, H, Sk, Dv] (Dv may differ
 from D: latent attention keeps 192-wide keys beside 128-wide values; the
 scale stays Q's), out [B, H, Sq, Dv], optional additive bias/mask
-broadcastable as [B, {1|H}, Sq, Sk]. The grid iterates
-(batch, head, q-block, k-block) with the k-block axis innermost ("arbitrary"
-semantics) so VMEM scratch accumulators carry across k-blocks while Mosaic
-pipelines the HBM->VMEM block copies.
+broadcastable as [B, {1|H}, Sq, Sk].
 
-The backward pass is ONE more Pallas kernel using the logsumexp
-residual — the flash-attention-2 recurrence with each score tile's s, p,
-dp and ds computed once and feeding dV, dK and dQ (a dq and a dkv kernel
-would each recompute them). Its grid is (batch, head, k-segment, q-block):
-a head's K and V, with f32 dK and dV accumulators beside them, stay
-resident in VMEM while the q-blocks stream, an in-kernel loop walks the
-k-blocks (up to the diagonal on a causal site), and dQ leaves finished.
-How much of K and V stays resident is a byte count against _VMEM_BUDGET:
-where a whole head does not fit, the same kernel takes them a segment at
-a time and dQ leaves as an f32 partial a segment for XLA to sum. An
+Both passes have one shape. A head's K and V stay resident in VMEM, a
+grid step is one q-block, and an in-kernel loop walks the k-blocks: up
+to the diagonal on a causal site, never past the last key. A score tile
+is held keys-down, [block_k, block_q], so what belongs to a query — the
+running max and sum, the logsumexp, delta — is a [1, block_q] row that
+reduces down sublanes and broadcasts along them. How much of K and V
+stays resident is a byte count against _VMEM_BUDGET: a whole head where
+it fits (every site of the benchmark's cells), else the fewest equal
+k-segments, walked by the same kernel.
+
+The forward (grid (batch, head, q-block, k-segment)) carries the
+statistics between tiles as values and keeps the accumulator turned,
+[dv, block_q], in scratch; o is turned back once a q-block and the
+logsumexp leaves compact, [B, H, Sq] f32. The masks run where they bite:
+the key-padding select only where the keys do not fill their last
+block, the causal select only in the tiles the diagonal crosses.
+
+The backward (grid (batch, head, k-segment, q-block)) is ONE more kernel
+on the logsumexp residual — the flash-attention-2 recurrence with each
+score tile's s, p, dp and ds computed once and feeding dV, dK and dQ (a
+dq and a dkv kernel would each recompute them); f32 dK and dV
+accumulators sit beside the resident K and V and dQ leaves finished, or,
+a segment at a time, as an f32 partial a segment for XLA to sum. An
 exact additive-bias gradient is emitted from the same tiles on request.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -47,162 +58,273 @@ def _ceil_to(x: int, m: int) -> int:
 from . import interpret_default as _interpret_default  # shared policy
 
 
-def _clamp_blocks(sq, sk, block_q, block_k, interpret):
-    """Mosaic requires block last-two dims (div 8, div 128) or full-dim.
-    Blocks over the scores matrix are (block_q, block_k), so compiled
-    kernels need block_q % 8 == 0 and block_k % 128 == 0.
+# What one call may plan to keep in VMEM: half of a v5e core's 128 MiB,
+# the rest being Mosaic's own. A call asks for what it counted
+# (vmem_limit_bytes), not for the default scoped 16 MiB.
+_VMEM_BUDGET = 64 << 20
 
-    The requested block size acts as a CAP: the axis is split into the
-    fewest blocks that respect it, then the block is shrunk to fit the
-    actual length so padding stays under one alignment unit PER BLOCK
-    (e.g. sq=1100 with cap 1024 -> 2 blocks of 552 = 1104 padded rows,
-    not 2 blocks of 1024 = 2048)."""
-    if interpret:
-        return min(block_q, _ceil_to(sq, 8)), min(block_k, _ceil_to(sk, 8))
-    nq = -(-sq // max(block_q, 8))
-    nk = -(-sk // max(block_k, 128))
-    return (_ceil_to(-(-sq // nq), 8), _ceil_to(-(-sk // nk), 128))
+
+def _blocks(sq, sk, block_q, block_k, interpret):
+    """A pass's tiles under its caps: each axis split into the fewest
+    tiles that respect its cap, the tile shrunk to fit (1100 under a cap
+    of 1024: two tiles of 640, not of 1024). A score tile is held
+    keys-down [block_k, block_q] and K and V are sliced by rows of
+    block_k, so compiled tiles are whole 128-lane columns one way and
+    whole packed sublane tiles the other."""
+    unit = 8 if interpret else 128
+
+    def fit(s, cap):
+        n = -(-s // max(cap, unit))
+        return _ceil_to(-(-s // n), unit)
+
+    return fit(sq, block_q), fit(sk, block_k)
+
+
+def _segments(nk, vmem_bytes):
+    """How much of a head's K and V stays resident is a byte count: all
+    `nk` k-blocks where that fits the budget, else the fewest equal
+    segments that do. Returns (segments, k-blocks a segment)."""
+    chunks = nk
+    while chunks > 1 and vmem_bytes(chunks) > _VMEM_BUDGET:
+        chunks -= 1
+    nseg = -(-nk // chunks)
+    return nseg, -(-nk // nseg)
+
+
+def _bias_kind(bias):
+    """What a bias costs a tile: a "key" row [.., 1, Sk|1] is one value
+    a key, a "score"-sized one [.., Sq, Sk|1] a block of the tile's
+    size."""
+    if bias is None:
+        return None
+    return "key" if bias.shape[2] == 1 else "score"
+
+
+def _bias_lanes(bias, block_q):
+    """Lanes a key's bias values fill as the keys-down kernels read
+    them (_keys_down_bias)."""
+    return {None: 0, "key": 128, "score": block_q}[_bias_kind(bias)]
+
+
+def _keys_down_bias(bias, sq, sk, sq_p, sk_p, seg, block_q, ks_iq):
+    """A bias as the keys-down kernels read it, broadcast dims of batch
+    and head kept unmaterialized: a key row as one value a key on every
+    lane, [.., sk_p, 128]; a score-sized one turned, [.., sk_p, sq_p].
+    `ks_iq` picks (k-segment, q-block) out of the grid's last two
+    indices. Returns the array and its BlockSpec."""
+    bb, bh = bias.shape[:2]
+    pad_k = ((0, 0), (0, 0), (0, sk_p - sk))
+    key = _bias_kind(bias) == "key"
+    if key:
+        biasp = jnp.broadcast_to(
+            jnp.pad(jnp.broadcast_to(bias[:, :, 0], (bb, bh, sk)),
+                    pad_k)[..., None], (bb, bh, sk_p, 128))
+    else:
+        biasp = jnp.pad(
+            jnp.swapaxes(jnp.broadcast_to(bias, (bb, bh, sq, sk)), 2, 3),
+            pad_k + ((0, sq_p - sq),))
+
+    def at(b, h, i, j):
+        ks, iq = ks_iq(i, j)
+        return (0 if bb == 1 else b, 0 if bh == 1 else h, ks,
+                0 if key else iq)
+
+    return biasp, pl.BlockSpec((1, 1, seg, _bias_lanes(bias, block_q)), at)
+
+
+def _caps(bias, bias_grad, causal, block_q, block_k):
+    """The tile caps of both passes (an explicit block_q / block_k always
+    wins), from what the call can see. A trained bias takes 128: its
+    gradient drifts with the tile (~4e-3 rel between 128 and 512 at
+    S=1024, fp32 reassociation). A score-sized bias hands every tile a
+    block of its own size and keeps 512. A key-row mask is one column
+    of a tile and caps nothing: such a site, like a bias-free one,
+    takes 1024, or 512 where it is causal, half of a tile on the
+    diagonal being masked work. Measured on a v5e, ms a site, tiles of
+    512 / 1024 — forward (PR 40; the grid-step-a-tile kernel before it
+    2.71 and 2.05 at the first two, 2.47 at the third): 64 heads x 2048
+    x 64 bf16 under a key-row mask 1.20 / 1.10 not causal, 0.91 / 0.95
+    causal; 32 heads x 4096 x 192 / 128 causal, no bias 2.10 / 2.16.
+    Backward (PR 38): 2.50 / 2.42, 1.81 / 1.99, 4.19 / 4.42."""
+    if bias_grad and bias is not None:
+        cap = 128
+    elif _bias_kind(bias) == "score" or causal:
+        cap = 512
+    else:
+        cap = 1024
+    return (cap if block_q is None else block_q,
+            cap if block_k is None else block_k)
 
 
 # ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, sm_scale, causal, block_q,
-                block_k, kv_len):
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+def _lanes(width):
+    return _ceil_to(width, 128)
 
-    @pl.when(ik == 0)
+
+def _fwd_vmem_bytes(chunks, block_q, block_k, d, dv, itemsize, bias_lanes):
+    """Bytes a forward call keeps in VMEM with `chunks` k-blocks of one
+    head resident: K and V (a row fills whole 128-lane words) and the
+    bias rows, double-buffered by the pipeline; one q-block's q and o
+    double-buffered, the f32 accumulator and its turned copy, the
+    statistics' rows; the f32 score-sized values of a tile in flight."""
+    per_key = 2 * itemsize * (_lanes(d) + _lanes(dv)) + 8 * bias_lanes
+    per_step = block_q * (2 * itemsize * (_lanes(d) + _lanes(dv))
+                          + 8 * _lanes(dv) + 256)
+    return (chunks * block_k * per_key + per_step
+            + 4 * 4 * block_q * block_k)
+
+
+def _fwd_kernel(*refs, sm_scale, scale_q, causal, block_q, block_k, kv_len,
+                chunks, nseg, bias_kind):
+    """One q-block against the `chunks` k-blocks of one resident
+    k-segment, scores held keys-down ([block_k, block_q]): the running
+    max and sum reduce down sublanes and travel between tiles as
+    [1, block_q] rows, the accumulator is [dv, block_q] and is turned
+    once a q-block. Across the segments of a head too long to stay
+    resident the rows and the accumulator wait in scratch."""
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if bias_kind is not None else None
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[-5:]
+    iq, ks = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ks == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Whole k-block above the causal diagonal -> nothing to do.
-    run = True
+    q = q_ref[0, 0]                                          # [bq, d]
+    if scale_q:       # a power of two: the scaled scores bit for bit
+        q = q * sm_scale
+    first = ks * chunks if nseg > 1 else 0
+    # k-blocks past the last key, or wholly above the causal diagonal:
+    # nothing to do
+    stop = -(-kv_len // block_k) - first
+    stop = min(chunks, stop) if nseg == 1 else jnp.minimum(chunks, stop)
     if causal:
-        run = iq * block_q + block_q - 1 >= ik * block_k
+        stop = jnp.minimum(
+            stop, (iq * block_q + block_q - 1) // block_k + 1 - first)
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0]                       # [bq, d]
-        k = k_ref[0, 0]                       # [bk, d]
-        v = v_ref[0, 0]                       # [bk, dv]
+    def _tile(c, carry, diagonal):
+        m, l = carry                                         # [1, bq]
+        rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+        k, v = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        kpos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)  # mask seq padding
-        if causal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [bk, bq]
+        if not scale_q:
+            s = s * sm_scale
+        if bias_kind == "key":      # one value a key, on every lane
+            s = s + bias_ref[0, 0, rows, :][:, :1].astype(jnp.float32)
+        elif bias_kind == "score":
+            s = s + bias_ref[0, 0, rows, :].astype(jnp.float32)
+        if kv_len % block_k or diagonal:
+            kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
+        if kv_len % block_k:        # mask seq padding
+            s = jnp.where(kpos < kv_len, s, NEG_INF)
+        if diagonal:
+            qpos = iq * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
             s = jnp.where(qpos >= kpos, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)                               # [bk, bq]
+        l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [dv, bq]
+        return m_new, l
 
-        m_prev = m_scr[:, :1]                                 # [bq, 1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                                # [bq, bk]
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, dv]
-        acc_scr[:] = acc_scr[:] * alpha + pv
+    carry = m_scr[:], l_scr[:]
+    # the causal select only in the tiles the diagonal crosses: the
+    # whole tiles below it first
+    whole = 0
+    if causal:
+        whole = jnp.clip((iq * block_q + 1) // block_k - first, 0, stop)
+        carry = jax.lax.fori_loop(
+            0, whole, functools.partial(_tile, diagonal=False), carry)
+    # a short walk of a known length is unrolled, so that the next tile's
+    # products overlap this tile's softmax (v5e, a site of 64 heads x
+    # 2048 x 64 not causal: 1.10 against 1.16 ms)
+    m, l = jax.lax.fori_loop(
+        whole, stop, functools.partial(_tile, diagonal=causal), carry,
+        unroll=True if isinstance(stop, int) and stop <= 4 else None)
+    m_scr[:], l_scr[:] = m, l
 
-    @pl.when(ik == nk - 1)
+    @pl.when(ks == nseg - 1)
     def _fin():
-        l = l_scr[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)       # fully-masked rows -> 0 out
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-37))
-        lse_ref[0, 0] = lse.astype(lse_ref.dtype)
+        safe = jnp.where(l == 0.0, 1.0, l)    # fully-masked rows -> 0 out
+        o_ref[0, 0] = (acc_scr[:] / safe).T.astype(o_ref.dtype)
+        lse_ref[0, 0, 0] = m + jnp.log(jnp.maximum(l, 1e-37))
 
 
-def _bias_spec(bias, sq_p, sk_p, block_q, block_k, order):
-    """Padded bias + BlockSpec keeping broadcast (size-1) dims
-    unmaterialized: broadcast dims get block size 1 and index 0, and the
-    kernel's `s + bias_block` broadcasts in-register. order 'qk' means the
-    grid is (b, h, iq, ik); 'kq' is (b, h, ik, iq)."""
-    bb, bh, bsq, bsk = bias.shape
-    biasp = jnp.pad(bias, ((0, 0), (0, 0),
-                           (0, sq_p - bsq if bsq != 1 else 0),
-                           (0, sk_p - bsk if bsk != 1 else 0)))
-    blk = (1, 1, block_q if bsq != 1 else 1, block_k if bsk != 1 else 1)
-
-    def im_qk(b, h, iq, ik):
-        return (0 if bb == 1 else b, 0 if bh == 1 else h,
-                0 if bsq == 1 else iq, 0 if bsk == 1 else ik)
-
-    def im_kq(b, h, ik, iq):
-        return im_qk(b, h, iq, ik)
-
-    return biasp, pl.BlockSpec(blk, im_qk if order == "qk" else im_kq)
-
-
-def _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret,
+         bias_grad):
+    """o [B, H, Sq, Dv] and the logsumexp [B, H, Sq] f32."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k, interpret)
-    sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_k)
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
-    grid = (b, h, sq_p // block_q, sk_p // block_k)
+    block_q, block_k = _blocks(
+        sq, sk, *_caps(bias, bias_grad, causal, block_q, block_k), interpret)
+    nq, nk = -(-sq // block_q), -(-sk // block_k)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda b, h, iq, ik: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b, h, iq, ik: (b, h, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, dv), lambda b, h, iq, ik: (b, h, ik, 0)),
-    ]
+    def vmem_bytes(chunks):
+        return _fwd_vmem_bytes(chunks, block_q, block_k, d, dv,
+                               q.dtype.itemsize,
+                               _bias_lanes(bias, block_q))
+
+    nseg, chunks = _segments(nk, vmem_bytes)
+    seg = chunks * block_k
+    sq_p, sk_p = nq * block_q, nseg * seg
+    _count_fwd_site("resident" if nseg == 1 else "partial")
+
+    pad_k = ((0, 0), (0, 0), (0, sk_p - sk), (0, 0))
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
+    kp, vp = jnp.pad(k, pad_k), jnp.pad(v, pad_k)
+
+    def qspec(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, iq, ks: (b, h, iq, 0))
+
+    def kspec(width):
+        return pl.BlockSpec((1, 1, seg, width),
+                            lambda b, h, iq, ks: (b, h, ks, 0))
+
+    in_specs = [qspec(d), kspec(d), kspec(dv)]
     args = [qp, kp, vp]
     if bias is not None:
-        biasp, bspec = _bias_spec(bias, sq_p, sk_p, block_q, block_k, "qk")
+        biasp, bspec = _keys_down_bias(bias, sq, sk, sq_p, sk_p, seg,
+                                       block_q, lambda iq, ks: (ks, iq))
         in_specs.append(bspec)
         args.append(biasp)
 
-        kernel = functools.partial(
-            _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, kv_len=sk)
-    else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m, l, a):
-            return _fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                               m, l, a, sm_scale=sm_scale, causal=causal,
-                               block_q=block_q, block_k=block_k, kv_len=sk)
-
-    scratch = [
-        _scratch((block_q, 128), jnp.float32),
-        _scratch((block_q, 128), jnp.float32),
-        _scratch((block_q, dv), jnp.float32),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((b, h, sq_p, dv), q.dtype),
-        jax.ShapeDtypeStruct((b, h, sq_p, 128), jnp.float32),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, 1, block_q, dv), lambda b, h, iq, ik: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_q, 128),
-                     lambda b, h, iq, ik: (b, h, iq, 0)),
-    ]
     o, lse = pl.pallas_call(
-        kernel,
+        functools.partial(
+            _fwd_kernel, sm_scale=sm_scale,
+            scale_q=math.frexp(sm_scale)[0] == 0.5, causal=causal,
+            block_q=block_q, block_k=block_k, kv_len=sk, chunks=chunks,
+            nseg=nseg, bias_kind=_bias_kind(bias)),
         name="flash_fwd",
-        grid=grid,
+        grid=(b, h, nq, nseg),
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_compiler_params(("parallel",) * 3 + ("arbitrary",)),
+        out_specs=[qspec(dv),
+                   pl.BlockSpec((1, 1, 1, 1, block_q),
+                                lambda b, h, iq, ks: (b, h, iq, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, h, sq_p, dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, nq, 1, block_q),
+                                        jnp.float32)],
+        scratch_shapes=[_scratch((1, block_q), jnp.float32),
+                        _scratch((1, block_q), jnp.float32),
+                        _scratch((dv, block_q), jnp.float32)],
+        compiler_params=_compiler_params(
+            ("parallel",) * 3 + ("arbitrary",),
+            vmem_limit_bytes=max(32 << 20, 5 * vmem_bytes(chunks) // 4)),
         interpret=interpret,
     )(*args)
-    return o[:, :, :sq], lse[:, :, :sq, :1]   # lse kept [B,H,Sq,1]
+    return o[:, :, :sq], lse.reshape(b, h, sq_p)[:, :, :sq]
 
 
 def _scratch(shape, dtype):
@@ -217,27 +339,6 @@ def _compiler_params(dimension_semantics, **more):
 # ---------------------------------------------------------------------------
 # backward kernel
 # ---------------------------------------------------------------------------
-
-# What one backward call may plan to keep in VMEM: half of a v5e core's
-# 128 MiB, the rest being Mosaic's own. The call asks for what it
-# counted (vmem_limit_bytes), not for the default scoped 16 MiB.
-_VMEM_BUDGET = 64 << 20
-
-
-def _bwd_blocks(sq, sk, block_q, block_k, interpret):
-    """The backward's tiles under the caps: each axis split into the
-    fewest tiles that respect its cap, the tile shrunk to fit. A score
-    tile is held keys-down [block_k, block_q] and K, V, dK, dV are
-    sliced by rows of block_k, so compiled tiles are whole 128-lane
-    columns one way and whole packed sublane tiles the other."""
-    unit = 8 if interpret else 128
-
-    def fit(s, cap):
-        n = -(-s // max(cap, unit))
-        return _ceil_to(-(-s // n), unit)
-
-    return fit(sq, block_q), fit(sk, block_k)
-
 
 def _bwd_vmem_bytes(chunks, block_q, block_k, d, dv, itemsize, bias_lanes,
                     emit_dbias):
@@ -336,6 +437,18 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, block_k, kv_len, chunks,
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _count_fwd_site(path):
+    from ...observability.registry import default_registry
+    default_registry().counter(
+        "paddle_tpu_flash_fwd_sites_total",
+        "flash-attention forward calls traced, by what the call's byte "
+        "count against the VMEM budget let it keep resident (resident: "
+        "a head's whole K and V, one grid step a q-block; partial: K "
+        "and V a segment at a time, the softmax statistics and the "
+        "accumulator carried across a q-block's segments in scratch).",
+        ("path",)).labels(path=path).inc()
+
+
 def _count_bwd_site(path):
     from ...observability.registry import default_registry
     default_registry().counter(
@@ -354,38 +467,19 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     do = g
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    bias_kind = None if bias is None else (
-        "key" if bias.shape[2] == 1 else "score")
+    bias_kind = _bias_kind(bias)
     emit_dbias = bias is not None and bias_needs_grad
-    # Tile caps the caller left open: a score-sized bias or dbias block
-    # rides with every tile, so those keep the forward's caps; a
-    # key-row mask is one column of a tile and caps nothing. Half of a
-    # tile on the causal diagonal is masked work, so a causal site
-    # takes the smaller tile (v5e, PR 38: 1.81 against 1.99 ms a site
-    # of 64 heads x 2048 x 64; not causal 2.50 against 2.42).
-    if emit_dbias:
-        cap = 128
-    elif bias_kind == "score" or causal:
-        cap = 512
-    else:
-        cap = 1024
-    block_q, block_k = _bwd_blocks(
-        sq, sk, cap if block_q is None else block_q,
-        cap if block_k is None else block_k, interpret)
+    block_q, block_k = _blocks(
+        sq, sk, *_caps(bias, bias_needs_grad, causal, block_q, block_k),
+        interpret)
     nq, nk = -(-sq // block_q), -(-sk // block_k)
-    # How much of a head's K and V stays resident is a byte count: all
-    # of it where that fits, else the fewest equal segments that do.
-    bias_lanes = {None: 0, "key": 128, "score": block_q}[bias_kind]
 
     def vmem_bytes(chunks):
         return _bwd_vmem_bytes(chunks, block_q, block_k, d, dv,
-                               q.dtype.itemsize, bias_lanes, emit_dbias)
+                               q.dtype.itemsize,
+                               _bias_lanes(bias, block_q), emit_dbias)
 
-    chunks = nk
-    while chunks > 1 and vmem_bytes(chunks) > _VMEM_BUDGET:
-        chunks -= 1
-    nseg = -(-nk // chunks)
-    chunks = -(-nk // nseg)
+    nseg, chunks = _segments(nk, vmem_bytes)
     seg = chunks * block_k
     sq_p, sk_p = nq * block_q, nseg * seg
     _count_bwd_site("resident" if nseg == 1 else "partial")
@@ -413,22 +507,12 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
     in_specs = [qspec(d), kspec(d), kspec(dv)]
     args = [qp, kp, vp]
     if bias is not None:
-        bb, bh = bias.shape[:2]
-        if bias_kind == "key":      # [.., 1, Sk|1] -> [.., sk_p, 128]
-            biasp = jnp.broadcast_to(
-                jnp.pad(jnp.broadcast_to(bias[:, :, 0], (bb, bh, sk)),
-                        pad_k[:3])[..., None], (bb, bh, sk_p, 128))
-        else:                       # [.., Sq, Sk|1] -> [.., sk_p, sq_p]
-            biasp = jnp.pad(
-                jnp.swapaxes(jnp.broadcast_to(bias, (bb, bh, sq, sk)), 2, 3),
-                ((0, 0), (0, 0), (0, sk_p - sk), (0, sq_p - sq)))
-        in_specs.append(pl.BlockSpec(
-            (1, 1, seg, bias_lanes),
-            lambda b, h, ks, iq: (0 if bb == 1 else b, 0 if bh == 1 else h,
-                                  ks, iq if bias_kind == "score" else 0)))
+        biasp, bspec = _keys_down_bias(bias, sq, sk, sq_p, sk_p, seg,
+                                       block_q, lambda ks, iq: (ks, iq))
+        in_specs.append(bspec)
         args.append(biasp)
     in_specs += [qspec(dv), rspec, rspec]
-    args += [dop, rows(lse[..., 0]), rows(delta)]
+    args += [dop, rows(lse), rows(delta)]
 
     # dQ a k-segment: finished where there is one, else an f32 partial
     out_shape = [
@@ -483,37 +567,18 @@ def _bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
 # public entry
 # ---------------------------------------------------------------------------
 
-def _fwd_caps(bias, bias_grad, block_q, block_k):
-    """The forward's tile caps (explicit block_q/block_k always win):
-    1024 for bias-free attention; a bias hands every tile a block of
-    its own, score-sized where it has a query axis, so mask-bias
-    defaults to 512 (~5 score-sized fp32 buffers = 5MB, well under the
-    16MB scoped-vmem limit). Trainable-bias grads show larger fp32
-    reassociation drift at big tiles (~4e-3 rel between 128 and 512 at
-    S=1024 on v5e) — they default to the original 128 tiling for
-    bit-stable gradients."""
-    if bias is None:
-        default_blk = 1024
-    elif bias_grad:
-        default_blk = 128
-    else:
-        default_blk = 512
-    return (default_blk if block_q is None else block_q,
-            default_blk if block_k is None else block_k)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret,
            bias_grad):
-    o, _ = _fwd(q, k, v, bias, sm_scale, causal,
-                *_fwd_caps(bias, bias_grad, block_q, block_k), interpret)
+    o, _ = _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
+                interpret, bias_grad)
     return o
 
 
 def _flash_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
                interpret, bias_grad):
-    o, lse = _fwd(q, k, v, bias, sm_scale, causal,
-                  *_fwd_caps(bias, bias_grad, block_q, block_k), interpret)
+    o, lse = _fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
+                  interpret, bias_grad)
     return o, (q, k, v, bias, o, lse)
 
 
@@ -547,12 +612,10 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
 
     block_q/block_k act as CAPS on the tile size: the sequence is split
     into the fewest cap-respecting tiles and the tile shrinks to fit
-    (minimizing padding), so an explicit 256 with sq=900 runs 4 tiles
-    of 232 forward (of 256 backward, whose compiled tiles are whole
-    128-lane columns). None lets each pass choose from what it is
-    handed: the forward by _fwd_caps, swept on v5e with stacked-layer
-    fwd+bwd marginal timing (1024x1024 beat 128x128 by 1.4x at seq 256,
-    2.7x at 1024, and was still fastest at 4096); the backward by _bwd.
+    (minimizing padding) in whole 128-lane columns, so an explicit 256
+    with sq=900 runs 4 tiles of 256. None lets _caps choose from what
+    the call can see (the bias's kind, causal), one rule for both
+    passes, measured on a v5e.
     """
     if interpret is None:
         interpret = _interpret_default()

@@ -121,11 +121,12 @@ def test_causal_flag_and_key_row_bias_equal_the_dense_bias(S):
                                    atol=1e-6, rtol=1e-6, err_msg=name)
 
 
-def _bwd_paths():
+def _paths(which):
+    """Counter of path: flash `fwd` or `bwd` calls traced so far."""
     import collections
 
     from paddle_tpu.observability import default_registry
-    fam = default_registry().get("paddle_tpu_flash_bwd_sites_total")
+    fam = default_registry().get(f"paddle_tpu_flash_{which}_sites_total")
     return collections.Counter() if fam is None else collections.Counter(
         {labels[0]: child.value for labels, child in fam.samples()})
 
@@ -163,6 +164,31 @@ def test_fused_backward_matches_naive(causal, bias_kind, dims, budget,
             budget)
     q, k = _rand((B, H, sq, d), 0), _rand((B, H, sk, d), 1)
     v, w = _rand((B, H, sk, dv), 2), _rand((B, H, sq, dv), 3)
+    bias = _fused_bias(bias_kind, B, H, sq, sk)
+    trained = bias_kind.startswith("trainable")
+    argnums = (0, 1, 2, 3) if trained else (0, 1, 2)
+
+    def loss_flash(q, k, v, b):
+        return jnp.sum(w * flash_attention(
+            q, k, v, b, causal=causal, block_q=cap, block_k=cap,
+            interpret=True, bias_grad=trained))
+
+    def loss_naive(q, k, v, b):
+        return jnp.sum(w * naive(q, k, v, b, causal=causal))
+
+    paths = _paths("bwd")
+    got = jax.grad(loss_flash, argnums)(q, k, v, bias)
+    segments = -(-sk // cap) if budget == 0 else 1
+    assert _paths("bwd") - paths == {
+        "resident" if segments == 1 else "partial": 1}
+    want = jax.grad(loss_naive, argnums)(q, k, v, bias)
+    for name, a, b in zip(("dq", "dk", "dv", "dbias"), got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+def _fused_bias(bias_kind, B, H, sq, sk):
     rng = np.random.RandomState(4)
     bias = {
         "none": None,
@@ -175,27 +201,88 @@ def test_fused_backward_matches_naive(causal, bias_kind, dims, budget,
         bias = np.asarray(bias, np.float32)
         if not bias_kind.startswith("trainable"):
             bias[..., 0] = 0.0      # no row without a key
-    trained = bias_kind.startswith("trainable")
-    argnums = (0, 1, 2, 3) if trained else (0, 1, 2)
+    return bias
 
-    def loss_flash(q, k, v, b):
-        return jnp.sum(w * flash_attention(
-            q, k, v, b, causal=causal, block_q=cap, block_k=cap,
-            interpret=True, bias_grad=trained))
 
-    def loss_naive(q, k, v, b):
-        return jnp.sum(w * naive(q, k, v, b, causal=causal))
-
-    paths = _bwd_paths()
-    got = jax.grad(loss_flash, argnums)(q, k, v, bias)
+@pytest.mark.parametrize("budget", [None, 0], ids=["resident", "partial"])
+@pytest.mark.parametrize("dims", _FUSED_DIMS,
+                         ids=lambda d: "x".join(map(str, d)))
+@pytest.mark.parametrize("bias_kind", ["none", "key_row", "dense",
+                                       "trainable_head_key",
+                                       "trainable_query_key"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_forward_matches_naive(causal, bias_kind, dims, budget, monkeypatch):
+    """The forward kernel (ISSUE 40: one grid step a q-block, the k-blocks
+    walked inside it, the statistics carried between tiles as rows)
+    against the f32 composition: `o` and the logsumexp it leaves the
+    backward, with a head's K and V resident and — the budget taken away
+    — a k-block a grid step, the statistics and the accumulator waiting
+    in scratch between them."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    sq, sk, d, dv, cap = dims
+    B, H = (1, 2) if sq > 1000 else (2, 2)
+    if sq > 1000 and bias_kind not in ("none", "key_row"):
+        pytest.skip("the long case runs the cells' two mask kinds")
+    if budget is not None:
+        monkeypatch.setattr(fa, "_VMEM_BUDGET", budget)
+    q, k = _rand((B, H, sq, d), 0), _rand((B, H, sk, d), 1)
+    v = _rand((B, H, sk, dv), 2)
+    bias = _fused_bias(bias_kind, B, H, sq, sk)
+    paths = _paths("fwd")
+    o, lse = fa._fwd(q, k, v, None if bias is None else jnp.asarray(bias),
+                     1.0 / np.sqrt(d), causal, cap, cap, True,
+                     bias_kind.startswith("trainable"))
     segments = -(-sk // cap) if budget == 0 else 1
-    assert _bwd_paths() - paths == {
+    assert _paths("fwd") - paths == {
         "resident" if segments == 1 else "partial": 1}
-    want = jax.grad(loss_naive, argnums)(q, k, v, bias)
-    for name, a, b in zip(("dq", "dk", "dv", "dbias"), got, want):
-        assert a.shape == b.shape
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4, rtol=1e-4, err_msg=name)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+    if bias is not None:
+        s = s + bias
+    if causal:
+        s = jnp.where(jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :],
+                      s, -1e30)
+    assert o.shape == (B, H, sq, dv) and lse.shape == (B, H, sq)
+    assert lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(o),
+        np.asarray(jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)),
+        atol=1e-5, rtol=1e-5, err_msg="o")
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.scipy.special.logsumexp(s, -1)),
+        atol=1e-5, rtol=1e-5, err_msg="lse")
+
+
+@pytest.mark.parametrize("budget", [None, 0], ids=["resident", "partial"])
+@pytest.mark.parametrize("S", [64, 80])
+def test_forward_gives_fully_masked_rows_zero(S, budget, monkeypatch):
+    """A query none of whose keys is open (every key of batch 0 at -inf)
+    reads 0, not the NaN of the composition's 0 / 0, and its gradients
+    are finite; the other batch is untouched by it. (Not so under
+    causal=True, now as before: the keys above the diagonal carry the
+    finite -1e30, and a row with nothing larger averages them.)"""
+    import importlib
+    if budget is not None:
+        monkeypatch.setattr(importlib.import_module(
+            "paddle_tpu.ops.pallas.flash_attention"), "_VMEM_BUDGET",
+            budget)
+    B, H, D = 2, 2, 16
+    q, k, v = (_rand((B, H, S, D), i) for i in range(3))
+    bias = np.zeros((B, 1, 1, S), np.float32)
+    bias[0] = -np.inf
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, jnp.asarray(bias), block_q=32,
+                               block_k=32, interpret=True)
+
+    o = attend(q, k, v)
+    assert not np.asarray(o[0]).any()
+    np.testing.assert_allclose(np.asarray(o[1]),
+                               np.asarray(naive(q, k, v)[1]),
+                               atol=1e-5, rtol=1e-5)
+    for g in jax.grad(lambda *a: jnp.sum(jnp.sin(attend(*a))),
+                      (0, 1, 2))(q, k, v):
+        assert np.isfinite(np.asarray(g)).all()
 
 
 def test_flash_uneven_kv_len():

@@ -60,34 +60,36 @@ def _sum_f32(outs):
                for o in jax.tree_util.tree_leaves(outs))
 
 
-def _bwd_sites():
-    """Counter of path: flash backward calls traced so far."""
+def _sites(which):
+    """Counter of path: flash `fwd` or `bwd` calls traced so far."""
     import collections
 
     from paddle_tpu.observability import default_registry
-    fam = default_registry().get("paddle_tpu_flash_bwd_sites_total")
+    fam = default_registry().get(f"paddle_tpu_flash_{which}_sites_total")
     return collections.Counter() if fam is None else collections.Counter(
         {labels[0]: child.value for labels, child in fam.samples()})
 
 
 # [B, H, S, D] of chip_smoke.py's train step (b4 x s2048, 8 heads of
-# 64), and a length that is not a multiple of 128: _clamp_blocks pads
-# it per block, which interpret mode never checks against Mosaic's
-# (8, 128) tiling. Then train-ep32's site (chipbench/configs/
-# joyai-llm-flash.json: 1 x 32 x 4096, 192-wide keys, 128-wide values,
-# causal, no bias), whose resident K and V with their f32 accumulators
-# pass the default scoped VMEM: the call asks for what it counted.
-@pytest.mark.parametrize("shape,bias", [
-    ((4, 8, s, 64, 64), m) for s in (2048, 1100)
+# 64), and a length that is not a multiple of 128: the tiles shrink to
+# it in whole 128-lane columns, which interpret mode never checks
+# against Mosaic's (8, 128) tiling. Then train-ep32's site (chipbench/
+# configs/joyai-llm-flash.json: 1 x 32 x 4096, 192-wide keys, 128-wide
+# values, causal, no bias), whose resident K and V with their f32
+# accumulators pass the default scoped VMEM: the call asks for what it
+# counted. Then serve-chat's two prefill sites (decoder-lm-base: f32, 8
+# heads of 64, causal, the 512 and 2048 prompt buckets).
+@pytest.mark.parametrize("shape,bias,dtype", [
+    ((4, 8, s, 64, 64), m, jnp.bfloat16) for s in (2048, 1100)
     for m in ("causal", "pad_row", "causal_pad_row", "dense")
-] + [((1, 32, 4096, 192, 128), "causal")],
-    ids=lambda v: v if isinstance(v, str) else "x".join(map(str, v)))
-def test_flash_attention_fwd_bwd_compiles(one_chip, shape, bias):
+] + [((1, 32, 4096, 192, 128), "causal", jnp.bfloat16)] + [
+    ((1, 8, s, 64, 64), "causal", jnp.float32) for s in (512, 2048)],
+    ids=lambda v: v if isinstance(v, str) else (
+        "x".join(map(str, v)) if isinstance(v, tuple) else v.__name__))
+def test_flash_attention_fwd_bwd_compiles(one_chip, shape, bias, dtype):
     b, h, seq, d, d_v = shape
-    q = jax.ShapeDtypeStruct((b, h, seq, d), jnp.bfloat16,
-                             sharding=one_chip)
-    v = jax.ShapeDtypeStruct((b, h, seq, d_v), jnp.bfloat16,
-                             sharding=one_chip)
+    q = jax.ShapeDtypeStruct((b, h, seq, d), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, h, seq, d_v), dtype, sharding=one_chip)
     # the masks models/transformer.py builds: a [B,1,1,S] pad-row mask
     # (encoder and cross attention), the same with causal=True (decoder
     # self-attention); a [B,1,S,S] bias is what an op handed a dense
@@ -104,16 +106,19 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, shape, bias):
     args = (q, q, v) if mshape is None else (
         q, q, v, jax.ShapeDtypeStruct(mshape, jnp.float32,
                                       sharding=one_chip))
-    sites = _bwd_sites()
+    fwd, bwd = _sites("fwd"), _sites("bwd")
     # forward + the one backward kernel, a head's K and V resident
     assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), *args) == 2
-    assert _bwd_sites() - sites == {"resident": 1}
+    assert _sites("fwd") - fwd == {"resident": 1}
+    assert _sites("bwd") - bwd == {"resident": 1}
 
 
 def test_flash_backward_beyond_the_vmem_budget_compiles(one_chip):
     """A head whose K and V with their accumulators pass the budget
     (32,768 keys of 128: 100 MB) is walked a segment at a time, dQ an
-    f32 partial a segment: the same kernel, and it compiles."""
+    f32 partial a segment: the same kernel, and it compiles. The
+    forward holds no accumulator a key: its count keeps these K and V
+    resident (34 MB double-buffered + a tile in flight)."""
     q = jax.ShapeDtypeStruct((1, 2, 32768, 128), jnp.bfloat16,
                              sharding=one_chip)
 
@@ -121,9 +126,36 @@ def test_flash_backward_beyond_the_vmem_budget_compiles(one_chip):
         return _sum_f32(flash_attention(q, k, v, causal=True,
                                         interpret=False))
 
-    sites = _bwd_sites()
+    fwd, bwd = _sites("fwd"), _sites("bwd")
     assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 2
-    assert _bwd_sites() - sites == {"partial": 1}
+    assert _sites("fwd") - fwd == {"resident": 1}
+    assert _sites("bwd") - bwd == {"partial": 1}
+
+
+@pytest.mark.parametrize("seq,budget", [(32768, 16 << 20), (65536, None)],
+                         ids=["32768_of_a_16MiB_budget", "65536"])
+def test_flash_forward_beyond_the_vmem_budget_compiles(one_chip, seq, budget,
+                                                       monkeypatch):
+    """A head whose K and V pass the forward's budget is walked a segment
+    a grid step, the statistics' rows and the accumulator waiting in
+    scratch between them: the same kernel, it compiles, and the
+    logsumexp still leaves compact. 65,536 keys of 128 pass the 64 MiB
+    as they are; 32,768 (ISSUE 40's shape, which the count keeps
+    resident) pass a budget of 16."""
+    import importlib
+    import re
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    if budget is not None:
+        monkeypatch.setattr(fa, "_VMEM_BUDGET", budget)
+    q = jax.ShapeDtypeStruct((1, 2, seq, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    fwd = _sites("fwd")
+    text = jax.jit(lambda q, k, v: fa._fwd(
+        q, k, v, None, 128 ** -0.5, True, None, None, False, False)).lower(
+        q, q, q).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert _sites("fwd") - fwd == {"partial": 1}
+    assert not re.findall(rf"f32\[1,2,{seq},128\]", text)
 
 
 def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_mask(
@@ -143,7 +175,7 @@ def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_
     from paddle_tpu.models import transformer
 
     batch, seq, vocab = 8, 2048, 32000
-    sites = _bwd_sites()
+    sites = _sites("bwd")
     # the rule asks jax.default_backend(), which is still the CPU here
     monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", "force")
     monkeypatch.setattr(
@@ -173,6 +205,9 @@ def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_
             feed = {n: sds((batch, seq, 1), jnp.int32)
                     for n in ("src_ids", "trg_ids", "trg_labels")}
             feed["pos_ids"] = sds((seq,), jnp.int32)
+            # (the build traced each site's forward once already, for
+            # its output's shape)
+            fwd_sites = _sites("fwd")
             text = step.jitted.lower(
                 feed, state(step.ro_names), state(step.rw_names),
                 sds((), jnp.int32)).compile().as_text()
@@ -183,7 +218,11 @@ def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_
         r"%\S*(flash_[a-z_]*?)_*\.\d+ = [^\n]*tpu_custom_call", text)
     assert sorted(set(calls)) == ["flash_bwd_dkv_dq", "flash_fwd"]
     assert calls.count("flash_fwd") == 18
-    assert _bwd_sites() - sites == {"resident": 18}
+    assert _sites("bwd") - sites == {"resident": 18}
+    assert _sites("fwd") - fwd_sites == {"resident": 18}
+    # the logsumexp leaves the forward [8, 8, 2048] f32 (ISSUE 40; until
+    # then 128 lanes wide, 67 MB a site, for XLA to cut a column out of)
+    assert not re.findall(rf"f32\[{batch},8,{seq},128\]", text)
     score_sized = re.findall(
         rf"f32\[(?:\d+,)*{seq},{seq}\]", text)
     # [8, 2048, d_inner = 2048] activations are the only such shape
