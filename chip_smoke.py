@@ -233,8 +233,8 @@ def phase_kernels(sm, cfg, interpret):
     fwd_sites = dict(flash_fwd_sites() - fwd_before)
     bwd_sites = dict(flash_bwd_sites() - bwd_before)
     # `both` traces the forward alone and again under jax.grad
-    sm.check(fwd_sites == {"resident": 2 * len(shapes)}
-             and bwd_sites == {"resident": len(shapes)},
+    sm.check(fwd_sites == {"resident/0/1": 2 * len(shapes)}
+             and bwd_sites == {"resident/0/1": len(shapes)},
              "kernel flash_attention: each forward and each backward kept "
              "its head's K and V resident (one grid step a q-block; dQ "
              "finished in the one backward kernel)",
@@ -499,13 +499,13 @@ def phase_train(sm, cfg, device, workdir):
     # only the decoder's own is causal, and no site is handed a mask
     # with a query axis (paddle_tpu_sdpa_sites_total{path,mask,causal})
     n_sites = 3 * cfg["n_layer"]
-    sm.check(sites == {"flash/key_row/0": n_sites - cfg["n_layer"],
-                       "flash/key_row/1": cfg["n_layer"]},
+    sm.check(sites == {"flash/key_row/0/0/1": n_sites - cfg["n_layer"],
+                       "flash/key_row/1/0/1": cfg["n_layer"]},
              "train: every attention site of the step took the flash "
              "kernels with a key-row mask, the decoder's own with the "
              "causal flag, none with a dense mask", sites=sites)
-    sm.check(fwd_sites == {"resident": n_sites}
-             and bwd_sites == {"resident": n_sites},
+    sm.check(fwd_sites == {"resident/0/1": n_sites}
+             and bwd_sites == {"resident/0/1": n_sites},
              "train: every site's forward and backward is one kernel with "
              "its head's K and V resident, none walks them in segments",
              flash_fwd_sites=fwd_sites, flash_bwd_sites=bwd_sites)

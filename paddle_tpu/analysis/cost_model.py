@@ -345,8 +345,13 @@ def _flops_for(op: ir.OpDesc,
         lead = _prod(q.shape[:-2])
         sq, d = q.shape[-2], q.shape[-1]
         d_v = v.shape[-1] if v is not None and v.shape else d
-        sk = _attended_rows(op, k)
-        return (2 * lead * sq * sk * (d + d_v) + 5 * lead * sq * sk,
+        pairs = sq * _attended_rows(op, k)
+        window = int(op.attrs.get("window", 0) or 0)
+        if 0 < window < sq == k.shape[-2]:
+            # a windowed site at its band (query i sees keys
+            # i - window < j <= i), not at the score matrix
+            pairs = window * (window + 1) // 2 + (sq - window) * window
+        return (2 * lead * pairs * (d + d_v) + 5 * lead * pairs,
                 True, None)
 
     if t in ("lstm", "gru"):

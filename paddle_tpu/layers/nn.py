@@ -359,15 +359,29 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, positions, theta=10000.0, name=None):
-    """Rotary position embedding of x [..., S, r] at the fed positions
-    [S], on neighbouring pairs (ops/nn_ops.py rotary_embedding)."""
+def rotary_embedding(x, positions, theta=10000.0, rotate_half=False,
+                     rotary_dim=None, inv_freq=None, scale=1.0, name=None):
+    """Rotary position embedding of x [..., S, w] at the fed positions
+    [S] (ops/nn_ops.py rotary_embedding): on neighbouring pairs, or
+    ``rotate_half`` on the columns (i, i + r/2); over the first
+    ``rotary_dim`` columns (all by default); at theta's frequencies or
+    at the ``inv_freq`` [r/2] given, cos and sin times ``scale``. The
+    keywords left at their defaults add no attr, so a program that
+    uses none of them is built as before."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_tmp_variable(x.dtype)
+    attrs = {"theta": float(theta)}
+    if rotate_half:
+        attrs["layout"] = "half"
+    if rotary_dim is not None and int(rotary_dim) != int(x.shape[-1]):
+        attrs["rotary_dim"] = int(rotary_dim)
+    if inv_freq is not None:
+        attrs["inv_freq"] = [float(f) for f in inv_freq]
+    if float(scale) != 1.0:
+        attrs["scale"] = float(scale)
     helper.append_op(type="rotary_embedding",
                      inputs={"X": x, "Positions": positions},
-                     outputs={"Out": out},
-                     attrs={"theta": float(theta)})
+                     outputs={"Out": out}, attrs=attrs)
     return out
 
 

@@ -1,16 +1,27 @@
-"""Decoder-only pre-norm language model with latent attention and sparse
-experts, for the trainer (ROADMAP B2(c), B4, B5; the block of
-DeepSeek-V2/V3, arXiv:2405.04434 and arXiv:2412.19437).
+"""Decoder-only pre-norm language model with latent or grouped-query
+attention and sparse experts, for the trainer (ROADMAP B2(c), B4, B5, B6;
+the block of DeepSeek-V2/V3, arXiv:2405.04434 and arXiv:2412.19437, and
+the window / full attention stacks that share its expert layer).
 
 Every block is ``x += Attn(RMSNorm(x)); x += FFN(RMSNorm(x))``:
 
-* attention is multi-head latent attention in its plain form: queries
+* ``attention="gqa"``: grouped-query attention, layer by layer
+  (``layer_types``, ``num_attention_heads_per_layer``): K and V at
+  ``num_key_value_heads`` heads, rotary embedding in the rotate-half
+  layout at the layer kind's ``rope_parameters`` block (a partial width,
+  a YaRN table), a ``sliding_window`` on the "sliding_attention" layers —
+  the fused attention op is handed K and V at their own head count and
+  the window as an attr — and, under ``gating``, each head's output
+  times a sigmoid gate of the layer's input before the output
+  projection (arXiv:2505.06708, head-wise);
+* ``attention="mla"``: multi-head latent attention in its plain form: queries
   and keys/values come through low-rank latents with an RMS norm on each,
   a query/key head is a position-free part beside a rotary part (ONE
   rotary key shared by all heads), and a value head may be narrower than
   a key head — K is materialised at full width and the fused attention op
   does the rest, causal by its attr;
-* the first ``first_k_dense_replace`` blocks have a dense gated FFN, the
+* the first ``first_k_dense_replace`` blocks (or those ``mlp_layer_types``
+  calls "dense") have a dense gated FFN, the
   others a router over ``n_routed_experts``, the expert layer's HELD
   share (``experts_held`` from ``expert_offset``: what this chip of an
   expert-parallel group owns; layers/nn.py moe_experts) and shared
@@ -36,6 +47,8 @@ t_i, ``trg_labels`` t_(i+1), ``src_ids`` t_(i+2) (the first module's
 labels), ``pos_ids`` the positions.
 """
 from __future__ import annotations
+
+import math
 
 from .. import layers, optimizer as opt
 from ..initializer import NormalInitializer, UniformInitializer
@@ -98,6 +111,72 @@ def latent_attention(x, positions, cfg):
     return _linear(merged, cfg["hidden_size"], "mla_o", _out_scale(cfg))
 
 
+def yarn_inv_freq(theta, rotary_dim, factor, original_max, beta_fast,
+                  beta_slow):
+    """YaRN's frequency table (arXiv:2309.00071, the Hugging Face
+    form): pair i keeps theta^(-2i/r) where it turns more than
+    ``beta_fast`` times over the original context, takes it divided by
+    ``factor`` where fewer than ``beta_slow``, a linear ramp between."""
+
+    def pair_turning(turns):
+        return rotary_dim * math.log(original_max / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(pair_turning(beta_fast)), 0)
+    hi = min(math.ceil(pair_turning(beta_slow)), rotary_dim - 1)
+    table = []
+    for i in range(rotary_dim // 2):
+        f = theta ** (-2.0 * i / rotary_dim)
+        keep = 1.0 - min(max((i - lo) / max(hi - lo, 1e-3), 0.0), 1.0)
+        table.append(f / factor * (1.0 - keep) + f * keep)
+    return table
+
+
+def _rope_keywords(params, head_dim):
+    """layers.rotary_embedding's keywords of one ``rope_parameters``
+    block: rotate-half over the first ``partial_rotary_factor`` of a
+    head."""
+    r = int(head_dim * float(params.get("partial_rotary_factor", 1)))
+    kw = {"theta": float(params["rope_theta"]), "rotate_half": True,
+          "rotary_dim": r}
+    kind = params.get("rope_type", "default")
+    if kind == "yarn":
+        kw["inv_freq"] = yarn_inv_freq(
+            kw["theta"], r, float(params["factor"]),
+            int(params["original_max_position_embeddings"]),
+            float(params.get("beta_fast", 32)),
+            float(params.get("beta_slow", 1)))
+        kw["scale"] = float(params.get("attention_factor") or
+                            0.1 * math.log(float(params["factor"])) + 1.0)
+    elif kind != "default":
+        raise ValueError(f"rope_type {kind!r}: default or yarn")
+    return kw
+
+
+def gqa_attention(x, positions, cfg, layer):
+    """Grouped-query attention of block ``layer`` over x [b, S, d]."""
+    n_head = cfg["num_attention_heads_per_layer"][layer]
+    n_kv, width = cfg["num_key_value_heads"], cfg["head_dim"]
+    kind = cfg["layer_types"][layer]
+    rope = _rope_keywords(cfg["rope_parameters"][kind], width)
+    q = layers.rotary_embedding(
+        _heads(_linear(x, n_head * width, "gqa_q"), n_head, width),
+        positions, **rope)
+    k = layers.rotary_embedding(
+        _heads(_linear(x, n_kv * width, "gqa_k"), n_kv, width),
+        positions, **rope)
+    v = _heads(_linear(x, n_kv * width, "gqa_v"), n_kv, width)
+    window = {"window": int(cfg["sliding_window"])} \
+        if kind == "sliding_attention" else {}
+    ctx = _sdpa_op(q, k, v, None, causal=True, **window)  # [b, h, S, w]
+    ctx = layers.transpose(ctx, [0, 2, 1, 3])
+    if cfg["gating"]:              # one gate a head, [b, S, h, 1]
+        gate = layers.sigmoid(_linear(x, n_head, "gqa_gate"))
+        ctx = layers.elementwise_mul(ctx, layers.unsqueeze(gate, [3]))
+    merged = layers.reshape(ctx, [0, 0, n_head * width])
+    return _linear(merged, cfg["hidden_size"], "gqa_o", _out_scale(cfg))
+
+
 def gated_ffn(x, d_inner, cfg, name):
     gate = layers.swish(_linear(x, d_inner, name + "_gate"))
     up = _linear(x, d_inner, name + "_up")
@@ -111,22 +190,29 @@ def moe_ffn(x, cfg):
         x, cfg["n_routed_experts"], cfg["num_experts_per_tok"],
         routed_scaling_factor=cfg["routed_scaling_factor"],
         selection_bias=UniformInitializer(-SELECTION_BIAS_RANGE,
-                                          SELECTION_BIAS_RANGE))
+                                          SELECTION_BIAS_RANGE)
+        if cfg["topk_method"] == "noaux_tc" else None)
     routed = layers.moe_experts(
         x, idx, weights, cfg["moe_intermediate_size"],
         cfg["n_routed_experts"], cfg["experts_held"], cfg["expert_offset"],
         down_init_scale=_out_scale(cfg))
     shared = gated_ffn(
-        x, cfg["n_shared_experts"] * cfg["moe_intermediate_size"], cfg,
+        x, cfg["shared_expert_intermediate_size"]
+        or cfg["n_shared_experts"] * cfg["moe_intermediate_size"], cfg,
         "moe_shared")
     return layers.elementwise_add(routed, shared)
 
 
-def decoder_block(x, positions, cfg, dense):
-    """x [b, S, d] -> the same."""
+def decoder_block(x, positions, cfg, dense, layer=None):
+    """x [b, S, d] -> the same; ``layer`` is the block's place in the
+    stack, which grouped-query attention reads its kind and head count
+    by."""
     eps = cfg["rms_norm_eps"]
+    h = layers.rms_norm(x, eps)
     x = layers.elementwise_add(
-        x, latent_attention(layers.rms_norm(x, eps), positions, cfg))
+        x, gqa_attention(h, positions, cfg, layer)
+        if cfg["attention"] == "gqa" else latent_attention(h, positions,
+                                                           cfg))
     h = layers.rms_norm(x, eps)
     f = gated_ffn(h, cfg["intermediate_size"], cfg, "ffn") if dense \
         else moe_ffn(h, cfg)
@@ -160,9 +246,12 @@ def decoder_moe_lm(tokens, labels, positions, cfg):
         ParamAttr(initializer=NormalInitializer(0.0, 1.0)),
         [cfg["trg_vocab"], d], "float32")
     x = _embed(table, tokens)
+    kinds = cfg["mlp_layer_types"]
     for i in range(cfg["num_hidden_layers"]):
-        x = decoder_block(x, positions, cfg,
-                          dense=i < cfg["first_k_dense_replace"])
+        x = decoder_block(
+            x, positions, cfg, layer=i,
+            dense=kinds[i] == "dense" if kinds
+            else i < cfg["first_k_dense_replace"])
     hidden = layers.rms_norm(x, eps)
     head = helper.create_parameter(None, [d, cfg["trg_vocab"]], "float32")
     loss = _mean_token_loss(hidden, head, labels[0])
@@ -179,6 +268,25 @@ def decoder_moe_lm(tokens, labels, positions, cfg):
     return loss
 
 
+def _check_gqa(cfg):
+    """What grouped-query attention reads is there, for every layer."""
+    if cfg["num_nextn_predict_layers"]:
+        raise ValueError("the prediction module is built on latent "
+                         "attention: num_nextn_predict_layers is 0 under "
+                         "attention='gqa'")
+    by_layer = ("layer_types", "num_attention_heads_per_layer")
+    short = [k for k in by_layer + ("num_key_value_heads", "head_dim",
+                                    "rope_parameters")
+             if cfg[k] is None or (k in by_layer and len(cfg[k])
+                                   < cfg["num_hidden_layers"])]
+    if short:
+        raise ValueError(
+            "attention='gqa' reads num_key_value_heads, head_dim, "
+            "rope_parameters and, for each of the "
+            f"{cfg['num_hidden_layers']} layers, layer_types and "
+            f"num_attention_heads_per_layer; missing or short: {short}")
+
+
 def build_train(trg_vocab=1024, max_len=64, lr=1e-3, hidden_size=256,
                 num_attention_heads=4, q_lora_rank=96, kv_lora_rank=64,
                 qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
@@ -190,11 +298,26 @@ def build_train(trg_vocab=1024, max_len=64, lr=1e-3, hidden_size=256,
                 rope_interleave=True,
                 rms_norm_eps=1e-6, num_hidden_layers=2,
                 first_k_dense_replace=1, num_nextn_predict_layers=1,
-                mtp_loss_weight=0.3, init_depth=None):
+                mtp_loss_weight=0.3, init_depth=None, attention="mla",
+                topk_method="noaux_tc", layer_types=None,
+                num_attention_heads_per_layer=None, mlp_layer_types=None,
+                num_key_value_heads=None, head_dim=None,
+                sliding_window=None, rope_parameters=None, gating=False,
+                shared_expert_intermediate_size=None):
     """(main, startup, {"loss": var}). The keywords are the published
-    config keys of the family; of ``scoring_func``, ``norm_topk_prob``
-    and ``rope_interleave`` only the values given here are built, any
-    other raises. ``experts_held`` / ``expert_offset`` say
+    config keys of the two families; of ``scoring_func``,
+    ``norm_topk_prob`` and ``rope_interleave`` only the values given
+    here are built, any other raises. ``attention`` chooses latent
+    ("mla": the keywords up to ``rope_interleave``) or grouped-query
+    attention ("gqa": ``layer_types``, ``num_attention_heads_per_layer``
+    — both read by layer, their first ``num_hidden_layers`` entries —
+    ``num_key_value_heads``, ``head_dim``, ``sliding_window``,
+    ``rope_parameters``, ``gating``); ``topk_method`` "noaux_tc" selects
+    the experts with the frozen selection bias, "greedy" by the scores
+    alone; ``mlp_layer_types`` ("dense" / "sparse" by layer) stands in
+    for ``first_k_dense_replace``, ``shared_expert_intermediate_size``
+    for ``n_shared_experts`` x ``moe_intermediate_size``.
+    ``experts_held`` / ``expert_offset`` say
     which of each layer's ``n_routed_experts`` this chip holds (all of
     them by default), ``init_depth`` the depth the projections into the
     residual stream are initialised for (``num_hidden_layers`` by
@@ -210,7 +333,14 @@ def build_train(trg_vocab=1024, max_len=64, lr=1e-3, hidden_size=256,
             "and rotary embedding on neighbouring pairs only, not "
             f"scoring_func={scoring_func!r}, norm_topk_prob="
             f"{norm_topk_prob!r}, rope_interleave={rope_interleave!r}")
+    if attention not in ("mla", "gqa") or \
+            topk_method not in ("noaux_tc", "greedy"):
+        raise ValueError(f"attention={attention!r} (mla or gqa), "
+                         f"topk_method={topk_method!r} (noaux_tc or "
+                         "greedy)")
     cfg = dict(locals())
+    if attention == "gqa":
+        _check_gqa(cfg)
     cfg["experts_held"] = n_routed_experts if experts_held is None \
         else experts_held
     cfg["init_depth"] = init_depth or num_hidden_layers

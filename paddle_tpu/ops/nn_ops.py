@@ -649,22 +649,43 @@ def _unstack(ctx):
 @register_op("rotary_embedding", no_grad_slots=["Positions"])
 def _rotary_embedding(ctx):
     """Rotary position embedding (Su et al., arXiv:2104.09864) on
-    X [..., S, r] with Positions [S]: the neighbours (x[2i], x[2i+1])
-    of a row at position p — the complex-number form — are turned by
-    the angle p * theta^(-2i/r). Angles and the rotation in float32,
-    the result at X's width."""
+    X [..., S, w] with Positions [S]: pair i of a row at position p is
+    turned by the angle p * theta^(-2i/r). The pairs are the neighbours
+    (x[2i], x[2i+1]) — the complex-number form — or, attr ``layout``
+    "half", the columns (x[i], x[i + r/2]) (rotate-half). Attr
+    ``rotary_dim`` r < w turns the first r columns and leaves the rest;
+    attr ``inv_freq`` [r/2] gives the frequencies as data where theta
+    alone does not (YaRN), and ``scale`` multiplies cos and sin (its
+    attention factor). Angles and the rotation in float32, the result
+    at X's width."""
     x = ctx.input("X")
     pos = ctx.input("Positions").reshape(-1).astype(jnp.float32)
-    r = x.shape[-1]
-    inv_freq = 1.0 / (float(ctx.attr("theta", 10000.0)) ** (
-        jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    r = int(ctx.attr("rotary_dim", 0) or x.shape[-1])
+    table = ctx.attr("inv_freq", None)
+    if table:
+        inv_freq = jnp.asarray(table, jnp.float32)
+    else:
+        inv_freq = 1.0 / (float(ctx.attr("theta", 10000.0)) ** (
+            jnp.arange(0, r, 2, dtype=jnp.float32) / r))
     angle = pos[:, None] * inv_freq[None, :]              # [S, r/2]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos],
-                    axis=-1).reshape(x.shape)
-    ctx.set_output("Out", out.astype(x.dtype))
+    scale = float(ctx.attr("scale", 1.0))
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    turned = x[..., :r].astype(jnp.float32)
+    if ctx.attr("layout", "interleaved") == "half":
+        a, b = turned[..., :r // 2], turned[..., r // 2:]
+        out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                              axis=-1)
+    else:
+        pairs = turned.reshape(x.shape[:-1] + (r // 2, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                        axis=-1).reshape(turned.shape)
+    out = out.astype(x.dtype)
+    if r < x.shape[-1]:
+        out = jnp.concatenate([out, x[..., r:]], axis=-1)
+    ctx.set_output("Out", out)
 
 
 def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,
@@ -682,7 +703,7 @@ def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,
         return name if ok else None
 
     b_ax = axis_for(batch_axis, q.shape[0])
-    h_ax = axis_for(head_axis, q.shape[1])
+    h_ax = axis_for(head_axis, k.shape[1])     # grouped: the key heads
     spec = P(b_ax, h_ax, None, None)
     args, specs = (q, k, v), (spec,) * 3
     if mask is not None:
@@ -699,7 +720,7 @@ def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,
         in_specs=specs, out_specs=spec, check_vma=False)(*args)
 
 
-def _count_sdpa_site(ctx, path, mask, causal):
+def _count_sdpa_site(ctx, path, mask, causal, window=None, group=1):
     """One count an attention site traced into a step program, in the
     idiom of ops/cache_ops.py _count_append_site (the build-time shape
     inference carries no program and is no site). A grad site that
@@ -719,11 +740,14 @@ def _count_sdpa_site(ctx, path, mask, causal):
         "longer than 1, which the kernels read a score-sized block of "
         "per tile; kv_len: no mask but each row's live length over a "
         "KV cache, which path decode_kernel reads only the live blocks "
-        "of and path composed slices to the bound and masks) and by the "
+        "of and path composed slices to the bound and masks), by the "
         "causal attr (1 lets the kernels skip the tiles above the "
-        "diagonal).",
-        ("path", "mask", "causal")).labels(
-            path=path, mask=mask, causal=str(int(causal))).inc()
+        "diagonal), by the window attr (0: none; W: query i sees keys "
+        "i - W < j <= i and the kernels walk that band alone) and by "
+        "the query heads that read one key head.",
+        ("path", "mask", "causal", "window", "group")).labels(
+            path=path, mask=mask, causal=str(int(causal)),
+            window=str(window or 0), group=str(group)).inc()
 
 
 def _decode_kernel_lane_axis(ctx, q, cache, bound):
@@ -759,15 +783,24 @@ def _sdpa(ctx):
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     mask = ctx.input("Mask")
     causal = bool(ctx.attr("causal", False))
+    # attr window W (a causal site's): query i sees keys i - W < j <= i.
+    # K and V may come at fewer heads than Q (grouped-query attention:
+    # query head h reads key head h // group).
+    window = int(ctx.attr("window", 0) or 0) or None
+    group = q.shape[1] // k.shape[1] if q.ndim == 4 else 1
+    if window is not None and not causal:
+        raise ValueError("scaled_dot_product_attention: a window belongs "
+                         "to a causal site")
 
     # Cached decode: K and V are whole KV caches [slots, h, max_seq, d]
     # and KvLen [slots] says how many of each slot's rows are live;
     # attr kv_bound (static) is the most any slot holds this step.
     kv_len = ctx.input("KvLen")
     if kv_len is not None:
-        if mask is not None or causal:
+        if mask is not None or causal or group != 1:
             raise ValueError("scaled_dot_product_attention: KvLen "
-                             "stands in for the mask and for causality")
+                             "stands in for the mask and for causality, "
+                             "over as many key heads as query heads")
         bound = int(ctx.attr("kv_bound", k.shape[2]))
         lane_axis = _decode_kernel_lane_axis(ctx, q, k, bound)
         if lane_axis is not None:
@@ -788,6 +821,9 @@ def _sdpa(ctx):
     seq_axis = ctx.attr("seq_axis", None)
     mesh = ctx.extra.get("mesh") if ctx.extra else None
     if seq_axis and mesh is not None and seq_axis in mesh.axis_names:
+        if window is not None or group != 1:
+            raise ValueError("sequence-parallel attention has neither a "
+                             "window nor grouped key heads")
         from ..parallel.context_parallel import sequence_parallel_attention
         kv_mask = None
         if mask is not None:
@@ -834,10 +870,11 @@ def _sdpa(ctx):
             "dense" if mask.ndim >= 2 and mask.shape[-2] > 1
             else "key_row"))
     _count_sdpa_site(ctx, "flash" if use_flash else "composed", mask_kind,
-                     causal)
+                     causal, window, group)
     if use_flash:
         from .pallas import flash_attention
-        attend = functools.partial(flash_attention, causal=causal)
+        attend = functools.partial(flash_attention, causal=causal,
+                                   window=window)
         if mesh is None:
             out = attend(q, k, v, mask)
         else:
@@ -848,6 +885,8 @@ def _sdpa(ctx):
         ctx.set_output("Out", out)
         return
     scale = 1.0 / np.sqrt(q.shape[-1])
+    if group != 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     scores = jnp.einsum("...qd,...kd->...qk", q, k) * scale
     if mask is not None:
         scores = scores + mask
@@ -855,7 +894,10 @@ def _sdpa(ctx):
         sq, sk = scores.shape[-2], scores.shape[-1]
         qpos = jnp.arange(sq)[:, None]
         kpos = jnp.arange(sk)[None, :]
-        scores = jnp.where(qpos >= kpos, scores, -1e30)
+        seen = qpos >= kpos
+        if window is not None:
+            seen = seen & (qpos - kpos < window)
+        scores = jnp.where(seen, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     ctx.set_output("Out", jnp.einsum("...qk,...kd->...qd", probs, v))
 

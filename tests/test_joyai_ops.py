@@ -13,6 +13,7 @@ import pytest
 
 import paddle_tpu as pt
 from chipbench import reference_joyai as ref
+from chipbench import reference_laguna
 from paddle_tpu import layers
 from paddle_tpu.core.registry import grad_var_name
 from paddle_tpu.layer_helper import LayerHelper
@@ -125,6 +126,86 @@ def test_rotary_embedding_on_interleaved_pairs(shape):
     _close(np.square(y).reshape(*shape[:-1], -1, 2).sum(-1),
            np.square(x).reshape(*shape[:-1], -1, 2).sum(-1), "norms",
            rtol=1e-4, atol=1e-5)
+
+
+YARN = {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+        "original_max_position_embeddings": 4096, "beta_slow": 1,
+        "beta_fast": 64, "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 0.5}
+
+
+@pytest.mark.parametrize("params", [
+    {"rope_type": "default", "rope_theta": 10000,
+     "partial_rotary_factor": 1},
+    {"rope_type": "default", "rope_theta": 10000,
+     "partial_rotary_factor": 0.5},
+    YARN,
+], ids=["rotate-half", "half-of-the-head", "yarn-table-and-scale"])
+def test_rotary_embedding_rotate_half_partial_and_given_table(params):
+    """The op's rotate-half layout, a rotary width under the head's and
+    a frequency table given as data with a scale on cos and sin, as
+    ``models.decoder_moe`` asks for them from one ``rope_parameters``
+    block, against the plain reference's own few lines."""
+    from paddle_tpu.models.decoder_moe import _rope_keywords
+    shape = (2, 3, 10, 16)
+    rng = np.random.RandomState(3)
+    x = rng.randn(*shape).astype(np.float32)
+    pos = (np.arange(10) * 37 + 5).astype(np.int64)
+    cot = rng.randn(*shape).astype(np.float32)
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        xv = layers.data("x", list(shape), append_batch_size=False,
+                         stop_gradient=False)
+        pv = layers.data("pos", [10], dtype="int64",
+                         append_batch_size=False)
+        cv = layers.data("cot", list(shape), append_batch_size=False)
+        out = layers.rotary_embedding(xv, pv, **_rope_keywords(params, 16))
+        pt.append_backward(layers.reduce_sum(
+            layers.elementwise_mul(out, cv)), program=main)
+    y, dx = pt.Executor().run(
+        main, feed={"x": x, "pos": pos, "cot": cot},
+        fetch_list=[out, grad_var_name("x")])
+    want = reference_laguna.rope(jnp.asarray(x), jnp.asarray(pos), params)
+    _close(y, want, "y", rtol=2e-4, atol=2e-5)
+    _close(dx, jax.grad(lambda x: jnp.sum(reference_laguna.rope(
+        x, jnp.asarray(pos), params) * cot))(jnp.asarray(x)), "dx",
+        rtol=2e-4, atol=2e-5)
+    r = int(16 * params["partial_rotary_factor"])
+    np.testing.assert_array_equal(y[..., r:], x[..., r:])
+    # columns (i, i + r/2) turn together, scaled by the attention factor
+    scale = params.get("attention_factor", 1.0)
+    _close(np.square(y[..., :r // 2]) + np.square(y[..., r // 2:r]),
+           scale ** 2 * (np.square(x[..., :r // 2])
+                         + np.square(x[..., r // 2:r])), "norms",
+           rtol=1e-4, atol=1e-5)
+
+
+def test_yarn_table_is_the_closed_form():
+    """theta 5e5, 64 rotary columns, factor 64 over an original 4096,
+    beta_fast 64, beta_slow 1: pairs 0-5 keep theta^(-2i/64) (they turn
+    more than 64 times over 4096 positions), pairs 16-31 take it over
+    64, a linear ramp between."""
+    from paddle_tpu.models.decoder_moe import yarn_inv_freq
+    table = yarn_inv_freq(5e5, 64, 64, 4096, 64, 1)
+
+    def turning(turns):
+        return 64 * np.log(4096 / (2 * np.pi * turns)) / (2 * np.log(5e5))
+
+    lo, hi = int(np.floor(turning(64))), int(np.ceil(turning(1)))
+    assert (lo, hi) == (5, 16) and len(table) == 32
+    plain = [5e5 ** (-2 * i / 64) for i in range(32)]
+    np.testing.assert_allclose(table[:6], plain[:6], rtol=1e-12)
+    np.testing.assert_allclose(table[16:], np.asarray(plain[16:]) / 64,
+                               rtol=1e-12)
+    for i in range(6, 16):
+        keep = 1 - (i - 5) / 11
+        assert table[i] == pytest.approx(
+            plain[i] / 64 * (1 - keep) + plain[i] * keep, rel=1e-12)
+    assert table[0] == 1.0
+    assert table[31] == pytest.approx(4.709153362717455e-08, rel=1e-9)
+    got, factor, r = reference_laguna.inv_freq(YARN, 128)
+    np.testing.assert_allclose(got, table, rtol=1e-12)
+    assert r == 64 and factor == pytest.approx(0.1 * np.log(64) + 1)
 
 
 def _router_inputs(seed):
@@ -256,27 +337,46 @@ def _ffn_over(x, idx, weights, mats, held, offset):
         mats[2].reshape(held, f, d), expert_offset=offset)
 
 
-def test_shares_of_a_group_add_up_to_the_uncut_layer():
-    """16 experts in 4 shares of 4: the four shares' routed parts plus
-    the shared expert ONCE equal the uncut reference layer (router,
-    all 16 experts, shared expert)."""
+@pytest.mark.parametrize("experts,shares,width,top_k,biased", [
+    (16, 4, F, K, True), (256, 32, 512, 8, False)],
+    ids=["4-shares-of-4-selection-bias", "32-shares-of-8-no-bias-512-wide"])
+def test_shares_of_a_group_add_up_to_the_uncut_layer(experts, shares, width,
+                                                     top_k, biased):
+    """The shares' routed parts plus the shared expert ONCE equal the
+    uncut reference layer (router, every expert, shared expert): 16
+    experts in 4 shares under a selection bias (reference_joyai), and
+    256 experts 512 wide in the 32 shares of 8 of the laguna-xs2
+    deployment, selected by the scores alone (reference_laguna)."""
     rng = np.random.RandomState(6)
+    held = experts // shares
     x = rng.randn(T, D).astype(np.float32)
-    router = (rng.randn(D, E) * 0.5).astype(np.float32)
-    bias = rng.uniform(-0.1, 0.1, E).astype(np.float32)
-    mats = _expert_weights(rng, E)
+    router = (rng.randn(D, experts) * 0.5).astype(np.float32)
+    mats = [(rng.randn(experts * D, width) * 0.3).astype(np.float32),
+            (rng.randn(experts * D, width) * 0.3).astype(np.float32),
+            (rng.randn(experts * width, D) * 0.3 / np.sqrt(width / F))
+            .astype(np.float32)]
     shared = [(rng.randn(D, F) * 0.3).astype(np.float32),
               (rng.randn(D, F) * 0.3).astype(np.float32),
               (rng.randn(F, D) * 0.3).astype(np.float32)]
-    m = dict(ROUTING, n_routed_experts=E, experts_held=E, expert_offset=0)
-    uncut = ref.moe_ffn(jnp.asarray(x), [router, bias] + mats + shared, m)
-    idx, weights = ref.route(x, router, bias, ROUTING)
+    m = dict(num_experts_per_tok=top_k, routed_scaling_factor=2.5,
+             n_routed_experts=experts, experts_held=experts,
+             expert_offset=0)
+    if biased:
+        bias = rng.uniform(-0.1, 0.1, experts).astype(np.float32)
+        uncut = ref.moe_ffn(jnp.asarray(x),
+                            [router, bias] + mats + shared, m)
+        idx, weights = ref.route(x, router, bias, m)
+    else:
+        uncut = reference_laguna.moe_ffn(jnp.asarray(x),
+                                         [router] + mats + shared, m)
+        idx, weights = reference_laguna.route(x, router, m)
     total = ref.gated_ffn(jnp.asarray(x), *shared)
-    for share in range(4):
-        rows = slice(share * 4 * D, (share + 1) * 4 * D)
-        down = slice(share * 4 * F, (share + 1) * 4 * F)
+    for share in range(shares):
+        rows = slice(share * held * D, (share + 1) * held * D)
+        down = slice(share * held * width, (share + 1) * held * width)
         part = [mats[0][rows], mats[1][rows], mats[2][down]]
-        total = total + _ffn_over(x, idx, weights, part, 4, share * 4)
+        total = total + _ffn_over(x, idx, weights, part, held,
+                                  share * held)
     _close(total, uncut, "sum of the shares", rtol=2e-4, atol=2e-5)
 
 

@@ -127,8 +127,10 @@ def _paths(which):
 
     from paddle_tpu.observability import default_registry
     fam = default_registry().get(f"paddle_tpu_flash_{which}_sites_total")
-    return collections.Counter() if fam is None else collections.Counter(
-        {labels[0]: child.value for labels, child in fam.samples()})
+    by_path = collections.Counter()     # over every window and group
+    for labels, child in (fam.samples() if fam is not None else ()):
+        by_path[labels[0]] += child.value
+    return by_path
 
 
 # (sq, sk, d, d_v, tile cap): lengths the tiles do not divide, a value
@@ -231,7 +233,7 @@ def test_forward_matches_naive(causal, bias_kind, dims, budget, monkeypatch):
     bias = _fused_bias(bias_kind, B, H, sq, sk)
     paths = _paths("fwd")
     o, lse = fa._fwd(q, k, v, None if bias is None else jnp.asarray(bias),
-                     1.0 / np.sqrt(d), causal, cap, cap, True,
+                     1.0 / np.sqrt(d), causal, None, cap, cap, True,
                      bias_kind.startswith("trainable"))
     segments = -(-sk // cap) if budget == 0 else 1
     assert _paths("fwd") - paths == {

@@ -66,8 +66,10 @@ def _sites(which):
 
     from paddle_tpu.observability import default_registry
     fam = default_registry().get(f"paddle_tpu_flash_{which}_sites_total")
-    return collections.Counter() if fam is None else collections.Counter(
-        {labels[0]: child.value for labels, child in fam.samples()})
+    by_path = collections.Counter()     # over every window and group
+    for labels, child in (fam.samples() if fam is not None else ()):
+        by_path[labels[0]] += child.value
+    return by_path
 
 
 # [B, H, S, D] of chip_smoke.py's train step (b4 x s2048, 8 heads of
@@ -113,6 +115,39 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, shape, bias, dtype):
     assert _sites("bwd") - bwd == {"resident": 1}
 
 
+# laguna-xs2.train-s8192's two kinds of site (chipbench/configs/
+# laguna-xs2.json: 1 x 8192, 8 key heads of 128): 48 query heads, causal;
+# 64 query heads under a window of 512. Then a window and a sequence that
+# are no multiple of the tile, which interpret mode never holds against
+# Mosaic's tiling.
+@pytest.mark.parametrize("heads,seq,window", [
+    (48, 8192, None), (64, 8192, 512), (64, 4096, 512), (16, 1100, 300)])
+def test_grouped_and_windowed_flash_sites_compile(one_chip, heads, seq,
+                                                  window):
+    import re
+    q = jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, seq, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return _sum_f32(flash_attention(q, k, v, causal=True,
+                                        window=window, interpret=False))
+
+    fwd, bwd = _sites("fwd"), _sites("bwd")
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = re.findall(
+        r"%\S*?(flash_[a-z_]*?)_*\.?\d* = [^\n]*tpu_custom_call", text)
+    suffix = "_window" if window else ""
+    assert sorted(calls) == ["flash_bwd_dkv_dq" + suffix,
+                             "flash_fwd" + suffix]
+    # a head's K and V resident in both passes at 8192 keys; dK and dV
+    # leave the kernel at the 8 key heads, summed over the group
+    assert _sites("fwd") - fwd == {"resident": 1}
+    assert _sites("bwd") - bwd == {"resident": 1}
+
+
 def test_flash_backward_beyond_the_vmem_budget_compiles(one_chip):
     """A head whose K and V with their accumulators pass the budget
     (32,768 keys of 128: 100 MB) is walked a segment at a time, dQ an
@@ -151,7 +186,8 @@ def test_flash_forward_beyond_the_vmem_budget_compiles(one_chip, seq, budget,
                              sharding=one_chip)
     fwd = _sites("fwd")
     text = jax.jit(lambda q, k, v: fa._fwd(
-        q, k, v, None, 128 ** -0.5, True, None, None, False, False)).lower(
+        q, k, v, None, 128 ** -0.5, True, None, None, None, False,
+        False)).lower(
         q, q, q).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert _sites("fwd") - fwd == {"partial": 1}
@@ -424,3 +460,76 @@ def test_decode_step_reads_its_caches_in_place(topo, one_chip,
                     if "multiply_reduce" in ln and " fusion(" in ln
                     and re.search(cache_re, ln)]
     assert not over_a_cache, over_a_cache[:2]
+
+
+def test_windowed_kernels_map_to_their_attention_op_by_role(one_chip,
+                                                            monkeypatch):
+    """A two-layer grouped-query step (a window layer, a full layer) at
+    128-wide heads, compiled whole for the described chip: one forward
+    and one backward kernel a site, the window layer's named
+    ``flash_*_window``, and the op table (core/op_table.py) charges
+    each to its attention op — forward to the op, backward to its grad
+    op — so the readers of device time by role count them."""
+    import collections
+    import importlib
+
+    import paddle_tpu as pt
+    from paddle_tpu.core import op_table
+    from paddle_tpu.models import decoder_moe
+
+    seq = 1024
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", "force")
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.pallas.flash_attention"),
+        "_interpret_default", lambda: False)
+    pt.reset_default_programs()
+    pt.reset_global_scope()
+    rope = {"rope_type": "default", "rope_theta": 10000,
+            "partial_rotary_factor": 1}
+    try:
+        with pt.amp.amp_guard(True):
+            main, startup, fetch = decoder_moe.build_train(
+                attention="gqa", topk_method="greedy", trg_vocab=512,
+                max_len=seq, hidden_size=256, intermediate_size=512,
+                moe_intermediate_size=128, n_routed_experts=8,
+                experts_held=2, num_experts_per_tok=2,
+                num_hidden_layers=2, num_nextn_predict_layers=0,
+                layer_types=["sliding_attention", "full_attention"],
+                num_attention_heads_per_layer=[4, 6],
+                mlp_layer_types=["dense", "sparse"],
+                num_key_value_heads=2, head_dim=128, sliding_window=512,
+                rope_parameters={"full_attention": rope,
+                                 "sliding_attention": rope}, gating=True)
+            exe = pt.Executor()
+            exe.run(startup)
+            scope = pt.global_scope()
+            step = exe._compile(main.desc, main.desc.block(0), None,
+                                [fetch["loss"].name], scope)
+
+            def sds(shape, dtype):
+                return jax.ShapeDtypeStruct(shape, dtype,
+                                            sharding=one_chip)
+
+            def state(names):
+                return {n: sds(scope.get(n).shape, scope.get(n).dtype)
+                        for n in names}
+
+            feed = {n: sds((1, seq, 1), jnp.int32)
+                    for n in ("src_ids", "trg_ids", "trg_labels")}
+            feed["pos_ids"] = sds((seq,), jnp.int32)
+            text = step.jitted.lower(
+                feed, state(step.ro_names), state(step.rw_names),
+                sds((), jnp.int32)).compile().as_text()
+    finally:
+        pt.reset_global_scope()
+    charged = collections.Counter(
+        (name.rstrip("0123456789._").replace("jvp_", ""), ref.op_type,
+         ref.role)
+        for name, ref in op_table.parse(text).ops.items()
+        if "flash_" in name)
+    attn = "scaled_dot_product_attention"
+    assert charged == {
+        ("flash_fwd_window", attn, "forward"): 1,
+        ("flash_bwd_dkv_dq_window", "__vjp__." + attn, "backward"): 1,
+        ("flash_fwd", attn, "forward"): 1,
+        ("flash_bwd_dkv_dq", "__vjp__." + attn, "backward"): 1}
