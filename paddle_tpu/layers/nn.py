@@ -421,10 +421,13 @@ def moe_experts(input, top_idx, top_w, d_inner, experts_total,
     experts' matrices are stacked by rows ([held * d, f] gate and up,
     [held * f, d] down) and drawn as ``held`` separate Xavier layers,
     the down projections times ``down_init_scale``. The layer keeps a
-    persistable ``<name>.live_rows`` [3] that no optimizer touches: the
+    persistable ``<name>.live_rows`` [4] that no optimizer touches: the
     rows the routing sent its experts, summed over the steps run, the
-    steps, and the last step's rows (what the grouped products' time
-    follows, and nothing else in the program shows)."""
+    steps, the last step's rows (what the grouped products' time
+    follows), and the rows of the buffer the step's other passes ran
+    over, summed over the steps (the blocks of ops/moe_ops.py
+    block_rows that held the live rows; nothing else in the program
+    shows either)."""
     helper = LayerHelper("moe_experts", name=name)
     held = experts_total if experts_held is None else int(experts_held)
     d, f = int(input.shape[-1]), int(d_inner)
@@ -439,22 +442,25 @@ def moe_experts(input, top_idx, top_w, d_inner, experts_total,
     w_down = stacked(f, d, down_init_scale)
     out = helper.create_tmp_variable(input.dtype)
     live = helper.create_tmp_variable("float32")
-    live.stop_gradient = True
+    buffer_rows = helper.create_tmp_variable("float32")
+    live.stop_gradient = buffer_rows.stop_gradient = True
     helper.append_op(type="moe_experts",
                      inputs={"X": input, "TopIdx": top_idx, "TopW": top_w,
                              "WGate": w_gate, "WUp": w_up,
                              "WDown": w_down},
-                     outputs={"Out": out, "LiveRows": live},
+                     outputs={"Out": out, "LiveRows": live,
+                              "BufferRows": buffer_rows},
                      attrs={"experts_total": int(experts_total),
                             "experts_held": held,
                             "expert_offset": int(expert_offset),
                             "top_k": int(top_idx.shape[-1])})
     tally = helper.create_global_variable(
-        shape=[3], dtype="float32", persistable=True,
+        shape=[4], dtype="float32", persistable=True,
         name=helper.name + ".live_rows")
     helper.set_variable_initializer(tally, ConstantInitializer(0.0))
     helper.append_op(type="moe_rows_tally",
-                     inputs={"Tally": tally, "LiveRows": live},
+                     inputs={"Tally": tally, "LiveRows": live,
+                             "BufferRows": buffer_rows},
                      outputs={"TallyOut": tally})
     return out
 

@@ -17,6 +17,7 @@ from chipbench import reference_laguna
 from paddle_tpu import layers
 from paddle_tpu.core.registry import grad_var_name
 from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.ops import moe_ops
 from paddle_tpu.ops.moe_ops import held_experts_ffn
 
 T, D, F, E, K = 24, 16, 12, 16, 4
@@ -301,8 +302,10 @@ def test_moe_experts_and_its_gradients(held, offset):
 
 def test_moe_experts_counts_its_live_rows_and_the_tally_keeps_them():
     """LiveRows is the number of assignments to held experts (4..6 of
-    16 here); ``moe_rows_tally`` folds a step's count into (sum over
-    the steps, steps, last)."""
+    16 here), BufferRows the rows of the blocks that held them (at this
+    size one block: the worst case, tokens x 3); ``moe_rows_tally`` folds a
+    step's counts into (live rows summed over the steps, steps, the
+    last step's live rows, buffer rows summed over the steps)."""
     rng = np.random.RandomState(9)
     x = rng.randn(T, D).astype(np.float32)
     idx = np.stack([rng.permutation(E)[:K] for _ in range(T)])
@@ -314,27 +317,31 @@ def test_moe_experts_counts_its_live_rows_and_the_tally_keeps_them():
     main, ov, feed = _run_op(
         "moe_experts", feeds, dict(experts_total=E, experts_held=3,
                                    expert_offset=4, top_k=K),
-        ["LiveRows", "Out"], ())
-    live = pt.Executor().run(main, feed=dict(
+        ["LiveRows", "BufferRows", "Out"], ())
+    live, buf = pt.Executor().run(main, feed=dict(
         feed, cot=np.zeros(T * D, np.float32)),
-        fetch_list=[ov["LiveRows"]])[0]
+        fetch_list=[ov["LiveRows"], ov["BufferRows"]])
     want = int(np.sum((idx >= 4) & (idx < 7)))
     assert 0 < want < T * 3 and float(live) == want
+    assert moe_ops.block_rows(T, K, 3, E) == T * 3
+    assert float(buf) == T * 3
     main, ov, feed = _run_op(
         "moe_rows_tally",
-        {"Tally": ("tally", np.array([40., 3., 9.], np.float32)),
-         "LiveRows": ("live", np.float32(want))}, {}, ["TallyOut"], ())
-    out = pt.Executor().run(main, feed=dict(feed, cot=np.zeros(3, np.float32)),
+        {"Tally": ("tally", np.array([40., 3., 9., 216.], np.float32)),
+         "LiveRows": ("live", np.float32(want)),
+         "BufferRows": ("buf", np.float32(72))}, {}, ["TallyOut"], ())
+    out = pt.Executor().run(main, feed=dict(feed, cot=np.zeros(4, np.float32)),
                             fetch_list=[ov["TallyOut"]])[0]
-    np.testing.assert_array_equal(out, [40 + want, 4, want])
+    np.testing.assert_array_equal(out, [40 + want, 4, want, 288])
 
 
-def _ffn_over(x, idx, weights, mats, held, offset):
+def _ffn_over(x, idx, weights, mats, held, offset, total=E):
     d, f = x.shape[-1], mats[0].shape[-1]
     return held_experts_ffn(
         jnp.asarray(x), jnp.asarray(idx, jnp.int32), jnp.asarray(weights),
         mats[0].reshape(held, d, f), mats[1].reshape(held, d, f),
-        mats[2].reshape(held, f, d), expert_offset=offset)
+        mats[2].reshape(held, f, d), expert_offset=offset,
+        experts_total=total)
 
 
 @pytest.mark.parametrize("experts,shares,width,top_k,biased", [
@@ -376,7 +383,7 @@ def test_shares_of_a_group_add_up_to_the_uncut_layer(experts, shares, width,
         down = slice(share * held * width, (share + 1) * held * width)
         part = [mats[0][rows], mats[1][rows], mats[2][down]]
         total = total + _ffn_over(x, idx, weights, part, held,
-                                  share * held)
+                                  share * held, experts)
     _close(total, uncut, "sum of the shares", rtol=2e-4, atol=2e-5)
 
 
@@ -421,6 +428,191 @@ def test_fewer_held_experts_than_picks_shrinks_the_buffer():
     got = _ffn_over(x, idx, weights, mats, 2, 6)
     _close(got, ref.routed_experts(x, idx, weights, *mats, 2, 6),
            "two held under top-4", rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((8192, 8, 8, 256), 8192),         # laguna-xs2.train-s8192: 8 blocks
+    ((4096, 8, 8, 256), 4096),         # joyai-llm-flash.train-ep32: 8 blocks
+    ((512, 2, 2, 16), 512),
+    ((1024, 4, 2, 64), 512),
+    ((1000, 8, 8, 256), 1024),         # up to a multiple of 512
+    ((24, 4, 16, 16), 96),             # every expert held: the worst case
+    ((512, 4, 2, 16), 1024),           # a quarter of the picks held: too
+    ((24, 4, 2, 16), 48),              # the worst case under 512 rows
+], ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_block_rows_from_the_shapes_alone(shape, want):
+    """Four times the uniform routing's rows, up to a multiple of 512,
+    and no more than the worst case."""
+    assert moe_ops.block_rows(*shape) == want
+
+
+# layers whose block is under their worst case: experts 6 and 7 of 16
+# under top-2 over 512 tokens (512-row blocks, 1,024 rows at the worst),
+# experts 9 and 10 of 64 under top-4 over 1,024 tokens (512, 2,048) and
+# under top-2 over 600 tokens (512, 1,200)
+BLOCKED = {"2-of-16-top-2": (512, 2, 2, 6, 16),
+           "2-of-64-top-4": (1024, 4, 2, 9, 64),
+           # 1,200 assignments, every one held at the worst: the third
+           # block runs past them, into the padding of the sort's order
+           "2-of-64-top-2-600-tokens": (600, 2, 2, 9, 64)}
+
+
+def _routing_with(rng, n_live, tokens, k, held, offset, total):
+    """TopIdx [tokens, k] of distinct picks a token, ``n_live`` of them
+    in [offset, offset + held), spread over shuffled tokens."""
+    count = np.full(tokens, n_live // tokens)
+    count[:n_live % tokens] += 1
+    count = count[rng.permutation(tokens)]
+    assert count.max() <= min(k, held)
+    idx = []
+    for c in count:
+        absent = rng.permutation(total - held)[:k - c]
+        picks = np.concatenate([offset + rng.permutation(held)[:c],
+                                np.where(absent >= offset, absent + held,
+                                         absent)])
+        idx.append(picks[rng.permutation(k)])
+    idx = np.stack(idx).astype(np.int32)
+    assert np.sum((idx >= offset) & (idx < offset + held)) == n_live
+    return idx
+
+
+def _block_cases():
+    for name, (tokens, k, held, _offset, total) in BLOCKED.items():
+        block = moe_ops.block_rows(tokens, k, held, total)
+        rows = tokens * min(k, held)
+        assert block < rows
+        for n_live in sorted({0, 1, rows} | {
+                n for edge in range(block, rows, block)
+                for n in (edge - 1, edge, edge + 1)}):
+            yield pytest.param(name, n_live, -(-n_live // block) * block,
+                               id=f"{name}-{n_live}-live")
+
+
+@pytest.mark.parametrize("layer,n_live,buffer_rows", list(_block_cases()))
+def test_every_count_of_blocks_gives_the_worst_cases_sums(layer, n_live,
+                                                          buffer_rows):
+    """At each block's edge, with no pick held and with every pick held
+    (hand-built TopIdx): Out and the gradients for X, TopW and the
+    three matrices equal the one worst-case pass's to float32 rounding
+    and the reference's to the file's limits; LiveRows and BufferRows
+    by hand."""
+    tokens, k, held, offset, total = BLOCKED[layer]
+    rng = np.random.RandomState(n_live)
+    x = rng.randn(tokens, D).astype(np.float32)
+    idx = _routing_with(rng, n_live, tokens, k, held, offset, total)
+    weights = rng.uniform(0.1, 1.0, (tokens, k)).astype(np.float32)
+    mats = _expert_weights(rng, held)
+    cot = rng.randn(tokens, D).astype(np.float32)
+    names = ("x", "topw", "w_gate", "w_up", "w_down")
+    feeds = dict(zip(("X", "TopW", "WGate", "WUp", "WDown"),
+                     zip(names, (x, weights, *mats))), TopIdx=("idx", idx))
+    main, ov, feed = _run_op(
+        "moe_experts", feeds,
+        dict(experts_total=total, experts_held=held, expert_offset=offset,
+             top_k=k), ["LiveRows", "BufferRows", "Out"], names)
+    (live, buf, out), grads = _fetch(
+        main, ov, feed, ["LiveRows", "BufferRows", "Out"], names, cot)
+    assert (float(live), float(buf)) == (n_live, buffer_rows)
+
+    def one_pass(x, tw, a, b, c):
+        return moe_ops._whole_buffer(
+            offset, x, jnp.asarray(idx), tw, a.reshape(held, D, F),
+            b.reshape(held, D, F), c.reshape(held, F, D))
+
+    def reference(x, tw, a, b, c):
+        return ref.routed_experts(x, idx, tw, a, b, c, held, offset)
+
+    for what, fn, (rtol, atol), (g_rtol, g_atol) in [
+            ("the worst-case pass", one_pass, (1e-6, 1e-6), (1e-5, 5e-5)),
+            ("the reference", reference, (2e-4, 2e-5), (1e-3, 1e-4))]:
+        want, pull = jax.vjp(fn, x, weights, *mats)
+        _close(out, want, f"out against {what}", rtol=rtol, atol=atol)
+        for name, g, w in zip(names, grads, pull(jnp.asarray(cot))):
+            _close(g, w, f"d{name} against {what}", rtol=g_rtol, atol=g_atol)
+
+
+def test_what_a_dead_row_holds_reaches_no_gradient(monkeypatch):
+    """On the chip the grouped products write nothing into a row past
+    the live ones, which then holds whatever the buffer held. With NaN
+    planted there, Out and every gradient are finite and equal the
+    clean run's."""
+    tokens, k, held, offset, total = BLOCKED["2-of-64-top-4"]
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(tokens, D), jnp.float32)
+    idx = _routing_with(rng, 700, tokens, k, held, offset, total)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, (tokens, k)), jnp.float32)
+    mats = [jnp.asarray(m) for m in _expert_weights(rng, held)]
+
+    def run():
+        return jax.value_and_grad(lambda *a: jnp.sum(_ffn_over(
+            a[0], idx, a[1], a[2:], held, offset, total) ** 2),
+            (0, 1, 2, 3, 4))(x, weights, *mats)
+
+    clean = run()
+    grouped = jax.lax.ragged_dot
+
+    def leaves_dead_rows_dirty(a, w, sizes, **kw):
+        dead = jnp.arange(a.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(dead[:, None], jnp.nan, grouped(a, w, sizes, **kw))
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", leaves_dead_rows_dirty)
+    dirty = run()
+    for want, got in zip(jax.tree.leaves(clean), jax.tree.leaves(dirty)):
+        assert np.isfinite(np.asarray(got)).all()
+        _close(got, want, "with NaN in the dead rows", rtol=1e-6, atol=1e-6)
+
+
+def _shapes_in(jaxpr):
+    """(primitive, shape) of everything a jaxpr computes, the jaxprs
+    inside its equations' parameters included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield eqn.primitive.name, tuple(getattr(v.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _shapes_in(sub)
+
+
+def _both_passes(tokens, k, held, total):
+    """The jaxpr of an expert layer's forward and backward passes."""
+    rng = np.random.RandomState(1)
+    x = jnp.asarray(rng.randn(tokens, D), jnp.float32)
+    idx = np.stack([rng.permutation(total)[:k] for _ in range(tokens)])
+    weights = jnp.asarray(rng.rand(tokens, k), jnp.float32)
+    mats = [jnp.asarray(m) for m in _expert_weights(rng, held)]
+
+    def loss(x, weights, *mats):
+        return jnp.sum(_ffn_over(x, idx, weights, mats, held, 0, total))
+
+    return jax.make_jaxpr(jax.grad(loss, (0, 1, 2, 3, 4)))(
+        x, weights, *mats).jaxpr
+
+
+def test_a_blocked_layer_has_no_pass_as_long_as_the_worst_case():
+    """Forward and backward of a layer of 512-row blocks whose worst
+    case is 2,048 rows and whose routing makes 4,096 assignments: no
+    array with either as its leading dimension beside a feature width
+    (the sort's keys and ``order`` and the gradient of TopW have none),
+    and both passes loop over the grouped products."""
+    tokens, k, held, _offset, total = BLOCKED["2-of-64-top-4"]
+    assert (moe_ops.block_rows(tokens, k, held, total), tokens * held,
+            tokens * k) == (512, 2048, 4096)
+    shapes = list(_shapes_in(_both_passes(tokens, k, held, total)))
+    prims = [p for p, _ in shapes]
+    assert "while" in prims and "ragged_dot_general" in prims
+    assert "remat2" not in prims and "cond" not in prims
+    long = [(p, s) for p, s in shapes
+            if len(s) > 1 and s[0] in (2048, 4096) and s[-1] in (D, F)]
+    assert not long, long
+    assert any(s == (512, D) for _, s in shapes)
+
+
+def test_a_layer_with_every_expert_held_traces_as_one_pass():
+    """Every assignment is live where every expert is held: the block
+    is the worst case, and the layer is the one pass it was — under
+    ``jax.checkpoint``, no loop, no branch."""
+    prims = [p for p, _ in _shapes_in(_both_passes(512, K, E, E))]
+    assert "remat2" in prims
+    assert not {"while", "cond"} & set(prims)
 
 
 def test_moe_experts_refuses_a_range_outside_the_layer():
