@@ -14,7 +14,9 @@ boundaries"): matmul/conv-family ops are counted exactly (2 x MACs,
 the same convention XLA's ``cost_analysis()`` uses for the dominant
 terms); ``__vjp__`` grad ops are costed at 2x their embedded forward op
 (the standard backward approximation — a train step totals ~3x the
-forward); everything else is approximated at one FLOP per output
+forward); the ops of a ``static_rnn``'s sub-block are counted once a
+trip (attr ``steps``, or the step input's length) and the loop's grad
+op at twice that; everything else is approximated at one FLOP per output
 element. ``bytes_accessed`` is the PRE-fusion operand traffic (every
 op reads its inputs and writes its outputs) — an upper bound that XLA's
 fusion then reduces, so arithmetic intensity from this model is a lower
@@ -501,6 +503,35 @@ def _bytes_override(op: ir.OpDesc,
     return None
 
 
+def _loop_body(op: ir.OpDesc):
+    """(sub-block idx, the loop op) for a counted loop or its grad op
+    (which embeds it), else None. A loop is an op whose trip count the
+    program states: ``static_rnn``. A ``while`` runs as often as the
+    run decides and is counted once, as before."""
+    if op.type == "__vjp__":
+        fwd = op.attrs.get("fwd_op") or {}
+        if fwd.get("type") != "static_rnn":
+            return None
+        op = ir.OpDesc.from_dict(fwd)
+    elif op.type != "static_rnn":
+        return None
+    idx = op.attrs.get("sub_block_idx")
+    return (idx, op) if isinstance(idx, int) else None
+
+
+def _loop_trips(op: ir.OpDesc, lookup) -> int:
+    """Times a ``static_rnn`` runs its sub-block: attr ``steps`` (the
+    counted form) or the leading dim of its first step input."""
+    steps = op.attrs.get("steps")
+    if steps:
+        return int(steps)
+    for name in op.input("X"):
+        v = lookup(name)
+        if v is not None and v.shape:
+            return max(1, int(v.shape[0]))
+    return 1
+
+
 # ---------------------------------------------------------------------------
 def program_cost(program, block_idx: int = 0,
                  feed_shapes: Optional[Dict[str, Sequence[int]]] = None,
@@ -532,6 +563,8 @@ def program_cost(program, block_idx: int = 0,
 
     param_reads: Dict[str, int] = {}
     op_costs: List[OpCost] = []
+    trips: Dict[int, int] = {}        # block idx -> times it runs a step
+    loop_grads: List[Tuple[OpCost, int]] = []   # (row, its loop's block)
 
     # one resolution cache per block, shared by every op in it: params
     # and activations are read by several ops (fwd, __vjp__, optimizer)
@@ -566,6 +599,10 @@ def program_cost(program, block_idx: int = 0,
             return info
 
         flops, exact, note = _flops_for(op, lookup)
+        runs = trips.get(blk.idx, 1)
+        body = _loop_body(op)
+        if body is not None and op.type != "__vjp__":
+            trips[body[0]] = runs * _loop_trips(body[1], lookup)
         in_infos = [lookup(n) for n in dict.fromkeys(op.input_names())]
         out_infos = [lookup(n) for n in dict.fromkeys(op.output_names())]
         if flops is None:
@@ -589,8 +626,21 @@ def program_cost(program, block_idx: int = 0,
             if v is not None and v.persistable:
                 pbytes += v.bytes
                 param_reads.setdefault(v.name, v.bytes)
+        if runs != 1:
+            flops, bytes_acc = flops * runs, bytes_acc * runs
+            note = f"{note}; x{runs} trips" if note else f"x{runs} trips"
         op_costs.append(OpCost(op.type, path, i, flops, bytes_acc,
                                pbytes, exact, note))
+        if op.type == "__vjp__" and body is not None:
+            loop_grads.append((op_costs[-1], body[0]))
+
+    # a loop's grad op: twice its body's work over all the trips (the
+    # body's rows come after the parent block's, so only now)
+    for row, sub_idx in loop_grads:
+        inside = [c for c in op_costs if sub_idx in c.block_path[1:]]
+        row.flops = 2 * sum(c.flops for c in inside)
+        row.bytes_accessed = 2 * sum(c.bytes_accessed for c in inside)
+        row.note = "vjp x2 of the loop's body over its trips"
 
     return ProgramCost(op_costs, sum(param_reads.values()), batch,
                        block_idx,

@@ -20,8 +20,18 @@ What a row can and cannot say:
   type>``) whether it replays its forward op or applies the pullback
   that op left (``.../__vjp__.relu/b0.12/transpose(relu)/b0.2/...``: the
   FIRST scope decides). A forward op with a sub-block (``while``,
-  ``conditional_block``) hands its instructions on to the op of the
-  sub-block that emitted them (the LAST scope).
+  ``conditional_block``, ``static_rnn``) hands its instructions on to
+  the op of the sub-block that emitted them (the LAST scope), and so
+  does its GRAD op, whose pullback is the loop's transpose
+  (``.../__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/
+  closed_call/mul/b0.1.7/dot_general``): where the last scope lies in
+  a deeper block than the grad op's own, the instruction goes to that
+  sub-block op as ``__vjp__.<its type>``, role ``backward``, at the
+  sub-block's path — a looped stack's backward keeps its rows by type
+  (``__vjp__.mul``, the backward flash kernel under
+  ``__vjp__.scaled_dot_product_attention``). What the transpose emits
+  outside any body op (the carry's slices and updates) stays on the
+  grad op.
 * An inner ``jax.jit`` that several sites share is lowered once, under
   its first site's scope: rows by TYPE are exact, rows by op index put
   every site's time on the first.
@@ -121,12 +131,19 @@ def _op_ref(op_name: str, known: Mapping, types: FrozenSet[str],
         if not found:
             continue
         op_type, m = found[0]
-        if not op_type.startswith("__vjp__"):
+        is_grad = op_type.startswith("__vjp__")
+        # a grad op whose last scope lies in a deeper block: a loop's
+        handed_on = is_grad and \
+            found[-1][1].group(1).count(".") > m.group(1).count(".")
+        if handed_on or not is_grad:
             op_type, m = found[-1]
+        if handed_on and not op_type.startswith("__vjp__"):
+            op_type = "__vjp__." + op_type
         path = tuple(int(x) for x in m.group(1).split(".") if x)
         index = int(m.group(2))
-        role = known.get((path[-1], index), (None,))[0] \
-            or _role(op_type, False, optimizers)
+        role = "backward" if handed_on else (
+            known.get((path[-1], index), (None,))[0]
+            or _role(op_type, False, optimizers))
         return OpRef(op_type, role, path, index)
     for one in op_name.split(";"):
         inner = [p for p in one.split("/") if not p.startswith("jit(")]

@@ -6,6 +6,15 @@ TPU-native design: these build sub-blocks in the IR which the executor
 lowers to jax.lax.scan / while_loop / cond — compiler-friendly control
 flow instead of the reference's nested-Executor interpretation
 (while_op.cc:35, recurrent_op.cc:222).
+
+A loop that runs a FIXED number of times over no sequence — a layer
+stack applied T times on shared weights (models/looped_lm.py) — is the
+counted form of StaticRNN: ``StaticRNN(steps=T)``, memories carried,
+step outputs stacked to ``[T, ...]``, no step input. It is one
+``static_rnn`` op over one sub-block and lowers to ONE ``jax.lax.scan``
+of length T whose body traces the sub-block once, whatever T; the
+parameters the body reads are closure of the op, and the scan's
+transpose sums a parameter's T gradient contributions inside the loop.
 """
 from __future__ import annotations
 
@@ -32,10 +41,27 @@ class StaticRNN:
             rnn.update_memory(prev, h)
             rnn.step_output(h)
         outs = rnn()
+
+    The counted form, ``StaticRNN(steps=T)``, takes its length from
+    ``steps`` and no ``step_input``: the body runs T times on the
+    memories alone (``memory(init=x0)`` / ``update_memory``) and every
+    ``step_output`` comes back stacked ``[T, ...]``. Both forms are one
+    ``static_rnn`` op lowered to one ``jax.lax.scan`` (length T, the
+    body traced once); a memory keeps its init's dtype from step to
+    step. Parameters made inside the guard live in the global block and
+    reach the body as closure: ``append_backward`` makes them inputs of
+    the op's grad op, and the scan's transpose sums a parameter's
+    contributions over the steps — one gradient a parameter.
     """
 
-    def __init__(self, name=None):
+    def __init__(self, name=None, steps=None):
         self.helper = LayerHelper("static_rnn", name=name)
+        if steps is not None and (isinstance(steps, bool)
+                                  or not isinstance(steps, int)
+                                  or steps < 1):
+            raise ValueError(f"StaticRNN(steps={steps!r}): a whole "
+                             "number of steps, at least 1")
+        self._steps = steps
         self._inputs: List[Variable] = []
         self._mem_init: List[Variable] = []
         self._mem_pre: List[Variable] = []
@@ -69,6 +95,11 @@ class StaticRNN:
 
     def step_input(self, x: Variable) -> Variable:
         """x: [T, ...]; returns the per-step slice variable."""
+        if self._steps is not None:
+            raise ValueError(
+                "StaticRNN(steps=T) runs its body T times over the "
+                "memories alone: a step_input would give the loop a "
+                "second length")
         sv = self._block.create_var(
             name=f"{x.name}@step", shape=list(x.shape[1:]) if x.shape
             else None, dtype=x.dtype)
@@ -110,6 +141,12 @@ class StaticRNN:
 
     def _finalize(self):
         helper = self.helper
+        if self._steps is None and not self._inputs:
+            raise ValueError("StaticRNN takes its length from a "
+                             "step_input, or from StaticRNN(steps=T)")
+        if any(new is None for new in self._mem_new):
+            raise ValueError("every StaticRNN.memory needs its "
+                             "update_memory before the step ends")
         self._result_vars = [
             helper.create_tmp_variable(o.dtype) for o in self._outputs]
         outputs = {"Out": self._result_vars}
@@ -118,6 +155,8 @@ class StaticRNN:
                  "mem_pre_names": [v.name for v in self._mem_pre],
                  "mem_new_names": [v.name for v in self._mem_new],
                  "out_names": [o.name for o in self._outputs]}
+        if self._steps is not None:
+            attrs["steps"] = self._steps
         _wire_nested_steps(helper, self._parent_prog,
                            [self._block.desc.idx], outputs, attrs)
         helper.append_op(

@@ -71,8 +71,9 @@ SELECTION_BIAS_RANGE = 0.1
 
 
 def _out_scale(cfg):
-    """What a projection into the residual stream is initialised at."""
-    return (2.0 * cfg["init_depth"]) ** -0.5
+    """What a projection into the residual stream is initialised at:
+    plain Xavier where the model gives no ``init_depth``."""
+    return (2.0 * cfg["init_depth"]) ** -0.5 if cfg["init_depth"] else 1.0
 
 
 def _heads(x, n_head, width):

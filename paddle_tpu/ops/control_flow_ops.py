@@ -90,9 +90,30 @@ def _zero_steps():
     return jnp.zeros((), jnp.int32)
 
 
+def _count_loop_site(ctx, passes, blk_idx):
+    """One count a counted loop traced into a step program, in the
+    idiom of ops/nn_ops.py _count_sdpa_site."""
+    if "program" not in ctx.extra:
+        return
+    from ..observability.registry import default_registry
+    default_registry().counter(
+        "paddle_tpu_loop_sites_total",
+        "Counted loops (StaticRNN(steps=T): one lax.scan over one "
+        "sub-block, no step input) traced into a step program, by the "
+        "passes the loop makes and the ops of its sub-block. A grad op "
+        "that replays its loop counts it again.",
+        ("passes", "body_ops")).labels(
+            passes=str(passes), body_ops=str(len(
+                ctx.extra["program"].blocks[blk_idx].ops))).inc()
+
+
 @register_op_CF("static_rnn")
 def _static_rnn(ctx):
-    """Scan over leading time axis of each step input."""
+    """Scan over leading time axis of each step input, or attr
+    ``steps`` times over the memories alone (StaticRNN's counted form):
+    one ``jax.lax.scan`` either way, the sub-block traced once. A
+    memory keeps its init's dtype (under AMP a body hands a float32
+    stream back at half width)."""
     xs = ctx.inputs("X")                 # each [T, ...]
     mem_init = ctx.inputs("MemInit")
     step_in = ctx.attr("step_in_names")
@@ -115,12 +136,17 @@ def _static_rnn(ctx):
         env, rep = _collect_reports(ctx, trace)
         maxes = tuple(jnp.maximum(m, rep.get(w, _zero_steps()))
                       for w, m in zip(nested, maxes))
-        new_carry = tuple(env[n] for n in mem_new)
+        new_carry = tuple(env[n].astype(c.dtype)
+                          for n, c in zip(mem_new, carry))
         outs = tuple(env[n] for n in out_names)
         return (new_carry, maxes), outs
 
+    steps = ctx.attr("steps", None)
+    if steps is not None:
+        _count_loop_site(ctx, int(steps), blk_idx)
     state0 = (tuple(mem_init), tuple(_zero_steps() for _ in nested))
-    (_, maxes), stacked = jax.lax.scan(body, state0, tuple(xs))
+    (_, maxes), stacked = jax.lax.scan(body, state0, tuple(xs),
+                                       length=steps)
     ctx.set_outputs("Out", list(stacked))
     ctx.set_outputs("NestedSteps", list(maxes))
     _publish_report(ctx, dict(zip(nested, maxes)))
@@ -503,7 +529,7 @@ def _go(ctx):
 #               Exhausted/Steps/NestedSteps flags are scalars.
 # - if_else:    Out[i] mirrors the true branch's i-th output var.
 # - static_rnn: Out[i] = [T, *step_out_shape] (scan stacks the per-step
-#               output over the leading time axis of X).
+#               output over the leading time axis of X, or attr `steps`).
 # - dynamic_rnn: Out[i] mirrors the sub-block step output (ragged,
 #               lod_level 1); LastMem[i] mirrors the init memory.
 #
@@ -545,7 +571,7 @@ def _if_else_infer(block_desc, op):
 
 def _static_rnn_infer(block_desc, op):
     specs = _scalar_specs(op, [("NestedSteps", "int32")])
-    t_dim = -1
+    t_dim = op.attrs.get("steps") or -1
     for xn in op.input("X"):
         xv = block_desc.find_var_recursive(xn)
         if xv is not None and xv.shape:

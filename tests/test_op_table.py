@@ -334,6 +334,77 @@ def test_parse_maps_an_instruction(name, ref):
     assert op_table.parse(_MODULE).ops[name] == ref
 
 
+# what the step of a looped stack carries (models/looped_lm.py, compiled
+# for a described v5e): the loop's forward under jax.vjp, then its grad
+# op's transpose of the scan, whose body holds the sub-block ops' rules
+_LOOP_MODULE = """HloModule jit_step_fn, is_scheduled=true
+
+%fwd_body (c: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %c = (s32[], f32[8]{0}) parameter(0)
+  ROOT %dot.3 = f32[8]{0} dot(%c, %c), metadata={op_name="jit(step_fn)/static_rnn/b0.12/jvp()/while/body/closed_call/mul/b0.1.7/dot_general"}
+}
+
+%bwd_body (c: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %c.1 = (s32[], f32[8]{0}) parameter(0)
+  %dot.9 = f32[8]{0} dot(%c.1, %c.1), metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/closed_call/mul/b0.1.7/dot_general"}
+  %call.4 = f32[8]{0} custom-call(%dot.9), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/closed_call/scaled_dot_product_attention/b0.1.9/flash_bwd_dkv_dq/pallas_call"}
+  ROOT %dynamic-slice.5 = f32[8]{0} dynamic-slice(%call.4), metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/dynamic_slice"}
+}
+
+ENTRY %main.9 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %while.14 = (s32[], f32[8]{0}) while(%x), condition=%cond, body=%fwd_body, metadata={op_name="jit(step_fn)/static_rnn/b0.12/jvp()/while"}
+  %while.15 = (s32[], f32[8]{0}) while(%while.14), condition=%cond, body=%bwd_body, metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while"}
+  ROOT %neg.2 = f32[8]{0} negate(%x), metadata={op_name="jit(step_fn)/__vjp__.relu/b0.99/transpose(relu)/b0.2/jvp()/neg"}
+}
+"""
+
+
+@pytest.mark.parametrize("name, ref", [
+    # the loop's forward: the sub-block op, as any forward op with a
+    # sub-block hands on
+    ("dot.3", OpRef("mul", "forward", (0, 1), 7)),
+    ("while.14", OpRef("static_rnn", "forward", (0,), 12)),
+    # the loop's GRAD op hands on too: the sub-block op whose rule the
+    # transpose ran, as that op's grad, at the sub-block's path
+    ("dot.9", OpRef("__vjp__.mul", "backward", (0, 1), 7)),
+    ("call.4", OpRef("__vjp__.scaled_dot_product_attention", "backward",
+                     (0, 1), 9)),
+    # what the transpose emits outside any body op stays on the grad op
+    ("dynamic-slice.5", OpRef("__vjp__.static_rnn", "backward", (0,), 98)),
+    ("while.15", OpRef("__vjp__.static_rnn", "backward", (0,), 98)),
+    # a grad op in ONE block with its forward op: the FIRST scope still
+    ("neg.2", OpRef("__vjp__.relu", "backward", (0,), 99)),
+])
+def test_a_loops_grad_op_hands_on_to_its_sub_blocks_ops(name, ref):
+    # the program calls the sub-block's op 7 a forward op: the role of
+    # what the GRAD op handed on is backward all the same
+    known = {(1, 7): ("forward", "mul"), (0, 12): ("forward", "static_rnn"),
+             (0, 98): ("backward", "__vjp__.static_rnn")}
+    assert op_table.parse(_LOOP_MODULE, known).ops[name] == ref
+    assert op_table.parse(_LOOP_MODULE).ops[name] == ref
+
+
+def test_a_program_without_a_sub_block_parses_to_the_table_it_had():
+    """Pinned on the module above as the parent parsed it: no program
+    of the benchmark's other cells has a sub-block, so no op_name of
+    theirs deepens and none changes hands."""
+    table = op_table.parse(_MODULE)
+    assert {n: tuple(r) for n, r in table.ops.items()} == {
+        "mul.1": ("matmul", "forward", (0,), 3),
+        "add.1": ("elementwise_add", "forward", (0,), 4),
+        "neg.1": ("__vjp__.relu", "backward", (0,), 12),
+        "tanh.7": ("tanh", "forward", (0, 1), 2),
+        "fusion.1": ("elementwise_add", "forward", (0,), 4),
+        "fusion.2": ("__vjp__.relu", "backward", (0,), 12),
+        "while.1": ("while", "forward", (0,), 5),
+        "merged.1": ("adam", "optimizer", (0,), 20),
+        "call.1": ("__vjp__.scaled_dot_product_attention", "backward",
+                   (0,), 30)}
+    assert set(table.unmapped) == {"p0", "p0.1", "c", "x", "copy.3",
+                                   "copy-done.1"}
+
+
 def test_parse_keeps_what_has_no_op_and_the_mixed_fusions():
     table = op_table.parse(_MODULE)
     assert table.module == "jit_step_fn"

@@ -533,3 +533,78 @@ def test_windowed_kernels_map_to_their_attention_op_by_role(one_chip,
         ("flash_bwd_dkv_dq_window", "__vjp__." + attn, "backward"): 1,
         ("flash_fwd", attn, "forward"): 1,
         ("flash_bwd_dkv_dq", "__vjp__." + attn, "backward"): 1}
+
+
+def test_a_looped_step_compiles_with_its_flash_calls_inside_the_while(
+        one_chip, monkeypatch):
+    """A two-layer, four-pass step of models/looped_lm.py at 128-wide
+    heads, compiled whole for the described chip: the loop is ONE
+    ``while`` each way, the two attention sites' kernels are compiled
+    once each way INSIDE its body (2 + 2 custom calls, not 8 + 8), under
+    the names the trace readers match, and the op table charges the
+    backward kernel, which the loop's grad op emitted, to the sub-block's
+    attention op as its grad."""
+    import collections
+    import importlib
+    import re
+
+    import paddle_tpu as pt
+    from paddle_tpu.core import op_table
+    from paddle_tpu.models import looped_lm
+
+    seq = 1024
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", "force")
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.pallas.flash_attention"),
+        "_interpret_default", lambda: False)
+    pt.reset_default_programs()
+    pt.reset_global_scope()
+    try:
+        with pt.amp.amp_guard(True):
+            main, startup, fetch = looped_lm.build_train(
+                trg_vocab=512, max_len=seq, hidden_size=256,
+                intermediate_size=512, num_hidden_layers=2,
+                num_attention_heads=2, num_key_value_heads=2,
+                head_dim=128, total_ut_steps=4)
+            exe = pt.Executor()
+            exe.run(startup)
+            scope = pt.global_scope()
+            step = exe._compile(main.desc, main.desc.block(0), None,
+                                [fetch["loss"].name], scope)
+
+            def sds(shape, dtype):
+                return jax.ShapeDtypeStruct(shape, dtype,
+                                            sharding=one_chip)
+
+            def state(names):
+                return {n: sds(scope.get(n).shape, scope.get(n).dtype)
+                        for n in names}
+
+            feed = {n: sds((1, seq, 1), jnp.int32)
+                    for n in ("src_ids", "trg_ids", "trg_labels")}
+            feed["pos_ids"] = sds((seq,), jnp.int32)
+            text = step.jitted.lower(
+                feed, state(step.ro_names), state(step.rw_names),
+                sds((), jnp.int32)).compile().as_text()
+    finally:
+        pt.reset_global_scope()
+    assert text.count("tpu_custom_call") == 4
+    bodies = set(re.findall(r"\bwhile\([^\n]*body=%?([^\s,)}]+)", text))
+    assert len(bodies) == 2           # the loop, and its transpose
+    inside, current = collections.Counter(), None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([^\s(]+) \(.*\{$", line)
+        if head:
+            current = head.group(1)
+        elif "tpu_custom_call" in line:
+            inside[current in bodies] += 1
+    assert inside == {True: 4}
+    charged = collections.Counter(
+        (name.rstrip("0123456789._").replace("jvp_", ""), ref.op_type,
+         ref.role, len(ref.block_path))
+        for name, ref in op_table.parse(text).ops.items()
+        if "flash_" in name)
+    attn = "scaled_dot_product_attention"
+    assert charged == {
+        ("flash_fwd", attn, "forward", 2): 2,
+        ("flash_bwd_dkv_dq", "__vjp__." + attn, "backward", 2): 2}
