@@ -35,7 +35,7 @@ from ..core import ir
 from .passes import AnalysisPass, PassContext, iter_ops, register_pass
 
 __all__ = ["OpCost", "ProgramCost", "program_cost", "CostModelPass",
-           "ZERO_FLOP_OPS", "ITEMSIZE"]
+           "ZERO_FLOP_OPS", "PRODUCT_OPS", "ITEMSIZE"]
 
 _ITEMSIZE = {"float64": 8, "int64": 8, "float32": 4, "int32": 4,
              "float16": 2, "bfloat16": 2, "int16": 2, "int8": 1,
@@ -57,6 +57,17 @@ ZERO_FLOP_OPS = frozenset({
     "expand_as", "tile", "reverse", "pad", "pad2d", "gather",
     "gather_nd", "lookup_table", "embedding_bag", "kv_cache_write",
     "kv_cache_append",
+})
+
+#: ops that ARE one contraction (`_flops_for` books 2 x MACs, the
+#: output is the product): the outputs a static_rnn keeps of a pass,
+#: with the norms and rotary embeddings they read, where it recomputes
+#: every other op's (ops/control_flow_ops.py _name_kept). An attention
+#: site is not among them: what a kernel computed of it is kept as the
+#: kernel's output, by the primitive
+PRODUCT_OPS = frozenset({
+    "mul", "matmul", "conv2d", "depthwise_conv2d", "conv3d",
+    "conv2d_transpose", "conv3d_transpose",
 })
 
 #: FLOPs per parameter element for each optimizer update rule (read +

@@ -393,6 +393,8 @@ def _trace_ops(block, env, extra, sites):
         from ..ops.core_ops import run_op_keeping_pullback
     keep = extra.get("keep_vars") or ()
     stats = extra.get("trace_stats")  # optional {.. -> peak_env_bytes}
+    # inside a static_rnn's body: names what the loop keeps of a pass
+    kept_outputs = extra.get("kept_outputs")
     path = _block_path(block)
     for index, op in enumerate(block.ops):
         # the op type (for a grad op, its forward op's too) and then
@@ -414,7 +416,9 @@ def _trace_ops(block, env, extra, sites):
             gop = sites and sites.get(
                 _wiring_key(op.type, op.inputs, op.outputs))
             outs = gop and run_op_keeping_pullback(op, gop, env, extra)
-            env.update(run_op(op, env, extra) if outs is None else outs)
+            if outs is None:
+                outs = run_op(op, env, extra)
+            env.update(kept_outputs(op, outs) if kept_outputs else outs)
         dead = op.attrs.get("__dead_vars__")
         if dead:
             for name in dead:

@@ -29,9 +29,13 @@ What a row can and cannot say:
   sub-block op as ``__vjp__.<its type>``, role ``backward``, at the
   sub-block's path — a looped stack's backward keeps its rows by type
   (``__vjp__.mul``, the backward flash kernel under
-  ``__vjp__.scaled_dot_product_attention``). What the transpose emits
-  outside any body op (the carry's slices and updates) stays on the
-  grad op.
+  ``__vjp__.scaled_dot_product_attention``). So does what the
+  transpose computes AGAIN of the body, which runs under
+  ``jax.checkpoint`` (``.../__vjp__.static_rnn/b0.98/transpose(jvp())/
+  while/body/closed_call/checkpoint/rematted_computation/rms_norm/
+  b0.1.3/mul``: a recomputed norm is a row of ``__vjp__.rms_norm``).
+  What the transpose emits outside any body op (the carry's slices and
+  updates) stays on the grad op.
 * An inner ``jax.jit`` that several sites share is lowered once, under
   its first site's scope: rows by TYPE are exact, rows by op index put
   every site's time on the first.

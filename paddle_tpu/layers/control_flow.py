@@ -52,6 +52,20 @@ class StaticRNN:
     reach the body as closure: ``append_backward`` makes them inputs of
     the op's grad op, and the scan's transpose sums a parameter's
     contributions over the steps — one gradient a parameter.
+
+    What a loop keeps of a step for its backward pass: the memories as
+    the step found them, the outputs of the body's matrix products
+    (``mul`` / ``matmul`` / convolutions, at the width the program holds
+    them: bfloat16 under AMP), the output of an ``rms_norm`` or a
+    ``rotary_embedding`` that a product or an attention site reads, and
+    the outputs of its Pallas kernels (an attention site's output and
+    logsumexp). Everything else of the body — what a norm or the rotary
+    embedding holds inside, the other norms, casts, relayouts,
+    elementwise ops, activations, residual adds — is computed again in
+    the backward pass from those, so T steps cost T times the products'
+    operands and outputs, not T times every intermediate; a body
+    without a product keeps its memories alone. A forward-only program (no grad op of the loop) is
+    unchanged (``ops/control_flow_ops.py _static_rnn``).
     """
 
     def __init__(self, name=None, steps=None):

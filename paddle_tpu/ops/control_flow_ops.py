@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from functools import partial
 
@@ -90,6 +91,21 @@ def _zero_steps():
     return jnp.zeros((), jnp.int32)
 
 
+# what a static_rnn keeps between its forward scan and its transpose.
+# While one traces its sub-block (and the blocks nested in it),
+# extra["kept_outputs"] is `_name_kept` over the block's operand names:
+# core/executor.py _trace_ops passes every op's outputs through it
+KEEPS = "products_and_kernels"
+
+# the ops whose output is kept too where a product or an attention site
+# of the body reads it: both work in float32 inside and hand on the
+# program's width, and XLA fuses a recomputed one into EVERY consumer
+# in the transpose (the step's executable grew by a quarter and every
+# weight gradient re-ran its norm: PERF.md section 6, PR 45);
+# everything else is recomputed
+_KEPT_OPERANDS = frozenset({"rms_norm", "rotary_embedding"})
+
+
 def _count_loop_site(ctx, passes, blk_idx):
     """One count a counted loop traced into a step program, in the
     idiom of ops/nn_ops.py _count_sdpa_site."""
@@ -100,11 +116,50 @@ def _count_loop_site(ctx, passes, blk_idx):
         "paddle_tpu_loop_sites_total",
         "Counted loops (StaticRNN(steps=T): one lax.scan over one "
         "sub-block, no step input) traced into a step program, by the "
-        "passes the loop makes and the ops of its sub-block. A grad op "
-        "that replays its loop counts it again.",
-        ("passes", "body_ops")).labels(
+        "passes the loop makes, the ops of its sub-block and what the "
+        "scan keeps of a pass for its transpose. A grad op that "
+        "replays its loop counts it again.",
+        ("passes", "body_ops", "keeps")).labels(
             passes=str(passes), body_ops=str(len(
-                ctx.extra["program"].blocks[blk_idx].ops))).inc()
+                ctx.extra["program"].blocks[blk_idx].ops)),
+            keeps=KEEPS).inc()
+
+
+def _operand_names(block):
+    """Names the block's products and attention sites read."""
+    from ..analysis.cost_model import PRODUCT_OPS
+    return frozenset(
+        n for op in block.ops
+        if op.type in PRODUCT_OPS
+        or op.type == "scaled_dot_product_attention"
+        for ns in op.inputs.values() for n in ns)
+
+
+def _name_kept(operands, op, outs):
+    """The output of a matrix product, and of a norm or rotary
+    embedding that feeds one (`_KEPT_OPERANDS`), under the name the
+    loop's policy keeps, AS THE PROGRAM HOLDS IT: under AMP `mul` hands
+    on its float32 accumulator rounded to bfloat16, and the rounded
+    value is what is named."""
+    from ..analysis.cost_model import PRODUCT_OPS
+    if op.type not in PRODUCT_OPS and not (
+            op.type in _KEPT_OPERANDS
+            and any(n in operands for ns in op.outputs.values()
+                    for n in ns)):
+        return outs
+    return {k: checkpoint_name(v, KEEPS) for k, v in outs.items()}
+
+
+_named_kept = jax.checkpoint_policies.save_only_these_names(KEEPS)
+
+
+def _kept(prim, *avals, **params):
+    """The loop's one policy: a named output (`_name_kept`) and every
+    output of a Pallas call (the flash forward's o and logsumexp: a
+    kernel never runs again in the transpose). A named value the
+    transpose does not read is not stacked: with the rotated q and k
+    kept, the q and k products' outputs are not."""
+    return prim.name == "pallas_call" or _named_kept(prim, *avals, **params)
 
 
 @register_op_CF("static_rnn")
@@ -113,7 +168,19 @@ def _static_rnn(ctx):
     ``steps`` times over the memories alone (StaticRNN's counted form):
     one ``jax.lax.scan`` either way, the sub-block traced once. A
     memory keeps its init's dtype (under AMP a body hands a float32
-    stream back at half width)."""
+    stream back at half width).
+
+    What the scan keeps of a pass for its transpose: the carry (scan's
+    own), the outputs of the body's matrix products at the width the
+    program holds them, the output of a norm or a rotary embedding
+    that a product or an attention site reads (`_name_kept`) and the
+    outputs of its Pallas calls; the body is under ``jax.checkpoint``
+    with that one policy (`_kept`), so everything else — the other
+    norms, casts, relayouts, elementwise ops, activations, residual
+    adds, an attention site no kernel served — is computed again in
+    the transpose from what was kept. A body without a product keeps
+    its carry alone. A program without a grad op of the loop is untouched:
+    without a transpose ``jax.checkpoint`` is the identity."""
     xs = ctx.inputs("X")                 # each [T, ...]
     mem_init = ctx.inputs("MemInit")
     step_in = ctx.attr("step_in_names")
@@ -123,6 +190,7 @@ def _static_rnn(ctx):
     blk_idx = ctx.attr("sub_block_idx")
     outer = dict(ctx.env)
     nested = nested_dynamic_wids(ctx.extra["program"], blk_idx)
+    operands = _operand_names(ctx.extra["program"].blocks[blk_idx])
 
     def body(state, x_t):
         carry, maxes = state
@@ -133,7 +201,12 @@ def _static_rnn(ctx):
             env.update(zip(step_in, x_t))
             return _trace_sub(ctx, blk_idx, env)
 
-        env, rep = _collect_reports(ctx, trace)
+        enclosing = ctx.extra.get("kept_outputs")
+        ctx.extra["kept_outputs"] = partial(_name_kept, operands)
+        try:
+            env, rep = _collect_reports(ctx, trace)
+        finally:
+            ctx.extra["kept_outputs"] = enclosing
         maxes = tuple(jnp.maximum(m, rep.get(w, _zero_steps()))
                       for w, m in zip(nested, maxes))
         new_carry = tuple(env[n].astype(c.dtype)
@@ -145,8 +218,11 @@ def _static_rnn(ctx):
     if steps is not None:
         _count_loop_site(ctx, int(steps), blk_idx)
     state0 = (tuple(mem_init), tuple(_zero_steps() for _ in nested))
-    (_, maxes), stacked = jax.lax.scan(body, state0, tuple(xs),
-                                       length=steps)
+    # prevent_cse=False: inside a scan the barrier buys nothing and
+    # costs fusions
+    (_, maxes), stacked = jax.lax.scan(
+        jax.checkpoint(body, policy=_kept, prevent_cse=False),
+        state0, tuple(xs), length=steps)
     ctx.set_outputs("Out", list(stacked))
     ctx.set_outputs("NestedSteps", list(maxes))
     _publish_report(ctx, dict(zip(nested, maxes)))

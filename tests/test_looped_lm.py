@@ -6,8 +6,10 @@ every parameter gradient, the shared arrays' one gradient against the
 sum of the per-pass gradients of untied copies, the exit distribution
 and the loss on a case small enough to do by hand, that the program
 holds ONE loop whose body does not depend on the number of passes, one
-pass against the straight-line build of the same blocks, and that the
-other two decoder configurations' programs are the parent's."""
+pass against the straight-line build of the same blocks, what the loop
+keeps of a pass for its transpose (the products' and the kernels'
+outputs) against the body traced without ``jax.checkpoint``, and that
+the other two decoder configurations' programs are the parent's."""
 import hashlib
 import json
 import os
@@ -255,16 +257,17 @@ def _scans(jaxpr, found=None):
     return found
 
 
-def _step_jaxpr(main, fetch_name, rows=2):
+def _step_jaxpr(main, fetch_name, rows=2, amp=False):
     exe = pt.Executor()
     scope = pt.global_scope()
-    step = exe._compile(main.desc, main.desc.block(0), None, [fetch_name],
-                        scope)
-    feed = {k: v for k, v in _batch(0, rows).items()
-            if main.desc.global_block.has_var(k)}
-    state = [{n: scope.get(n) for n in names}
-             for names in (step.ro_names, step.rw_names)]
-    jaxpr = jax.make_jaxpr(step.jitted)(feed, *state, np.int32(0))
+    with pt.amp.amp_guard(amp):
+        step = exe._compile(main.desc, main.desc.block(0), None,
+                            [fetch_name], scope)
+        feed = {k: v for k, v in _batch(0, rows).items()
+                if main.desc.global_block.has_var(k)}
+        state = [{n: scope.get(n) for n in names}
+                 for names in (step.ro_names, step.rw_names)]
+        jaxpr = jax.make_jaxpr(step.jitted)(feed, *state, np.int32(0))
     exe.close()
     return jaxpr.jaxpr
 
@@ -301,6 +304,98 @@ def test_the_train_step_holds_scans_of_length_t_alone():
         assert scans and {s.params["length"] for s in scans} == {passes}
         counts[passes] = len(scans)
     assert counts[1] == counts[4]       # the forward scan, its transpose
+
+
+def _unwrapped(monkeypatch):
+    """The parent's loop: the body handed to the scan as it is."""
+    monkeypatch.setattr(jax, "checkpoint", lambda body, **_kw: body)
+
+
+def _stacked(main, fetch, rows=2):
+    """[(dtype, shape a pass)] of what the AMP train step's forward scan
+    stacks: its step outputs, and what it keeps for its transpose."""
+    fwd, bwd = _scans(_step_jaxpr(main, fetch["loss"].name, rows,
+                                  amp=True))
+    assert not fwd.params["reverse"] and bwd.params["reverse"]
+    return [(str(v.aval.dtype), tuple(v.aval.shape[1:]))
+            for v in fwd.outvars[fwd.params["num_carry"]:]]
+
+
+def _nbytes(arrays):
+    return sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+               for dtype, shape in arrays)
+
+
+@pytest.mark.parametrize("path", ["composed", "flash"])
+def test_under_amp_the_loop_stacks_its_products_and_kernel_outputs(
+        monkeypatch, path):
+    """A pass leaves the transpose its carry, its products' outputs at
+    the width the program holds them (bfloat16: never the float32
+    accumulator), the output of each norm and rotary embedding that a
+    product or the attention site reads, the flash forward's o and
+    logsumexp, and nothing a norm or the rotary embedding holds inside,
+    nothing of the post-norms, a cast or an activation."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", KNOB[path])
+    rows = 2
+    main, fetch, exe, _names, _tape = _started()
+    exe.close()
+    kept = _stacked(main, fetch, rows)
+    stream = (rows, S, MODEL["hidden_size"])
+    ffn = (rows, S, MODEL["intermediate_size"])
+    # the float32 arrays of the stream's shape are the loop's memory,
+    # which keeps its init's dtype (the embedding's rows at pass 0), and
+    # the pass's first norm, which reads it and hands on its width
+    assert [a for a in kept if a[0] == "float32"
+            and a[1] in (stream, ffn)] == 2 * [("float32", stream)]
+    # a block's v, o and down products and the two norms a product
+    # reads (less the pass's first), and the pass's step output
+    assert kept.count(("bfloat16", stream)) == 5 * L - 1 + 1
+    assert kept.count(("bfloat16", ffn)) == 2 * L       # gate, up
+    # q and k as the attention site reads them (their products' outputs
+    # are then not read by the transpose, and not stacked), the kernel's o
+    heads = (rows, MODEL["num_attention_heads"], S, MODEL["head_dim"])
+    assert kept.count(("bfloat16", heads)) == 2 * L + (
+        L if path == "flash" else 0)
+    assert len(kept) == 9 * L + 2 + (2 * L if path == "flash" else 0)
+    with monkeypatch.context() as m:
+        _unwrapped(m)
+        before = _stacked(main, fetch, rows)
+    assert len([a for a in before if a[0] == "float32"
+                and a[1] in (stream, ffn)]) > 4 * L
+    assert _nbytes(kept) <= 0.45 * _nbytes(before)
+
+
+def test_the_transpose_runs_no_forward_kernel(monkeypatch):
+    """The flash forward's outputs are kept, so the loop's transpose
+    holds the backward kernel of each site and no forward kernel."""
+    from test_vjp_reuse import _kernel_calls
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", KNOB["flash"])
+    main, fetch, exe, _names, _tape = _started()
+    exe.close()
+    fwd, bwd = _scans(_step_jaxpr(main, fetch["loss"].name, amp=True))
+    assert _kernel_calls(fwd.params["jaxpr"]) == {"flash_fwd": L}
+    assert _kernel_calls(bwd.params["jaxpr"]) == {"flash_bwd_dkv_dq": L}
+
+
+@pytest.mark.parametrize("path", ["composed", "flash"])
+def test_loss_and_gradients_are_the_unwrapped_bodys_in_f32(
+        monkeypatch, path):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", KNOB[path])
+    batch = _batch(4)
+    got = {}
+    for wrapped in (True, False):
+        with monkeypatch.context() as m:
+            if not wrapped:
+                _unwrapped(m)
+            main, fetch, exe, names, _tape = _started()
+            got[wrapped] = exe.run(
+                main, feed=batch, fetch_list=[fetch["loss"]]
+                + [grad_var_name(n) for n in names])
+            exe.close()
+    (loss, *grads), (want_loss, *want) = got[True], got[False]
+    assert np.asarray(loss).tobytes() == np.asarray(want_loss).tobytes()
+    for name, g, w in zip(names, grads, want):
+        _close(g, w, 1e-6, 1e-6, name)
 
 
 def _straight_line(max_len, **kw):

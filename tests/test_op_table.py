@@ -348,7 +348,9 @@ _LOOP_MODULE = """HloModule jit_step_fn, is_scheduled=true
   %c.1 = (s32[], f32[8]{0}) parameter(0)
   %dot.9 = f32[8]{0} dot(%c.1, %c.1), metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/closed_call/mul/b0.1.7/dot_general"}
   %call.4 = f32[8]{0} custom-call(%dot.9), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/closed_call/scaled_dot_product_attention/b0.1.9/flash_bwd_dkv_dq/pallas_call"}
-  ROOT %dynamic-slice.5 = f32[8]{0} dynamic-slice(%call.4), metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/dynamic_slice"}
+  %multiply.6 = f32[8]{0} multiply(%call.4, %call.4), metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/rms_norm/b0.1.3/mul"}
+  %multiply.7 = f32[8]{0} multiply(%multiply.6, %call.4), metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/closed_call/checkpoint/rms_norm/b0.1.3/mul"}
+  ROOT %dynamic-slice.5 = f32[8]{0} dynamic-slice(%multiply.7), metadata={op_name="jit(step_fn)/__vjp__.static_rnn/b0.98/transpose(jvp())/while/body/dynamic_slice"}
 }
 
 ENTRY %main.9 (x: f32[8]) -> f32[8] {
@@ -370,6 +372,12 @@ ENTRY %main.9 (x: f32[8]) -> f32[8] {
     ("dot.9", OpRef("__vjp__.mul", "backward", (0, 1), 7)),
     ("call.4", OpRef("__vjp__.scaled_dot_product_attention", "backward",
                      (0, 1), 9)),
+    # the body under jax.checkpoint (op_names as the ouro-2p6b step's
+    # compiled module has them): a norm the transpose computes AGAIN
+    # from what the scan kept, and its backward proper, are both the
+    # norm's grad rows
+    ("multiply.6", OpRef("__vjp__.rms_norm", "backward", (0, 1), 3)),
+    ("multiply.7", OpRef("__vjp__.rms_norm", "backward", (0, 1), 3)),
     # what the transpose emits outside any body op stays on the grad op
     ("dynamic-slice.5", OpRef("__vjp__.static_rnn", "backward", (0,), 98)),
     ("while.15", OpRef("__vjp__.static_rnn", "backward", (0,), 98)),

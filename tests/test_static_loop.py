@@ -2,7 +2,10 @@
 run T times on its memories alone, against a Python loop over the same
 weights — the forward value, the stacked step outputs, the gradient of a
 parameter the body reads as closure on every pass (ONE gradient
-variable, the sum over the passes), T = 1, and what misuse raises."""
+variable, the sum over the passes), T = 1, what misuse raises, and
+what either form keeps of a pass for its transpose (its products'
+outputs; the rest of the body is computed again) against the body traced
+without ``jax.checkpoint``."""
 import jax
 import numpy as np
 import pytest
@@ -139,6 +142,116 @@ def test_the_loop_site_is_counted_with_its_passes(fresh):
             fetch_list=[loss])
     exe.close()
     assert count() == before + 1
+
+
+def test_the_loop_site_says_what_it_keeps(fresh):
+    from paddle_tpu.observability.registry import default_registry
+    main, startup, _stacked, loss = _build(5, lr=0.1)
+    exe = pt.Executor()
+    exe.run(startup)
+    exe.run(main, feed={"x": np.zeros((2, D), np.float32)},
+            fetch_list=[loss])
+    exe.close()
+    fam = default_registry().get("paddle_tpu_loop_sites_total")
+    assert fam.labelnames == ("passes", "body_ops", "keeps")
+    sub_ops = len(main.desc.blocks[1].ops)
+    (site,) = [labels for labels, _child in fam.samples()
+               if labels[0] == "5"]
+    assert site == ("5", str(sub_ops), "products_and_kernels")
+
+
+T_IN = 5
+
+
+def _stepped(product, lr=0.5):
+    """The step-input form: h <- tanh(x_t W + h U + b) with `product`,
+    h <- tanh(x_t * w + h * u) (no product in the body) without."""
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        x = layers.data("x", [T_IN, 3, D], append_batch_size=False)
+        h0 = layers.data("h0", [3, D], append_batch_size=False)
+        rnn = StaticRNN()
+        with rnn.step():
+            word = rnn.step_input(x)
+            prev = rnn.memory(init=h0)
+            if product:
+                new = layers.tanh(layers.elementwise_add(
+                    layers.fc(word, size=D, bias_attr=False),
+                    layers.fc(prev, size=D)))
+            else:
+                w, u = (layers.create_parameter(
+                    [D], "float32",
+                    default_initializer=pt.initializer.UniformInitializer(
+                        0.5, 1.5, seed=seed)) for seed in (1, 2))
+                new = layers.tanh(layers.elementwise_add(
+                    layers.elementwise_mul(word, w),
+                    layers.elementwise_mul(prev, u)))
+            rnn.update_memory(prev, new)
+            rnn.step_output(new)
+        loss = layers.mean(layers.square(rnn()))
+        pt.optimizer.SGDOptimizer(learning_rate=lr).minimize(loss)
+    return main, startup, loss
+
+
+def _run(main, startup, loss, feed):
+    """(loss, every parameter's gradient, arrays a step the forward scan
+    stacks) of one step."""
+    from paddle_tpu.core.registry import grad_var_name
+    from test_looped_lm import _scans
+    names = [p.name for p in main.all_parameters()]
+    exe = pt.Executor()
+    exe.run(startup)
+    scope = pt.global_scope()
+    step = exe._compile(main.desc, main.desc.block(0), None, [loss.name],
+                        scope)
+    state = [{n: scope.get(n) for n in ns}
+             for ns in (step.ro_names, step.rw_names)]
+    fwd, bwd = _scans(jax.make_jaxpr(step.jitted)(
+        feed, *state, np.int32(0)).jaxpr)
+    assert not fwd.params["reverse"] and bwd.params["reverse"]
+    got = exe.run(main, feed=feed,
+                  fetch_list=[loss] + [grad_var_name(n) for n in names])
+    exe.close()
+    return got[0], got[1:], len(fwd.outvars) - fwd.params["num_carry"]
+
+
+@pytest.mark.parametrize("form", ["counted", "stepped_product",
+                                  "stepped_no_product"])
+def test_loss_and_gradients_are_the_unwrapped_bodys(monkeypatch, form):
+    """Bit-equal loss, gradients to f32 rounding, and what the scan
+    stacks: the step output and the carry, the two products' outputs
+    where the body has them, nothing of tanh or the adds."""
+    from test_looped_lm import _unwrapped
+    rng = np.random.RandomState(3)
+    if form == "counted":
+        feed = {"x": rng.randn(3, D).astype(np.float32)}
+    else:
+        feed = {"x": rng.randn(T_IN, 3, D).astype(np.float32),
+                "h0": rng.randn(3, D).astype(np.float32)}
+    got = {}
+    for wrapped in (True, False):
+        pt.reset_default_programs()
+        pt.reset_global_scope()
+        with monkeypatch.context() as m:
+            if not wrapped:
+                _unwrapped(m)
+            if form == "counted":
+                main, startup, _stacked, loss = _build(4, lr=0.5)
+            else:
+                main, startup, loss = _stepped(form == "stepped_product")
+            got[wrapped] = _run(main, startup, loss, feed)
+    pt.reset_global_scope()
+    (loss, grads, kept), (want_loss, want, before) = got[True], got[False]
+    assert np.asarray(loss).tobytes() == np.asarray(want_loss).tobytes()
+    assert len(grads) == len(want) == (3 if form == "stepped_product"
+                                       else 2)
+    for g, w in zip(grads, want):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-7)
+    products = {"counted": 1, "stepped_product": 2,
+                "stepped_no_product": 0}[form]
+    # the step output and the carry a pass, beside the products' outputs
+    # (a step's input the transpose reads from the scan's own argument)
+    assert kept == 2 + products <= before
 
 
 def test_a_memory_keeps_its_dtype_across_steps(fresh):
