@@ -57,7 +57,7 @@ FULL = dict(vocab=32000, n_layer=6, n_head=8, d_model=512, d_inner=2048,
             flash_odd_seq=1100, mesh_batch=8, mesh_steps=3,
             # joyai-llm-flash.train-ep32's attention site: B,H,S,d,d_v
             latent_site=(1, 32, 4096, 192, 128))
-TINY = dict(vocab=96, n_layer=1, n_head=2, d_model=32, d_inner=64,
+TINY = dict(vocab=96, n_layer=1, n_head=4, d_model=256, d_inner=512,
             batch=2, seq=16, steps=4, stream_steps=2,
             prompt_buckets=[8, 16], cache_buckets=[16, 32],
             prompt_lens=[3, 5, 9, 12], deep_prompt=14,
@@ -233,8 +233,8 @@ def phase_kernels(sm, cfg, interpret):
     fwd_sites = dict(flash_fwd_sites() - fwd_before)
     bwd_sites = dict(flash_bwd_sites() - bwd_before)
     # `both` traces the forward alone and again under jax.grad
-    sm.check(fwd_sites == {"resident/0/1": 2 * len(shapes)}
-             and bwd_sites == {"resident/0/1": len(shapes)},
+    sm.check(fwd_sites == {"resident/0/1/1": 2 * len(shapes)}
+             and bwd_sites == {"resident/0/1/1": len(shapes)},
              "kernel flash_attention: each forward and each backward kept "
              "its head's K and V resident (one grid step a q-block; dQ "
              "finished in the one backward kernel)",
@@ -499,15 +499,21 @@ def phase_train(sm, cfg, device, workdir):
     # only the decoder's own is causal, and no site is handed a mask
     # with a query axis (paddle_tpu_sdpa_sites_total{path,mask,causal})
     n_sites = 3 * cfg["n_layer"]
-    sm.check(sites == {"flash/key_row/0/0/1": n_sites - cfg["n_layer"],
-                       "flash/key_row/1/0/1": cfg["n_layer"]},
+    sm.check(sites == {
+        "flash/key_row/0/0/1/bshd": n_sites - cfg["n_layer"],
+        "flash/key_row/1/0/1/bshd": cfg["n_layer"]},
              "train: every attention site of the step took the flash "
              "kernels with a key-row mask, the decoder's own with the "
-             "causal flag, none with a dense mask", sites=sites)
-    sm.check(fwd_sites == {"resident/0/1": n_sites}
-             and bwd_sites == {"resident/0/1": n_sites},
+             "causal flag, none with a dense mask, all on the arrays as "
+             "the projections left them (layout bshd)", sites=sites)
+    from paddle_tpu.ops.pallas.flash_attention import _heads_a_block
+    d_key = cfg["d_model"] // cfg["n_head"]
+    heads = _heads_a_block(d_key, d_key)    # that fill a 128-lane word
+    sm.check(fwd_sites == {f"resident/0/1/{heads}": n_sites}
+             and bwd_sites == {f"resident/0/1/{heads}": n_sites},
              "train: every site's forward and backward is one kernel with "
-             "its head's K and V resident, none walks them in segments",
+             "its head block's K and V resident, none walks them in "
+             "segments, none was transposed back to head-major (relaid)",
              flash_fwd_sites=fwd_sites, flash_bwd_sites=bwd_sites)
     # a JAX or libtpu that empties the HLO metadata fails here, on the
     # chip, and not silently in a per-layer metric

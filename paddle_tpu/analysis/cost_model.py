@@ -231,12 +231,12 @@ class ProgramCost:
 # ---------------------------------------------------------------------------
 # FLOP rules
 # ---------------------------------------------------------------------------
-def _attended_rows(op: ir.OpDesc, kv: _VarInfo) -> int:
-    """Key rows an attention site reads of ``kv``: all of them, or for
-    a cached-decode site (KvLen), which is handed the whole cache, at
-    most attr kv_bound — the static worst case, the live lengths being
-    run-time data."""
-    return min(kv.shape[-2], int(op.attrs.get("kv_bound", kv.shape[-2])))
+def _attended_rows(op: ir.OpDesc, rows: int) -> int:
+    """Key rows an attention site reads of the ``rows`` its K and V
+    hold: all of them, or for a cached-decode site (KvLen), which is
+    handed the whole cache, at most attr kv_bound — the static worst
+    case, the live lengths being run-time data."""
+    return min(rows, int(op.attrs.get("kv_bound", rows)))
 
 
 def _flops_for(op: ir.OpDesc,
@@ -355,12 +355,17 @@ def _flops_for(op: ir.OpDesc,
         q, k, v = first("Q"), first("K"), first("V")
         if q is None or k is None or len(q.shape) < 3:
             return None, False, None
-        lead = _prod(q.shape[:-2])
-        sq, d = q.shape[-2], q.shape[-1]
+        # attr layout says where the rows are: [.., h, S, d] head-major,
+        # [b, S, h, d] sequence-major ("bshd": never a cached decode)
+        d = q.shape[-1]
+        if op.attrs.get("layout", "bhsd") == "bshd" and len(q.shape) == 4:
+            lead, sq, sk = q.shape[0] * q.shape[2], q.shape[1], k.shape[1]
+        else:
+            lead, sq, sk = _prod(q.shape[:-2]), q.shape[-2], k.shape[-2]
         d_v = v.shape[-1] if v is not None and v.shape else d
-        pairs = sq * _attended_rows(op, k)
+        pairs = sq * _attended_rows(op, sk)
         window = int(op.attrs.get("window", 0) or 0)
-        if 0 < window < sq == k.shape[-2]:
+        if 0 < window < sq == sk:
             # a windowed site at its band (query i sees keys
             # i - window < j <= i), not at the score matrix
             pairs = window * (window + 1) // 2 + (sq - window) * window
@@ -494,7 +499,8 @@ def _bytes_override(op: ir.OpDesc,
             v = lookup(op.input(slot)[0])
             if v is None:
                 return None
-            total += v.bytes // v.shape[-2] * _attended_rows(op, v)
+            total += v.bytes // v.shape[-2] * _attended_rows(
+                op, v.shape[-2])
         for name in (op.input("Q") + op.input("KvLen")
                      + op.output("Out")):
             v = lookup(name)

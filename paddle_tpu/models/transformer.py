@@ -38,15 +38,22 @@ def multi_head_attention(q_in, k_in, v_in, d_model, n_head, mask=None,
                   bias_attr=False, name="tp_col_qkv")
 
     def split_heads(x):
-        # [b, t, d_model] -> [b, n_head, t, d_key]
-        reshaped = layers.reshape(x, [0, 0, n_head, d_key])
-        return layers.transpose(reshaped, [0, 2, 1, 3])
+        # [b, t, d_model] -> [b, t, n_head, d_key]: a view, no data moves
+        return layers.reshape(x, [0, 0, n_head, d_key])
 
-    qh, kh, vh = split_heads(q), split_heads(k), split_heads(v)
-    # context parallelism over the named mesh axis (ring/ulysses)
-    cp = {"seq_axis": seq_axis, "seq_impl": seq_impl} if seq_axis else {}
-    ctx_v = _sdpa_op(qh, kh, vh, mask, causal, **cp)
-    merged = layers.transpose(ctx_v, [0, 2, 1, 3])
+    if seq_axis:
+        # context parallelism over the named mesh axis (ring/ulysses)
+        # shards head-major arrays: [b, n_head, t, d_key] through the op
+        qh, kh, vh = (layers.transpose(split_heads(x), [0, 2, 1, 3])
+                      for x in (q, k, v))
+        ctx_v = _sdpa_op(qh, kh, vh, mask, causal, seq_axis=seq_axis,
+                         seq_impl=seq_impl)
+        merged = layers.transpose(ctx_v, [0, 2, 1, 3])
+    else:
+        # the op reads q, k, v and writes its output where the
+        # projections left them (attr layout "bshd"): no transpose op
+        merged = _sdpa_op(split_heads(q), split_heads(k), split_heads(v),
+                          mask, causal, layout="bshd")
     merged = layers.reshape(merged, [0, 0, d_model])
     out = layers.fc(merged, size=d_model, num_flatten_dims=2,
                     bias_attr=False, name="tp_row_proj")

@@ -689,13 +689,15 @@ def _rotary_embedding(ctx):
 
 
 def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,
-                         head_axis):
+                         head_axis, head_dim=1):
     """``attend(q, k, v, mask)`` run per shard of a mesh. GSPMD cannot
     partition a Mosaic kernel (the TPU lowering raises "Mosaic kernels
     cannot be automatically partitioned"), so under a ParallelExecutor
     mesh the flash kernel runs inside shard_map over the batch and head
     dims — attention is independent across both, so no collective is
-    added. A dim its axis does not divide stays replicated."""
+    added. A dim its axis does not divide stays replicated. ``head_dim``
+    is where q, k and v hold their heads: 1 head-major, 2
+    sequence-major (the mask keeps its heads at dim 1 either way)."""
     from jax.sharding import PartitionSpec as P
 
     def axis_for(name, dim):
@@ -703,8 +705,9 @@ def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,
         return name if ok else None
 
     b_ax = axis_for(batch_axis, q.shape[0])
-    h_ax = axis_for(head_axis, k.shape[1])     # grouped: the key heads
-    spec = P(b_ax, h_ax, None, None)
+    h_ax = axis_for(head_axis, k.shape[head_dim])  # grouped: the key heads
+    spec = P(b_ax, h_ax, None, None) if head_dim == 1 else \
+        P(b_ax, None, h_ax, None)
     args, specs = (q, k, v), (spec,) * 3
     if mask is not None:
         if mask.ndim == 2:        # [Sq|1, Sk|1], as flash_attention reads it
@@ -713,14 +716,15 @@ def _per_shard_attention(attend, mesh, q, k, v, mask, batch_axis,
             mask = mask[:, None]
         args += (mask,)
         specs += (P(b_ax if mask.shape[0] == q.shape[0] else None,
-                    h_ax if mask.shape[1] == q.shape[1] else None,
+                    h_ax if mask.shape[1] == q.shape[head_dim] else None,
                     None, None),)
     return jax.shard_map(
         lambda q, k, v, mask=None: attend(q, k, v, mask), mesh=mesh,
         in_specs=specs, out_specs=spec, check_vma=False)(*args)
 
 
-def _count_sdpa_site(ctx, path, mask, causal, window=None, group=1):
+def _count_sdpa_site(ctx, path, mask, causal, window=None, group=1,
+                     layout="bhsd"):
     """One count an attention site traced into a step program, in the
     idiom of ops/cache_ops.py _count_append_site (the build-time shape
     inference carries no program and is no site). A grad site that
@@ -743,11 +747,15 @@ def _count_sdpa_site(ctx, path, mask, causal, window=None, group=1):
         "of and path composed slices to the bound and masks), by the "
         "causal attr (1 lets the kernels skip the tiles above the "
         "diagonal), by the window attr (0: none; W: query i sees keys "
-        "i - W < j <= i and the kernels walk that band alone) and by "
-        "the query heads that read one key head.",
-        ("path", "mask", "causal", "window", "group")).labels(
+        "i - W < j <= i and the kernels walk that band alone), by "
+        "the query heads that read one key head and by the layout attr "
+        "(bhsd: Q, K, V and Out head-major [b,h,S,d]; bshd: sequence-"
+        "major [b,S,h,d], as the projections write and read them — "
+        "path flash then reads and writes them in place, path composed "
+        "transposes inside the rule).",
+        ("path", "mask", "causal", "window", "group", "layout")).labels(
             path=path, mask=mask, causal=str(int(causal)),
-            window=str(window or 0), group=str(group)).inc()
+            window=str(window or 0), group=str(group), layout=layout).inc()
 
 
 def _decode_kernel_lane_axis(ctx, q, cache, bound):
@@ -779,15 +787,30 @@ def _sdpa(ctx):
     shapes use the naive composition, which XLA fuses fine. Mask is a
     constant (no_grad_slots) on both paths; a *trainable* additive bias
     should call ops.pallas.flash_attention(bias_grad=True) directly.
+
+    Attr ``layout``: "bhsd" (default) — Q [b, h, Sq, d], K [b, hk, Sk,
+    d], V [b, hk, Sk, dv], Out [b, h, Sq, dv]; "bshd" — Q [b, Sq, h, d],
+    K [b, Sk, hk, d], V [b, Sk, hk, dv], Out [b, Sq, h, dv], what a
+    reshape of a projection's [b, S, h*d] gives with no transpose. The
+    flash path reads and writes "bshd" arrays where they lie; the
+    composition transposes inside this rule to the arrays a "bhsd" site
+    is handed. Mask is [b|1, h|1, Sq|1, Sk] in both. KvLen (the caches
+    are head-major) and an active seq_axis refuse "bshd".
     """
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     mask = ctx.input("Mask")
     causal = bool(ctx.attr("causal", False))
+    layout = ctx.attr("layout", "bhsd") or "bhsd"
+    if layout not in ("bhsd", "bshd"):
+        raise ValueError("scaled_dot_product_attention: layout "
+                         f"{layout!r} is neither 'bhsd' nor 'bshd'")
+    # where Q, K and V hold their heads and their rows
+    head_dim, seq_dim = (2, 1) if layout == "bshd" else (1, 2)
     # attr window W (a causal site's): query i sees keys i - W < j <= i.
     # K and V may come at fewer heads than Q (grouped-query attention:
     # query head h reads key head h // group).
     window = int(ctx.attr("window", 0) or 0) or None
-    group = q.shape[1] // k.shape[1] if q.ndim == 4 else 1
+    group = q.shape[head_dim] // k.shape[head_dim] if q.ndim == 4 else 1
     if window is not None and not causal:
         raise ValueError("scaled_dot_product_attention: a window belongs "
                          "to a causal site")
@@ -797,6 +820,10 @@ def _sdpa(ctx):
     # attr kv_bound (static) is the most any slot holds this step.
     kv_len = ctx.input("KvLen")
     if kv_len is not None:
+        if layout != "bhsd":
+            raise ValueError("scaled_dot_product_attention: KvLen reads "
+                             "KV caches, which are head-major [slots, h, "
+                             "max_seq, d]: layout 'bshd' does not apply")
         if mask is not None or causal or group != 1:
             raise ValueError("scaled_dot_product_attention: KvLen "
                              "stands in for the mask and for causality, "
@@ -820,6 +847,10 @@ def _sdpa(ctx):
     # sequence dim is sharded over (parallel/context_parallel.py).
     seq_axis = ctx.attr("seq_axis", None)
     mesh = ctx.extra.get("mesh") if ctx.extra else None
+    if seq_axis and layout != "bhsd":
+        raise ValueError("scaled_dot_product_attention: sequence-parallel "
+                         "attention (seq_axis) shards head-major arrays: "
+                         "layout 'bshd' does not apply")
     if seq_axis and mesh is not None and seq_axis in mesh.axis_names:
         if window is not None or group != 1:
             raise ValueError("sequence-parallel attention has neither a "
@@ -863,27 +894,33 @@ def _sdpa(ctx):
         forced = interp is None
         min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "512"))
         use_flash = (enabled and q.ndim == 4
-                     and (forced or (q.shape[2] >= min_seq
-                                     and k.shape[2] >= min_seq)))
+                     and (forced or (q.shape[seq_dim] >= min_seq
+                                     and k.shape[seq_dim] >= min_seq)))
+    # the build's shape inference (its context carries no program) asks
+    # for Out's shape alone: the composition says it without tracing a
+    # kernel body (15-40 ms a site, a second a program of 18)
+    use_flash = bool(use_flash) and "program" in ctx.extra
     mask_kind = "kv_len" if kv_len is not None else (
         "none" if mask is None else (
             "dense" if mask.ndim >= 2 and mask.shape[-2] > 1
             else "key_row"))
     _count_sdpa_site(ctx, "flash" if use_flash else "composed", mask_kind,
-                     causal, window, group)
+                     causal, window, group, layout)
     if use_flash:
         from .pallas import flash_attention
         attend = functools.partial(flash_attention, causal=causal,
-                                   window=window)
+                                   window=window, layout=layout)
         if mesh is None:
             out = attend(q, k, v, mask)
         else:
             out = _per_shard_attention(
                 attend, mesh, q, k, v, mask,
                 ctx.attr("batch_axis", "data"),
-                ctx.attr("head_axis", "model"))
+                ctx.attr("head_axis", "model"), head_dim)
         ctx.set_output("Out", out)
         return
+    if layout == "bshd":      # the arrays a head-major site is handed
+        q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     scale = 1.0 / np.sqrt(q.shape[-1])
     if group != 1:
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
@@ -899,7 +936,9 @@ def _sdpa(ctx):
             seen = seen & (qpos - kpos < window)
         scores = jnp.where(seen, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    ctx.set_output("Out", jnp.einsum("...qk,...kd->...qd", probs, v))
+    out = jnp.einsum("...qk,...kd->...qd", probs, v)
+    ctx.set_output("Out", jnp.swapaxes(out, 1, 2) if layout == "bshd"
+                   else out)
 
 
 # -- misc -------------------------------------------------------------------

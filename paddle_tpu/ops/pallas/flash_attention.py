@@ -6,12 +6,28 @@ matmul/softmax in python/paddle/fluid/nets.py:312; its hand-fused CUDA
 analogue for recurrent hot loops is paddle/cuda/src/hl_cuda_lstm.cu —
 Pallas is the TPU-native equivalent of that hand-fusion layer).
 
-Layout: q [B, H, Sq, D], k [B, Hk, Sk, D], v [B, Hk, Sk, Dv] (Dv may
-differ from D: latent attention keeps 192-wide keys beside 128-wide
-values; the scale stays Q's. Hk divides H: grouped-query attention, query
-head h reads key head h // (H / Hk) through the K and V BlockSpecs, and
-no H-head copy of K or V exists), out [B, H, Sq, Dv], optional additive
-bias/mask broadcastable as [B, {1|H}, Sq, Sk].
+Layout "bhsd" (head-major): q [B, H, Sq, D], k [B, Hk, Sk, D], v [B, Hk,
+Sk, Dv] (Dv may differ from D: latent attention keeps 192-wide keys
+beside 128-wide values; the scale stays Q's. Hk divides H: grouped-query
+attention, query head h reads key head h // (H / Hk) through the K and V
+BlockSpecs, and no H-head copy of K or V exists), out [B, H, Sq, Dv],
+optional additive bias/mask broadcastable as [B, {1|H}, Sq, Sk].
+
+Layout "bshd" (sequence-major): q [B, Sq, H, D], k [B, Sk, Hk, D], v [B,
+Sk, Hk, Dv], out [B, Sq, H, Dv] — the reshape of what a projection
+writes and reads, [B, S, H * D], in which head h of a row lies in lanes
+h * D .. (h + 1) * D. The SAME two kernels read that view through other
+BlockSpecs, `(1, rows, 128-lane block)` at (batch, row-block, block of
+heads): a block is the fewest heads that fill whole 128-lane words (two
+at D = 64, four at 32, one where D and Dv are multiples of 128), the
+grid's head axis counts blocks, and a grid step runs the tile
+recurrence once a head of its block (a static loop in _fwd_kernel, a
+fori_loop in _bwd_kernel: their docstrings say why). No array is
+transposed on either side of a site, a resident K / V row is 128 dense
+lanes, o's stores are unmasked. What the blocks cannot hold
+(_seq_major_serves) the entry transposes and runs head-major, counted
+path="relaid". A head-major call traces exactly the kernels it traced
+before the second layout existed.
 
 Both passes have one shape. A head's K and V stay resident in VMEM, a
 grid step is one q-block, and an in-kernel loop walks the k-blocks: up
@@ -226,18 +242,48 @@ def _fwd_vmem_bytes(chunks, block_q, block_k, d, dv, itemsize, bias_lanes):
             + 4 * 4 * block_q * block_k)
 
 
+def _head_lanes(x, i, heads, other=0):
+    """`x` [rows, heads * width] with every lane but head i's zeroed (or
+    taken from `other`): a product that contracts over the block's lanes
+    then yields head i's alone, and one whose output keeps the lanes
+    leaves the other heads' zero. A block of one head is handed back as
+    it is; `i` may be a loop's index (the backward's)."""
+    if heads == 1:
+        return x
+    width = x.shape[-1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where((lane >= i * width) & (lane < (i + 1) * width), x,
+                     jnp.asarray(other, x.dtype))
+
+
 def _fwd_kernel(*refs, sm_scale, scale_q, causal, window, block_q, block_k,
-                kv_len, chunks, nseg, bias_kind):
+                kv_len, chunks, nseg, bias_kind, heads, at):
     """One q-block against the `chunks` k-blocks of one resident
     k-segment, scores held keys-down ([block_k, block_q]): the running
     max and sum reduce down sublanes and travel between tiles as
     [1, block_q] rows, the accumulator is [dv, block_q] and is turned
     once a q-block. Across the segments of a head too long to stay
-    resident the rows and the accumulator wait in scratch."""
+    resident the rows and the accumulator wait in scratch.
+
+    `at` leads a block's rows and lanes: (0, 0) on a head-major array,
+    (0,) on a sequence-major one, whose block holds `heads` heads side
+    by side in its lanes. The recurrence then runs once a head: the
+    scores from q with the other heads' lanes zeroed (the contraction
+    runs over the block's whole lanes and k is read as it lies), the
+    values by a static lane slice of v (under a head's p the block's
+    whole values would stream twice the rows: v5e, ms a site of 64 heads
+    x 2048 x 64, 1.07 against 0.91 not causal, 0.82 against 0.71
+    causal; k sliced too reads the same as zeroed q, 0.89 and 0.72),
+    each head's statistics in its own row of the scratch and its
+    accumulator in its own dv rows of [heads * dv, block_q], so that ONE
+    turn a q-block writes o's dense lanes. The heads are a static loop:
+    a lane slice needs a head known at trace time, and a branch a head
+    inside a tile costs what the whole values cost (1.05 and 0.85 ms)."""
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if bias_kind is not None else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[-5:]
     iq, ks = pl.program_id(2), pl.program_id(3)
+    dv = v_ref.shape[-1] // heads
 
     @pl.when(ks == 0)
     def _init():
@@ -245,9 +291,9 @@ def _fwd_kernel(*refs, sm_scale, scale_q, causal, window, block_q, block_k,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0]                                          # [bq, d]
+    block = q_ref[at]                                        # [bq, d]
     if scale_q:       # a power of two: the scaled scores bit for bit
-        q = q * sm_scale
+        block = block * sm_scale
     first = ks * chunks if nseg > 1 else 0
     # k-blocks past the last key, or wholly above the causal diagonal:
     # nothing to do
@@ -257,99 +303,230 @@ def _fwd_kernel(*refs, sm_scale, scale_q, causal, window, block_q, block_k,
         stop = jnp.minimum(
             stop, (iq * block_q + block_q - 1) // block_k + 1 - first)
 
-    def _tile(c, carry, diagonal, lower=False):
-        m, l = carry                                         # [1, bq]
-        rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
-        k, v = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
-        s = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bk, bq]
-        if not scale_q:
-            s = s * sm_scale
-        if bias_kind == "key":      # one value a key, on every lane
-            s = s + bias_ref[0, 0, rows, :][:, :1].astype(jnp.float32)
-        elif bias_kind == "score":
-            s = s + bias_ref[0, 0, rows, :].astype(jnp.float32)
-        if kv_len % block_k or diagonal:
-            kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-        if kv_len % block_k:        # mask seq padding
-            s = jnp.where(kpos < kv_len, s, NEG_INF)
-        if diagonal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = _visible(s, qpos, kpos, window if lower else None)
-        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)                               # [bk, bq]
-        l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [dv, bq]
-        return m_new, l
+    def _head(i):
+        # this head's row of the statistics; its lanes of v, which are
+        # its rows of the accumulator
+        row = slice(None) if heads == 1 else slice(i, i + 1)
+        own = slice(None) if heads == 1 else slice(i * dv, (i + 1) * dv)
+        q = _head_lanes(block, i, heads)
 
-    carry = m_scr[:], l_scr[:]
-    if window is not None:
-        m, l = _walk_band(_tile, carry, iq, first, stop, block_q, block_k,
-                          window)
-    else:
-        # the causal select only in the tiles the diagonal crosses: the
-        # whole tiles below it first
-        whole = 0
-        if causal:
-            whole = jnp.clip((iq * block_q + 1) // block_k - first, 0,
-                             stop)
-            carry = jax.lax.fori_loop(
-                0, whole, functools.partial(_tile, diagonal=False), carry)
-        # a short walk of a known length is unrolled, so that the next
-        # tile's products overlap this tile's softmax (v5e, a site of 64
-        # heads x 2048 x 64 not causal: 1.10 against 1.16 ms)
-        m, l = jax.lax.fori_loop(
-            whole, stop, functools.partial(_tile, diagonal=causal), carry,
-            unroll=True if isinstance(stop, int) and stop <= 4 else None)
-    m_scr[:], l_scr[:] = m, l
+        def _tile(c, carry, diagonal, lower=False):
+            m, l = carry                                     # [1, bq]
+            rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+            k, v = k_ref[(*at, rows, slice(None))], v_ref[(*at, rows, own)]
+            s = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [bk, bq]
+            if not scale_q:
+                s = s * sm_scale
+            if bias_kind == "key":      # one value a key, on every lane
+                s = s + bias_ref[0, 0, rows, :][:, :1].astype(jnp.float32)
+            elif bias_kind == "score":
+                s = s + bias_ref[0, 0, rows, :].astype(jnp.float32)
+            if kv_len % block_k or diagonal:
+                kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0)
+            if kv_len % block_k:        # mask seq padding
+                s = jnp.where(kpos < kv_len, s, NEG_INF)
+            if diagonal:
+                qpos = iq * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                s = _visible(s, qpos, kpos, window if lower else None)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)                           # [bk, bq]
+            l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
+            acc_scr[own] = acc_scr[own] * alpha + jax.lax.dot_general(
+                v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [dv, bq]
+            return m_new, l
 
-    @pl.when(ks == nseg - 1)
-    def _fin():
-        safe = jnp.where(l == 0.0, 1.0, l)    # fully-masked rows -> 0 out
-        o_ref[0, 0] = (acc_scr[:] / safe).T.astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = m + jnp.log(jnp.maximum(l, 1e-37))
+        carry = m_scr[row], l_scr[row]
+        if window is not None:
+            m, l = _walk_band(_tile, carry, iq, first, stop, block_q,
+                              block_k, window)
+        else:
+            # the causal select only in the tiles the diagonal crosses:
+            # the whole tiles below it first
+            whole = 0
+            if causal:
+                whole = jnp.clip((iq * block_q + 1) // block_k - first, 0,
+                                 stop)
+                carry = jax.lax.fori_loop(
+                    0, whole, functools.partial(_tile, diagonal=False),
+                    carry)
+            # a short walk of a known length is unrolled, so that the
+            # next tile's products overlap this tile's softmax (v5e, a
+            # site of 64 heads x 2048 x 64 not causal: 1.10 against 1.16
+            # ms)
+            m, l = jax.lax.fori_loop(
+                whole, stop, functools.partial(_tile, diagonal=causal),
+                carry,
+                unroll=True if isinstance(stop, int) and stop <= 4
+                else None)
+        m_scr[row], l_scr[row] = m, l
+
+        @pl.when(ks == nseg - 1)
+        def _fin():
+            safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0
+            if heads == 1:
+                o_ref[at] = (acc_scr[:] / safe).T.astype(o_ref.dtype)
+            else:
+                acc_scr[own] = acc_scr[own] / safe
+            lse_ref[0, i, 0] = m + jnp.log(jnp.maximum(l, 1e-37))
+
+    for i in range(heads):      # static: see the docstring
+        _head(i)
+    if heads > 1:
+        @pl.when(ks == nseg - 1)
+        def _turn():
+            o_ref[at] = acc_scr[:].T.astype(o_ref.dtype)
+
+
+class _Site:
+    """A call's arrays as its layout holds them, read once by both
+    passes. Head-major ("bhsd", and "relaid": a sequence-major call the
+    entry transposed) q is [B, H, Sq, D] and a block one head's rows,
+    `(1, 1, rows, width)` at (batch, head, row-block, 0). Sequence-major
+    ("bshd") q is [B, Sq, H, D] read as its free view [B, Sq, H * D] and
+    a block the rows of `heads` heads side by side in whole 128-lane
+    words, `(1, rows, heads * width)` at (batch, row-block, head-block).
+    `h` and `hk` count head BLOCKS, the kernels' grid axis."""
+
+    def __init__(self, layout, q, k, v):
+        self.seq_major = layout == "bshd"
+        if self.seq_major:
+            self.b, self.sq, h, self.d = q.shape
+            self.sk, hk, self.dv = k.shape[1], k.shape[2], v.shape[3]
+            self.heads = _heads_a_block(self.d, self.dv)
+        else:
+            self.b, h, self.sq, self.d = q.shape
+            hk, self.sk, self.dv = k.shape[1], k.shape[2], v.shape[3]
+            self.heads = 1
+        self.group = h // hk
+        self.h, self.hk = h // self.heads, hk // self.heads
+        # what leads a block's rows and lanes inside the kernels
+        self.at = (0,) if self.seq_major else (0, 0)
+        self.relaid = layout == "relaid"
+
+    def rows(self, x, pad):
+        """`x` as the kernels read it, its rows padded by `pad`."""
+        if self.seq_major:
+            return jnp.pad(x.reshape(*x.shape[:2], -1),
+                           ((0, 0), (0, pad), (0, 0)))
+        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+
+    def shape(self, blocks, rows, width):
+        """Of an output of `blocks` head blocks a batch."""
+        if self.seq_major:
+            return self.b, rows, blocks * self.heads * width
+        return self.b, blocks, rows, width
+
+    def spec(self, rows, width, where, lead=None):
+        """The BlockSpec of `rows` rows of one head block; `where` maps
+        the grid's indices to (batch, head block, row-block), `lead` to
+        the index of a leading axis the array has besides (dQ's partial
+        a k-segment)."""
+        if self.seq_major:
+            block = (1, rows, self.heads * width)
+
+            def index(*grid):
+                b, h, r = where(*grid)
+                return b, r, h
+        else:
+            block = (1, 1, rows, width)
+
+            def index(*grid):
+                return (*where(*grid), 0)
+        if lead is None:
+            return pl.BlockSpec(block, index)
+        return pl.BlockSpec((1,) + block,
+                            lambda *grid: (lead(*grid), *index(*grid)))
+
+    def stat_spec(self, block_q, where):
+        """Of the logsumexp and delta rows [B, H, nq, 1, block_q]: a
+        block's heads, one row each."""
+        return pl.BlockSpec((1, self.heads, 1, 1, block_q),
+                            lambda *grid: (*where(*grid), 0, 0))
+
+    def unview(self, x, rows, width):
+        """An output's first `rows` rows, in the layout's 4-D form."""
+        if self.seq_major:
+            return x[:, :rows].reshape(self.b, rows, -1, width)
+        return x[:, :, :rows]
+
+
+def _heads_a_block(d, dv):
+    """Heads a sequence-major block holds: the fewest that fill whole
+    128-lane words — 128 // d where the one width of keys and values
+    divides 128, one where both are multiples of 128, None where the
+    lanes cannot be cut that way."""
+    if d % 128 == 0 and dv % 128 == 0:
+        return 1
+    if d == dv and 128 % d == 0:
+        return 128 // d
+    return None
+
+
+def _fwd_plan(site, bias, bias_grad, causal, block_q, block_k, interpret,
+              itemsize):
+    """A forward call's tiles and segments, from its shapes alone:
+    (block_q, block_k, q-blocks, k-segments, k-blocks a segment, the
+    bytes it keeps in VMEM)."""
+    block_q, block_k = _blocks(
+        site.sq, site.sk, *_caps(bias, bias_grad, causal, block_q, block_k),
+        interpret)
+    nq, nk = -(-site.sq // block_q), -(-site.sk // block_k)
+
+    def vmem_bytes(chunks):
+        return _fwd_vmem_bytes(chunks, block_q, block_k,
+                               site.heads * site.d, site.heads * site.dv,
+                               itemsize, _bias_lanes(bias, block_q))
+
+    nseg, chunks = _segments(nk, vmem_bytes)
+    return block_q, block_k, nq, nseg, chunks, vmem_bytes(chunks)
 
 
 def _fwd(q, k, v, bias, sm_scale, causal, window, block_q, block_k,
-         interpret, bias_grad):
-    """o [B, H, Sq, Dv] and the logsumexp [B, H, Sq] f32."""
-    b, h, sq, d = q.shape
-    sk, dv = k.shape[2], v.shape[3]
-    group = h // k.shape[1]
-    block_q, block_k = _blocks(
-        sq, sk, *_caps(bias, bias_grad, causal, block_q, block_k), interpret)
-    nq, nk = -(-sq // block_q), -(-sk // block_k)
+         interpret, bias_grad, layout="bhsd"):
+    """o as q's layout holds it and the logsumexp [B, H, Sq] f32. Counts
+    the site, then _fwd_call. A sequence-major call's ops — the kernel's
+    body above all, 40-90 ms of Python a site, and a two-head body holds
+    every op twice — are traced ONCE a signature (_fwd_call_once) and
+    inlined wherever a site of that signature is traced again (18 sites
+    of two signatures in transformer-base's step). A head-major call is
+    traced at every site as it always was: a shared trace shares its
+    lowered private functions too, jax numbers a module's functions in
+    the order it first lowers them, and every head-major program's text
+    would change by those numbers."""
+    site = _Site(layout, q, k, v)
+    plan = _fwd_plan(site, bias, bias_grad, causal, block_q, block_k,
+                     interpret, q.dtype.itemsize)
+    _count_site("fwd", plan[3], window, site)
+    call = _fwd_call_once if site.seq_major else _fwd_call
+    return call(q, k, v, bias, sm_scale, causal, window, interpret, layout,
+                plan)
 
-    def vmem_bytes(chunks):
-        return _fwd_vmem_bytes(chunks, block_q, block_k, d, dv,
-                               q.dtype.itemsize,
-                               _bias_lanes(bias, block_q))
 
-    nseg, chunks = _segments(nk, vmem_bytes)
+def _fwd_call(q, k, v, bias, sm_scale, causal, window, interpret, layout,
+              plan):
+    site = _Site(layout, q, k, v)
+    b, h, sq, sk, d, dv = site.b, site.h, site.sq, site.sk, site.d, site.dv
+    group, heads = site.group, site.heads
+    block_q, block_k, nq, nseg, chunks, vmem = plan
     seg = chunks * block_k
     sq_p, sk_p = nq * block_q, nseg * seg
-    _count_site("fwd", "resident" if nseg == 1 else "partial", window,
-                group)
 
-    pad_k = ((0, 0), (0, 0), (0, sk_p - sk), (0, 0))
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
-    kp, vp = jnp.pad(k, pad_k), jnp.pad(v, pad_k)
+    qp = site.rows(q, sq_p - sq)
+    kp, vp = site.rows(k, sk_p - sk), site.rows(v, sk_p - sk)
 
     def qspec(width):
-        return pl.BlockSpec((1, 1, block_q, width),
-                            lambda b, h, iq, ks: (b, h, iq, 0))
+        return site.spec(block_q, width, lambda b, h, iq, ks: (b, h, iq))
 
     def kspec(width):        # a group's query heads read one key head
-        return pl.BlockSpec(
-            (1, 1, seg, width),
-            lambda b, h, iq, ks: (b, h if group == 1 else h // group,
-                                  ks, 0))
+        return site.spec(
+            seg, width,
+            lambda b, h, iq, ks: (b, h if group == 1 else h // group, ks))
 
     in_specs = [qspec(d), kspec(d), kspec(dv)]
     args = [qp, kp, vp]
@@ -365,25 +542,31 @@ def _fwd(q, k, v, bias, sm_scale, causal, window, block_q, block_k,
             _fwd_kernel, sm_scale=sm_scale,
             scale_q=math.frexp(sm_scale)[0] == 0.5, causal=causal,
             window=window, block_q=block_q, block_k=block_k, kv_len=sk,
-            chunks=chunks, nseg=nseg, bias_kind=_bias_kind(bias)),
+            chunks=chunks, nseg=nseg, bias_kind=_bias_kind(bias),
+            heads=heads, at=site.at),
         name="flash_fwd" + _window_suffix(window),
         grid=(b, h, nq, nseg),
         in_specs=in_specs,
         out_specs=[qspec(dv),
-                   pl.BlockSpec((1, 1, 1, 1, block_q),
-                                lambda b, h, iq, ks: (b, h, iq, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sq_p, dv), q.dtype),
-                   jax.ShapeDtypeStruct((b, h, nq, 1, block_q),
+                   site.stat_spec(block_q,
+                                  lambda b, h, iq, ks: (b, h, iq))],
+        out_shape=[jax.ShapeDtypeStruct(site.shape(h, sq_p, dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, h * heads, nq, 1, block_q),
                                         jnp.float32)],
-        scratch_shapes=[_scratch((1, block_q), jnp.float32),
-                        _scratch((1, block_q), jnp.float32),
-                        _scratch((dv, block_q), jnp.float32)],
+        scratch_shapes=[_scratch((heads, block_q), jnp.float32),
+                        _scratch((heads, block_q), jnp.float32),
+                        _scratch((heads * dv, block_q), jnp.float32)],
         compiler_params=_compiler_params(
             ("parallel",) * 3 + ("arbitrary",),
-            vmem_limit_bytes=max(32 << 20, 5 * vmem_bytes(chunks) // 4)),
+            vmem_limit_bytes=max(32 << 20, 5 * vmem // 4)),
         interpret=interpret,
     )(*args)
-    return o[:, :, :sq], lse.reshape(b, h, sq_p)[:, :, :sq]
+    return (site.unview(o, sq, dv),
+            lse.reshape(b, h * heads, sq_p)[:, :, :sq])
+
+
+_fwd_call_once = jax.jit(_fwd_call, static_argnums=tuple(range(4, 10)),
+                         inline=True)
 
 
 def _scratch(shape, dtype):
@@ -415,14 +598,27 @@ def _bwd_vmem_bytes(chunks, block_q, block_k, d, dv, itemsize, bias_lanes,
 
 
 def _bwd_kernel(*refs, sm_scale, causal, window, block_q, block_k, kv_len,
-                chunks, nq, group, bias_kind, emit_dbias):
+                chunks, nq, group, bias_kind, emit_dbias, heads, at):
     """One q-block against the `chunks` k-blocks of one resident
     k-segment, scores held keys-down ([block_k, block_q]: the row
     statistics are rows, and only dQ's product contracts over a
     transposed operand). Each tile's s, p, dp, ds are computed once and
     feed dV, dK and dQ. The grid's last axis walks the `nq` q-blocks of
     each query head that reads this key head, one head after another:
-    dK and dV are summed over the group where they are accumulated."""
+    dK and dV are summed over the group where they are accumulated.
+
+    On a sequence-major block (`at` == (0,), `heads` heads in its lanes)
+    the recurrence runs once a head on q and do with the other heads'
+    lanes zeroed: the scores and dp contract over the block's whole
+    lanes, dV and dK gain this head's lanes and zeros beside them, and
+    of dQ, which k's other lanes fill too, this head's lanes are kept.
+    No product gains from a lane slice here (v5e, ms a site of 64 heads
+    x 2048 x 64, forward and backward: 2.85 all zeroed, 2.85-2.87 with
+    any one of the five sliced, 2.86 with all; causal 2.06, 2.07-2.10,
+    2.11): k, v and the accumulators' rows stay whole 128-lane words.
+    So the head is the index of a fori_loop, its body traced and
+    compiled once (the same ms a site as a static loop; a backward
+    kernel compiles in 1.2 s for 1.5, 5 s a step of 18 sites)."""
     n_in = 6 + (bias_kind is not None)
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if bias_kind is not None else None
@@ -438,67 +634,84 @@ def _bwd_kernel(*refs, sm_scale, causal, window, block_q, block_k, kv_len,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    dq_scr[:] = jnp.zeros_like(dq_scr)
-    if emit_dbias:        # the tiles that do not run still own a block
-        dbias_ref[0, 0] = jnp.zeros_like(dbias_ref[0, 0])
+    def _head(i):
+        """Head i's walk: dK and dV gain in scratch, dQ is left in
+        dq_scr."""
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+        if emit_dbias:    # the tiles that do not run still own a block
+            dbias_ref[0, 0] = jnp.zeros_like(dbias_ref[0, 0])
 
-    q, do = q_ref[0, 0], do_ref[0, 0]          # [bq, d], [bq, dv]
-    lse, delta = lse_ref[0, 0, 0], delta_ref[0, 0, 0]       # [1, bq]
-    first = ks * chunks
-    # k-blocks past the last key, or wholly above the causal diagonal:
-    # nothing to do
-    stop = jnp.minimum(chunks, -(-kv_len // block_k) - first)
-    if causal:
-        stop = jnp.minimum(
-            stop, (iq * block_q + block_q - 1) // block_k + 1 - first)
+        q = _head_lanes(q_ref[at], i, heads)                 # [bq, d]
+        do = _head_lanes(do_ref[at], i, heads)               # [bq, dv]
+        lse, delta = lse_ref[0, i, 0], delta_ref[0, i, 0]    # [1, bq]
+        first = ks * chunks
+        # k-blocks past the last key, or wholly above the causal
+        # diagonal: nothing to do
+        stop = jnp.minimum(chunks, -(-kv_len // block_k) - first)
+        if causal:
+            stop = jnp.minimum(
+                stop, (iq * block_q + block_q - 1) // block_k + 1 - first)
 
-    def _tile(c, carry, diagonal=causal, lower=False):
-        rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
-        k, v = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
-        s = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bk, bq]
-        if bias_kind == "key":      # one value a key, on every lane
-            s = s + bias_ref[0, 0, rows, :][:, :1].astype(jnp.float32)
-        elif bias_kind == "score":
-            s = s + bias_ref[0, 0, rows, :].astype(jnp.float32)
-        kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        if kv_len % block_k:        # mask seq padding
-            s = jnp.where(kpos < kv_len, s, NEG_INF)
-        if diagonal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = _visible(s, qpos, kpos, window if lower else None)
-        p = jnp.exp(s - lse)                                 # [bk, bq]
-        dv_scr[rows, :] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bk, dv]
-        dp = jax.lax.dot_general(
-            v, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bk, bq]
-        ds = p * (dp - delta)
-        if emit_dbias:
-            dbias_ref[0, 0, rows, :] = ds
-        ds = ds.astype(q.dtype)
-        dk_scr[rows, :] += sm_scale * jax.lax.dot_general(
-            ds, q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bk, d]
-        dq_scr[:] += sm_scale * jax.lax.dot_general(
-            ds, k, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq, d]
-        return carry
+        def _tile(c, carry, diagonal=causal, lower=False):
+            rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+            k, v = k_ref[(*at, rows, slice(None))], \
+                v_ref[(*at, rows, slice(None))]
+            s = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [bk, bq]
+            if bias_kind == "key":      # one value a key, on every lane
+                s = s + bias_ref[0, 0, rows, :][:, :1].astype(jnp.float32)
+            elif bias_kind == "score":
+                s = s + bias_ref[0, 0, rows, :].astype(jnp.float32)
+            kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            if kv_len % block_k:        # mask seq padding
+                s = jnp.where(kpos < kv_len, s, NEG_INF)
+            if diagonal:
+                qpos = iq * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                s = _visible(s, qpos, kpos, window if lower else None)
+            p = jnp.exp(s - lse)                             # [bk, bq]
+            dv_scr[rows, :] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [bk, dv]
+            dp = jax.lax.dot_general(
+                v, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [bk, bq]
+            ds = p * (dp - delta)
+            if emit_dbias:
+                dbias_ref[0, 0, rows, :] = ds
+            ds = ds.astype(q.dtype)
+            dk_scr[rows, :] += sm_scale * jax.lax.dot_general(
+                ds, q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [bk, d]
+            dq_scr[:] += sm_scale * jax.lax.dot_general(
+                ds, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [bq, d]
+            return carry
 
-    if window is None:
-        jax.lax.fori_loop(0, stop, _tile, None)
+        if window is None:
+            jax.lax.fori_loop(0, stop, _tile, None)
+        else:
+            _walk_band(_tile, None, iq, first, stop, block_q, block_k,
+                       window)
+
+    if heads == 1:
+        _head(0)
+        dq_ref[(0, *at)] = dq_scr[:].astype(dq_ref.dtype)
     else:
-        _walk_band(_tile, None, iq, first, stop, block_q, block_k, window)
-    dq_ref[0, 0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        def _each(i, _):
+            _head(i)
+            # of a head's dQ its own lanes; the others' are the other
+            # heads' to write
+            dq_ref[(0, *at)] = _head_lanes(
+                dq_scr[:].astype(dq_ref.dtype), i, heads, dq_ref[(0, *at)])
+        jax.lax.fori_loop(0, heads, _each, None)
 
     @pl.when(step == pl.num_programs(3) - 1)
     def _fin():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[at] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[at] = dv_scr[:].astype(dv_ref.dtype)
 
 
 _SITE_HELP = {
@@ -515,15 +728,22 @@ _SITE_HELP = {
 }
 
 
-def _count_site(which, path, window, group):
+def _count_site(which, nseg, window, site):
     from ...observability.registry import default_registry
+    path = "relaid" if site.relaid else (
+        "resident" if nseg == 1 else "partial")
     default_registry().counter(
         f"paddle_tpu_flash_{which}_sites_total",
         _SITE_HELP[which] + ", by the window (0: none; the kernel is "
-        "then named without _window) and by the query heads that read "
-        "one key head.",
-        ("path", "window", "group")).labels(
-            path=path, window=str(window or 0), group=str(group)).inc()
+        "then named without _window), by the query heads that read one "
+        "key head and by the heads a block holds (1: a head-major call, "
+        "or a sequence-major one whose heads fill whole 128-lane words; "
+        "128 // D on a sequence-major call with narrower heads). path "
+        "relaid: a sequence-major call whose shape the sequence-major "
+        "blocks cannot serve, transposed by the entry and run head-major.",
+        ("path", "window", "group", "heads_a_block")).labels(
+            path=path, window=str(window or 0), group=str(site.group),
+            heads_a_block=str(site.heads)).inc()
 
 
 def _window_suffix(window):
@@ -532,40 +752,62 @@ def _window_suffix(window):
     return "" if window is None else "_window"
 
 
-def _bwd(res, g, sm_scale, causal, window, block_q, block_k, interpret,
-         bias_needs_grad):
-    q, k, v, bias, o, lse = res
-    do = g
-    b, h, sq, d = q.shape
-    hk, sk, dv = k.shape[1], k.shape[2], v.shape[3]
-    group = h // hk
-    bias_kind = _bias_kind(bias)
-    emit_dbias = bias is not None and bias_needs_grad
+def _bwd_plan(site, bias, bias_needs_grad, causal, block_q, block_k,
+              interpret, itemsize):
+    """A backward call's tiles and segments (as _fwd_plan)."""
     block_q, block_k = _blocks(
-        sq, sk, *_caps(bias, bias_needs_grad, causal, block_q, block_k),
-        interpret)
-    nq, nk = -(-sq // block_q), -(-sk // block_k)
+        site.sq, site.sk,
+        *_caps(bias, bias_needs_grad, causal, block_q, block_k), interpret)
+    nq, nk = -(-site.sq // block_q), -(-site.sk // block_k)
 
     def vmem_bytes(chunks):
-        return _bwd_vmem_bytes(chunks, block_q, block_k, d, dv,
-                               q.dtype.itemsize,
-                               _bias_lanes(bias, block_q), emit_dbias)
+        return _bwd_vmem_bytes(chunks, block_q, block_k,
+                               site.heads * site.d, site.heads * site.dv,
+                               itemsize, _bias_lanes(bias, block_q),
+                               bias is not None and bias_needs_grad)
 
     nseg, chunks = _segments(nk, vmem_bytes)
+    return block_q, block_k, nq, nseg, chunks, vmem_bytes(chunks)
+
+
+def _bwd(res, g, sm_scale, causal, window, block_q, block_k, interpret,
+         bias_needs_grad, layout="bhsd"):
+    """(dq, dk, dv, dbias). Counts the site, then _bwd_call, traced once
+    a signature where _fwd_call is."""
+    q, k, v, bias = res[:4]
+    site = _Site(layout, q, k, v)
+    plan = _bwd_plan(site, bias, bias_needs_grad, causal, block_q, block_k,
+                     interpret, q.dtype.itemsize)
+    _count_site("bwd", plan[3], window, site)
+    call = _bwd_call_once if site.seq_major else _bwd_call
+    return call(res, g, sm_scale, causal, window, interpret,
+                bias_needs_grad, layout, plan)
+
+
+def _bwd_call(res, g, sm_scale, causal, window, interpret, bias_needs_grad,
+              layout, plan):
+    q, k, v, bias, o, lse = res
+    do = g
+    site = _Site(layout, q, k, v)
+    b, h, hk, sq, sk, d, dv = (site.b, site.h, site.hk, site.sq, site.sk,
+                               site.d, site.dv)
+    group, heads = site.group, site.heads
+    bias_kind = _bias_kind(bias)
+    emit_dbias = bias is not None and bias_needs_grad
+    block_q, block_k, nq, nseg, chunks, vmem = plan
     seg = chunks * block_k
     sq_p, sk_p = nq * block_q, nseg * seg
-    _count_site("bwd", "resident" if nseg == 1 else "partial", window,
-                group)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)                                # [B,H,Sq]
-    pad_q = ((0, 0), (0, 0), (0, sq_p - sq), (0, 0))
-    pad_k = ((0, 0), (0, 0), (0, sk_p - sk), (0, 0))
-    qp, dop = jnp.pad(q, pad_q), jnp.pad(do, pad_q)
-    kp, vp = jnp.pad(k, pad_k), jnp.pad(v, pad_k)
+    if site.seq_major:
+        delta = jnp.swapaxes(delta, 1, 2)
+    qp, dop = site.rows(q, sq_p - sq), site.rows(do, sq_p - sq)
+    kp, vp = site.rows(k, sk_p - sk), site.rows(v, sk_p - sk)
 
     def rows(x):        # a row a q-block; pads read lse 0: exp stays finite
-        return jnp.pad(x, pad_q[:3]).reshape(b, h, nq, 1, block_q)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, sq_p - sq))).reshape(
+            b, h * heads, nq, 1, block_q)
 
     # the grid is (batch, KEY head, k-segment, step); a step is one
     # q-block of one of the group's query heads
@@ -574,16 +816,14 @@ def _bwd(res, g, sm_scale, causal, window, block_q, block_k, interpret,
             return hk, step
         return hk * group + step // nq, step % nq
 
-    def qspec(width):
-        return pl.BlockSpec((1, 1, block_q, width),
-                            lambda b, hk, ks, st: (b, *at(hk, st), 0))
+    def qspec(width, lead=None):
+        return site.spec(block_q, width,
+                         lambda b, hk, ks, st: (b, *at(hk, st)), lead)
 
     def kspec(width):
-        return pl.BlockSpec((1, 1, seg, width),
-                            lambda b, hk, ks, st: (b, hk, ks, 0))
+        return site.spec(seg, width, lambda b, hk, ks, st: (b, hk, ks))
 
-    rspec = pl.BlockSpec((1, 1, 1, 1, block_q),
-                         lambda b, hk, ks, st: (b, *at(hk, st), 0, 0))
+    rspec = site.stat_spec(block_q, lambda b, hk, ks, st: (b, *at(hk, st)))
     in_specs = [qspec(d), kspec(d), kspec(dv)]
     args = [qp, kp, vp]
     if bias is not None:
@@ -597,14 +837,12 @@ def _bwd(res, g, sm_scale, causal, window, block_q, block_k, interpret,
 
     # dQ a k-segment: finished where there is one, else an f32 partial
     out_shape = [
-        jax.ShapeDtypeStruct((nseg, b, h, sq_p, d),
+        jax.ShapeDtypeStruct((nseg, *site.shape(h, sq_p, d)),
                              q.dtype if nseg == 1 else jnp.float32),
-        jax.ShapeDtypeStruct((b, hk, sk_p, d), k.dtype),
-        jax.ShapeDtypeStruct((b, hk, sk_p, dv), v.dtype)]
-    out_specs = [
-        pl.BlockSpec((1, 1, 1, block_q, d),
-                     lambda b, hk, ks, st: (ks, b, *at(hk, st), 0)),
-        kspec(d), kspec(dv)]
+        jax.ShapeDtypeStruct(site.shape(hk, sk_p, d), k.dtype),
+        jax.ShapeDtypeStruct(site.shape(hk, sk_p, dv), v.dtype)]
+    out_specs = [qspec(d, lead=lambda b, hk, ks, st: ks),
+                 kspec(d), kspec(dv)]
     if emit_dbias:
         out_shape.append(jax.ShapeDtypeStruct(
             (b, h, sk_p, sq_p), jnp.float32))
@@ -617,19 +855,19 @@ def _bwd(res, g, sm_scale, causal, window, block_q, block_k, interpret,
             _bwd_kernel, sm_scale=sm_scale, causal=causal, window=window,
             block_q=block_q, block_k=block_k, kv_len=sk, chunks=chunks,
             nq=nq, group=group, bias_kind=bias_kind,
-            emit_dbias=emit_dbias),
+            emit_dbias=emit_dbias, heads=heads, at=site.at),
         # the benchmark's readers find the backward by flash_bwd_(dq|dkv)
         name="flash_bwd_dkv_dq" + _window_suffix(window),
         grid=(b, hk, nseg, group * nq),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[_scratch((block_q, d), jnp.float32),
-                        _scratch((seg, d), jnp.float32),
-                        _scratch((seg, dv), jnp.float32)],
+        scratch_shapes=[_scratch((block_q, heads * d), jnp.float32),
+                        _scratch((seg, heads * d), jnp.float32),
+                        _scratch((seg, heads * dv), jnp.float32)],
         compiler_params=_compiler_params(
             ("parallel",) * 3 + ("arbitrary",),
-            vmem_limit_bytes=max(32 << 20, 5 * vmem_bytes(chunks) // 4)),
+            vmem_limit_bytes=max(32 << 20, 5 * vmem // 4)),
         interpret=interpret,
     )(*args)
     dq, dk, dv_ = outs[:3]
@@ -643,36 +881,57 @@ def _bwd(res, g, sm_scale, causal, window, block_q, block_k, interpret,
         dbias = dbias.astype(bias.dtype)
     else:
         dbias = jnp.zeros_like(bias) if bias is not None else None
-    return dq[:, :, :sq], dk[:, :, :sk], dv_[:, :, :sk], dbias
+    return (site.unview(dq, sq, d), site.unview(dk, sk, d),
+            site.unview(dv_, sk, dv), dbias)
+
+
+_bwd_call_once = jax.jit(_bwd_call, static_argnums=tuple(range(2, 9)),
+                         inline=True)
 
 
 # ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, bias, sm_scale, causal, window, block_q, block_k,
-           interpret, bias_grad):
+           interpret, bias_grad, layout):
     o, _ = _fwd(q, k, v, bias, sm_scale, causal, window, block_q, block_k,
-                interpret, bias_grad)
+                interpret, bias_grad, layout)
     return o
 
 
 def _flash_fwd(q, k, v, bias, sm_scale, causal, window, block_q, block_k,
-               interpret, bias_grad):
+               interpret, bias_grad, layout):
     o, lse = _fwd(q, k, v, bias, sm_scale, causal, window, block_q,
-                  block_k, interpret, bias_grad)
+                  block_k, interpret, bias_grad, layout)
     return o, (q, k, v, bias, o, lse)
 
 
 def _flash_bwd(sm_scale, causal, window, block_q, block_k, interpret,
-               bias_grad, res, g):
+               bias_grad, layout, res, g):
     dq, dk, dv, dbias = _bwd(res, g, sm_scale, causal, window, block_q,
-                             block_k, interpret, bias_needs_grad=bias_grad)
+                             block_k, interpret, bias_needs_grad=bias_grad,
+                             layout=layout)
     return dq, dk, dv, dbias
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _seq_major_serves(q, k, v, bias, bias_grad):
+    """Whether the sequence-major blocks can hold this call: heads that
+    cut the lanes into whole 128-lane words (_heads_a_block), whole
+    blocks of them, a block of query heads reading the block of key
+    heads at the same lanes (grouped key heads only where a head is a
+    block), and no bias but an untrained key row shared by the heads."""
+    heads = _heads_a_block(q.shape[3], v.shape[3])
+    if heads is None or q.shape[2] % heads or k.shape[2] % heads \
+            or (heads > 1 and q.shape[2] != k.shape[2]):
+        return False
+    return bias is None or (_bias_kind(bias) == "key"
+                            and bias.shape[1] == 1 and not bias_grad)
 
 
 def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
@@ -681,13 +940,28 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    bias_grad: bool = False) -> jax.Array:
+                    bias_grad: bool = False,
+                    layout: str = "bhsd") -> jax.Array:
     """Tiled online-softmax attention.
 
-    q: [B, H, Sq, D]; k: [B, Hk, Sk, D]; v: [B, Hk, Sk, Dv], Hk a divisor
-    of H (query head h reads key head h // (H / Hk)); bias additive with
-    any of the four dims broadcast (size 1). Returns [B, H, Sq, Dv].
-    sm_scale defaults to 1/sqrt(D), Q's width, whatever Dv is.
+    layout "bhsd" (head-major): q [B, H, Sq, D]; k [B, Hk, Sk, D];
+    v [B, Hk, Sk, Dv]; returns [B, H, Sq, Dv]. layout "bshd"
+    (sequence-major, what a projection writes and reads): q [B, Sq, H, D];
+    k [B, Sk, Hk, D]; v [B, Sk, Hk, Dv]; returns [B, Sq, H, Dv] — the
+    kernels read each as its free view [B, S, H * D] through their
+    BlockSpecs, a block the fewest heads that fill whole 128-lane words
+    (128 // D of them where D = Dv divides 128, one where both are
+    multiples of 128), and no array is transposed. A sequence-major call
+    the blocks cannot serve (_seq_major_serves: 192-wide keys beside
+    128-wide values, a head count that splits a block, grouped key heads
+    under heads narrower than a word, a score-sized, per-head or trained
+    bias) is transposed here and run head-major; the site counters then
+    read path="relaid".
+
+    Hk divides H (query head h reads key head h // (H / Hk)); bias
+    additive [B, H, Sq, Sk] with any of the four dims broadcast (size 1),
+    in either layout. sm_scale defaults to 1/sqrt(D), Q's width,
+    whatever Dv is.
 
     window=W (causal sites only): query i sees keys i - W < j <= i, its
     own among them (the Hugging Face sliding_window convention). Both
@@ -707,24 +981,36 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     the call can see (the bias's kind, causal), one rule for both
     passes, measured on a v5e.
     """
+    if layout not in ("bhsd", "bshd"):
+        raise ValueError(f"flash_attention: layout {layout!r} is neither "
+                         "'bhsd' nor 'bshd'")
     if interpret is None:
         interpret = _interpret_default()
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
-        raise ValueError(f"flash_attention: {q.shape[1]} query heads over "
-                         f"{k.shape[1]} key and {v.shape[1]} value heads")
+    head_dim, seq_dim = (2, 1) if layout == "bshd" else (1, 2)
+    if q.shape[head_dim] % k.shape[head_dim] \
+            or k.shape[head_dim] != v.shape[head_dim]:
+        raise ValueError(
+            f"flash_attention: {q.shape[head_dim]} query heads over "
+            f"{k.shape[head_dim]} key and {v.shape[head_dim]} value heads")
     if window is not None:
         if not causal or int(window) < 1:
             raise ValueError("flash_attention: a window is a whole number "
                              "of keys on a causal site")
-        window = None if int(window) >= k.shape[2] else int(window)
+        window = None if int(window) >= k.shape[seq_dim] else int(window)
     if bias is not None:
         if bias.ndim == 2:        # [Sq|1, Sk|1]
             bias = bias[None, None]
         elif bias.ndim == 3:      # [B|1, Sq|1, Sk|1]
             bias = bias[:, None]
-    return _flash(q, k, v, bias, float(sm_scale), bool(causal), window,
-                  None if block_q is None else int(block_q),
-                  None if block_k is None else int(block_k),
-                  bool(interpret), bool(bias_grad))
+    relaid = layout == "bshd" and not _seq_major_serves(q, k, v, bias,
+                                                        bias_grad)
+    if relaid:
+        q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+        layout = "relaid"
+    o = _flash(q, k, v, bias, float(sm_scale), bool(causal), window,
+               None if block_q is None else int(block_q),
+               None if block_k is None else int(block_k),
+               bool(interpret), bool(bias_grad), layout)
+    return jnp.swapaxes(o, 1, 2) if relaid else o

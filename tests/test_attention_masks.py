@@ -26,7 +26,8 @@ S, V = 16, 50
 
 
 def _sdpa_sites():
-    """{(path, mask, causal, window, group): sites traced so far}."""
+    """{(path, mask, causal, window, group, layout): sites traced so
+    far}."""
     fam = default_registry().get("paddle_tpu_sdpa_sites_total")
     if fam is None:
         return collections.Counter()
@@ -73,8 +74,9 @@ def test_train_program_computes_the_dense_triangle_and_pad_bias(
     loss, *grads = exe.run(
         main, feed=batch,
         fetch_list=[fetch["loss"]] + [grad_var_name(n) for n in params])
-    assert _traced(before) == {(path, "key_row", "0", "0", "1"): 4,
-                               (path, "key_row", "1", "0", "1"): 2}
+    assert _traced(before) == {
+        (path, "key_row", "0", "0", "1", "bshd"): 4,
+        (path, "key_row", "1", "0", "1", "bshd"): 2}
     want = reference.encdec_loss(tape, batch, MODEL)
     np.testing.assert_allclose(float(np.asarray(loss).reshape(())), want,
                                rtol=2e-5)
@@ -113,4 +115,4 @@ def test_counter_tells_a_dense_mask_from_structure(monkeypatch, path):
     pt.Executor().run(main, fetch_list=[out], feed={
         "q": rng.randn(3, 2, S, 8).astype(np.float32),
         "mask": np.broadcast_to(tri, (3, 1, S, S)).copy()})
-    assert _traced(before) == {(path, "dense", "0", "0", "1"): 1}
+    assert _traced(before) == {(path, "dense", "0", "0", "1", "bhsd"): 1}

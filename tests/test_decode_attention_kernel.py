@@ -76,7 +76,7 @@ def test_kernel_matches_the_composed_rule_on_ragged_lengths(dtype, bound):
     before = _sdpa_sites()
     want = _rule(q, k, v, kv_len, bound)
     assert dict(_sdpa_sites() - before) == \
-        {("composed", "kv_len", "0", "0", "1"): 1}          # the CPU's path
+        {("composed", "kv_len", "0", "0", "1", "bhsd"): 1}  # the CPU's path
     got = kernel.decode_attention(q, k, v, kv_len, bound=bound)
     assert got.shape == q.shape and got.dtype == q.dtype
     live = lens > 0
@@ -195,7 +195,7 @@ def test_a_cache_the_kernel_cannot_serve_is_refused_and_composed(
     before = _sdpa_sites()
     got = _rule(q, k, v, kv_len, 512)   # the CPU answers lane axis 3
     assert dict(_sdpa_sites() - before) == \
-        {("composed", "kv_len", "0", "0", "1"): 1}
+        {("composed", "kv_len", "0", "0", "1", "bhsd"): 1}
     np.testing.assert_array_equal(
         np.asarray(got), np.asarray(_masked_slice(q, k, v, kv_len, 512)))
     with pytest.raises(ValueError, match="cannot serve"):
@@ -213,7 +213,7 @@ def test_rule_runs_the_kernel_where_it_is_chosen(monkeypatch):
     before = _sdpa_sites()
     got = _rule(q, k, v, kv_len, 512)
     assert dict(_sdpa_sites() - before) == \
-        {("decode_kernel", "kv_len", "0", "0", "1"): 1}
+        {("decode_kernel", "kv_len", "0", "0", "1", "bhsd"): 1}
     live = np.asarray(kv_len) > 0
     np.testing.assert_allclose(np.asarray(got)[live],
                                np.asarray(want)[live],

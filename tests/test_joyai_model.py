@@ -82,7 +82,7 @@ def test_loss_and_every_gradient_match_the_reference_in_f32(
     # 2 blocks + the prediction module's: causal, maskless attention
     # sites and (the dense block apart) expert-layer sites
     assert dict(_counts("paddle_tpu_sdpa_sites_total") - sdpa) == \
-        {(path, "none", "1", "0", "1"): 3}
+        {(path, "none", "1", "0", "1", "bhsd"): 3}
     assert dict(_counts("paddle_tpu_moe_sites_total") - moe) == \
         {("ragged_dot", "2", "8"): 2}
     want = reference_joyai.loss(tape, batch, MODEL)

@@ -83,7 +83,8 @@ def test_loss_and_every_gradient_match_the_reference_in_f32(
         fetch_list=[fetch["loss"]] + [grad_var_name(n) for n in names])
     # causal, maskless sites by window and by the query heads a key head
     assert dict(_counts("paddle_tpu_sdpa_sites_total") - sdpa) == \
-        {(path, "none", "1", "0", "3"): 1, (path, "none", "1", "8", "4"): 2}
+        {(path, "none", "1", "0", "3", "bhsd"): 1,
+         (path, "none", "1", "8", "4", "bhsd"): 2}
     assert dict(_counts("paddle_tpu_moe_sites_total") - moe) == \
         {("ragged_dot", "2", "8"): 2}
     want = reference_laguna.loss(tape, batch, MODEL)

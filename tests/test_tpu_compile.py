@@ -148,6 +148,58 @@ def test_grouped_and_windowed_flash_sites_compile(one_chip, heads, seq,
     assert _sites("bwd") - bwd == {"resident": 1}
 
 
+# The same sites sequence-major (layout="bshd", [B, S, H, D]): train-s2048's
+# three kinds (two 64-wide heads a 128-lane block, under a key-row mask),
+# the mesh cell's shard (4 heads: two blocks), a length that is no
+# multiple of 128, four 32-wide heads a block, and 128-wide heads under
+# grouped key heads (a head a block). What interpret mode never holds
+# against Mosaic: the per-head row and sublane slices of the scratch, the
+# 128-lane blocks of a [B, S, H * D] view, the turn of a two-head
+# accumulator.
+@pytest.mark.parametrize("shape,hk,causal,masked,heads", [
+    ((8, 2048, 8, 64), 8, False, True, 2),
+    ((8, 2048, 8, 64), 8, True, True, 2),
+    ((8, 2048, 4, 64), 4, True, True, 2),
+    ((4, 1100, 8, 64), 8, True, True, 2),
+    ((2, 2048, 16, 32), 16, True, False, 4),
+    ((1, 4096, 16, 128), 4, True, False, 1),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_sequence_major_flash_sites_compile(one_chip, shape, hk, causal,
+                                            masked, heads):
+    import collections
+
+    from paddle_tpu.observability import default_registry
+    b, seq, h, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, seq, hk, d), jnp.bfloat16,
+                              sharding=one_chip)
+    args = (q, kv, kv)
+    if masked:
+        args += (jax.ShapeDtypeStruct((b, 1, 1, seq), jnp.float32,
+                                      sharding=one_chip),)
+
+    def loss(q, k, v, m=None):
+        return _sum_f32(flash_attention(q, k, v, m, causal=causal,
+                                        interpret=False, layout="bshd"))
+
+    def by_block():     # (path, heads a block), over windows and groups
+        fam = default_registry().get("paddle_tpu_flash_bwd_sites_total")
+        counts = collections.Counter()
+        for labels, child in (fam.samples() if fam is not None else ()):
+            counts[labels[0], labels[3]] += child.value
+        return counts
+
+    before = by_block()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert by_block() - before == {("resident", str(heads)): 1}
+    # q, k, v, o and their gradients are read and written where they
+    # lie: XLA lays no head-major copy beside the calls
+    assert f"bf16[{b},{h},{seq},{d}]" not in text
+    assert " transpose(" not in text
+
+
 def test_flash_backward_beyond_the_vmem_budget_compiles(one_chip):
     """A head whose K and V with their accumulators pass the budget
     (32,768 keys of 128: 100 MB) is walked a segment at a time, dQ an
@@ -241,8 +293,6 @@ def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_
             feed = {n: sds((batch, seq, 1), jnp.int32)
                     for n in ("src_ids", "trg_ids", "trg_labels")}
             feed["pos_ids"] = sds((seq,), jnp.int32)
-            # (the build traced each site's forward once already, for
-            # its output's shape)
             fwd_sites = _sites("fwd")
             text = step.jitted.lower(
                 feed, state(step.ro_names), state(step.rw_names),
@@ -259,6 +309,9 @@ def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_
     # the logsumexp leaves the forward [8, 8, 2048] f32 (ISSUE 40; until
     # then 128 lanes wide, 67 MB a site, for XLA to cut a column out of)
     assert not re.findall(rf"f32\[{batch},8,{seq},128\]", text)
+    # the sites read q, k, v and write o sequence-major (ISSUE 47): no
+    # head-major [8, 8, 2048, 64] array is laid out anywhere in the step
+    assert not re.findall(rf"bf16\[{batch},8,{seq},64\]", text)
     score_sized = re.findall(
         rf"f32\[(?:\d+,)*{seq},{seq}\]", text)
     # [8, 2048, d_inner = 2048] activations are the only such shape
@@ -294,11 +347,14 @@ def test_fused_rnn_fwd_bwd_compiles(one_chip, cell, dtype):
                        *args, sds(b, dt=jnp.int32)) == 2
 
 
-def test_flash_attention_under_a_mesh_compiles_per_shard(topo):
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+def test_flash_attention_under_a_mesh_compiles_per_shard(topo, layout):
     """GSPMD cannot partition a Mosaic kernel: lowering the bare kernel
     with mesh-sharded operands raises "Mosaic kernels cannot be
     automatically partitioned" (what ParallelExecutor hit at S >= 512
-    before PR 22). ops/nn_ops.py runs it per shard instead."""
+    before PR 22). ops/nn_ops.py runs it per shard instead — of batch
+    and heads, wherever the layout holds the heads (train-mesh-dp2tp2's
+    sites are sequence-major since PR 47)."""
     import functools
 
     import numpy as np
@@ -307,19 +363,24 @@ def test_flash_attention_under_a_mesh_compiles_per_shard(topo):
     from paddle_tpu.ops.nn_ops import _per_shard_attention
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
-    qs = NamedSharding(mesh, P("data", "model", None, None))
-    q = jax.ShapeDtypeStruct((8, 8, 2048, 64), jnp.bfloat16, sharding=qs)
-    m = jax.ShapeDtypeStruct((8, 1, 1, 2048), jnp.float32,
+    head_dim = 1 if layout == "bhsd" else 2
+    shape, spec = [16, 2048, 2048, 64], ["data", None, None, None]
+    shape[head_dim], spec[head_dim] = 8, "model"
+    q = jax.ShapeDtypeStruct(tuple(shape), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P(*spec)))
+    m = jax.ShapeDtypeStruct((16, 1, 1, 2048), jnp.float32,
                              sharding=NamedSharding(mesh, P("data")))
     attend = functools.partial(flash_attention, causal=True,
-                               interpret=False)
+                               interpret=False, layout=layout)
     with pytest.raises(NotImplementedError, match="shard_map"):
         jax.jit(attend).lower(q, q, q, m)
     sharded = functools.partial(_per_shard_attention, attend, mesh,
-                                batch_axis="data", head_axis="model")
+                                batch_axis="data", head_axis="model",
+                                head_dim=head_dim)
     text = jax.jit(sharded).lower(q, q, q, m).compile().as_text()
     assert text.count("tpu_custom_call") == 1
-    # each device attends its own [4, 4, 2048, 64] shard: no collective
+    # each device attends its own shard of 8 rows and 4 heads: no
+    # collective
     assert "all-gather" not in text and "all-reduce" not in text
     # and differentiates it there: the forward and the one backward
     # kernel a shard, what train-mesh-dp2tp2 runs 18 times a step
@@ -328,6 +389,7 @@ def test_flash_attention_under_a_mesh_compiles_per_shard(topo):
         argnums=(0, 1, 2))).lower(q, q, q, m).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     assert "all-gather" not in text and "all-reduce" not in text
+    assert " transpose(" not in text
 
 
 # serve-chat's caches (128 slots x 8 heads x 2048 positions, chipbench/
