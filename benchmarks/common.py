@@ -2,13 +2,19 @@
 benchmark/fluid/run.sh contract: --batch_size / --iterations /
 --skip_batch_num, then report average throughput).
 
-Timing uses the marginal-cost method from bench.py (see its module
-docstring)."""
+Timing is MARGINAL-COST: run N1 and N2 iterations, each fully synced by a
+host readback of the final loss (step i+1 consumes step i's donated state,
+so the readback drains the whole chain), and divide the extra work by the
+extra time. This cancels the fixed cost of a window (first dispatch, the
+final readback) that would otherwise be billed to the steps. These scripts
+are the parity surface of PARITY.md; the numbers the repo stands behind
+come from BENCHMARK.json + chipbench/."""
 from __future__ import annotations
 
 import argparse
 import os
 import sys
+import time
 
 import numpy as np
 
@@ -35,13 +41,46 @@ def parse_args(extra=None):
     return args
 
 
+def _marginal_steps_per_sec(exe, program, feed, loss_var, n1, n2):
+    """Marginal steps/sec via two synced runs of different lengths: the
+    (n1, n2) pair is measured twice; returns the MEDIAN estimate and
+    the relative spread (max-min over median)."""
+    def one_step():
+        (out,) = exe.run(program, feed=feed, fetch_list=[loss_var],
+                         return_numpy=False)
+        return out
+
+    def timed(n):
+        t0 = time.perf_counter()
+        out = None
+        for _ in range(n):
+            out = one_step()
+        val = np.asarray(out)  # host readback drains the step chain
+        if not np.isfinite(np.ravel(val)[0]):
+            raise RuntimeError("non-finite loss in bench — result invalid")
+        return time.perf_counter() - t0
+
+    for _ in range(3):
+        one_step()   # the frozen feed is uploaded (and cached) here
+    timed(1)  # synced throwaway: drains lazy compiles
+    ests = []
+    for _ in range(2):
+        t1 = timed(n1)
+        t2 = timed(n2)
+        if t2 <= t1:
+            raise RuntimeError(
+                f"marginal timing invalid: t({n2})={t2:.3f}s <= "
+                f"t({n1})={t1:.3f}s — timing not steady-state")
+        ests.append((n2 - n1) / (t2 - t1))
+    med = float(np.median(ests))
+    return med, (max(ests) - min(ests)) / med
+
+
 def run_benchmark(exe, program, feed, loss_var, args, unit_per_step,
                   unit="samples"):
     """Warm up, then marginal-cost time (iterations - skip_batch_num
-    extra steps) via bench.py's shared helper; print the
-    reference-style summary line."""
-    from bench import _marginal_steps_per_sec
-    steps_per_sec = _marginal_steps_per_sec(
+    extra steps); print the reference-style summary line."""
+    steps_per_sec, spread = _marginal_steps_per_sec(
         exe, program, feed, loss_var,
         n1=args.skip_batch_num, n2=args.iterations)
     (loss,) = exe.run(program, feed=feed, fetch_list=[loss_var],
@@ -50,50 +89,6 @@ def run_benchmark(exe, program, feed, loss_var, args, unit_per_step,
     per_sec = unit_per_step * steps_per_sec
     print(f"last loss: {last_loss:.4f}")
     print(f"throughput: {per_sec:,.1f} {unit}/sec "
-          f"({1.0 / steps_per_sec * 1e3:.1f} ms/batch)")
+          f"({1.0 / steps_per_sec * 1e3:.1f} ms/batch, "
+          f"spread {100 * spread:.0f}%)")
     return per_sec
-
-
-def time_chain(fn, x0, flops_per_call, label, n1=10, n2=110,
-               repeats=3, peak_flops=None):
-    """Kernel-A/B marginal timing: jit with donated self-chained arg,
-    3 warmups + a synced throwaway, then median of `repeats` marginal
-    deltas t(n2)-t(n1). Shared by the kernel A/B harnesses so protocol
-    fixes land once."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    if peak_flops is None:  # the attached device's peak, or an error
-        from paddle_tpu.observability.attribution import \
-            require_peak_flops
-        peak_flops = require_peak_flops()
-
-    jitted = jax.jit(fn, donate_argnums=(0,))
-    x = jnp.copy(x0)
-
-    def run_n(x, n):
-        t0 = time.perf_counter()
-        for _ in range(n):
-            x = jitted(x)
-        s = float(np.asarray(jnp.sum(
-            jnp.ravel(x)[:1].astype(jnp.float32))))
-        assert np.isfinite(s), label
-        return x, time.perf_counter() - t0
-
-    for _ in range(3):
-        x = jitted(x)
-    x, _ = run_n(x, 1)
-    ests = []
-    for _ in range(repeats):
-        x, t1 = run_n(x, n1)
-        x, t2 = run_n(x, n2)
-        ests.append((t2 - t1) / (n2 - n1))
-    dt = float(np.median(ests))
-    spread = (max(ests) - min(ests)) / dt
-    tflops = flops_per_call / dt / 1e12
-    print(f"{label:26s} {dt * 1e3:8.2f} ms/call  {tflops:6.1f} TFLOP/s"
-          f" ({100 * tflops * 1e12 / peak_flops:4.1f}% of peak)  "
-          f"spread {100 * spread:.0f}%", flush=True)
-    return dt

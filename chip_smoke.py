@@ -53,7 +53,7 @@ FULL = dict(vocab=32000, n_layer=6, n_head=8, d_model=512, d_inner=2048,
             batch=4, seq=2048, steps=8, stream_steps=4,
             prompt_buckets=[128, 512], cache_buckets=[512, 2048],
             prompt_lens=[37, 120, 300, 480], deep_prompt=500,
-            new_tokens=32, rnn=(64, 64, 512),       # bench.py LSTM-LM T,B,H
+            new_tokens=32, rnn=(64, 64, 512),       # the stacked-LSTM LM's T,B,H
             flash_odd_seq=1100, mesh_batch=8, mesh_steps=3,
             # joyai-llm-flash.train-ep32's attention site: B,H,S,d,d_v
             latent_site=(1, 32, 4096, 192, 128))
@@ -663,8 +663,7 @@ def phase_serve(sm, cfg, device):
         lm = model.programs["decode"][bucket]
         model.run_decode(np.ones(spec.slots, np.int64),
                          np.zeros(spec.slots, np.int64), bucket)
-        compiled = aot_compiled_for(model.executor, lm.main,
-                                    scope=model.scope)
+        compiled = aot_compiled_for(model.executor, lm.main)
         text = compiled.as_text()
         header = text[:text.find("\n\n")] if "\n\n" in text \
             else text[:20000]

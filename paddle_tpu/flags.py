@@ -16,8 +16,8 @@ from typing import Dict
 # name -> (default, where it is read, what it does)
 FLAGS: Dict[str, tuple] = {
     "PADDLE_TPU_AMP": (
-        "0", "amp.py / bench.py",
-        "bf16 mixed precision (f32 master weights); bench enables it"),
+        "0", "amp.py",
+        "bf16 mixed precision (f32 master weights)"),
     "PADDLE_TPU_CHECK_NAN_INF": (
         "0", "core/executor.py",
         "scan fetched values for NaN/Inf after each run (reference "
@@ -27,23 +27,13 @@ FLAGS: Dict[str, tuple] = {
         "donate rw persistable state to the jitted step (XLA aliases "
         "state-in to state-out in place of a copy per step); 0 restores "
         "copy-per-step for callers holding scope state across runs"),
-    "PADDLE_TPU_CONV_LAYOUT": (
-        "nchw", "ops/nn_ops.py",
-        "conv internal layout A/B knob ('nhwc' transposes at conv "
-        "boundaries; XLA cancels them between convs). NCHW measured "
-        ">= NHWC on chip"),
-    "PADDLE_TPU_RNN_UNROLL": (
-        "4", "ops/sequence_ops.py",
-        "lax.scan unroll factor for masked RNN scans; 1 disables "
-        "(also accepts off/false/no/none/disabled)"),
     "PADDLE_TPU_PALLAS_LSTM": (
         "1", "ops/sequence_ops.py",
         "fused Pallas LSTM kernel on TPU ('force' = interpret mode "
         "anywhere for tests, '0' = scan path)"),
     "PADDLE_TPU_PALLAS_GRU": (
         "1", "ops/sequence_ops.py",
-        "fused Pallas GRU kernel on TPU (~1.8x over scan on v5e; same "
-        "force/0/1 semantics)"),
+        "fused Pallas GRU kernel on TPU (same force/0/1 semantics)"),
     "PADDLE_TPU_CHECK_WHILE_BOUND": (
         "0", "core/executor.py",
         "raise when a top-level bounded While (max_steps=N) truncated a "
@@ -66,34 +56,6 @@ FLAGS: Dict[str, tuple] = {
         "8", "core/executor.py",
         "max entries in the device-side feed cache (frozen ndarrays "
         "uploaded once)"),
-    # bench-only knobs
-    "BENCH_BATCH": ("128", "bench.py", "ResNet bench batch size"),
-    "BENCH_WARMUP": ("3", "bench.py", "warmup steps"),
-    "BENCH_N1": ("5", "bench.py", "short marginal-timing run"),
-    "BENCH_N2": ("25", "bench.py", "long marginal-timing run"),
-    "BENCH_EXTRAS": ("1", "bench.py", "run the LSTM-LM extra metric"),
-    "BENCH_REAL_INPUT": ("1", "bench.py",
-                         "measure end-to-end throughput with the real "
-                         "input pipeline (recordio loader -> device "
-                         "prefetch) in the timed loop"),
-    "BENCH_DATA_DIR": ("/tmp/pt_bench_imagenet", "bench.py",
-                       "synthetic recordio shard directory for the "
-                       "real-input bench"),
-    "BENCH_TRANSFORMER": ("1", "bench.py",
-                          "run the transformer extra metric"),
-    "PADDLE_TPU_FUSED_XENT": (
-        "0", "ops/nn_ops.py",
-        "opt-in streaming softmax-cross-entropy (custom vjp, no "
-        "full-vocab f32 buffer) for very large vocabularies; measured "
-        "15% slower than the autodiff path at 32k vocab on v5e"),
-    "BENCH_REPEATS": ("2", "bench.py",
-                      "repeat the headline marginal measurement and "
-                      "report median + spread"),
-    "PADDLE_TPU_FLASH_MIN_SEQ": (
-        "512", "ops/nn_ops.py",
-        "minimum sequence length at which fused attention auto-routes "
-        "to the Pallas flash kernel; below it the naive composition "
-        "wins on v5e (crossover ~512 as measured in round 3)"),
     "PADDLE_TPU_ATTRIBUTION": (
         "1", "observability/attribution.py (published from trainer.py, "
         "serving/engine.py)",
@@ -135,7 +97,8 @@ FLAGS: Dict[str, tuple] = {
         "flash-kernel choice for scaled_dot_product_attention ops "
         "that carry no use_flash attr of their own, read when the op "
         "(and its grad op) is traced: '1' leaves the measured min-seq "
-        "policy in charge on a TPU (PADDLE_TPU_FLASH_MIN_SEQ), "
+        "policy in charge on a TPU (ops/pallas/flash_attention.py "
+        "FLASH_CROSSOVER_SEQ), "
         "'force' engages the kernel anywhere (interpret mode off-TPU "
         "— test coverage), '0' pins the naive composition"),
     "PADDLE_TPU_INPUT_WORKERS": (
@@ -209,11 +172,6 @@ FLAGS: Dict[str, tuple] = {
         "— a dense per-row counter would be O(vocab) host memory, "
         "unpayable at 1e9 rows); pruned back to this size whenever it "
         "doubles"),
-    "PADDLE_TPU_BN_CUSTOM_VJP": (
-        "0", "ops/nn_ops.py",
-        "use the round-2 hand-written BatchNorm backward (custom_vjp) "
-        "instead of autodiff; the autodiff default lets XLA fuse the "
-        "backward reductions into conv gradient fusions"),
 }
 
 

@@ -111,10 +111,10 @@ def attribution_enabled() -> bool:
 
 
 def set_attribution_enabled(v: Optional[bool]) -> Optional[bool]:
-    """Override the env toggle (None restores env-driven behaviour) —
-    the A/B lever for benchmarks/telemetry_overhead.py. Also installs/
-    removes the profiler event listener, so the disabled arm restores
-    the listener-free hot path (one list truthiness test per event).
+    """Override the env toggle (None restores env-driven behaviour).
+    Also installs/removes the profiler event listener, so that disabling
+    restores the listener-free hot path (one list truthiness test per
+    event).
     Returns the previous override so callers can restore it."""
     global _enabled_override
     prev = _enabled_override

@@ -25,10 +25,11 @@ Design:
   CircuitBreakers) that cannot push on every update: each registered
   callback runs at scrape/snapshot time and mirrors its source into
   registry instruments. Global collectors run against EVERY registry,
-  so swapping the default registry (tests, the overhead benchmark)
-  never loses the resilience series.
+  so swapping the default registry (tests) never loses the
+  resilience series.
 - ``MetricsRegistry(enabled=False)`` hands out shared no-op
-  instruments — the "off" arm of benchmarks/telemetry_overhead.py.
+  instruments: telemetry off costs one shared object, no branch in
+  the callers.
 
 Thread-safety: instrument creation, child lookup, mutation, and
 rendering all take fine-grained locks; ``render_prometheus()`` can run

@@ -10,7 +10,6 @@ for the MXU internally, so parity costs nothing on TPU.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +27,6 @@ def _pair(v):
 
 
 # -- convolution ------------------------------------------------------------
-
-def _conv_nhwc():
-    """Read at trace time (not import) so in-process A/B toggling works.
-    A/B on real TPU showed NCHW ≥ NHWC (XLA's layout assignment already
-    re-tiles internally), so NCHW stays the default."""
-    return os.environ.get("PADDLE_TPU_CONV_LAYOUT", "nchw") == "nhwc"
-
 
 def _conv2d_impl(x, w, strides, paddings, dilations, groups):
     # A strided 1x1 conv only READS the subsampled grid: slicing first
@@ -56,25 +48,14 @@ def _conv2d_impl(x, w, strides, paddings, dilations, groups):
     # preferred_element_type=f32's conv transpose rule rejects
     # mixed-dtype cotangents, so full-bf16 it is.)
     x, w = amp_cast(x, w)
-    nhwc = _conv_nhwc()
-    if nhwc:
-        # API stays NCHW; internally convs run NHWC. XLA cancels the
-        # transposes between consecutive convs, so the whole network
-        # effectively switches layout.
-        x = jnp.transpose(x, (0, 2, 3, 1))
-        w = jnp.transpose(w, (2, 3, 1, 0))
-    out = jax.lax.conv_general_dilated(
+    return jax.lax.conv_general_dilated(
         x, w,
         window_strides=strides,
         padding=[(paddings[0], paddings[0]), (paddings[1], paddings[1])],
         rhs_dilation=dilations,
-        dimension_numbers=(("NHWC", "HWIO", "NHWC") if nhwc
-                           else ("NCHW", "OIHW", "NCHW")),
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
         feature_group_count=groups,
     )
-    if nhwc:
-        out = jnp.transpose(out, (0, 3, 1, 2))
-    return out
 
 
 @register_op("conv2d")
@@ -200,32 +181,15 @@ def _bn_bshape(x, ch_axis):
 
 
 def _bn_train(x, scale, bias, red_axes, eps):
-    """Train-mode BN forward, LEFT TO AUTODIFF on purpose (round 3):
-    traced on TPU, XLA fuses the single-pass stats and the coefficient
-    normalize into the producing convolution's fusion, and — decisive —
-    it also fuses the autodiffed backward reductions into the conv
-    gradient fusions. The round-2 hand-written custom_vjp backward
-    (kept below as _bn_train_custom for the A/B) pinned those
-    reductions as standalone convert_reduce fusions: the device trace
-    showed 64 of them costing ~30ms/step vs ~0 for this form."""
-    (y, _m, _v), _res = _bn_train_fwd(x, scale, bias, red_axes, eps)
-    return y
-
-
-# round-2 variant: same forward under a custom_vjp with the
-# hand-derived 2-pass backward. Superseded as the default (see
-# _bn_train) but kept selectable for A/Bs via PADDLE_TPU_BN_CUSTOM_VJP.
-_bn_train_custom = functools.partial(jax.custom_vjp,
-                                     nondiff_argnums=(3, 4))(_bn_train)
-
-
-def _bn_train_fwd(x, scale, bias, red_axes, eps):
-    """Single-pass stats (sum / sum-of-squares fuse into ONE sweep over
-    x) + a coefficient-form normalize (y = x*a + b with per-channel
-    a,b). Written this way so XLA can fuse both the stats and the
-    normalize into the producing conv's fusion — and, under autodiff
-    (the default path), the backward reductions into the conv gradient
-    fusions; see _bn_train."""
+    """Train-mode BN forward: single-pass stats (sum / sum-of-squares
+    fuse into ONE sweep over x) + a coefficient-form normalize
+    (y = x*a + b with per-channel a,b), so that XLA can fuse both into
+    the producing conv's fusion. The backward is LEFT TO AUTODIFF on
+    purpose (round 3): traced on TPU, XLA also fuses the autodiffed
+    backward reductions into the conv gradient fusions, where a
+    hand-written custom_vjp backward pinned them as standalone
+    convert_reduce fusions (64 of them, ~30ms/step in the device
+    trace, against ~0 for this form)."""
     ch_axis = [i for i in range(x.ndim) if i not in red_axes][0]
     bshape = _bn_bshape(x, ch_axis)
     n = 1
@@ -239,36 +203,7 @@ def _bn_train_fwd(x, scale, bias, red_axes, eps):
     inv = jax.lax.rsqrt(var + eps)
     a = scale * inv                      # [C] f32
     b = bias - mean * a
-    y = (xf * a.reshape(bshape) + b.reshape(bshape)).astype(x.dtype)
-    return (y, mean, var), (x, scale, mean, inv)
-
-
-def _bn_train_bwd(red_axes, eps, res, dy):
-    x, scale, mean, inv = res
-    ch_axis = [i for i in range(x.ndim) if i not in red_axes][0]
-    bshape = _bn_bshape(x, ch_axis)
-    n = 1
-    for i in red_axes:
-        n *= x.shape[i]
-    xf = x.astype(jnp.float32)
-    dyf = dy.astype(jnp.float32)
-    xhat = (xf - mean.reshape(bshape)) * inv.reshape(bshape)
-    # pass 1: both channel reductions in one sweep over (x, dy)
-    dbias = jnp.sum(dyf, axis=red_axes)
-    dscale = jnp.sum(dyf * xhat, axis=red_axes)
-    # pass 2: dx
-    coef = (scale * inv).reshape(bshape)
-    dx = coef * (dyf - (dbias.reshape(bshape)
-                        + xhat * dscale.reshape(bshape)) / n)
-    return dx.astype(x.dtype), dscale, dbias
-
-
-def _bn_train_vjp_fwd(x, scale, bias, red_axes, eps):
-    (y, _m, _v), res = _bn_train_fwd(x, scale, bias, red_axes, eps)
-    return y, res
-
-
-_bn_train_custom.defvjp(_bn_train_vjp_fwd, _bn_train_bwd)
+    return (xf * a.reshape(bshape) + b.reshape(bshape)).astype(x.dtype)
 
 
 @register_op("batch_norm")
@@ -301,12 +236,9 @@ def _batch_norm(ctx):
         ctx.set_output("SavedVariance", var_in)
         return
 
-    if os.environ.get("PADDLE_TPU_BN_CUSTOM_VJP", "0") == "1":
-        y = _bn_train_custom(x, scale, bias, red_axes, eps)  # round-2 A/B
-    else:
-        y = _bn_train(x, scale, bias, red_axes, eps)
-    # stats recomputed OUTSIDE the custom_vjp so running-stat updates
-    # carry no gradient plumbing; XLA CSEs them with the fwd pass sums
+    y = _bn_train(x, scale, bias, red_axes, eps)
+    # the running-stat updates take their own sums; XLA CSEs them with
+    # the forward's
     xf = x.astype(jnp.float32)
     n = 1
     for i in red_axes:
@@ -419,50 +351,6 @@ def _cross_entropy(ctx):
     ctx.set_output("Y", loss)
 
 
-@jax.custom_vjp
-def _softmax_xent_hard(logits, lab):
-    loss, _ = _softmax_xent_hard_fwd(logits, lab)
-    return loss
-
-
-def _softmax_xent_hard_fwd(logits, lab):
-    """Hard-label softmax cross-entropy that never materializes a
-    full-vocab f32 buffer: loss_i = logsumexp(x_i) - x_i[label]. The
-    f32 upcast fuses into the two reductions, so big-vocab heads (e.g.
-    the transformer's [B*S, 32k] logits — ~17% of the step in the
-    device trace) stream at bf16 width."""
-    xf = logits.astype(jnp.float32)
-    m = jnp.max(xf, axis=-1, keepdims=True)
-    z = m + jnp.log(jnp.sum(jnp.exp(xf - m), axis=-1, keepdims=True))
-    picked = jnp.take_along_axis(xf, lab[..., None], axis=-1)
-    loss = z - picked
-    return loss, (logits, lab, z)
-
-
-def _softmax_xent_hard_bwd(res, g):
-    logits, lab, z = res
-    xf = logits.astype(jnp.float32)
-    p = jnp.exp(xf - z)                       # softmax, one fused pass
-    dl = p * g                                # g: [..., 1] cotangent
-    # subtract g at the label position (the one-hot term) via scatter
-    sub = jnp.take_along_axis(dl, lab[..., None], axis=-1) - g
-    dl = _put_along_axis(dl, lab[..., None], sub)
-    return dl.astype(logits.dtype), None
-
-
-def _put_along_axis(a, idx, vals):
-    """a.at[..., idx].set(vals) along the last axis."""
-    flat_a = a.reshape(-1, a.shape[-1])
-    flat_i = idx.reshape(-1)
-    flat_v = vals.reshape(-1)
-    rows = jnp.arange(flat_a.shape[0])
-    out = flat_a.at[rows, flat_i].set(flat_v)
-    return out.reshape(a.shape)
-
-
-_softmax_xent_hard.defvjp(_softmax_xent_hard_fwd, _softmax_xent_hard_bwd)
-
-
 @register_op("softmax_with_cross_entropy", no_grad_slots=["Label"])
 def _softmax_with_cross_entropy(ctx):
     logits = ctx.input("Logits")
@@ -477,17 +365,8 @@ def _softmax_with_cross_entropy(ctx):
     lab = label.reshape(label.shape[:-1]) if label.shape[-1] == 1 \
         else label
     lab = lab.astype(jnp.int32)
-    if os.environ.get("PADDLE_TPU_FUSED_XENT", "0") == "1":
-        # streaming custom-vjp variant: never materializes a full-vocab
-        # f32 buffer — keeps peak memory O(bf16 logits) for very large
-        # vocabularies. A/B on v5e at 32k vocab measured it 15% SLOWER
-        # than XLA's autodiffed log_softmax (the backward scatter beats
-        # the saved bandwidth only when memory is the binding
-        # constraint), so it is opt-in.
-        loss = _softmax_xent_hard(logits, lab)
-    else:
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        loss = -jnp.take_along_axis(logp, lab[..., None], axis=-1)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    loss = -jnp.take_along_axis(logp, lab[..., None], axis=-1)
     # Softmax output computed independently; dead-code-eliminated by
     # XLA unless a consumer actually reads it
     ctx.set_output("Softmax",
@@ -881,18 +760,11 @@ def _sdpa(ctx):
     if use_flash is None:
         # the op leaves the choice open: PADDLE_TPU_PALLAS_SDPA decides.
         # "force" engages the kernel anywhere, "0" pins the composition,
-        # "1" (a TPU only) leaves it to the measured crossover on v5e
-        # (bf16, h8 d64, fwd+bwd, marginal protocol): naive/XLA wins
-        # 1.56x at S=256, parity at S=512, flash wins 2.5x at S=1024 and
-        # 5.6x at S=4096 — the S^2 score materialization only starts to
-        # bind around 512. Round 2's threshold of 128 routed the
-        # transformer bench's S=256 through flash and cost it ~35%
-        # end-to-end. (Round-3 numbers, taken before this tree's first
-        # chip_smoke.py run; not re-measured.)
+        # "1" (a TPU only) leaves it to the flash module's crossover
         from .pallas import pallas_dispatch
+        from .pallas.flash_attention import FLASH_CROSSOVER_SEQ as min_seq
         enabled, interp = pallas_dispatch("PADDLE_TPU_PALLAS_SDPA", "1")
         forced = interp is None
-        min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "512"))
         use_flash = (enabled and q.ndim == 4
                      and (forced or (q.shape[seq_dim] >= min_seq
                                      and k.shape[seq_dim] >= min_seq)))

@@ -7,8 +7,6 @@ Pallas kernels; everything else rides XLA fusion:
 
   flash_attention    tiled attention, forward and backward
   fused_lstm / gru   the recurrent time loop, state kept in VMEM
-  fused_conv         conv with BatchNorm's sweeps folded in (ResNet)
-  block_megakernel   a whole ResNet bottleneck block, tiled by batch
   kv_cache_append    the decode step's cache append: every slot's new
                      K or V row in one call, the cache updated in place
   decode_attention   the decode step's attention over the KV cache: one

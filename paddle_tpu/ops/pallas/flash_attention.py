@@ -84,6 +84,17 @@ def _ceil_to(x: int, m: int) -> int:
 from . import interpret_default as _interpret_default  # shared policy
 
 
+# The shortest sequence a dispatcher that was left the choice hands to
+# these kernels (ops/nn_ops.py _sdpa: q AND k at least this long;
+# parallel/context_parallel.py: the gathered q). The measured crossover
+# on v5e (bf16, h8 d64, fwd+bwd, marginal protocol): naive/XLA wins
+# 1.56x at S=256, parity at S=512, flash wins 2.5x at S=1024 and 5.6x
+# at S=4096 — the S^2 score materialization only starts to bind around
+# 512. Round 2's threshold of 128 routed the transformer bench's S=256
+# through flash and cost it ~35% end-to-end. (Round-3 numbers, taken
+# before this tree's first chip_smoke.py run; not re-measured.)
+FLASH_CROSSOVER_SEQ = 512
+
 # What one call may plan to keep in VMEM: half of a v5e core's 128 MiB,
 # the rest being Mosaic's own. A call asks for what it counted
 # (vmem_limit_bytes), not for the default scoped 16 MiB.

@@ -11,8 +11,6 @@ compiles one fused loop body instead of per-timestep kernel launches.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -272,21 +270,10 @@ def _row_conv(ctx):
 
 # -- recurrent nets ---------------------------------------------------------
 
-def _rnn_unroll():
-    """Scan unroll factor, read at trace time (PADDLE_TPU_RNN_UNROLL,
-    1 disables). Unrolling amortizes loop overhead across the small
-    per-step recurrent matmuls; A/B on real TPU: unroll=4 ~ +30%
-    tokens/s on the LSTM-LM bench (unroll=8 regressed)."""
-    raw = os.environ.get("PADDLE_TPU_RNN_UNROLL", "4")
-    try:
-        return max(int(raw), 1)
-    except ValueError:
-        if raw.strip().lower() in ("off", "false", "no", "none",
-                                   "disabled", ""):
-            return 1
-        raise ValueError(
-            f"PADDLE_TPU_RNN_UNROLL={raw!r}: expected an integer or a "
-            "disable word (off/false/no/none/disabled)")
+# Unrolling amortizes the loop's overhead across the small per-step
+# recurrent matmuls: 4 won (~ +30% tokens/s on the LSTM-LM bench, A/B on
+# a real TPU), 8 regressed.
+_SCAN_UNROLL = 4
 
 
 def _masked_scan_rnn(step, xs, init_states, lengths):
@@ -314,7 +301,7 @@ def _masked_scan_rnn(step, xs, init_states, lengths):
 
     xs_t = jnp.moveaxis(xs, 1, 0)  # [t, n, ...]
     carry, outs = jax.lax.scan(body, init_states, (tpos, xs_t),
-                               unroll=_rnn_unroll())
+                               unroll=_SCAN_UNROLL)
     if isinstance(outs, tuple):
         return carry, tuple(jnp.moveaxis(o, 0, 1) for o in outs)
     return carry, jnp.moveaxis(outs, 0, 1)

@@ -233,20 +233,20 @@ def assert_collectives(inv, expectations, forbid=()) -> None:
                 "inventory:\n" + format_inventory(inv))
 
 
-def aot_compiled_for(exe, program, scope=None):
+def aot_compiled_for(exe, program):
     """AOT re-lower + compile the cached executable for `program` in
     executor `exe`, with the abstract arguments of the run that compiled
     it: the look-up by uid in front of `CompiledProgram.lower_again`,
     the one implementation of lowering a cache entry again (used by the
-    collective audit, bench.py's cost analysis, the benchmark's memory
-    reading and the op table). `scope` is accepted and not read: the
-    entry keeps its own abstract values since PR 39."""
+    collective audit, the benchmark's memory reading and the op
+    table). The entry keeps its own abstract values: no scope, feed or
+    open run is needed."""
     uid = program.desc.uid if hasattr(program, "desc") else program.uid
     entry = next(v for k, v in exe._cache.items() if k[0] == uid)
     return entry.lower_again()
 
 
-def compiled_hlo_for(exe, program, scope=None) -> str:
+def compiled_hlo_for(exe, program) -> str:
     """Compiled HLO text of the (single) cached executable for
     `program` in executor `exe`."""
-    return aot_compiled_for(exe, program, scope=scope).as_text()
+    return aot_compiled_for(exe, program).as_text()

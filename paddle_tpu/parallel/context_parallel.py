@@ -23,7 +23,6 @@ float32 regardless of input dtype.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -203,12 +202,11 @@ def ulysses_attention(q, k, v, kv_mask=None, axis_name: str = "seq",
         full = jax.lax.all_gather(kv_mask, axis_name, axis=1, tiled=True)
         bias = full[:, None, None, :]                  # [B,1,1,Sk]
     if use_flash is None:
-        # one routing policy with ops/nn_ops._sdpa: the measured v5e
-        # crossover puts flash ahead of the naive composition only
-        # from gathered S ~512
-        min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "512"))
+        # one routing policy with ops/nn_ops._sdpa: the flash module's
+        # crossover, held against the GATHERED sequence
+        from ..ops.pallas.flash_attention import FLASH_CROSSOVER_SEQ
         use_flash = (jax.default_backend() == "tpu"
-                     and qf.shape[2] >= min_seq)
+                     and qf.shape[2] >= FLASH_CROSSOVER_SEQ)
     if use_flash:
         from ..ops.pallas import flash_attention
         of = flash_attention(qf, kf, vf, bias, causal=causal,

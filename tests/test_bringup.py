@@ -80,7 +80,7 @@ def test_native_library_rebuilds_when_a_source_is_newer(
     assert native._stale() is stale
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_chip_entry_points_refuse_a_machine_without_a_tpu(script):
     """No fallback to the CPU under a device metric's name: non-zero
     exit and nothing on stdout."""

@@ -22,6 +22,12 @@ metric-name lint from the observability PR (tests/test_metric_names.py):
 And two checks of the flag table (paddle_tpu/flags.py) against the
 code: a flag whose reader was deleted must leave the table with it, and
 an environment variable the package reads must be in the table.
+
+And the op layer's rule: a kernel file under ops/pallas/ is reached
+from an op rule, an op rule chooses its path from what it is handed or
+through the one test lever (ops/pallas/__init__.py pallas_dispatch) and
+never from an environment read of its own, and the flash crossover has
+one owner.
 """
 import ast
 import os
@@ -223,7 +229,7 @@ def test_lint_rules_allow_benign_forms(snippet):
 # the flag table against the code
 # ---------------------------------------------------------------------------
 _ROOT = os.path.dirname(_PKG)
-_FLAG_NAME = re.compile(r"(PADDLE_TPU|BENCH)_[A-Z0-9_]+")
+_FLAG_NAME = re.compile(r"PADDLE_TPU_[A-Z0-9_]+")
 
 
 def _string_constants(paths):
@@ -243,11 +249,11 @@ def _package_code():
 
 def test_every_registered_flag_is_read():
     """A name in flags.FLAGS is a string some code hands to an
-    environment read: in the package or, for the BENCH_* rows, in
-    bench.py / benchmarks/. A flag left in the table after its reader
-    went fails here."""
+    environment read: in the package or in the parity scripts under
+    benchmarks/. A flag left in the table after its reader went fails
+    here."""
     from paddle_tpu import flags
-    harness = [os.path.join(_ROOT, "bench.py")] + [
+    harness = [
         os.path.join(_ROOT, "benchmarks", f)
         for f in sorted(os.listdir(os.path.join(_ROOT, "benchmarks")))
         if f.endswith(".py")]
@@ -259,7 +265,7 @@ def test_every_registered_flag_is_read():
 
 
 def test_every_env_read_is_registered():
-    """Every PADDLE_TPU_* / BENCH_* name the package uses as a string
+    """Every PADDLE_TPU_* name the package uses as a string
     (os.environ.get and the helpers that wrap it) has its row in
     flags.FLAGS."""
     from paddle_tpu import flags
@@ -270,3 +276,91 @@ def test_every_env_read_is_registered():
     assert not missing, (
         "environment variables read but not in paddle_tpu/flags.py: "
         f"{missing}")
+
+
+# -- the op layer ------------------------------------------------------------
+
+_PALLAS = os.path.join(_PKG, "ops", "pallas")
+_KERNELS = sorted(f[:-3] for f in os.listdir(_PALLAS)
+                  if f.endswith(".py") and f != "__init__.py")
+
+
+def _imports(path):
+    """(absolute module, imported name) of every `from m import n` and
+    (module, None) of every `import m` in `path`, relative imports
+    resolved against the file's own package."""
+    pkg = os.path.relpath(os.path.dirname(path), _ROOT).split(os.sep)
+    for node in ast.walk(_parse(path)):
+        if isinstance(node, ast.Import):
+            for a in node.names:
+                yield a.name, None
+        elif isinstance(node, ast.ImportFrom):
+            base = pkg[:len(pkg) - node.level + 1] if node.level else []
+            mod = ".".join(base + ([node.module] if node.module else []))
+            for a in node.names:
+                yield mod, a.name
+
+
+@pytest.mark.parametrize("kernel", _KERNELS)
+def test_every_pallas_kernel_is_reached_from_an_op_rule(kernel):
+    """A kernel file nothing in the package imports has no op rule in
+    front of it: no cell, model or user program can run it, and its
+    only readers are its own tests. It goes, or an op rule takes it."""
+    exported = {n for m, n in _imports(os.path.join(_PALLAS, "__init__.py"))
+                if m == f"paddle_tpu.ops.pallas.{kernel}"}
+    users = []
+    for path in _py_files():
+        if os.path.dirname(path) == _PALLAS:
+            continue
+        for mod, name in _imports(path):
+            full = mod if name is None else f"{mod}.{name}"
+            if (full == f"paddle_tpu.ops.pallas.{kernel}"
+                    or mod == f"paddle_tpu.ops.pallas.{kernel}"
+                    or (mod == "paddle_tpu.ops.pallas"
+                        and name in exported)):
+                users.append(_rel(path))
+    assert users, (
+        f"ops/pallas/{kernel}.py is imported by no module of paddle_tpu/ "
+        "outside ops/pallas/")
+
+
+_OP_RULE_FILES = ["ops/nn_ops.py", "ops/sequence_ops.py", "ops/moe_ops.py",
+                  "ops/cache_ops.py", "ops/control_flow_ops.py",
+                  "parallel/context_parallel.py"]
+
+
+@pytest.mark.parametrize("rel", _OP_RULE_FILES)
+def test_op_rules_read_no_environment(rel):
+    """An op rule is traced from shapes, dtypes, the backend and the
+    mesh; the one environment reader the op layer has is
+    ops/pallas/__init__.py pallas_dispatch, the tests' lever onto each
+    kernel's path. A switch inside a rule is a path no cell measures."""
+    with open(os.path.join(_PKG, rel), encoding="utf-8") as f:
+        src = f.read()
+    reads = re.findall(r"\bos\.(?:environ|getenv)\b|\bfrom os import\b",
+                       src)
+    assert not reads, f"{rel} reads the environment: {reads}"
+
+
+def test_flash_crossover_has_one_owner():
+    """The sequence length from which a dispatcher that was left the
+    choice takes the flash kernels is ONE constant of the flash module:
+    ops/nn_ops.py _sdpa and parallel/context_parallel.py both import it
+    and nothing else defines it."""
+    owner = "paddle_tpu.ops.pallas.flash_attention"
+    taken = {}
+    for rel in ("ops/nn_ops.py", "parallel/context_parallel.py"):
+        taken[rel] = {n for m, n in _imports(os.path.join(_PKG, rel))
+                      if m == owner and n and n.isupper()}
+    names = set().union(*taken.values())
+    assert len(names) == 1 and all(taken.values()), taken
+    (name,) = names
+    defined = []
+    for path in _py_files():
+        for node in ast.walk(_parse(path)):
+            targets = node.targets if isinstance(node, ast.Assign) else (
+                [node.target] if isinstance(node, ast.AnnAssign) else [])
+            if any(isinstance(t, ast.Name) and t.id == name
+                   for t in targets):
+                defined.append(_rel(path))
+    assert defined == ["paddle_tpu/ops/pallas/flash_attention.py"], defined

@@ -213,9 +213,9 @@ def test_aot_compiled_for_goes_through_the_entry(trained, monkeypatch):
     real = entry.lower_again
     monkeypatch.setattr(entry, "lower_again",
                         lambda: calls.append(1) or real())
-    # neither the scope's state nor the last feed is read any more
+    # the last feed is not read: the entry keeps its abstract values
     exe._last_feed_vals = None
-    compiled = aot_compiled_for(exe, main, scope=pt.Scope())
+    compiled = aot_compiled_for(exe, main)
     assert calls == [1] and "HloModule" in compiled.as_text()
 
 

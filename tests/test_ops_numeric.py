@@ -2,6 +2,8 @@
 (reference: the 202 per-op unittests built on op_test.py; this battery
 covers one op per family — dense math, conv, norm, softmax/xent, pooling,
 embedding lookup, sequence/ragged, broadcasting elementwise, reduction)."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.core.lod import LoDTensor, RaggedPair
@@ -78,6 +80,34 @@ def test_conv2d_op():
     t.check_output({"Output": conv(x, w, 1)}, atol=1e-4, rtol=1e-4)
     t.check_grad(["x", "w"], output_slot="Output",
                  max_relative_error=1e-2)
+
+
+def test_strided_1x1_conv_subsample_rewrite_exact():
+    """ops/nn_ops.py lowers a strided 1x1 conv to subsample + stride-1
+    conv (clean MXU gradients); forward must be bit-identical to the
+    strided lax.conv and gradients must match autodiff of it."""
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(2, 8, 10, 10), jnp.float32)
+    w = jnp.asarray(rng.randn(16, 8, 1, 1) * 0.2, jnp.float32)
+    from paddle_tpu.ops.nn_ops import _conv2d_impl
+
+    def direct(x, w):
+        return jax.lax.conv_general_dilated(
+            x, w, (2, 2), [(0, 0), (0, 0)],
+            dimension_numbers=("NCHW", "OIHW", "NCHW"))
+
+    y1 = _conv2d_impl(x, w, (2, 2), (0, 0), (1, 1), 1)
+    y2 = direct(x, w)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
+                               rtol=1e-6, atol=1e-6)
+    g1 = jax.grad(lambda x, w: jnp.sum(
+        jnp.sin(_conv2d_impl(x, w, (2, 2), (0, 0), (1, 1), 1))),
+        argnums=(0, 1))(x, w)
+    g2 = jax.grad(lambda x, w: jnp.sum(jnp.sin(direct(x, w))),
+                  argnums=(0, 1))(x, w)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_pool2d_max():

@@ -351,9 +351,6 @@ def test_attention_routing_threshold(monkeypatch):
 
     monkeypatch.setattr(pallas_pkg, "flash_attention", fake_flash)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    # pin the DEFAULT threshold — an exported tuning knob must not
-    # flip the boundary this test asserts
-    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
 
     for seq, expect_flash in ((512, True), (256, False)):
         pt.reset_default_programs()

@@ -319,7 +319,7 @@ def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_
         sorted(set(score_sized))
 
 
-# bench.py's stacked-LSTM LM shapes (T=64, B=64, H=512): fused_lstm and
+# the stacked-LSTM LM's shapes (T=64, B=64, H=512; chip_smoke.py's rnn): fused_lstm and
 # fused_gru are ON by default on a TPU (ops/sequence_ops.py), so every
 # LSTM/GRU user reaches them; bf16 is what AMP hands them.
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
