@@ -372,6 +372,32 @@ def _flops_for(op: ir.OpDesc,
         return (2 * lead * pairs * (d + d_v) + 5 * lead * pairs,
                 True, None)
 
+    if t == "ssd_prefill":
+        # the chunked scan (ops/ssm_ops.py): inside a chunk the scores
+        # C B^T and the masked product with X, between chunks what each
+        # leaves its end and what the carried state gives each row
+        x, b = first("X"), first("B")
+        if x is None or b is None or len(x.shape) != 3:
+            return None, False, None
+        n, s, cols = x.shape
+        q = min(int(op.attrs.get("chunk", 256)), s)
+        d_state = b.shape[-1]
+        return (2 * n * s * (q * d_state + q * cols + 2 * d_state * cols),
+                True, None)
+
+    if t == "ssm_state_update":
+        # decay, the outer product's add, the contraction with C: five
+        # operations an element of the state
+        st = first("State")
+        return (None, False, None) if st is None else \
+            (5 * st.numel, True, None)
+
+    if t in ("causal_conv1d", "conv_state_update"):
+        x, w = first("X"), first("W")
+        if x is None or w is None:
+            return None, False, None
+        return 2 * w.shape[0] * x.numel, True, None
+
     if t in ("lstm", "gru"):
         # the fused recurrence mega-ops (ops/sequence_ops.py, Pallas
         # fused_lstm/fused_gru): the per-step recurrent matmul
@@ -465,6 +491,14 @@ def _bytes_override(op: ir.OpDesc,
                 if v is not None:
                     idx += v.bytes
         return 2 * new_b + idx, "kv cache: updated rows only"
+    if op.type == "slot_state_write":
+        # as kv_cache_write: the slot's rows, not the whole array
+        total = 0
+        for slot in ("New", "Slot"):
+            v = lookup(op.input(slot)[0]) if op.input(slot) else None
+            if v is not None:
+                total += v.bytes * (2 if slot == "New" else 1)
+        return total, "slot state: the written slot only"
     if op.type in ("sparse_sgd", "sparse_adagrad", "sparse_adam"):
         # sparse apply touches the DEDUPED rows only: the generic walk
         # would charge the full [vocab, dim] param (and each slot) as

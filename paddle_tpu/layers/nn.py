@@ -784,13 +784,19 @@ def row_conv(input, future_context_size, param_attr=None, act=None):
 
 # -- math wrappers ----------------------------------------------------------
 
-def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
+def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
+           out_dtype=None):
+    """``out_dtype`` (left out of the op where None): the width the
+    product is accumulated to AND returned at, whatever the operands'
+    — float32 logits from a bfloat16 head."""
     helper = LayerHelper("matmul", name=name)
-    out = helper.create_tmp_variable(x.dtype)
+    out = helper.create_tmp_variable(out_dtype or x.dtype)
+    attrs = {"transpose_X": transpose_x, "transpose_Y": transpose_y,
+             "alpha": alpha}
+    if out_dtype is not None:
+        attrs["out_dtype"] = out_dtype
     helper.append_op(type="matmul", inputs={"X": x, "Y": y},
-                     outputs={"Out": out},
-                     attrs={"transpose_X": transpose_x,
-                            "transpose_Y": transpose_y, "alpha": alpha})
+                     outputs={"Out": out}, attrs=attrs)
     return out
 
 

@@ -100,7 +100,10 @@ def _matmul(ctx):
         x = jnp.swapaxes(x, -1, -2) if x.ndim > 1 else x
     if ctx.attr("transpose_Y", False):
         y = jnp.swapaxes(y, -1, -2) if y.ndim > 1 else y
-    out = _mxu_matmul(x, y)
+    wide = ctx.attr("out_dtype", None)
+    # attr out_dtype: accumulated to and returned at that width
+    out = _mxu_matmul(x, y) if wide is None else jnp.matmul(
+        x, y, preferred_element_type=jnp_dtype(wide))
     alpha = ctx.attr("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha

@@ -703,15 +703,15 @@ def _sdpa(ctx):
             raise ValueError("scaled_dot_product_attention: KvLen reads "
                              "KV caches, which are head-major [slots, h, "
                              "max_seq, d]: layout 'bshd' does not apply")
-        if mask is not None or causal or group != 1:
+        if mask is not None or causal:
             raise ValueError("scaled_dot_product_attention: KvLen "
-                             "stands in for the mask and for causality, "
-                             "over as many key heads as query heads")
+                             "stands in for the mask and for causality")
         bound = int(ctx.attr("kv_bound", k.shape[2]))
         lane_axis = _decode_kernel_lane_axis(ctx, q, k, bound)
         if lane_axis is not None:
             from .pallas.decode_attention import decode_attention
-            _count_sdpa_site(ctx, "decode_kernel", "kv_len", causal)
+            _count_sdpa_site(ctx, "decode_kernel", "kv_len", causal,
+                             group=group)
             ctx.set_output("Out", decode_attention(
                 q, k, v, kv_len, bound=bound, lane_axis=lane_axis))
             return

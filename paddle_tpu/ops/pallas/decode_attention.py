@@ -92,8 +92,16 @@ def _work_list(kv_len, bound, rows):
 
 def _kernel(n_ref, slot_ref, block_ref, len_ref,      # scalar prefetch
             q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *, rows, scale):
+            k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *, rows, scale,
+            group=1):
     n_items = n_ref[0]
+    key_heads = k_buf.shape[1]
+    # the query heads that read key head h are rows g * key_heads + h
+    # of q, o and the accumulators, g < group: one pass over the block
+    # for each g (one over all rows where a key head has one query head)
+    passes = [(slice(None), Ellipsis)] if group == 1 else [
+        (slice(g * key_heads, (g + 1) * key_heads),) * 2
+        for g in range(group)]
 
     def copies(item, buf):
         at = (slot_ref[item], slice(None), slice(None),
@@ -127,8 +135,9 @@ def _kernel(n_ref, slot_ref, block_ref, len_ref,      # scalar prefetch
         base = pl.multiple_of(s // LANES * LANES, LANES)
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, LANES), 2)
         mine = lane == s % LANES
-        q = jnp.sum(jnp.where(mine, q_ref[:, :, pl.ds(base, LANES)], 0.0),
-                    axis=2, keepdims=True)            # [h, d, 1]
+        qs = [jnp.sum(jnp.where(mine, q_ref[hq, :, pl.ds(base, LANES)],
+                                0.0), axis=2, keepdims=True)
+              for hq, _ in passes]                    # [h, d, 1] each
 
         @pl.when(j == 0)
         def _():
@@ -140,23 +149,27 @@ def _kernel(n_ref, slot_ref, block_ref, len_ref,      # scalar prefetch
             c.wait()
         k = k_buf[buf].astype(jnp.float32)            # [h, d, rows]
         v = v_buf[buf].astype(jnp.float32)
-        scores = jnp.sum(q * k, axis=1, keepdims=True) * scale
-        pos = j * rows + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, rows), 2)
-        scores = jnp.where(pos < kv_len, scores, _NEG)  # [h, 1, rows]
-        m_prev = m_ref[...]                           # [h, 1, LANES]
-        m_new = jnp.maximum(
-            m_prev, jnp.max(scores, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new[:, :, :1])
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
-        m_ref[...] = m_new
-        pv = p * v                                    # [h, d, rows]
-        # the lanes are summed once a slot, not once a block
-        part = pv[:, :, :LANES]
-        for c in range(1, rows // LANES):
-            part = part + pv[:, :, c * LANES:(c + 1) * LANES]
-        acc_ref[...] = alpha * acc_ref[...] + part
+        pos = None
+        for q, (_, at) in zip(qs, passes):
+            scores = jnp.sum(q * k, axis=1, keepdims=True) * scale
+            if pos is None:
+                pos = j * rows + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, 1, rows), 2)
+            scores = jnp.where(pos < kv_len, scores, _NEG)  # [h, 1, rows]
+            m_prev = m_ref[at]                        # [h, 1, LANES]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(scores, axis=2, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new[:, :, :1])
+            l_ref[at] = alpha * l_ref[at] \
+                + jnp.sum(p, axis=2, keepdims=True)
+            m_ref[at] = m_new
+            pv = p * v                                # [h, d, rows]
+            # the lanes are summed once a slot, not once a block
+            part = pv[:, :, :LANES]
+            for c in range(1, rows // LANES):
+                part = part + pv[:, :, c * LANES:(c + 1) * LANES]
+            acc_ref[at] = alpha * acc_ref[at] + part
 
         @pl.when((j + 1) * rows >= kv_len)
         def _():
@@ -175,16 +188,23 @@ def _kernel(n_ref, slot_ref, block_ref, len_ref,      # scalar prefetch
 @functools.partial(jax.jit, static_argnames=("bound", "rows", "interpret"))
 def _attend(q, k_cache, v_cache, kv_len, *, bound, rows, interpret):
     slots, heads, _, d_key = k_cache.shape
+    group = q.shape[1] // heads
     kv_len = jnp.clip(kv_len.astype(jnp.int32), 0, bound)
     n_items, item_slot, item_block = _work_list(kv_len, bound, rows)
     # the query rows as the cache holds its rows: d_key on the
     # sublanes, one slot a lane
-    q_t = jnp.transpose(q[:, :, 0, :].astype(jnp.float32), (1, 2, 0))
+    q_rows = q[:, :, 0, :]
+    if group != 1:
+        # query head h * group + g reads key head h: rows g * heads + h
+        q_rows = q_rows.reshape(slots, heads, group, d_key).swapaxes(
+            1, 2).reshape(slots, group * heads, d_key)
+    q_t = jnp.transpose(q_rows.astype(jnp.float32), (1, 2, 0))
     q_t = jnp.pad(q_t, ((0, 0), (0, 0), (0, -slots % LANES)))
     whole = pl.BlockSpec(q_t.shape, lambda i, *_: (0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, rows=rows,
-                          scale=float(1.0 / np.sqrt(d_key))),
+                          scale=float(1.0 / np.sqrt(d_key)),
+                          **({} if group == 1 else {"group": group})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(1,),
             in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
@@ -194,22 +214,26 @@ def _attend(q, k_cache, v_cache, kv_len, *, bound, rows, interpret):
                 pltpu.VMEM((2, heads, d_key, rows), k_cache.dtype),
                 pltpu.VMEM((2, heads, d_key, rows), v_cache.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((heads, 1, LANES), jnp.float32),
-                pltpu.VMEM((heads, 1, LANES), jnp.float32),
-                pltpu.VMEM((heads, d_key, LANES), jnp.float32)]),
+                pltpu.VMEM((group * heads, 1, LANES), jnp.float32),
+                pltpu.VMEM((group * heads, 1, LANES), jnp.float32),
+                pltpu.VMEM((group * heads, d_key, LANES), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q_t.shape, jnp.float32),
         name="decode_attention",
         interpret=interpret,
     )(n_items, item_slot, item_block, kv_len, q_t,
       jnp.swapaxes(k_cache, 2, 3), jnp.swapaxes(v_cache, 2, 3))
-    return jnp.transpose(out[:, :, :slots], (2, 0, 1))[:, :, None, :] \
-        .astype(q.dtype)
+    out = jnp.transpose(out[:, :, :slots], (2, 0, 1))
+    if group != 1:
+        out = out.reshape(slots, group, heads, -1).swapaxes(1, 2).reshape(
+            slots, group * heads, -1)
+    return out[:, :, None, :].astype(q.dtype)
 
 
 def decode_attention(q, k_cache, v_cache, kv_len, *, bound, lane_axis=2,
                      interpret=None):
     """softmax(q k^T / sqrt(d_key)) v over keys ``[0, kv_len[s])`` of
-    every slot ``s``: ``q`` [slots, heads, 1, d_key], the caches
+    every slot ``s``: ``q`` [slots, group * heads, 1, d_key] (query
+    head h * group + g reads key head h), the caches
     [slots, heads, max_seq, d_key], ``kv_len`` [slots] int, clipped to
     ``[0, bound]``; a slot of length 0 gets zeros. ``bound`` (static)
     is the most any slot may hold this step, ``lane_axis`` the axis of

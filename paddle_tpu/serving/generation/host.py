@@ -29,7 +29,7 @@ from typing import Dict, Optional, Union
 from ...observability.registry import MetricsRegistry, default_registry
 from ...resilience.health import HealthMonitor
 from ..admission import ServiceOverloadedError
-from .engine import GenerationConfig, GenerationEngine, GenerationFuture
+from .engine import GenerationConfig, GenerationFuture
 from .metrics import GenerationMetrics
 from .model import GenerationModel, GenerationSpec
 
@@ -154,9 +154,8 @@ class GenerationHost:
     def _start_engine(self, name, gmodel, budget, mode) -> _Hosted:
         metrics = GenerationMetrics(registry=self._registry,
                                     label=f"{self.host_label}_{name}")
-        engine = GenerationEngine(gmodel, config=self._config,
-                                  metrics=metrics,
-                                  health=HealthMonitor(), mode=mode)
+        engine = gmodel.serve(config=self._config, metrics=metrics,
+                              health=HealthMonitor(), mode=mode)
         engine.start()
         return _Hosted(gmodel, engine, metrics,
                        int(budget) if budget is not None

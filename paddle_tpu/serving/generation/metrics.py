@@ -104,6 +104,13 @@ class GenerationMetrics:
             "reads) and skipped (the rest of the blocks under the "
             "step's bucket, which it leaves; attention composed over a "
             "slice reads those too).", ("engine", "state"))
+        self._state_bytes_family = reg.gauge(
+            "paddle_tpu_decode_state_bytes",
+            "Bytes reserved on the device for per-slot state, by kind: "
+            "kv (the attention layers' KV caches), conv (the "
+            "state-space layers' convolution windows) and ssm (their "
+            "recurrent states); reserved for every slot to its limit, "
+            "whatever the slots hold.", ("engine", "kind"))
         self.step_seconds = histogram(
             "paddle_tpu_decode_step_seconds",
             "Wall time of one decode step (dispatch to materialized "
@@ -158,6 +165,13 @@ class GenerationMetrics:
             self._kv_blocks_family.labels(engine=self.engine_label,
                                           state=state).inc(n)
 
+    def state_bytes(self, by_kind: Dict[str, int]) -> None:
+        """What the model this engine serves reserves a kind of
+        per-slot state (GenerationModel.state_bytes)."""
+        for kind, n in by_kind.items():
+            self._state_bytes_family.labels(engine=self.engine_label,
+                                            kind=kind).set(n)
+
     def _by_reason(self, family) -> Dict[str, float]:
         out = {}
         for key, child in family.samples():
@@ -185,7 +199,7 @@ class GenerationMetrics:
         for fam in self._owned_families:
             fam.discard(key)
         for family in (self._retired_family, self._shed_family,
-                       self._kv_blocks_family):
+                       self._kv_blocks_family, self._state_bytes_family):
             for k, _ in family.samples():
                 if k[0] == self.engine_label:
                     family.discard(k)
@@ -210,6 +224,7 @@ class GenerationMetrics:
             "retired_by_reason": self._by_reason(self._retired_family),
             "shed_by_reason": self._by_reason(self._shed_family),
             "kv_blocks_by_state": self._by_reason(self._kv_blocks_family),
+            "state_bytes_by_kind": self._by_reason(self._state_bytes_family),
             "mfu": self.mfu.value if self.mfu is not None else 0.0,
         }
         if executor is not None:

@@ -1,11 +1,15 @@
 """GenerationModel: one decoder-LM's full program set (prefill /
-decode-step / re-forward baseline), pinned weights, and KV-cache state
+decode-step / re-forward baseline), pinned weights, and per-slot state
 in a private scope.
 
 The batch-serving analog is ServableModel (one frozen program); a
 generation model is a FAMILY of programs sharing one parameter set by
-name (models/transformer.py build_decoder_lm), plus persistable
-``kv_cache.*`` state the decode programs update in place via donation.
+name, plus persistable per-slot state the decode programs update in
+place via donation. A spec names the architecture family that builds
+its programs (``FAMILIES``): "transformer" (models/transformer.py
+build_decoder_lm: ``kv_cache.*`` state) or "hybrid_ssm"
+(models/hybrid_ssm.py: KV caches beside convolution windows and
+recurrent states, three kinds a slot).
 All programs live in one Executor compile cache — hosting N models on
 a shared executor (GenerationHost) dedupes nothing but ALSO collides
 nothing, because the cache key includes each program's uid/version.
@@ -42,12 +46,22 @@ class GenerationSpec:
     FIELDS = ("vocab_size", "max_seq_len", "slots", "prompt_buckets",
               "cache_buckets", "n_layer", "n_head", "d_model", "d_inner",
               "seed", "eos_id", "kv_cache_layout")
+    # what a family other than the default adds: a saved transformer
+    # spec holds FIELDS alone, as it always has, and loads as one
+    FAMILY_FIELDS = ("family", "arch")
 
     def __init__(self, vocab_size, max_seq_len, slots=None,
                  prompt_buckets=None, cache_buckets=None,
                  n_layer=2, n_head=4, d_model=64, d_inner=128, seed=0,
                  eos_id=0,
-                 kv_cache_layout="[slots, n_head, max_seq_len, d_key]"):
+                 kv_cache_layout="[slots, n_head, max_seq_len, d_key]",
+                 family="transformer", arch=None):
+        """``family`` names the builder (``FAMILIES``); ``arch`` is that
+        family's own description of the stack — for "hybrid_ssm" the
+        keywords of models/hybrid_ssm.py build_hybrid_lm beyond the
+        sizes above: ``arch`` (the published config keys), ``dtypes``,
+        ``embedding_std`` — and None for "transformer", which reads
+        n_layer, n_head, d_model and d_inner."""
         from ... import flags
         if slots is None:
             slots = int(flags.get("PADDLE_TPU_DECODE_SLOTS"))
@@ -73,13 +87,21 @@ class GenerationSpec:
         self.seed = int(seed)
         self.eos_id = int(eos_id)
         self.kv_cache_layout = str(kv_cache_layout)
+        if family not in FAMILIES:
+            raise ValueError(f"generation family {family!r}: one of "
+                             f"{sorted(FAMILIES)}")
+        self.family = str(family)
+        self.arch = dict(arch) if arch else None
 
     def to_dict(self) -> Dict:
-        return {f: getattr(self, f) for f in self.FIELDS}
+        fields = self.FIELDS if self.family == "transformer" \
+            else self.FIELDS + self.FAMILY_FIELDS
+        return {f: getattr(self, f) for f in fields}
 
     @classmethod
     def from_dict(cls, d: Dict) -> "GenerationSpec":
-        return cls(**{f: d[f] for f in cls.FIELDS if f in d})
+        return cls(**{f: d[f] for f in cls.FIELDS + cls.FAMILY_FIELDS
+                      if f in d})
 
     def __eq__(self, other):
         return isinstance(other, GenerationSpec) and \
@@ -89,8 +111,33 @@ class GenerationSpec:
         return f"GenerationSpec({self.to_dict()})"
 
 
+def _build_transformer(spec: GenerationSpec) -> Dict:
+    return build_decoder_lm(
+        vocab_size=spec.vocab_size, max_seq_len=spec.max_seq_len,
+        slots=spec.slots, prompt_buckets=spec.prompt_buckets,
+        cache_buckets=spec.cache_buckets, n_layer=spec.n_layer,
+        n_head=spec.n_head, d_model=spec.d_model,
+        d_inner=spec.d_inner, seed=spec.seed)
+
+
+def _build_hybrid_ssm(spec: GenerationSpec) -> Dict:
+    from ...models.hybrid_ssm import build_hybrid_lm
+    return build_hybrid_lm(
+        vocab_size=spec.vocab_size, max_seq_len=spec.max_seq_len,
+        slots=spec.slots, prompt_buckets=spec.prompt_buckets,
+        cache_buckets=spec.cache_buckets, seed=spec.seed,
+        **(spec.arch or {}))
+
+
+#: family name -> the builder of its program set; each returns what
+#: build_decoder_lm returns, and may add "state_kinds" ({kind: [names]})
+#: and "state_prefixes" where a slot owns more than KV caches
+FAMILIES = {"transformer": _build_transformer,
+            "hybrid_ssm": _build_hybrid_ssm}
+
+
 class GenerationModel:
-    """Program set + weights + KV-cache state for one decoder LM.
+    """Program set + weights + per-slot state for one decoder LM.
 
     ``executor``/``run_lock`` follow the ServableModel sharing
     contract: a GenerationHost passes the same pair to every hosted
@@ -116,7 +163,13 @@ class GenerationModel:
         self._run_lock = run_lock if run_lock is not None \
             else threading.Lock()
         self.version = version
-        self.cache_names = kv_cache_names(spec.n_layer)
+        # every persistable state a slot owns, and its names by kind
+        self.state_kinds = programs.get("state_kinds") \
+            or {"kv": kv_cache_names(spec.n_layer)}
+        self.cache_names = [n for names in self.state_kinds.values()
+                            for n in names]
+        self._state_prefixes = tuple(programs.get(
+            "state_prefixes", (KV_CACHE_PREFIX,)))
         self._check_frozen()
         self._verify()
         if init_scope:
@@ -130,14 +183,8 @@ class GenerationModel:
               run_lock: Optional[threading.Lock] = None,
               version: Optional[str] = None) -> "GenerationModel":
         """Fresh model (randomly initialized weights) from a spec."""
-        programs = build_decoder_lm(
-            vocab_size=spec.vocab_size, max_seq_len=spec.max_seq_len,
-            slots=spec.slots, prompt_buckets=spec.prompt_buckets,
-            cache_buckets=spec.cache_buckets, n_layer=spec.n_layer,
-            n_head=spec.n_head, d_model=spec.d_model,
-            d_inner=spec.d_inner, seed=spec.seed)
-        return cls(programs, spec, executor=executor, run_lock=run_lock,
-                   version=version)
+        return cls(FAMILIES[spec.family](spec), spec, executor=executor,
+                   run_lock=run_lock, version=version)
 
     @classmethod
     def load(cls, dirname: str, executor: Optional[Executor] = None,
@@ -166,7 +213,7 @@ class GenerationModel:
         # overwrite the fresh random weights with the checkpoint's; the
         # full program's persistable set is exactly the weights (no
         # cache vars), so caches stay zero
-        full = model.programs["full"][spec.prompt_buckets[-1]]
+        full = model._full(spec.prompt_buckets[-1])
         with scope_guard(model.scope):
             io.load_vars(probe_exe, dirname, full.main,
                          predicate=lambda v: v.persistable)
@@ -177,7 +224,7 @@ class GenerationModel:
         """Freeze the re-forward program + weights + generation spec.
         The full program has no cache ops, so the saved persistable set
         is the weights only — cache state never ships."""
-        full = self.programs["full"][self.spec.prompt_buckets[-1]]
+        full = self._full(self.spec.prompt_buckets[-1])
         block = full.main.global_block()
         with scope_guard(self.scope):
             io.save_inference_model(
@@ -188,42 +235,58 @@ class GenerationModel:
         return dirname
 
     # ------------------------------------------------------------------
-    def _check_frozen(self):
+    def _built(self):
+        return [(mode, bucket, lm) for mode in ("prefill", "decode", "full")
+                for bucket, lm in self.programs[mode].items()]
+
+    def _full(self, bucket):
+        """The re-forward program of a prompt bucket. A family may
+        build these when first asked for (models/hybrid_ssm.py _OnAsk);
+        such a program passes the two gates of the constructor here,
+        before its first use."""
+        held = self.programs["full"]
+        fresh = bucket not in held
+        lm = held[bucket]
+        if fresh:
+            self._check_frozen([("full", bucket, lm)])
+            self._verify([("full", bucket, lm)])
+        return lm
+
+    def _check_frozen(self, programs=None):
         """Generation programs may write persistable state ONLY under
-        the kv_cache.* prefix — any other persistable write is a
-        training op that would silently mutate pinned weights on
-        traffic (the generation analog of ServableModel._check_frozen)."""
+        the family's state prefixes (kv_cache.*; conv_state.* and
+        ssm_state.* too for a hybrid stack) — any other persistable
+        write is a training op that would silently mutate pinned
+        weights on traffic (the generation analog of
+        ServableModel._check_frozen)."""
         offenders = []
-        for mode in ("prefill", "decode", "full"):
-            for bucket, lm in self.programs[mode].items():
-                for block in lm.main.desc.blocks:
-                    for op in block.ops:
-                        for name in op.output_names():
-                            v = block.find_var_recursive(name)
-                            if v is not None and v.persistable and \
-                                    not name.startswith(KV_CACHE_PREFIX):
-                                offenders.append(
-                                    (mode, bucket, op.type, name))
+        for mode, bucket, lm in programs or self._built():
+            for block in lm.main.desc.blocks:
+                for op in block.ops:
+                    for name in op.output_names():
+                        v = block.find_var_recursive(name)
+                        if v is not None and v.persistable and \
+                                not name.startswith(self._state_prefixes):
+                            offenders.append((mode, bucket, op.type, name))
         if offenders:
             raise ValueError(
                 "generation program set is not frozen — ops write "
                 f"non-cache persistable vars: {offenders}")
 
-    def _verify(self):
+    def _verify(self, programs=None):
         """Static verification of every program at load/build time
         (startup included, so the cache vars' zero-fill satisfies the
         uninit-persistable pass). Honors PADDLE_TPU_VERIFY=0."""
         from ...analysis import verify_enabled, verify_program
         if not verify_enabled():
             return
-        for mode in ("prefill", "decode", "full"):
-            for bucket, lm in self.programs[mode].items():
-                verify_program(
-                    lm.main, startup=lm.startup,
-                    feed_names=lm.feed_names,
-                    fetch_names=[lm.fetch_name],
-                    program_label=f"generation {mode}[{bucket}]",
-                ).raise_if_errors(context="GenerationModel load")
+        for mode, bucket, lm in programs or self._built():
+            verify_program(
+                lm.main, startup=lm.startup,
+                feed_names=lm.feed_names,
+                fetch_names=[lm.fetch_name],
+                program_label=f"generation {mode}[{bucket}]",
+            ).raise_if_errors(context="GenerationModel load")
 
     # ------------------------------------------------------------------
     def _run(self, lm, feed) -> np.ndarray:
@@ -273,12 +336,18 @@ class GenerationModel:
         """Re-forward baseline step: full causal forward over the whole
         (padded) [slots, bucket] token matrix; returns [slots] next
         tokens at each row's last real position."""
-        lm = self.programs["full"][int(bucket)]
+        lm = self._full(int(bucket))
         out = self._run(lm, {
             "token_ids": token_matrix.reshape(
                 self.spec.slots, int(bucket), 1).astype(np.int64),
             "lengths": lengths.astype(np.int64)})
         return out.reshape(-1)
+
+    def state_bytes(self) -> Dict[str, int]:
+        """Bytes reserved for per-slot state, by kind (kv / conv /
+        ssm), whatever the slots hold."""
+        return {kind: int(sum(self.scope.get(n).nbytes for n in names))
+                for kind, names in self.state_kinds.items()}
 
     def last_cost(self):
         """Static cost of the most recent dispatch's executable."""
@@ -295,5 +364,7 @@ class GenerationModel:
         """Create (but do not start) a GenerationEngine bound to this
         model."""
         from .engine import GenerationEngine
-        return GenerationEngine(self, config=config, metrics=metrics,
-                                health=health, mode=mode)
+        engine = GenerationEngine(self, config=config, metrics=metrics,
+                                  health=health, mode=mode)
+        engine.metrics.state_bytes(self.state_bytes())
+        return engine

@@ -670,3 +670,60 @@ def test_a_looped_step_compiles_with_its_flash_calls_inside_the_while(
     assert charged == {
         ("flash_fwd", attn, "forward", 2): 2,
         ("flash_bwd_dkv_dq", "__vjp__." + attn, "backward", 2): 2}
+
+
+# The hybrid family's decode step (models/hybrid_ssm.py, granite-4.0-h-micro's
+# widths: 64 slots, 64 heads x 64 with a 128-wide state; 32 query heads
+# over 8 key heads of 64, bfloat16 caches to 4096 positions).
+def test_ssm_state_update_compiles_in_place(one_chip):
+    """One custom call, the 134-MB state aliased to its output, nothing
+    state-sized copied or planned as a temporary."""
+    import re
+
+    from paddle_tpu.ops.pallas.ssm_state_update import ssm_state_update
+
+    slots, d_state, columns = 64, 128, 4096
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda s, a, dx, b, c: ssm_state_update(s, a, dx, b, c,
+                                                interpret=False),
+        donate_argnums=0).lower(
+        sds(slots, d_state, columns), sds(slots, columns),
+        sds(slots, columns), sds(slots, d_state),
+        sds(slots, d_state)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"%ssm_state_update\S* = ", text)   # named for the trace
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slots * d_state * columns * 4
+    assert memory.temp_size_in_bytes < 8 << 20
+    assert not re.findall(r"= f32\[64,128,4096\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("bound", [1024, 4096])
+def test_decode_attention_at_four_query_heads_a_key_head_compiles(
+        topo, one_chip, bound):
+    from paddle_tpu.ops.cache_ops import device_lane_axis
+    from paddle_tpu.ops.pallas.decode_attention import (decode_attention,
+                                                        fits)
+
+    shape = (64, 8, 4096, 64)
+    # a bfloat16 cache of 64-wide heads is held with its positions on
+    # the lanes too: the kernel serves it
+    assert device_lane_axis(shape, jnp.bfloat16, topo.devices[0]) == 2
+    assert fits(shape, jnp.bfloat16, 2, bound)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, n: decode_attention(q, k, v, n, bound=bound,
+                                            interpret=False)).lower(
+        sds((64, 32, 1, 64), jnp.bfloat16), sds(shape, jnp.bfloat16),
+        sds(shape, jnp.bfloat16), sds((64,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
