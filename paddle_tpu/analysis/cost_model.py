@@ -66,7 +66,7 @@ ZERO_FLOP_OPS = frozenset({
 #: site is not among them: what a kernel computed of it is kept as the
 #: kernel's output, by the primitive
 PRODUCT_OPS = frozenset({
-    "mul", "matmul", "conv2d", "depthwise_conv2d", "conv3d",
+    "mul", "fanout_mul", "matmul", "conv2d", "depthwise_conv2d", "conv3d",
     "conv2d_transpose", "conv3d_transpose",
 })
 
@@ -257,16 +257,15 @@ def _flops_for(op: ir.OpDesc,
     if t in ZERO_FLOP_OPS:
         return 0, True, None
 
-    if t == "mul":
-        x, y = first("X"), first("Y")
-        if x is None or y is None:
+    if t in ("mul", "fanout_mul"):      # fanout_mul: a product a Y
+        x, ys = first("X"), [lookup(n) for n in op.input("Y")]
+        if x is None or None in ys:
             return None, False, None
         xn = int(op.attrs.get("x_num_col_dims", 1))
         yn = int(op.attrs.get("y_num_col_dims", 1))
         m = _prod(x.shape[:xn])
         k = _prod(x.shape[xn:])
-        n = _prod(y.shape[yn:])
-        return 2 * m * k * n, True, None
+        return sum(2 * m * k * _prod(y.shape[yn:]) for y in ys), True, None
 
     if t == "matmul":
         x, o = first("X"), out("Out")

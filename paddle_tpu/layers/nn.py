@@ -16,7 +16,7 @@ from ..initializer import ConstantInitializer, NormalInitializer, \
     UniformInitializer, XavierInitializer
 
 __all__ = [
-    "fc", "embedding", "dynamic_lstm", "dynamic_gru", "conv2d",
+    "fc", "fc_fanout", "embedding", "dynamic_lstm", "dynamic_gru", "conv2d",
     "depthwise_conv2d", "conv2d_transpose", "pool2d", "batch_norm",
     "layer_norm", "rms_norm", "rotary_embedding", "moe_router",
     "moe_experts", "dropout", "cross_entropy", "softmax_with_cross_entropy",
@@ -80,6 +80,38 @@ def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
                          outputs={"Out": pre_bias})
     pre_act = helper.append_bias_op(pre_bias, size=size)
     return helper.append_activation(pre_act)
+
+
+def fc_fanout(input, sizes, num_flatten_dims=1, name=None):
+    """`len(sizes)` bias-free fc projections of ONE dense input, as one
+    `fanout_mul` op: the parameters `fc` would create, one a size, in
+    that order and under those names, so a program that projects one
+    activation several ways (q, k, v) holds what the separate `fc`
+    calls gave it. One op lets the rule see the products together:
+    under a mesh that shards the weights' output features their input
+    gradients are summed on the shard (ops/math_ops.py _mxu_fanout)."""
+    if len(sizes) == 1:      # nothing to see together: `mul`
+        return [fc(input, sizes[0], num_flatten_dims, bias_attr=False,
+                   name=name)]
+    if input.lod_level:
+        raise ValueError("fc_fanout projects a dense input; a ragged one "
+                         "goes through fc, one call a projection")
+    flat_dim = 1
+    for d in input.shape[num_flatten_dims:]:
+        flat_dim *= int(d)
+    weights, outs = [], []
+    for size in sizes:
+        # a helper a projection, as a call to fc makes one: the names
+        helper = LayerHelper("fc", name=name)
+        weights.append(helper.create_parameter(
+            helper.param_attr, shape=[flat_dim, size], dtype=input.dtype))
+        outs.append(helper.create_tmp_variable(
+            input.dtype,
+            shape=list(input.shape[:num_flatten_dims]) + [size]))
+    helper.append_op(type="fanout_mul", inputs={"X": input, "Y": weights},
+                     outputs={"Out": outs},
+                     attrs={"x_num_col_dims": num_flatten_dims})
+    return outs
 
 
 def embedding(input, size, is_sparse=False, is_distributed=False,

@@ -12,6 +12,8 @@ key block and skip the tiles above the diagonal, and the ring of the
 context-parallel path rotates the row with its K/V block."""
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 
 from .. import layers, optimizer as opt
@@ -30,12 +32,16 @@ def multi_head_attention(q_in, k_in, v_in, d_model, n_head, mask=None,
     # columns" spec makes GSPMD reshard activations around EVERY
     # matmul — measured 7.3 GB/step of permute/all-gather traffic at
     # bench shapes vs ~0.2 GB paired (SCALING.json, round 4).
-    q = layers.fc(q_in, size=d_model, num_flatten_dims=2,
-                  bias_attr=False, name="tp_col_qkv")
-    k = layers.fc(k_in, size=d_model, num_flatten_dims=2,
-                  bias_attr=False, name="tp_col_qkv")
-    v = layers.fc(v_in, size=d_model, num_flatten_dims=2,
-                  bias_attr=False, name="tp_col_qkv")
+    # Projections of ONE activation (self-attention's q, k, v; cross-
+    # attention's k, v of the encoder's output) are one fan-out op, so
+    # that under the mesh their input gradients cross the 'model' axis
+    # as one sum, not one each. The parameters are what three fc calls
+    # made: names, shapes, initialisers and order (q, k, v).
+    runs = [list(run) for _, run in
+            itertools.groupby((q_in, k_in, v_in), key=id)]
+    q, k, v = (out for run in runs for out in layers.fc_fanout(
+        run[0], [d_model] * len(run), num_flatten_dims=2,
+        name="tp_col_qkv"))
 
     def split_heads(x):
         # [b, t, d_model] -> [b, t, n_head, d_key]: a view, no data moves

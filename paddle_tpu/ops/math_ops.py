@@ -8,6 +8,8 @@ jax.numpy, so the MXU sees large fused matmuls and XLA fuses the rest.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -16,19 +18,56 @@ from ..core.registry import register_op
 from .core_ops import jnp_dtype
 
 
+def _mxu_operands(x, *ys):
+    """(operands, accumulation dtype, result dtype) of an MXU product
+    under AMP: bf16 operands, float32 accumulation, and a bf16 RESULT so
+    activations thread end-to-end at half width (the f32->bf16 rounding
+    happens in the matmul epilogue, fused)."""
+    out_dtype = functools.reduce(jnp.promote_types,
+                                 (a.dtype for a in (x, *ys)))
+    ops = amp_cast(x, *ys)
+    if all(a.dtype == jnp.bfloat16 for a in ops) and \
+            out_dtype == jnp.float32:
+        return ops, jnp.float32, jnp.bfloat16
+    return ops, None, out_dtype
+
+
 def _mxu_matmul(x, y):
-    """matmul that engages the MXU in one pass under AMP: bf16 operands,
-    float32 accumulation, and a bf16 RESULT so activations thread
-    end-to-end at half width (the f32->bf16 rounding happens in the
-    matmul epilogue, fused)."""
-    out_dtype = jnp.promote_types(x.dtype, y.dtype)
-    x, y = amp_cast(x, y)
-    if x.dtype == jnp.bfloat16 == y.dtype and out_dtype == jnp.float32:
-        out_dtype = jnp.bfloat16
-        pref = jnp.float32
-    else:
-        pref = None
+    """matmul that engages the MXU in one pass under AMP
+    (`_mxu_operands`)."""
+    (x, y), pref, out_dtype = _mxu_operands(x, y)
     return jnp.matmul(x, y, preferred_element_type=pref).astype(out_dtype)
+
+
+def _mxu_fanout(x, ys):
+    """[m, d] against k [d, f] matrices, `_mxu_matmul`'s widths: the k
+    products as they are, and a pullback whose input gradient is ONE
+    contraction, over (k, f), of the stacked cotangents with the
+    stacked matrices. Where a mesh axis shards f the k partial sums are
+    then added on the shard, in the float32 accumulator, rounded once,
+    and cross that axis as ONE all-reduce; left to autodiff each
+    product's input gradient is all-reduced by itself and the k results
+    are added after (XLA does not reassociate the sum). The stacks are
+    of shards; concatenating along f instead would cross the sharded
+    axis and reshard the activations. (The same contraction as the
+    FORWARD, `md,kdf->kmf`, saves the same all-reduces and costs 16
+    layout copies of a q-sized array a layer pair: PERF.md, PR 50.)"""
+    (x, *ys), pref, out_dtype = _mxu_operands(x, *ys)
+
+    @jax.custom_vjp
+    def products(x, ys):
+        return [jnp.matmul(x, y, preferred_element_type=pref
+                           ).astype(out_dtype) for y in ys]
+
+    def pullback(operands, gs):
+        x, ys = operands
+        dx = jnp.einsum("kmf,kdf->md", jnp.stack(gs), jnp.stack(ys),
+                        preferred_element_type=pref).astype(x.dtype)
+        return dx, [jnp.matmul(x.T, g, preferred_element_type=pref
+                               ).astype(y.dtype) for g, y in zip(gs, ys)]
+
+    products.defvjp(lambda x, ys: (products(x, ys), (x, ys)), pullback)
+    return products(x, ys)
 
 
 def _broadcast_y(x, y, axis: int):
@@ -90,6 +129,65 @@ def _prod(dims):
     for d in dims:
         p *= int(d)
     return p
+
+
+def _column_sharded(ctx, names):
+    """Does the mesh this step is traced for shard the OUTPUT features of
+    every weight in `names` over one and the same axis? Read from what
+    ParallelExecutor hands the trace: its mesh and the PartitionSpecs of
+    the state (extra["state_specs"]). No mesh, a replicated weight, or a
+    value under another name than its parameter's: no."""
+    mesh, specs = ctx.extra.get("mesh"), ctx.extra.get("state_specs")
+    if mesh is None or not specs:
+        return False
+
+    def column_axes(spec):
+        ax = tuple(spec)[1] if spec is not None and len(spec) > 1 else None
+        return (ax,) if isinstance(ax, str) else tuple(ax or ())
+
+    axes = {column_axes(specs.get(n)) for n in names}
+    return len(axes) == 1 and _prod(mesh.shape[a] for a in axes.pop()) > 1
+
+
+def _count_fanout_site(ctx, products, path):
+    """One count a fan-out site traced into a step program, in the idiom
+    of ops/nn_ops.py _count_sdpa_site (the build-time shape inference
+    carries no program and is no site)."""
+    if "program" not in ctx.extra:
+        return
+    from ..observability.registry import default_registry
+    default_registry().counter(
+        "paddle_tpu_fanout_sites_total",
+        "fanout_mul sites (several weight matrices against ONE "
+        "activation: q, k, v of a self-attention, k, v of a "
+        "cross-attention) traced into a step program, by the number of "
+        "products and the path taken: stacked (one contraction over the "
+        "stacked weights, under a mesh that shards their output "
+        "features: the input gradients reach that axis as one "
+        "all-reduce) or separate (one matmul a weight, as `mul`). A grad "
+        "site that replays its forward rule counts again.",
+        ("products", "path")).labels(
+            products=str(products), path=path).inc()
+
+
+@register_op("fanout_mul")
+def _fanout_mul(ctx):
+    """`mul` of ONE X against k matrices Y of one shape, k Out: X
+    flattened to 2-D at x_num_col_dims, one product a matrix, shapes
+    restored. Two branches of one rule, chosen by what the trace
+    observes (`_column_sharded`): separate matmuls, or `_mxu_fanout`."""
+    x = ctx.input("X")
+    ys = ctx.inputs("Y")
+    xn = ctx.attr("x_num_col_dims", 1)
+    lead = tuple(x.shape[:xn])
+    x2 = x.reshape((_prod(lead), _prod(x.shape[xn:])))
+    stacked = len({y.shape for y in ys}) == 1 and ys[0].ndim == 2 and \
+        _column_sharded(ctx, ctx.op.input("Y"))
+    _count_fanout_site(ctx, len(ys), "stacked" if stacked else "separate")
+    outs = _mxu_fanout(x2, ys) if stacked else \
+        [_mxu_matmul(x2, y) for y in ys]
+    ctx.set_outputs("Out", [o.reshape(lead + tuple(y.shape[1:]))
+                            for o, y in zip(outs, ys)])
 
 
 @register_op("matmul")

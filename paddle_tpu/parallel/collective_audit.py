@@ -40,11 +40,19 @@ def _shape_bytes(shape_str: str) -> int:
 
 
 class Collective:
-    __slots__ = ("kind", "bytes", "groups", "pairs", "axes")
+    __slots__ = ("kind", "bytes", "groups", "pairs", "axes", "tensors",
+                 "op_name")
 
-    def __init__(self, kind, nbytes, groups=None, pairs=None):
+    def __init__(self, kind, nbytes, groups=None, pairs=None, tensors=(),
+                 op_name=""):
         self.kind = kind
         self.bytes = nbytes
+        # the arrays it carries, "bf16[16384,512]" each: XLA's combiner
+        # packs several into one instruction, differently by backend,
+        # so a layout's cost is counted in tensors, not instructions
+        self.tensors = tuple(tensors)
+        # the metadata's op_name (of ONE of a combined instruction's)
+        self.op_name = op_name
         self.groups = groups    # list[list[int]] or None
         self.pairs = pairs      # list[(src, dst)] or None
         self.axes: Optional[Tuple[str, ...]] = None
@@ -105,7 +113,12 @@ def parse_collectives(hlo_text: str) -> List[Collective]:
                     int(g), int(s),
                     [int(d) for d in dims.split(",")],
                     [int(p) for p in perm.split(",")] if perm else None)
-        out.append(Collective(kind, _shape_bytes(shape), groups, pairs))
+        name = re.search(r'op_name="([^"]*)"', ln)
+        out.append(Collective(
+            kind, _shape_bytes(shape), groups, pairs,
+            tensors=[f"{dt}[{dims}]" for dt, dims in
+                     re.findall(r"(\w+)\[([\d,]*)\]", shape)],
+            op_name=name.group(1) if name else ""))
     return out
 
 
@@ -162,6 +175,28 @@ def inventory(hlo_text: str, mesh) -> Dict[Tuple[str, Tuple[str, ...]],
         cnt, b = inv.get(key, (0, 0))
         inv[key] = (cnt + 1, b + c.bytes)
     return inv
+
+
+def tensor_elements(tensor: str) -> int:
+    """Elements of one of `Collective.tensors` ("bf16[16384,512]")."""
+    dims = tensor[tensor.index("[") + 1:-1]
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+def tensors_over(hlo_text: str, mesh, axis: str,
+                 min_elements: int = 0) -> Dict[Tuple[str, str], int]:
+    """{(kind, "dtype[dims]"): tensors} of the collectives whose axis
+    set contains `axis`, a tuple's elements counted one by one, arrays
+    under `min_elements` left out (activation-sized ones: pass the
+    elements of one device's activation)."""
+    out: Dict[Tuple[str, str], int] = {}
+    for c in classify(parse_collectives(hlo_text), mesh):
+        if axis not in (c.axes or ()):
+            continue
+        for t in c.tensors:
+            if tensor_elements(t) >= min_elements:
+                out[(c.kind, t)] = out.get((c.kind, t), 0) + 1
+    return out
 
 
 def format_inventory(inv) -> str:

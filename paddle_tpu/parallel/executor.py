@@ -159,6 +159,10 @@ class ParallelExecutor(Executor):
                 "step": step,
                 "mesh": mesh,
                 "feed_axis": feed_axis,
+                # what shards each state array: a rule that can sum on
+                # the shard before a reduction (ops/math_ops.py
+                # fanout_mul) reads its weights' specs here
+                "state_specs": state_specs,
                 "keep_vars": set(fetch_names) | set(write_names),
                 "prng": lambda seed: jax.random.fold_in(
                     jax.random.PRNGKey(seed), step),
@@ -242,6 +246,8 @@ class ParallelExecutor(Executor):
             n: NamedSharding(mesh, state_spec(n)) for n in ro_names}
         rw_shardings = {
             n: NamedSharding(mesh, state_spec(n)) for n in rw_names}
+        state_specs = {n: sh.spec for n, sh in
+                       (*ro_shardings.items(), *rw_shardings.items())}
         self._state_shardings.update(ro_shardings)
         self._state_shardings.update(rw_shardings)
 

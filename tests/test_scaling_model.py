@@ -98,3 +98,24 @@ def test_sensitivity_band_orders_with_bandwidth():
     hi = predict(inv, sizes, t_comp=5e-3, bw=ICI_BW * 2.0)
     assert lo["eff_serial"] < base["eff_serial"] < hi["eff_serial"]
     assert lo["t_comm_ms"] > base["t_comm_ms"] > hi["t_comm_ms"]
+
+
+def test_a_tensor_parallel_layer_pair_is_modelled_at_eleven_tensors():
+    """The model is driven by the audit, so it follows the layout's
+    inventory: since ISSUE 50 the Megatron-paired transformer step
+    brings 11 activation-sized tensors across 'model' an encoder +
+    decoder layer pair (16 before: q, k, v each brought its input
+    gradient across), and `predict` charges the axis those bytes."""
+    from test_fanout_mul import BATCH, SEQ, WIDTHS, _compiled_step
+
+    hlo, mesh = _compiled_step(1)
+    inv = ca.inventory(hlo, mesh)
+    a_tensor = BATCH // 2 * SEQ * WIDTHS["d_model"] * 4     # f32: no AMP
+    _count, nbytes = inv[("all-reduce", ("model",))]
+    assert nbytes == 11 * a_tensor
+    pred = sm.predict(inv, {"data": 2, "model": 2}, t_comp=1e-3)
+    want = sum(sm._collective_time(kind, b, cnt, 2)
+               for (kind, axes), (cnt, b) in inv.items()
+               if axes == ("model",))
+    assert want > 11 * a_tensor / sm.ICI_BW     # a two-chip ring: B each
+    assert abs(pred["per_axis_ms"]["model"] - want * 1e3) < 1e-3

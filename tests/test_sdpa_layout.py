@@ -153,7 +153,8 @@ def test_the_attention_builder_places_no_transpose_op():
         out = transformer.multi_head_attention(x, mem, mem, 256, 4)
     types = [op.type for op in main.global_block().ops]
     assert "transpose" not in types
-    assert types == ["mul"] * 3 + ["reshape"] * 3 + [
+    # q by `mul`; k and v, which read ONE memory, by one fan-out op
+    assert types == ["mul", "fanout_mul"] + ["reshape"] * 3 + [
         "scaled_dot_product_attention", "reshape", "mul"]
     assert tuple(out.shape)[1:] == (S, 256)
     (site,) = [op for op in main.global_block().ops

@@ -392,6 +392,81 @@ def test_flash_attention_under_a_mesh_compiles_per_shard(topo, layout):
     assert " transpose(" not in text
 
 
+def test_a_mesh_layer_pair_all_reduces_one_tensor_a_shared_input(
+        topo, monkeypatch):
+    """ONE encoder and ONE decoder layer of transformer-base.train-mesh-
+    dp2tp2's step (batch 16 x 2048 on ('data','model') = (2,2), AMP
+    bf16, tp_param_specs; a 1,024-row vocabulary: the head is not the
+    subject), compiled for the described v5e:2x2 through
+    ParallelExecutor._compile with the executor's own arg_shardings on
+    ShapeDtypeStructs — the recipe for the whole cell (6 + 6 layers, the
+    32k vocabulary, ~60 s: PERF.md section 7). Over 'model' the step
+    all-reduces 11 tensors of bf16[16384,512] — 2 + 2 an encoder layer,
+    3 + 4 a decoder layer; 16 before ISSUE 50, when each of q, k, v
+    (and of cross-attention's k, v) brought its input gradient across
+    by itself — and nothing else activation-sized crosses it: the
+    stacked contraction of ops/math_ops.py fanout_mul reshards
+    nothing."""
+    import importlib
+
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer
+    from paddle_tpu.parallel import collective_audit as ca
+    from paddle_tpu.parallel.executor import ParallelExecutor, ShardingSpec
+
+    batch, seq, vocab = 16, 2048, 1024
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", "force")
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.pallas.flash_attention"),
+        "_interpret_default", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    pt.reset_default_programs()
+    pt.reset_global_scope()
+    try:
+        with pt.amp.amp_guard(True):
+            main, startup, fetch = transformer.build_train(
+                src_vocab=vocab, trg_vocab=vocab, max_len=seq, n_layer=1,
+                n_head=8, d_model=512, d_inner=2048)
+            pt.Executor().run(startup)
+            scope = pt.global_scope()
+            sharding = ShardingSpec(specs=transformer.tp_param_specs(main),
+                                    feed_axis="data")
+            sharding.specs["pos_ids"] = P()
+            ids = ((batch, seq, 1), "int64")
+            sig = tuple(sorted(
+                [(n, ids) for n in ("src_ids", "trg_ids", "trg_labels")]
+                + [("pos_ids", ((seq,), "int64"))]))
+            step = ParallelExecutor(mesh=mesh, sharding=sharding)._compile(
+                main.desc, main.desc.block(0), sig, [fetch["loss"].name],
+                scope)
+            feeds, ro, rw, step_sh = step.arg_shardings
+
+            def state(names, shardings):
+                return {n: jax.ShapeDtypeStruct(
+                    scope.get(n).shape, scope.get(n).dtype,
+                    sharding=shardings[n]) for n in names}
+
+            text = step.jitted.lower(
+                {n: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                         sharding=feeds[n])
+                 for n, (shape, _) in sig},
+                state(step.ro_names, ro), state(step.rw_names, rw),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=step_sh)
+            ).compile().as_text()
+    finally:
+        pt.reset_global_scope()
+    a_shard = batch // 2 * seq * 512 // 2   # of a column-parallel output
+    assert ca.tensors_over(text, mesh, "model", min_elements=a_shard) == {
+        ("all-reduce", "bf16[16384,512]"): 11}
+    assert "all-reduce-start" not in text           # all synchronous
+    ca.assert_collectives(ca.inventory(text, mesh),
+                          [(("all-reduce",), "data")])
+    assert text.count("tpu_custom_call") == 6
+
+
 # serve-chat's caches (128 slots x 8 heads x 2048 positions, chipbench/
 # configs/decoder-lm-base.json) and the shapes the next serving
 # configuration may store: d_key 128, bf16. A v5e holds a d_key of 64
