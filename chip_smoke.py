@@ -233,8 +233,8 @@ def phase_kernels(sm, cfg, interpret):
     fwd_sites = dict(flash_fwd_sites() - fwd_before)
     bwd_sites = dict(flash_bwd_sites() - bwd_before)
     # `both` traces the forward alone and again under jax.grad
-    sm.check(fwd_sites == {"resident/0/1/1": 2 * len(shapes)}
-             and bwd_sites == {"resident/0/1/1": len(shapes)},
+    sm.check(_all_under(fwd_sites, "resident/0/1/1/", 2 * len(shapes))
+             and _all_under(bwd_sites, "resident/0/1/1/", len(shapes)),
              "kernel flash_attention: each forward and each backward kept "
              "its head's K and V resident (one grid step a q-block; dQ "
              "finished in the one backward kernel)",
@@ -338,6 +338,15 @@ def _site_counts(family):
 def sdpa_sites():
     """Counter of "path/mask/causal": attention sites traced so far."""
     return _site_counts("paddle_tpu_sdpa_sites_total")
+
+
+def _all_under(sites, prefix, n):
+    """Whether `n` flash sites were counted, all under labels that start
+    `prefix`: what is left is rows_a_block, the batch rows a grid step
+    holds — 1 at the real lengths, the short-sequence plan's choice at a
+    rehearsal's toy ones."""
+    return sum(sites.values()) == n and all(
+        k.startswith(prefix) for k in sites)
 
 
 def flash_bwd_sites():
@@ -509,8 +518,8 @@ def phase_train(sm, cfg, device, workdir):
     from paddle_tpu.ops.pallas.flash_attention import _heads_a_block
     d_key = cfg["d_model"] // cfg["n_head"]
     heads = _heads_a_block(d_key, d_key)    # that fill a 128-lane word
-    sm.check(fwd_sites == {f"resident/0/1/{heads}": n_sites}
-             and bwd_sites == {f"resident/0/1/{heads}": n_sites},
+    sm.check(_all_under(fwd_sites, f"resident/0/1/{heads}/", n_sites)
+             and _all_under(bwd_sites, f"resident/0/1/{heads}/", n_sites),
              "train: every site's forward and backward is one kernel with "
              "its head block's K and V resident, none walks them in "
              "segments, none was transposed back to head-major (relaid)",

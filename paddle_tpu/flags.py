@@ -98,7 +98,9 @@ FLAGS: Dict[str, tuple] = {
         "that carry no use_flash attr of their own, read when the op "
         "(and its grad op) is traced: '1' leaves the measured min-seq "
         "policy in charge on a TPU (ops/pallas/flash_attention.py "
-        "FLASH_CROSSOVER_SEQ), "
+        "FLASH_CROSSOVER_SEQ: q and k of 256 or more since PR 51, a "
+        "site of 256-511 under the kernels' short-sequence plan; a "
+        "shorter one is composed), "
         "'force' engages the kernel anywhere (interpret mode off-TPU "
         "— test coverage), '0' pins the naive composition"),
     "PADDLE_TPU_INPUT_WORKERS": (

@@ -41,7 +41,13 @@ running max and sum, the logsumexp, delta — is a [1, block_q] row that
 reduces down sublanes and broadcasts along them. How much of K and V
 stays resident is a byte count against _VMEM_BUDGET: a whole head where
 it fits (every site of the benchmark's cells), else the fewest equal
-k-segments, walked by the same kernel.
+k-segments, walked by the same kernel. Where the whole sequence is ONE
+tile each way (a site of 256-511 rows: the lengths the crossover below
+hands these kernels first) a grid step would be a microsecond of work,
+so a step holds several batch rows of the block (_rows_a_block) and
+both bodies compute each row's one tile as values, the rows unrolled;
+every longer call keeps one row a step and traces the kernels it always
+traced.
 
 The forward (grid (batch, head, q-block, k-segment)) carries the
 statistics between tiles as values and keeps the accumulator turned,
@@ -64,6 +70,7 @@ exact additive-bias gradient is emitted from the same tiles on request.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 from typing import Optional
@@ -86,19 +93,36 @@ from . import interpret_default as _interpret_default  # shared policy
 
 # The shortest sequence a dispatcher that was left the choice hands to
 # these kernels (ops/nn_ops.py _sdpa: q AND k at least this long;
-# parallel/context_parallel.py: the gathered q). The measured crossover
-# on v5e (bf16, h8 d64, fwd+bwd, marginal protocol): naive/XLA wins
-# 1.56x at S=256, parity at S=512, flash wins 2.5x at S=1024 and 5.6x
-# at S=4096 — the S^2 score materialization only starts to bind around
-# 512. Round 2's threshold of 128 routed the transformer bench's S=256
-# through flash and cost it ~35% end-to-end. (Round-3 numbers, taken
-# before this tree's first chip_smoke.py run; not re-measured.)
-FLASH_CROSSOVER_SEQ = 512
+# parallel/context_parallel.py: the gathered q). Measured in PR 51 on a
+# TPU v5e (16 GB, JAX 0.9.0), bf16, 8 heads x 64, a key-row mask,
+# layout "bshd", forward + backward, 16-18 k tokens a site. One site
+# alone (`_cmp/pr51_site.py`: 40 dependent sites in one jit, ms a site,
+# kernels / composition): 64 x 256 0.825 / 1.092 (causal 0.806 / 1.096),
+# 48 x 384 1.065 / 1.758 (1.074 / 1.801), 32 x 512 1.104 / 2.494 (0.976
+# / 2.489) — the kernels win 1.3x, 1.65x and 2.3-2.6x. At the step's
+# level (transformer-base, batch 64 x 256, 18 sites; chipbench's
+# `train-s256`, 40-s windows) the composition pays its score tensors'
+# layout copies too (8 % of busy time under no program op): 176.2 k
+# tokens/s composed, 209.9 k through the kernels as PR 50 left them
+# (where one site alone read 1.162 against 1.092: the step, not the site
+# alone, decides), 229.8 k under the short-sequence plan (P C C P, two
+# seeds: PERF.md section 6). NOT below 256 without a measurement of its
+# own: the token server's 128-token prefill bucket (forward only, one
+# prompt) takes the composition, and `ttft_ms_p95` is its metric. The
+# 512 this replaces was a round-3 reading (the composition 1.56x faster
+# at 256, parity at 512) of kernels that have since become 3.4x faster.
+FLASH_CROSSOVER_SEQ = 256
 
 # What one call may plan to keep in VMEM: half of a v5e core's 128 MiB,
 # the rest being Mosaic's own. A call asks for what it counted
 # (vmem_limit_bytes), not for the default scoped 16 MiB.
 _VMEM_BUDGET = 64 << 20
+
+# The most batch rows a grid step holds under the short-sequence plan
+# (_rows_a_block has the measurement: 4 rows read within 1 % of 8, whose
+# unrolled bodies take up to twice as long to compile — 0.8 -> 1.3 and
+# 1.2 -> 2.4 s a forward and backward pair; 16 read worse).
+_ROWS_A_STEP = 4
 
 
 def _blocks(sq, sk, block_q, block_k, interpret):
@@ -143,12 +167,15 @@ def _bias_lanes(bias, block_q):
     return {None: 0, "key": 128, "score": block_q}[_bias_kind(bias)]
 
 
-def _keys_down_bias(bias, sq, sk, sq_p, sk_p, seg, block_q, where):
+def _keys_down_bias(bias, sq, sk, sq_p, sk_p, seg, block_q, where,
+                    rows_a_block=1):
     """A bias as the keys-down kernels read it, broadcast dims of batch
     and head kept unmaterialized: a key row as one value a key on every
     lane, [.., sk_p, 128]; a score-sized one turned, [.., sk_p, sq_p].
     `where` picks (query head, k-segment, q-block) out of the grid's
-    last three indices. Returns the array and its BlockSpec."""
+    last three indices. Returns the array and its BlockSpec, a block the
+    `rows_a_block` batch rows of a grid step where the bias has a row a
+    batch row."""
     bb, bh = bias.shape[:2]
     pad_k = ((0, 0), (0, 0), (0, sk_p - sk))
     key = _bias_kind(bias) == "key"
@@ -166,7 +193,9 @@ def _keys_down_bias(bias, sq, sk, sq_p, sk_p, seg, block_q, where):
         return (0 if bb == 1 else b, 0 if bh == 1 else head, ks,
                 0 if key else iq)
 
-    return biasp, pl.BlockSpec((1, 1, seg, _bias_lanes(bias, block_q)), at)
+    return biasp, pl.BlockSpec(
+        (1 if bb == 1 else rows_a_block, 1, seg,
+         _bias_lanes(bias, block_q)), at)
 
 
 def _caps(bias, bias_grad, causal, block_q, block_k):
@@ -267,8 +296,23 @@ def _head_lanes(x, i, heads, other=0):
                      jnp.asarray(other, x.dtype))
 
 
+
+
+def _each_row(body, at, rows_a_block):
+    """`body(at)` once a batch row of a grid step's block: `at` as it is
+    where the block holds one row; else the row leads `at`, the index of
+    a loop that is traced once and unrolled whole, so that the rows lie
+    in one block of straight-line code."""
+    if rows_a_block == 1:
+        body(at)
+    else:
+        jax.lax.fori_loop(0, rows_a_block,
+                          lambda r, _: body((r, *at[1:])), None,
+                          unroll=True)
+
+
 def _fwd_kernel(*refs, sm_scale, scale_q, causal, window, block_q, block_k,
-                kv_len, chunks, nseg, bias_kind, heads, at):
+                kv_len, chunks, nseg, bias_kind, heads, at, rows_a_block):
     """One q-block against the `chunks` k-blocks of one resident
     k-segment, scores held keys-down ([block_k, block_q]): the running
     max and sum reduce down sublanes and travel between tiles as
@@ -289,109 +333,154 @@ def _fwd_kernel(*refs, sm_scale, scale_q, causal, window, block_q, block_k,
     accumulator in its own dv rows of [heads * dv, block_q], so that ONE
     turn a q-block writes o's dense lanes. The heads are a static loop:
     a lane slice needs a head known at trace time, and a branch a head
-    inside a tile costs what the whole values cost (1.05 and 0.85 ms)."""
+    inside a tile costs what the whole values cost (1.05 and 0.85 ms).
+
+    Under the short-sequence plan (_rows_a_block: the whole sequence ONE
+    tile) a block holds `rows_a_block` > 1 batch rows, the row the head
+    of `at` (_each_row); a head of a row is then computed as values —
+    s, its max, p, its sum, v^T p, all over the one tile, bit for bit
+    what the walk above gives — with no scratch and no `pl.when`, so
+    that the rows' products and softmaxes overlap."""
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if bias_kind is not None else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[-5:]
     iq, ks = pl.program_id(2), pl.program_id(3)
     dv = v_ref.shape[-1] // heads
+    one_tile = rows_a_block > 1
+    if one_tile:
+        iq = 0
 
-    @pl.when(ks == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def _row(at):
+        # the bias's row: this batch row's, or the one all share
+        brow = at[0] if bias_kind and bias_ref.shape[0] > 1 else 0
 
-    block = q_ref[at]                                        # [bq, d]
-    if scale_q:       # a power of two: the scaled scores bit for bit
-        block = block * sm_scale
-    first = ks * chunks if nseg > 1 else 0
-    # k-blocks past the last key, or wholly above the causal diagonal:
-    # nothing to do
-    stop = -(-kv_len // block_k) - first
-    stop = min(chunks, stop) if nseg == 1 else jnp.minimum(chunks, stop)
-    if causal:
-        stop = jnp.minimum(
-            stop, (iq * block_q + block_q - 1) // block_k + 1 - first)
+        if not one_tile:
+            @pl.when(ks == 0)
+            def _init():
+                m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+                l_scr[:] = jnp.zeros_like(l_scr)
+                acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _head(i):
-        # this head's row of the statistics; its lanes of v, which are
-        # its rows of the accumulator
-        row = slice(None) if heads == 1 else slice(i, i + 1)
-        own = slice(None) if heads == 1 else slice(i * dv, (i + 1) * dv)
-        q = _head_lanes(block, i, heads)
+        block = q_ref[at]                                    # [bq, d]
+        if scale_q:       # a power of two: the scaled scores bit for bit
+            block = block * sm_scale
+        first = ks * chunks if nseg > 1 else 0
+        # k-blocks past the last key, or wholly above the causal
+        # diagonal: nothing to do
+        stop = -(-kv_len // block_k) - first
+        stop = (min(chunks, stop) if nseg == 1
+                else jnp.minimum(chunks, stop))
+        if causal:
+            stop = jnp.minimum(
+                stop, (iq * block_q + block_q - 1) // block_k + 1 - first)
 
-        def _tile(c, carry, diagonal, lower=False):
-            m, l = carry                                     # [1, bq]
-            rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
-            k, v = k_ref[(*at, rows, slice(None))], v_ref[(*at, rows, own)]
-            s = jax.lax.dot_general(
-                k, q, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [bk, bq]
-            if not scale_q:
-                s = s * sm_scale
-            if bias_kind == "key":      # one value a key, on every lane
-                s = s + bias_ref[0, 0, rows, :][:, :1].astype(jnp.float32)
-            elif bias_kind == "score":
-                s = s + bias_ref[0, 0, rows, :].astype(jnp.float32)
-            if kv_len % block_k or diagonal:
-                kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 0)
-            if kv_len % block_k:        # mask seq padding
-                s = jnp.where(kpos < kv_len, s, NEG_INF)
-            if diagonal:
-                qpos = iq * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1)
-                s = _visible(s, qpos, kpos, window if lower else None)
-            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)                           # [bk, bq]
-            l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
-            acc_scr[own] = acc_scr[own] * alpha + jax.lax.dot_general(
-                v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [dv, bq]
-            return m_new, l
+        def _head(i):
+            # this head's row of the statistics; its lanes of v, which
+            # are its rows of the accumulator
+            row = slice(None) if heads == 1 else slice(i, i + 1)
+            own = (slice(None) if heads == 1
+                   else slice(i * dv, (i + 1) * dv))
+            q = _head_lanes(block, i, heads)
 
-        carry = m_scr[row], l_scr[row]
-        if window is not None:
-            m, l = _walk_band(_tile, carry, iq, first, stop, block_q,
-                              block_k, window)
-        else:
-            # the causal select only in the tiles the diagonal crosses:
-            # the whole tiles below it first
-            whole = 0
-            if causal:
-                whole = jnp.clip((iq * block_q + 1) // block_k - first, 0,
-                                 stop)
-                carry = jax.lax.fori_loop(
-                    0, whole, functools.partial(_tile, diagonal=False),
-                    carry)
-            # a short walk of a known length is unrolled, so that the
-            # next tile's products overlap this tile's softmax (v5e, a
-            # site of 64 heads x 2048 x 64 not causal: 1.10 against 1.16
-            # ms)
-            m, l = jax.lax.fori_loop(
-                whole, stop, functools.partial(_tile, diagonal=causal),
-                carry,
-                unroll=True if isinstance(stop, int) and stop <= 4
-                else None)
-        m_scr[row], l_scr[row] = m, l
+            def _scores(c, diagonal, lower=False):
+                """Tile c's scores [bk, bq] and its values."""
+                rows = pl.ds(pl.multiple_of(c * block_k, block_k),
+                             block_k)
+                k = k_ref[(*at, rows, slice(None))]
+                v = v_ref[(*at, rows, own)]
+                s = jax.lax.dot_general(
+                    k, q, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [bk, bq]
+                if not scale_q:
+                    s = s * sm_scale
+                if bias_kind == "key":      # one value a key, on every lane
+                    s = s + bias_ref[brow, 0, rows, :][:, :1].astype(
+                        jnp.float32)
+                elif bias_kind == "score":
+                    s = s + bias_ref[brow, 0, rows, :].astype(jnp.float32)
+                if kv_len % block_k or diagonal:
+                    kpos = (first + c) * block_k \
+                        + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                if kv_len % block_k:        # mask seq padding
+                    s = jnp.where(kpos < kv_len, s, NEG_INF)
+                if diagonal:
+                    qpos = iq * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 1)
+                    s = _visible(s, qpos, kpos, window if lower else None)
+                return s, v
 
-        @pl.when(ks == nseg - 1)
-        def _fin():
-            safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0
-            if heads == 1:
-                o_ref[at] = (acc_scr[:] / safe).T.astype(o_ref.dtype)
+            if one_tile:
+                # the whole sequence: no statistic to carry, no
+                # accumulator to rescale, nothing waits in scratch
+                s, v = _scores(0, causal, window is not None)
+                m = jnp.max(s, axis=0, keepdims=True)        # [1, bq]
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=0, keepdims=True)
+                acc = jax.lax.dot_general(
+                    v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [dv, bq]
+                lse_ref[at[0], i, 0] = m + jnp.log(jnp.maximum(l, 1e-37))
+                return acc / jnp.where(l == 0.0, 1.0, l)
+
+            def _tile(c, carry, diagonal, lower=False):
+                m, l = carry                                 # [1, bq]
+                s, v = _scores(c, diagonal, lower)
+                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)                       # [bk, bq]
+                l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
+                acc_scr[own] = acc_scr[own] * alpha + jax.lax.dot_general(
+                    v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [dv, bq]
+                return m_new, l
+
+            carry = m_scr[row], l_scr[row]
+            if window is not None:
+                m, l = _walk_band(_tile, carry, iq, first, stop, block_q,
+                                  block_k, window)
             else:
-                acc_scr[own] = acc_scr[own] / safe
-            lse_ref[0, i, 0] = m + jnp.log(jnp.maximum(l, 1e-37))
+                # the causal select only in the tiles the diagonal
+                # crosses: the whole tiles below it first
+                whole = 0
+                if causal:
+                    whole = jnp.clip(
+                        (iq * block_q + 1) // block_k - first, 0, stop)
+                    carry = jax.lax.fori_loop(
+                        0, whole, functools.partial(_tile, diagonal=False),
+                        carry)
+                # a short walk of a known length is unrolled, so that
+                # the next tile's products overlap this tile's softmax
+                # (v5e, a site of 64 heads x 2048 x 64 not causal: 1.10
+                # against 1.16 ms)
+                m, l = jax.lax.fori_loop(
+                    whole, stop, functools.partial(_tile, diagonal=causal),
+                    carry,
+                    unroll=True if isinstance(stop, int) and stop <= 4
+                    else None)
+            m_scr[row], l_scr[row] = m, l
 
-    for i in range(heads):      # static: see the docstring
-        _head(i)
-    if heads > 1:
-        @pl.when(ks == nseg - 1)
-        def _turn():
-            o_ref[at] = acc_scr[:].T.astype(o_ref.dtype)
+            @pl.when(ks == nseg - 1)
+            def _fin():
+                # fully-masked rows -> 0
+                safe = jnp.where(l == 0.0, 1.0, l)
+                if heads == 1:
+                    o_ref[at] = (acc_scr[:] / safe).T.astype(o_ref.dtype)
+                else:
+                    acc_scr[own] = acc_scr[own] / safe
+                lse_ref[at[0], i, 0] = m + jnp.log(jnp.maximum(l, 1e-37))
+
+        if one_tile:
+            o_ref[at] = jnp.concatenate(
+                [_head(i) for i in range(heads)]).T.astype(o_ref.dtype)
+            return
+        for i in range(heads):      # static: see the docstring
+            _head(i)
+        if heads > 1:
+            @pl.when(ks == nseg - 1)
+            def _turn():
+                o_ref[at] = acc_scr[:].T.astype(o_ref.dtype)
+
+    _each_row(_row, at, rows_a_block)
 
 
 class _Site:
@@ -402,9 +491,13 @@ class _Site:
     ("bshd") q is [B, Sq, H, D] read as its free view [B, Sq, H * D] and
     a block the rows of `heads` heads side by side in whole 128-lane
     words, `(1, rows, heads * width)` at (batch, row-block, head-block).
-    `h` and `hk` count head BLOCKS, the kernels' grid axis."""
+    `h` and `hk` count head BLOCKS, the kernels' grid axis. Either
+    way a block is `rows_a_block` batch rows of that (_rows_a_block: one
+    but under the short-sequence plan) and the grid's first axis counts
+    such blocks."""
 
-    def __init__(self, layout, q, k, v):
+    def __init__(self, layout, q, k, v, rows_a_block=1):
+        self.rows_a_block = rows_a_block
         self.seq_major = layout == "bshd"
         if self.seq_major:
             self.b, self.sq, h, self.d = q.shape
@@ -439,13 +532,13 @@ class _Site:
         the index of a leading axis the array has besides (dQ's partial
         a k-segment)."""
         if self.seq_major:
-            block = (1, rows, self.heads * width)
+            block = (self.rows_a_block, rows, self.heads * width)
 
             def index(*grid):
                 b, h, r = where(*grid)
                 return b, r, h
         else:
-            block = (1, 1, rows, width)
+            block = (self.rows_a_block, 1, rows, width)
 
             def index(*grid):
                 return (*where(*grid), 0)
@@ -457,8 +550,9 @@ class _Site:
     def stat_spec(self, block_q, where):
         """Of the logsumexp and delta rows [B, H, nq, 1, block_q]: a
         block's heads, one row each."""
-        return pl.BlockSpec((1, self.heads, 1, 1, block_q),
-                            lambda *grid: (*where(*grid), 0, 0))
+        return pl.BlockSpec(
+            (self.rows_a_block, self.heads, 1, 1, block_q),
+            lambda *grid: (*where(*grid), 0, 0))
 
     def unview(self, x, rows, width):
         """An output's first `rows` rows, in the layout's 4-D form."""
@@ -479,11 +573,46 @@ def _heads_a_block(d, dv):
     return None
 
 
+# A call's tiles and segments: block_q, block_k, q-blocks, k-segments,
+# k-blocks a segment, batch rows a block, the bytes it keeps in VMEM.
+_Plan = collections.namedtuple(
+    "_Plan", "block_q block_k nq nseg chunks rows_a_block vmem")
+
+
+def _rows_a_block(site, nq, nk, vmem_a_row):
+    """Batch rows a grid step holds: the short-sequence plan. One
+    wherever a head's sequence is more than one tile (every call of 1024
+    or longer, 512 causal), which then traces the kernels it always
+    traced. Where the whole sequence is ONE q-block against ONE k-block
+    nothing overlaps inside a head's walk — a product, then its softmax,
+    then the next product, through scratch and `pl.when`s that a single
+    tile does not need — and a grid step is a microsecond of that. Such
+    a call takes the most rows up to _ROWS_A_STEP that divide the batch
+    (and fit the budget, counted a row as the plan counts a call), and
+    with more than one row the bodies change form: each row's heads are
+    computed as values, no scratch, no conditional, in one unrolled
+    block, so that one row's products run under another's softmax.
+    Grouped key heads keep one row: the backward's last grid axis walks
+    the group over one dK / dV accumulator. Measured on a v5e (PR 51,
+    `_cmp/pr51_site.py`: ms a site of 64 x 256 x 8 heads x 64 bf16 under
+    a key-row mask, forward + backward, 40 dependent sites in one jit,
+    0.15 of it the scan's own passes): one row through scratch 1.166
+    (causal 1.214); 8 or 16 such rows a step in a loop 1.139 / 1.193 —
+    the fixed cost of a grid step is NOT what a short site pays; one row
+    as values 0.935 (0.920); 2 / 4 / 8 / 16 rows as values, unrolled,
+    0.844 / 0.825 / 0.820 / 0.875 (causal 0.825 / 0.806 / 0.799 /
+    0.855). 48 x 384: 1.309 -> 1.065; 32 x 512: 1.156 -> 1.104, causal
+    1.241 -> 0.976. The composition: 1.092, 1.758, 2.494."""
+    if nq * nk > 1 or site.group > 1:
+        return 1
+    most = max(1, min(_ROWS_A_STEP, _VMEM_BUDGET // vmem_a_row))
+    return max(r for r in range(1, most + 1) if site.b % r == 0)
+
+
 def _fwd_plan(site, bias, bias_grad, causal, block_q, block_k, interpret,
               itemsize):
-    """A forward call's tiles and segments, from its shapes alone:
-    (block_q, block_k, q-blocks, k-segments, k-blocks a segment, the
-    bytes it keeps in VMEM)."""
+    """A forward call's tiles and segments (_Plan), from its shapes
+    alone."""
     block_q, block_k = _blocks(
         site.sq, site.sk, *_caps(bias, bias_grad, causal, block_q, block_k),
         interpret)
@@ -495,7 +624,9 @@ def _fwd_plan(site, bias, bias_grad, causal, block_q, block_k, interpret,
                                itemsize, _bias_lanes(bias, block_q))
 
     nseg, chunks = _segments(nk, vmem_bytes)
-    return block_q, block_k, nq, nseg, chunks, vmem_bytes(chunks)
+    rows = _rows_a_block(site, nq, nk, vmem_bytes(chunks))
+    return _Plan(block_q, block_k, nq, nseg, chunks, rows,
+                 rows * vmem_bytes(chunks))
 
 
 def _fwd(q, k, v, bias, sm_scale, causal, window, block_q, block_k,
@@ -513,7 +644,7 @@ def _fwd(q, k, v, bias, sm_scale, causal, window, block_q, block_k,
     site = _Site(layout, q, k, v)
     plan = _fwd_plan(site, bias, bias_grad, causal, block_q, block_k,
                      interpret, q.dtype.itemsize)
-    _count_site("fwd", plan[3], window, site)
+    _count_site("fwd", plan, window, site)
     call = _fwd_call_once if site.seq_major else _fwd_call
     return call(q, k, v, bias, sm_scale, causal, window, interpret, layout,
                 plan)
@@ -521,10 +652,10 @@ def _fwd(q, k, v, bias, sm_scale, causal, window, block_q, block_k,
 
 def _fwd_call(q, k, v, bias, sm_scale, causal, window, interpret, layout,
               plan):
-    site = _Site(layout, q, k, v)
+    block_q, block_k, nq, nseg, chunks, rows_a_block, vmem = plan
+    site = _Site(layout, q, k, v, rows_a_block)
     b, h, sq, sk, d, dv = site.b, site.h, site.sq, site.sk, site.d, site.dv
     group, heads = site.group, site.heads
-    block_q, block_k, nq, nseg, chunks, vmem = plan
     seg = chunks * block_k
     sq_p, sk_p = nq * block_q, nseg * seg
 
@@ -544,7 +675,7 @@ def _fwd_call(q, k, v, bias, sm_scale, causal, window, interpret, layout,
     if bias is not None:
         biasp, bspec = _keys_down_bias(
             bias, sq, sk, sq_p, sk_p, seg, block_q,
-            lambda h, iq, ks: (h, ks, iq))
+            lambda h, iq, ks: (h, ks, iq), rows_a_block)
         in_specs.append(bspec)
         args.append(biasp)
 
@@ -554,9 +685,9 @@ def _fwd_call(q, k, v, bias, sm_scale, causal, window, interpret, layout,
             scale_q=math.frexp(sm_scale)[0] == 0.5, causal=causal,
             window=window, block_q=block_q, block_k=block_k, kv_len=sk,
             chunks=chunks, nseg=nseg, bias_kind=_bias_kind(bias),
-            heads=heads, at=site.at),
+            heads=heads, at=site.at, rows_a_block=rows_a_block),
         name="flash_fwd" + _window_suffix(window),
-        grid=(b, h, nq, nseg),
+        grid=(b // rows_a_block, h, nq, nseg),
         in_specs=in_specs,
         out_specs=[qspec(dv),
                    site.stat_spec(block_q,
@@ -609,7 +740,8 @@ def _bwd_vmem_bytes(chunks, block_q, block_k, d, dv, itemsize, bias_lanes,
 
 
 def _bwd_kernel(*refs, sm_scale, causal, window, block_q, block_k, kv_len,
-                chunks, nq, group, bias_kind, emit_dbias, heads, at):
+                chunks, nq, group, bias_kind, emit_dbias, heads, at,
+                rows_a_block):
     """One q-block against the `chunks` k-blocks of one resident
     k-segment, scores held keys-down ([block_k, block_q]: the row
     statistics are rows, and only dQ's product contracts over a
@@ -629,7 +761,13 @@ def _bwd_kernel(*refs, sm_scale, causal, window, block_q, block_k, kv_len,
     2.11): k, v and the accumulators' rows stay whole 128-lane words.
     So the head is the index of a fori_loop, its body traced and
     compiled once (the same ms a site as a static loop; a backward
-    kernel compiles in 1.2 s for 1.5, 5 s a step of 18 sites)."""
+    kernel compiles in 1.2 s for 1.5, 5 s a step of 18 sites).
+
+    Under the short-sequence plan (_rows_a_block) a block holds
+    `rows_a_block` > 1 batch rows (_each_row), and a row's heads (a
+    static loop here) hand back their one tile's dV, dK and dQ as
+    values: summed, laid side by side and written once, no scratch, no
+    `pl.when` (as in _fwd_kernel)."""
     n_in = 6 + (bias_kind is not None)
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if bias_kind is not None else None
@@ -639,90 +777,136 @@ def _bwd_kernel(*refs, sm_scale, causal, window, block_q, block_k, kv_len,
     dq_scr, dk_scr, dv_scr = refs[-3:]
     ks, step = pl.program_id(2), pl.program_id(3)
     iq = step if group == 1 else step % nq
+    one_tile = rows_a_block > 1
+    if one_tile:
+        iq = ks = 0
 
-    @pl.when(step == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+    def _gain(scr, where, product):
+        """scr[where] += product(); one tile keeps nothing in scratch
+        and is handed the product."""
+        if one_tile:
+            return product()
+        scr[where] += product()
 
-    def _head(i):
-        """Head i's walk: dK and dV gain in scratch, dQ is left in
-        dq_scr."""
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-        if emit_dbias:    # the tiles that do not run still own a block
-            dbias_ref[0, 0] = jnp.zeros_like(dbias_ref[0, 0])
+    def _row(at):
+        brow = at[0] if bias_kind and bias_ref.shape[0] > 1 else 0
 
-        q = _head_lanes(q_ref[at], i, heads)                 # [bq, d]
-        do = _head_lanes(do_ref[at], i, heads)               # [bq, dv]
-        lse, delta = lse_ref[0, i, 0], delta_ref[0, i, 0]    # [1, bq]
-        first = ks * chunks
-        # k-blocks past the last key, or wholly above the causal
-        # diagonal: nothing to do
-        stop = jnp.minimum(chunks, -(-kv_len // block_k) - first)
-        if causal:
-            stop = jnp.minimum(
-                stop, (iq * block_q + block_q - 1) // block_k + 1 - first)
+        if not one_tile:
+            @pl.when(step == 0)
+            def _init():
+                dk_scr[:] = jnp.zeros_like(dk_scr)
+                dv_scr[:] = jnp.zeros_like(dv_scr)
 
-        def _tile(c, carry, diagonal=causal, lower=False):
-            rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
-            k, v = k_ref[(*at, rows, slice(None))], \
-                v_ref[(*at, rows, slice(None))]
-            s = jax.lax.dot_general(
-                k, q, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # [bk, bq]
-            if bias_kind == "key":      # one value a key, on every lane
-                s = s + bias_ref[0, 0, rows, :][:, :1].astype(jnp.float32)
-            elif bias_kind == "score":
-                s = s + bias_ref[0, 0, rows, :].astype(jnp.float32)
-            kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            if kv_len % block_k:        # mask seq padding
-                s = jnp.where(kpos < kv_len, s, NEG_INF)
-            if diagonal:
-                qpos = iq * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1)
-                s = _visible(s, qpos, kpos, window if lower else None)
-            p = jnp.exp(s - lse)                             # [bk, bq]
-            dv_scr[rows, :] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [bk, dv]
-            dp = jax.lax.dot_general(
-                v, do, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [bk, bq]
-            ds = p * (dp - delta)
-            if emit_dbias:
-                dbias_ref[0, 0, rows, :] = ds
-            ds = ds.astype(q.dtype)
-            dk_scr[rows, :] += sm_scale * jax.lax.dot_general(
-                ds, q, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [bk, d]
-            dq_scr[:] += sm_scale * jax.lax.dot_general(
-                ds, k, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [bq, d]
-            return carry
+        def _head(i):
+            """Head i's walk: dK and dV gain in scratch, dQ is left in
+            dq_scr (one tile: all three are handed back)."""
+            if not one_tile:
+                dq_scr[:] = jnp.zeros_like(dq_scr)
+            if emit_dbias:    # tiles that do not run still own a block
+                dbias_ref[at[0], 0] = jnp.zeros_like(dbias_ref[at[0], 0])
 
-        if window is None:
-            jax.lax.fori_loop(0, stop, _tile, None)
+            q = _head_lanes(q_ref[at], i, heads)             # [bq, d]
+            do = _head_lanes(do_ref[at], i, heads)           # [bq, dv]
+            lse = lse_ref[at[0], i, 0]                       # [1, bq]
+            delta = delta_ref[at[0], i, 0]
+            first = ks * chunks
+            # k-blocks past the last key, or wholly above the causal
+            # diagonal: nothing to do
+            stop = jnp.minimum(chunks, -(-kv_len // block_k) - first)
+            if causal:
+                stop = jnp.minimum(
+                    stop,
+                    (iq * block_q + block_q - 1) // block_k + 1 - first)
+
+            def _tile(c, carry, diagonal=causal, lower=False):
+                """What tile c adds to its keys' dV and dK and to this
+                head's dQ: gained in scratch, or (one tile) handed
+                back."""
+                rows = pl.ds(pl.multiple_of(c * block_k, block_k),
+                             block_k)
+                k, v = k_ref[(*at, rows, slice(None))], \
+                    v_ref[(*at, rows, slice(None))]
+                s = jax.lax.dot_general(
+                    k, q, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                if bias_kind == "key":      # one value a key, every lane
+                    s = s + bias_ref[brow, 0, rows, :][:, :1].astype(
+                        jnp.float32)
+                elif bias_kind == "score":
+                    s = s + bias_ref[brow, 0, rows, :].astype(jnp.float32)
+                kpos = (first + c) * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0)
+                if kv_len % block_k:        # mask seq padding
+                    s = jnp.where(kpos < kv_len, s, NEG_INF)
+                if diagonal:
+                    qpos = iq * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 1)
+                    s = _visible(s, qpos, kpos, window if lower else None)
+                p = jnp.exp(s - lse)                         # [bk, bq]
+                dv = _gain(                                  # [bk, dv]
+                    dv_scr, (rows, slice(None)),
+                    lambda: jax.lax.dot_general(
+                        p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+                dp = jax.lax.dot_general(
+                    v, do, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [bk, bq]
+                ds = p * (dp - delta)
+                if emit_dbias:
+                    dbias_ref[at[0], 0, rows, :] = ds
+                ds = ds.astype(q.dtype)
+                dk = _gain(                                  # [bk, d]
+                    dk_scr, (rows, slice(None)),
+                    lambda: sm_scale * jax.lax.dot_general(
+                        ds, q, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+                dq = _gain(                                  # [bq, d]
+                    dq_scr, slice(None),
+                    lambda: sm_scale * jax.lax.dot_general(
+                        ds, k, (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+                return (dv, dk, dq) if one_tile else carry
+
+            if one_tile:
+                return _tile(0, None, causal, window is not None)
+
+            if window is None:
+                jax.lax.fori_loop(0, stop, _tile, None)
+            else:
+                _walk_band(_tile, None, iq, first, stop, block_q, block_k,
+                           window)
+
+        if one_tile:
+            # the whole sequence: the heads' dV and dK summed and their
+            # dQ laid side by side as values, nothing waits in scratch
+            dv, dk, dq = _head(0)
+            for i in range(1, heads):
+                dv_i, dk_i, dq_i = _head(i)
+                dv, dk = dv + dv_i, dk + dk_i
+                dq = _head_lanes(dq_i, i, heads, dq)
+            dq_ref[(0, *at)] = dq.astype(dq_ref.dtype)
+            dk_ref[at] = dk.astype(dk_ref.dtype)
+            dv_ref[at] = dv.astype(dv_ref.dtype)
+            return
+        if heads == 1:
+            _head(0)
+            dq_ref[(0, *at)] = dq_scr[:].astype(dq_ref.dtype)
         else:
-            _walk_band(_tile, None, iq, first, stop, block_q, block_k,
-                       window)
+            def _each(i, _):
+                _head(i)
+                # of a head's dQ its own lanes; the others' are the
+                # other heads' to write
+                dq_ref[(0, *at)] = _head_lanes(
+                    dq_scr[:].astype(dq_ref.dtype), i, heads,
+                    dq_ref[(0, *at)])
+            jax.lax.fori_loop(0, heads, _each, None)
 
-    if heads == 1:
-        _head(0)
-        dq_ref[(0, *at)] = dq_scr[:].astype(dq_ref.dtype)
-    else:
-        def _each(i, _):
-            _head(i)
-            # of a head's dQ its own lanes; the others' are the other
-            # heads' to write
-            dq_ref[(0, *at)] = _head_lanes(
-                dq_scr[:].astype(dq_ref.dtype), i, heads, dq_ref[(0, *at)])
-        jax.lax.fori_loop(0, heads, _each, None)
+        @pl.when(step == pl.num_programs(3) - 1)
+        def _fin():
+            dk_ref[at] = dk_scr[:].astype(dk_ref.dtype)
+            dv_ref[at] = dv_scr[:].astype(dv_ref.dtype)
 
-    @pl.when(step == pl.num_programs(3) - 1)
-    def _fin():
-        dk_ref[at] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[at] = dv_scr[:].astype(dv_ref.dtype)
+    _each_row(_row, at, rows_a_block)
 
 
 _SITE_HELP = {
@@ -739,22 +923,27 @@ _SITE_HELP = {
 }
 
 
-def _count_site(which, nseg, window, site):
+def _count_site(which, plan, window, site):
     from ...observability.registry import default_registry
     path = "relaid" if site.relaid else (
-        "resident" if nseg == 1 else "partial")
+        "resident" if plan.nseg == 1 else "partial")
     default_registry().counter(
         f"paddle_tpu_flash_{which}_sites_total",
         _SITE_HELP[which] + ", by the window (0: none; the kernel is "
         "then named without _window), by the query heads that read one "
         "key head and by the heads a block holds (1: a head-major call, "
         "or a sequence-major one whose heads fill whole 128-lane words; "
-        "128 // D on a sequence-major call with narrower heads). path "
-        "relaid: a sequence-major call whose shape the sequence-major "
-        "blocks cannot serve, transposed by the entry and run head-major.",
-        ("path", "window", "group", "heads_a_block")).labels(
+        "128 // D on a sequence-major call with narrower heads) and the "
+        "batch rows a grid step holds (1 wherever a head's sequence is "
+        "more than one tile; the short-sequence plan's choice where it "
+        "is one). path relaid: a sequence-major call whose shape the "
+        "sequence-major blocks cannot serve, transposed by the entry and "
+        "run head-major.",
+        ("path", "window", "group", "heads_a_block",
+         "rows_a_block")).labels(
             path=path, window=str(window or 0), group=str(site.group),
-            heads_a_block=str(site.heads)).inc()
+            heads_a_block=str(site.heads),
+            rows_a_block=str(plan.rows_a_block)).inc()
 
 
 def _window_suffix(window):
@@ -778,7 +967,9 @@ def _bwd_plan(site, bias, bias_needs_grad, causal, block_q, block_k,
                                bias is not None and bias_needs_grad)
 
     nseg, chunks = _segments(nk, vmem_bytes)
-    return block_q, block_k, nq, nseg, chunks, vmem_bytes(chunks)
+    rows = _rows_a_block(site, nq, nk, vmem_bytes(chunks))
+    return _Plan(block_q, block_k, nq, nseg, chunks, rows,
+                 rows * vmem_bytes(chunks))
 
 
 def _bwd(res, g, sm_scale, causal, window, block_q, block_k, interpret,
@@ -789,7 +980,7 @@ def _bwd(res, g, sm_scale, causal, window, block_q, block_k, interpret,
     site = _Site(layout, q, k, v)
     plan = _bwd_plan(site, bias, bias_needs_grad, causal, block_q, block_k,
                      interpret, q.dtype.itemsize)
-    _count_site("bwd", plan[3], window, site)
+    _count_site("bwd", plan, window, site)
     call = _bwd_call_once if site.seq_major else _bwd_call
     return call(res, g, sm_scale, causal, window, interpret,
                 bias_needs_grad, layout, plan)
@@ -799,13 +990,13 @@ def _bwd_call(res, g, sm_scale, causal, window, interpret, bias_needs_grad,
               layout, plan):
     q, k, v, bias, o, lse = res
     do = g
-    site = _Site(layout, q, k, v)
+    block_q, block_k, nq, nseg, chunks, rows_a_block, vmem = plan
+    site = _Site(layout, q, k, v, rows_a_block)
     b, h, hk, sq, sk, d, dv = (site.b, site.h, site.hk, site.sq, site.sk,
                                site.d, site.dv)
     group, heads = site.group, site.heads
     bias_kind = _bias_kind(bias)
     emit_dbias = bias is not None and bias_needs_grad
-    block_q, block_k, nq, nseg, chunks, vmem = plan
     seg = chunks * block_k
     sq_p, sk_p = nq * block_q, nseg * seg
 
@@ -840,7 +1031,8 @@ def _bwd_call(res, g, sm_scale, causal, window, interpret, bias_needs_grad,
     if bias is not None:
         biasp, bspec = _keys_down_bias(
             bias, sq, sk, sq_p, sk_p, seg, block_q,
-            lambda hk, ks, st: (at(hk, st)[0], ks, at(hk, st)[1]))
+            lambda hk, ks, st: (at(hk, st)[0], ks, at(hk, st)[1]),
+            rows_a_block)
         in_specs.append(bspec)
         args.append(biasp)
     in_specs += [qspec(dv), rspec, rspec]
@@ -858,7 +1050,7 @@ def _bwd_call(res, g, sm_scale, causal, window, interpret, bias_needs_grad,
         out_shape.append(jax.ShapeDtypeStruct(
             (b, h, sk_p, sq_p), jnp.float32))
         out_specs.append(pl.BlockSpec(
-            (1, 1, seg, block_q),
+            (rows_a_block, 1, seg, block_q),
             lambda b, hk, ks, st: (b, at(hk, st)[0], ks, at(hk, st)[1])))
 
     outs = pl.pallas_call(
@@ -866,10 +1058,11 @@ def _bwd_call(res, g, sm_scale, causal, window, interpret, bias_needs_grad,
             _bwd_kernel, sm_scale=sm_scale, causal=causal, window=window,
             block_q=block_q, block_k=block_k, kv_len=sk, chunks=chunks,
             nq=nq, group=group, bias_kind=bias_kind,
-            emit_dbias=emit_dbias, heads=heads, at=site.at),
+            emit_dbias=emit_dbias, heads=heads, at=site.at,
+            rows_a_block=rows_a_block),
         # the benchmark's readers find the backward by flash_bwd_(dq|dkv)
         name="flash_bwd_dkv_dq" + _window_suffix(window),
-        grid=(b, hk, nseg, group * nq),
+        grid=(b // rows_a_block, hk, nseg, group * nq),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,

@@ -78,7 +78,8 @@ def _seq_major(q, k, v, mask, causal, **kw):
 def _check(sq, sk, h, hk, d, causal, masked, label, dv=None, bq=16, bk=16):
     """Output and gradients of the sequence-major call against the
     head-major kernels and the plain reference; the site counters read
-    `label` (path, window, group, heads a block) for the sequence-major
+    `label` (path, window, group, heads a block; one batch row a block,
+    these sequences being several tiles) for the sequence-major
     passes."""
     q, k, v, w, mask = _operands(sq, sk, h, hk, d, dv, masked=masked)
     tiles = dict(block_q=bq, block_k=bk)
@@ -97,6 +98,7 @@ def _check(sq, sk, h, hk, d, causal, masked, label, dv=None, bq=16, bk=16):
     fwd, bwd = _sites("fwd"), _sites("bwd")
     out = ours(q, k, v)
     got = grads(ours)
+    label = (*label, "1")
     assert (_sites("fwd") - fwd) == {label: 2}    # alone, and under grad
     assert (_sites("bwd") - bwd) == {label: 1}
     assert out.shape == want.shape
@@ -166,7 +168,7 @@ def test_a_bias_other_than_a_shared_key_row_is_relaid(kind):
     fwd = _sites("fwd")
     out = _seq_major(q, k, v, bias, False, block_q=16, block_k=16,
                      bias_grad=trained)
-    assert (_sites("fwd") - fwd) == {("relaid", "0", "1", "1"): 1}
+    assert (_sites("fwd") - fwd) == {("relaid", "0", "1", "1", "1"): 1}
     np.testing.assert_allclose(out, _reference(q, k, v, bias, False),
                                atol=2e-5, rtol=2e-5)
     if trained:     # the bias's gradient comes back through the fallback
@@ -205,7 +207,7 @@ def test_sequence_major_windowed_site():
     fwd = _sites("fwd")
     got = _turn(flash_attention(_turn(q), _turn(k), _turn(v),
                                 layout="bshd", **kw))
-    assert (_sites("fwd") - fwd) == {("resident", "20", "1", "2"): 1}
+    assert (_sites("fwd") - fwd) == {("resident", "20", "1", "2", "1"): 1}
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
 
 

@@ -85,7 +85,9 @@ def test_output_and_gradients_match_a_masked_softmax(s, h, hk, d, window,
         assert a.shape == b.shape, name      # dK, dV at the key heads
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5,
                                    err_msg="d" + name)
-    label = ("resident", str(window or 0), str(h // hk), "1")
+    # one head and (a group walks one dK / dV accumulator) one batch row
+    # a block
+    label = ("resident", str(window or 0), str(h // hk), "1", "1")
     assert (_sites("fwd") - fwd)[label] == 2      # alone, and under grad
     assert (_sites("bwd") - bwd)[label] == 1
 

@@ -244,9 +244,12 @@ def _forward_program(passes):
 
 
 def _scans(jaxpr, found=None):
-    """Every scan equation of a jaxpr, the nested ones too."""
+    """Every scan equation of a jaxpr, the nested ones too (a Pallas
+    kernel's own loops are not the program's)."""
     found = [] if found is None else found
     for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
         if eqn.primitive.name == "scan":
             found.append(eqn)
         for v in eqn.params.values():

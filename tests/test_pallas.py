@@ -328,11 +328,14 @@ def test_sdpa_op_flash_flag():
                                atol=1e-5, rtol=1e-5)
 
 
-def test_attention_routing_threshold(monkeypatch):
+@pytest.mark.parametrize("seq,expect_flash", [
+    (512, True), (256, True), (255, False), (128, False)])
+def test_attention_routing_threshold(monkeypatch, seq, expect_flash):
     """Verify WHICH attention path runs. The routing threshold puts
-    flash ahead only from S~512, so on a TPU backend the sdpa op must
-    dispatch the Pallas kernel at S>=512 and keep the naive
-    composition below (the bench transformer's S=256 routes naive)."""
+    flash ahead from S = 256 (ISSUE 51; 512 until then), so on a TPU
+    backend the sdpa op must dispatch the Pallas kernel at S >= 256
+    (the bench transformer's S = 256 among them) and keep the naive
+    composition below (the token server's 128-token prefill bucket)."""
     import jax
     import paddle_tpu as pt
     from paddle_tpu import layers
@@ -352,26 +355,24 @@ def test_attention_routing_threshold(monkeypatch):
     monkeypatch.setattr(pallas_pkg, "flash_attention", fake_flash)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    for seq, expect_flash in ((512, True), (256, False)):
-        pt.reset_default_programs()
-        pt.reset_global_scope()
-        calls.clear()
-        B, H, D = 2, 8, 64
-        main, startup = pt.Program(), pt.Program()
-        with pt.program_guard(main, startup):
-            q = layers.data("q", [H, seq, D], dtype="float32")
-            helper = LayerHelper("sdpa")
-            out = helper.create_tmp_variable("float32")
-            helper.append_op(type="scaled_dot_product_attention",
-                             inputs={"Q": q, "K": q, "V": q},
-                             outputs={"Out": out},
-                             attrs={"causal": True})
-        exe = pt.Executor()
-        exe.run(startup)
-        qv = np.random.RandomState(0).randn(B, H, seq, D).astype(
-            np.float32)
-        exe.run(main, feed={"q": qv}, fetch_list=[out])
-        assert bool(calls) == expect_flash, (seq, calls)
+    pt.reset_default_programs()
+    pt.reset_global_scope()
+    B, H, D = 2, 8, 64
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        q = layers.data("q", [H, seq, D], dtype="float32")
+        helper = LayerHelper("sdpa")
+        out = helper.create_tmp_variable("float32")
+        helper.append_op(type="scaled_dot_product_attention",
+                         inputs={"Q": q, "K": q, "V": q},
+                         outputs={"Out": out},
+                         attrs={"causal": True})
+    exe = pt.Executor()
+    exe.run(startup)
+    qv = np.random.RandomState(0).randn(B, H, seq, D).astype(
+        np.float32)
+    exe.run(main, feed={"q": qv}, fetch_list=[out])
+    assert bool(calls) == expect_flash, (seq, calls)
 
 
 @pytest.mark.parametrize("with_mask", [False, True])

@@ -111,7 +111,9 @@ def test_first_loss_and_adam_update_equal_the_head_major_forms(
         (path, "key_row", "1", "0", "1", "bshd"): 1}
     # (the build's shape inference traces no kernel: the composition
     # says Out's shape)
-    kernels = {("resident", "0", "1", "2"): 3} if path == "flash" else {}
+    # (4 batch rows of one tile each: the short-sequence plan's 4 a step)
+    kernels = ({("resident", "0", "1", "2", "4"): 3} if path == "flash"
+               else {})
     assert dict(_sites("flash_bwd") - bwd) == kernels
     assert dict(_sites("flash_fwd") - fwd) == kernels
     sites = [op for op in main.global_block().ops
@@ -203,7 +205,8 @@ def test_under_a_mesh_the_site_runs_per_shard_of_batch_and_heads(
     # and 2 heads, one block; forward and again under the grad op
     assert seen and all(dim == 2 for _, dim in seen)
     assert {shape for shape, _ in seen} == {(4, S, 4, 64)}
-    assert set(_sites("flash_fwd") - fwd) == {("resident", "0", "1", "2")}
+    assert set(_sites("flash_fwd") - fwd) == {
+        ("resident", "0", "1", "2", "2")}      # a shard's 2 rows a step
     np.testing.assert_allclose(float(np.asarray(got).reshape(())), loss,
                                rtol=2e-5)
 

@@ -60,16 +60,23 @@ def _sum_f32(outs):
                for o in jax.tree_util.tree_leaves(outs))
 
 
-def _sites(which):
-    """Counter of path: flash `fwd` or `bwd` calls traced so far."""
+def _site_counts(family, label=0):
+    """Counter of one label (by default the first, path) of a site
+    counter family, summed over the others."""
     import collections
 
     from paddle_tpu.observability import default_registry
-    fam = default_registry().get(f"paddle_tpu_flash_{which}_sites_total")
-    by_path = collections.Counter()     # over every window and group
+    fam = default_registry().get(family)
+    counts = collections.Counter()
     for labels, child in (fam.samples() if fam is not None else ()):
-        by_path[labels[0]] += child.value
-    return by_path
+        counts[labels[label]] += child.value
+    return counts
+
+
+def _sites(which, label=0):
+    """Counter of path (or of label 4, rows_a_block): flash `fwd` or
+    `bwd` calls traced so far."""
+    return _site_counts(f"paddle_tpu_flash_{which}_sites_total", label)
 
 
 # [B, H, S, D] of chip_smoke.py's train step (b4 x s2048, 8 heads of
@@ -246,8 +253,10 @@ def test_flash_forward_beyond_the_vmem_budget_compiles(one_chip, seq, budget,
     assert not re.findall(rf"f32\[1,2,{seq},128\]", text)
 
 
+@pytest.mark.parametrize("batch,seq,rows", [(8, 2048, 1), (64, 256, 4)],
+                         ids=["8x2048", "64x256"])
 def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_mask(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, batch, seq, rows):
     """The step of transformer-base.train-s2048 (chipbench/configs,
     batch 8 x sequence 2048, AMP bf16), compiled whole for the described
     chip: 18 attention sites x (forward, one backward kernel that forms
@@ -255,17 +264,28 @@ def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_
     backward with its head's K and V resident, and causality reaches
     them as a flag, so nothing of [.., 2048, 2048] f32 is an operand or
     a constant (ISSUE 32; until then the decoder's self-attention was
-    handed an f32[8,1,2048,2048] sum of triangle and pad mask)."""
+    handed an f32[8,1,2048,2048] sum of triangle and pad mask).
+
+    And the step of transformer-base.train-s256 (batch 64 x 256: ISSUE
+    51), whose sites the dispatcher — left the choice, as on the chip —
+    hands the same two kernels since the crossover stands at 256, under
+    the short-sequence plan (`rows` batch rows a grid step): no site is
+    composed and no [64, 8, 256, 256] score tensor exists in the step."""
     import importlib
     import re
 
     import paddle_tpu as pt
     from paddle_tpu.models import transformer
 
-    batch, seq, vocab = 8, 2048, 32000
+    vocab = 32000
     sites = _sites("bwd")
-    # the rule asks jax.default_backend(), which is still the CPU here
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_SDPA", "force")
+    sdpa = _site_counts("paddle_tpu_sdpa_sites_total")
+    fwd_rows, bwd_rows = _sites("fwd", 4), _sites("bwd", 4)
+    # the rule asks jax.default_backend(), which is still the CPU here:
+    # the knob as a TPU reads its default, so that the crossover decides
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.pallas"),
+        "pallas_dispatch", lambda knob, default: (True, False))
     monkeypatch.setattr(
         importlib.import_module("paddle_tpu.ops.pallas.flash_attention"),
         "_interpret_default", lambda: False)
@@ -306,6 +326,14 @@ def test_train_step_holds_18_forward_and_18_backward_kernels_and_no_score_sized_
     assert calls.count("flash_fwd") == 18
     assert _sites("bwd") - sites == {"resident": 18}
     assert _sites("fwd") - fwd_sites == {"resident": 18}
+    # every site was handed the kernels (the build's shape inference
+    # counts no site), each call with the plan's batch rows a grid step
+    # (a grad op applies its forward op's pullback and is no site)
+    assert _site_counts("paddle_tpu_sdpa_sites_total") - sdpa == {
+        "flash": 18}
+    assert _sites("bwd", 4) - bwd_rows == {str(rows): 18}
+    assert _sites("fwd", 4) - fwd_rows == {str(rows): 18}
+    assert f"[{batch},8,{seq},{seq}]" not in text
     # the logsumexp leaves the forward [8, 8, 2048] f32 (ISSUE 40; until
     # then 128 lanes wide, 67 MB a site, for XLA to cut a column out of)
     assert not re.findall(rf"f32\[{batch},8,{seq},128\]", text)
