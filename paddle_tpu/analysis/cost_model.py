@@ -397,6 +397,31 @@ def _flops_for(op: ir.OpDesc,
             return None, False, None
         return 2 * w.shape[0] * x.numel, True, None
 
+    if t in ("grouped_causal_conv1d", "grouped_conv_state_update"):
+        # a [width, width] product a head a tap (ops/cca_ops.py): W is
+        # [taps * heads * width, width]
+        x, w = first("X"), first("W")
+        if x is None or w is None or len(w.shape) != 2:
+            return None, False, None
+        return (2 * (x.numel // x.shape[-1]) * w.shape[0] * w.shape[1],
+                True, None)
+
+    if t == "cca_qk_mix":
+        # the mean join, the squared norm and the scaling of each head
+        z = first("Z")
+        return (None, False, None) if z is None else \
+            (10 * z.numel, False, None)
+
+    if t == "mlp_router":
+        # the down-projection, two hidden layers and the scores, then
+        # the GELUs and the softmax
+        x, wd, w3 = first("X"), first("WDown"), first("W3")
+        if x is None or wd is None or w3 is None:
+            return None, False, None
+        rows, h = x.numel // x.shape[-1], wd.shape[1]
+        return (2 * rows * (x.shape[-1] * h + 2 * h * h + h * w3.shape[1])
+                + 20 * rows * h, True, None)
+
     if t in ("lstm", "gru"):
         # the fused recurrence mega-ops (ops/sequence_ops.py, Pallas
         # fused_lstm/fused_gru): the per-step recurrent matmul

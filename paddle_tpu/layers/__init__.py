@@ -10,6 +10,7 @@ from .learning_rate_scheduler import *  # noqa: F401,F403
 from .detection import *  # noqa: F401,F403
 from .csp import *  # noqa: F401,F403
 from .ssm import *  # noqa: F401,F403
+from .cca import *  # noqa: F401,F403
 from . import math_op_patch
 from .math_op_patch import monkey_patch_variable  # noqa: F401
 

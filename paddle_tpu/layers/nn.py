@@ -445,7 +445,7 @@ def moe_router(input, experts_total, top_k, routed_scaling_factor=1.0,
 
 def moe_experts(input, top_idx, top_w, d_inner, experts_total,
                 experts_held=None, expert_offset=0, down_init_scale=1.0,
-                name=None):
+                name=None, tally=True):
     """The gated-FFN experts [expert_offset, expert_offset +
     experts_held) of a layer of ``experts_total``, applied to the tokens
     the routing (top_idx, top_w) sends them (ops/moe_ops.py
@@ -459,7 +459,9 @@ def moe_experts(input, top_idx, top_w, d_inner, experts_total,
     follows), and the rows of the buffer the step's other passes ran
     over, summed over the steps (the blocks of ops/moe_ops.py
     block_rows that held the live rows; nothing else in the program
-    shows either)."""
+    shows either) — unless ``tally`` is False: a served program is
+    frozen and writes no persistable but its slots' state
+    (models/cca_moe.py counts its rows in the router)."""
     helper = LayerHelper("moe_experts", name=name)
     held = experts_total if experts_held is None else int(experts_held)
     d, f = int(input.shape[-1]), int(d_inner)
@@ -486,6 +488,8 @@ def moe_experts(input, top_idx, top_w, d_inner, experts_total,
                             "experts_held": held,
                             "expert_offset": int(expert_offset),
                             "top_k": int(top_idx.shape[-1])})
+    if not tally:
+        return out
     tally = helper.create_global_variable(
         shape=[4], dtype="float32", persistable=True,
         name=helper.name + ".live_rows")

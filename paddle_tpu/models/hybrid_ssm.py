@@ -40,10 +40,12 @@ import math
 import numpy as np
 
 from .. import layers
-from ..initializer import (ConstantInitializer, NormalInitializer,
-                           NumpyArrayInitializer, UniformInitializer)
+from ..initializer import (NormalInitializer, NumpyArrayInitializer,
+                           UniformInitializer)
 from ..layer_helper import LayerHelper, ParamAttr
 from .decoder_moe import _embed, _heads, _linear, gated_ffn
+from .served_lm import (create_states, last_real_rows, mode_feeds,
+                        program_set, rms as _rms, tied_head)
 from .transformer import (KV_CACHE_PREFIX, LMProgram, _cache_update,
                           _sdpa_op)
 
@@ -105,18 +107,6 @@ def _sizes(arch):
         head_dim=arch["hidden_size"] // arch["num_attention_heads"])
 
 
-def _rms(x, eps, name="norm"):
-    """layers.rms_norm with a float32 scale whatever x's width."""
-    helper = LayerHelper(name)
-    scale = helper.create_parameter(
-        None, [int(x.shape[-1])], "float32",
-        default_initializer=ConstantInitializer(1.0))
-    out = helper.create_tmp_variable(x.dtype)
-    helper.append_op(type="rms_norm", inputs={"X": x, "Scale": scale},
-                     outputs={"Y": out}, attrs={"epsilon": eps})
-    return out
-
-
 def _mamba_parameters(arch, layer, seed, dtype):
     """The mixer's small parameters, made by every program alike (the
     projections come from ``_linear`` where they are used): the
@@ -145,9 +135,9 @@ def _mamba_parameters(arch, layer, seed, dtype):
     return w, bias, a_log, dt_bias, d
 
 
-def _create_states(arch, slots, max_seq_len, dtypes):
-    """{name: var}: every persistable state of the stack, zero-filled
-    by the startup program."""
+def _state_shapes(arch, slots, max_seq_len, dtypes):
+    """{name: (shape, dtype)} of every persistable state of the stack,
+    by kind."""
     size = _sizes(arch)
     shapes = {
         "kv": ([slots, arch["num_key_value_heads"], max_seq_len,
@@ -156,16 +146,9 @@ def _create_states(arch, slots, max_seq_len, dtypes):
                  dtypes["conv"]),
         "ssm": ([slots, arch["mamba_d_state"], size["d_inner"]],
                 dtypes["ssm"])}
-    helper = LayerHelper("slot_state")
-    out = {}
-    for kind, names in state_names(arch["layer_types"]).items():
-        shape, dtype = shapes[kind]
-        for name in names:
-            v = helper.create_global_variable(shape, dtype, name=name,
-                                              persistable=True)
-            helper.set_variable_initializer(v, ConstantInitializer(0.0))
-            out[name] = v
-    return out
+    return {name: shapes[kind]
+            for kind, names in state_names(arch["layer_types"]).items()
+            for name in names}
 
 
 def _build_program(mode, seq_len, arch, vocab_size, max_seq_len, slots,
@@ -189,24 +172,10 @@ def _build_program(mode, seq_len, arch, vocab_size, max_seq_len, slots,
     main.random_seed = startup.random_seed = seed
     with pt.program_guard(main, startup), framework.isolated_name_scope():
         decode = mode == "decode"
-        n = 1 if mode == "prefill" else slots
-        ids = layers.data("token_ids", [n, 1 if decode else seq_len, 1],
-                          dtype="int64", append_batch_size=False)
-        feeds = ["token_ids"]
-        slot = positions = None
-        if decode:
-            positions = layers.data("positions", [slots], dtype="int64",
-                                    append_batch_size=False)
-            feeds.append("positions")
-        lengths = layers.data("lengths", [n], dtype="int64",
-                              append_batch_size=False)
-        feeds.append("lengths")
-        if mode == "prefill":
-            slot = layers.data("slot", [1], dtype="int64",
-                               append_batch_size=False)
-            feeds.append("slot")
-        states = _create_states(arch, slots, max_seq_len, dtypes) \
-            if mode != "full" else {}
+        ids, positions, lengths, slot, feeds = mode_feeds(mode, seq_len,
+                                                          slots)
+        states = create_states(_state_shapes(
+            arch, slots, max_seq_len, dtypes)) if mode != "full" else {}
 
         helper = LayerHelper("hybrid_lm")
         table = helper.create_parameter(
@@ -288,40 +257,12 @@ def _build_program(mode, seq_len, arch, vocab_size, max_seq_len, slots,
             x = layers.elementwise_add(x, layers.scale(f, scale=residual))
 
         if not decode:
-            # each row's last real position, before the head: one row
-            # of logits a request, not one a position
-            one = layers.fill_constant([1], "int64", 1)
-            last = layers.elementwise_sub(layers.unsqueeze(lengths, [1]),
-                                          one)
-            pick = layers.cast(layers.one_hot(last, seq_len), w_dtype)
-            x = layers.unsqueeze(layers.reduce_sum(
-                layers.elementwise_mul(x, layers.unsqueeze(pick, [2])),
-                dim=1), [1])                               # [n, 1, d]
+            x = last_real_rows(x, lengths, seq_len, w_dtype)   # [n, 1, d]
         logits = layers.scale(
-            layers.matmul(_rms(x, eps, "final_norm"), table,
-                          transpose_y=True, out_dtype="float32"),
+            tied_head(x, table, eps),
             scale=1.0 / float(arch["logits_scaling"]))     # [n, 1, V]
         next_tok = layers.argmax(logits, axis=-1)          # [n, 1]
     return LMProgram(main, startup, feeds, next_tok.name)
-
-
-class _OnAsk(dict):
-    """{bucket: LMProgram} whose programs are built when first asked
-    for. The served path (mode "cached") never runs a re-forward
-    program, and building and verifying one of 40 layers costs what a
-    prefill program's costs (8 s a bucket of the cell's set-up); the
-    names line up whenever it is built (``isolated_name_scope``).
-    ``items()`` and ``in`` see what has been built."""
-
-    def __init__(self, buckets, build):
-        super().__init__()
-        self._buckets, self._build = tuple(buckets), build
-
-    def __missing__(self, bucket):
-        if bucket not in self._buckets:
-            raise KeyError(bucket)
-        lm = self[bucket] = self._build(bucket)
-        return lm
 
 
 def build_hybrid_lm(arch, vocab_size=1000, max_seq_len=64, slots=4,
@@ -334,26 +275,12 @@ def build_hybrid_lm(arch, vocab_size=1000, max_seq_len=64, slots=4,
     [names]}, "state_prefixes": (...)}. ``arch`` holds
     the published config keys (ARCH_KEYS), ``dtypes`` the storage
     width by kind (SERVED_DTYPES where None). The "full" programs are
-    built when first asked for (``_OnAsk``)."""
+    built when first asked for (served_lm.py ``OnAsk``)."""
     _check(arch)
     dtypes = dict(SERVED_DTYPES, **(dtypes or {}))
-    prompt_buckets = sorted(set(int(s) for s in prompt_buckets))
-    cache_buckets = sorted(set(int(c) for c in cache_buckets))
-    if prompt_buckets[-1] > max_seq_len or cache_buckets[-1] > max_seq_len:
-        raise ValueError(
-            f"bucket exceeds max_seq_len={max_seq_len}: prompt "
-            f"{prompt_buckets}, cache {cache_buckets}")
     args = (arch, vocab_size, max_seq_len, slots, seed, dtypes,
             float(embedding_std))
-    out = {"prefill": {}, "decode": {}, "full": _OnAsk(
-        prompt_buckets, lambda s: _build_program("full", s, *args))}
-    for s in prompt_buckets:
-        out["prefill"][s] = _build_program("prefill", s, *args)
-    for c in cache_buckets:
-        out["decode"][c] = _build_program("decode", c, *args)
-    out["startup"] = out["prefill"][prompt_buckets[0]].startup
-    kinds = state_names(arch["layer_types"])
-    out["state_kinds"] = kinds
-    out["cache_names"] = kinds["kv"] + kinds["conv"] + kinds["ssm"]
-    out["state_prefixes"] = STATE_PREFIXES
-    return out
+    return program_set(
+        lambda mode, bucket: _build_program(mode, bucket, *args),
+        max_seq_len, prompt_buckets, cache_buckets,
+        state_names(arch["layer_types"]), STATE_PREFIXES)

@@ -27,3 +27,4 @@ from . import augment_ops  # noqa: F401
 from . import cache_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
+from . import cca_ops  # noqa: F401

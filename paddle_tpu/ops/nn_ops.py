@@ -794,6 +794,10 @@ def _sdpa(ctx):
     if layout == "bshd":      # the arrays a head-major site is handed
         q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     scale = 1.0 / np.sqrt(q.shape[-1])
+    if group != 1 and kv_len is not None:
+        ctx.set_output("Out", _grouped_cached_attention(q, k, v, mask,
+                                                        group, scale))
+        return
     if group != 1:
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     scores = jnp.einsum("...qd,...kd->...qk", q, k) * scale
@@ -811,6 +815,26 @@ def _sdpa(ctx):
     out = jnp.einsum("...qk,...kd->...qd", probs, v)
     ctx.set_output("Out", jnp.swapaxes(out, 1, 2) if layout == "bshd"
                    else out)
+
+
+def _grouped_cached_attention(q, k, v, mask, group, scale):
+    """The composition over KV caches at ``group`` query heads a key
+    head: q [b, hk * group, Sq, d], k and v [b, hk, Sk, d], mask
+    [b, 1, 1, Sk]. A key head's query heads are rows of ONE product over
+    its keys, so nothing as large as K or V is repeated (repeated, a
+    decode step of 96 slots at a bucket of 2048 writes and reads 0.8 GB
+    a layer beside the 0.2 GB it attends to). Scores and softmax in
+    float32, the products' operands at the arrays' own width (the
+    wider of Q's and the cache's)."""
+    b, h, sq, d = q.shape
+    rows = q.reshape(b, h // group, group * sq, d)
+    scores = jnp.einsum("bkqd,bksd->bkqs", rows, k,
+                        preferred_element_type=jnp.float32) * scale
+    probs = jax.nn.softmax(scores + mask, axis=-1)
+    out = jnp.einsum("bkqs,bksd->bkqd",
+                     probs.astype(jnp.result_type(q.dtype, v.dtype)), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, h, sq, v.shape[-1]).astype(q.dtype)
 
 
 # -- misc -------------------------------------------------------------------

@@ -462,6 +462,9 @@ class GenerationEngine:
             self.metrics.step_seconds.record(t1 - t0)
             if blocks is not None:
                 self.metrics.kv_blocks(*blocks)
+                rows = self.model.last_expert_rows()
+                if rows is not None:
+                    self.metrics.expert_rows(rows)
             if obs_attr.attribution_enabled():
                 cost = self.model.last_cost()
                 peak = obs_attr.peak_flops()

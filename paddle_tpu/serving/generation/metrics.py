@@ -37,6 +37,10 @@ class GenerationMetrics:
       slot — circuit_open, queue_full, model_budget (host routing)
     - kv_blocks_total{state}: cache blocks a cached step's attention
       reads (a live row in them) and skips (the rest under the bucket)
+    - expert_rows_total{expert} / experts_read_total: the rows a
+      decode step's routing sent each expert and the experts a step
+      read, both summed over the layers (a model with expert layers
+      alone: the series appear with its first step)
     - step_seconds / prefill_seconds: device step wall time
     - queue_wait_seconds / ttft_seconds: each retired request's wait
       for a slot and time to first token, from the timestamps on its
@@ -140,6 +144,9 @@ class GenerationMetrics:
         self._attr_job = f"engine_gen_{self.engine_label}"
         self.mfu = None
         self.model_flops = None
+        # as lazy: a model without expert layers leaves no series
+        self._expert_rows_family = None
+        self.experts_read = None
 
     def retired(self, reason: str, future=None) -> None:
         """One request left the engine. ``future`` (its
@@ -164,6 +171,31 @@ class GenerationMetrics:
         for state, n in (("read", read), ("skipped", under_bound - read)):
             self._kv_blocks_family.labels(engine=self.engine_label,
                                           state=state).inc(n)
+
+    def expert_rows(self, rows) -> None:
+        """One decode step's routing (GenerationModel.last_expert_rows):
+        ``rows`` [experts + 1], the rows each expert was sent summed
+        over the layers, then the experts any row reached — whose
+        weights the step read — summed over the layers."""
+        if self._expert_rows_family is None:
+            self._expert_rows_family = self.registry.counter(
+                "paddle_tpu_decode_expert_rows_total",
+                "Rows the decode steps' routing sent each expert, "
+                "summed over the expert layers: a live slot's token is "
+                "one row a layer; an empty slot's is none.",
+                ("engine", "expert"))
+            fam = self.registry.counter(
+                "paddle_tpu_decode_experts_read_total",
+                "Experts the decode steps read the weights of, summed "
+                "over the expert layers and the steps: an expert a "
+                "step's routing sent no row is not read.", ("engine",))
+            self._owned_families.append(fam)
+            self.experts_read = fam.labels(engine=self.engine_label)
+        for expert, n in enumerate(rows[:-1]):
+            if n:
+                self._expert_rows_family.labels(
+                    engine=self.engine_label, expert=str(expert)).inc(int(n))
+        self.experts_read.inc(int(rows[-1]))
 
     def state_bytes(self, by_kind: Dict[str, int]) -> None:
         """What the model this engine serves reserves a kind of
@@ -199,8 +231,9 @@ class GenerationMetrics:
         for fam in self._owned_families:
             fam.discard(key)
         for family in (self._retired_family, self._shed_family,
-                       self._kv_blocks_family, self._state_bytes_family):
-            for k, _ in family.samples():
+                       self._kv_blocks_family, self._state_bytes_family,
+                       self._expert_rows_family):
+            for k, _ in family.samples() if family is not None else ():
                 if k[0] == self.engine_label:
                     family.discard(k)
         if self.mfu is not None:
@@ -227,6 +260,10 @@ class GenerationMetrics:
             "state_bytes_by_kind": self._by_reason(self._state_bytes_family),
             "mfu": self.mfu.value if self.mfu is not None else 0.0,
         }
+        if self._expert_rows_family is not None:
+            out["expert_rows_by_expert"] = self._by_reason(
+                self._expert_rows_family)
+            out["experts_read"] = self.experts_read.value
         if executor is not None:
             cs = dict(executor.cache_stats)
             total = cs["hits"] + cs["misses"]

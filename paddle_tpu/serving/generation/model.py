@@ -7,9 +7,11 @@ generation model is a FAMILY of programs sharing one parameter set by
 name, plus persistable per-slot state the decode programs update in
 place via donation. A spec names the architecture family that builds
 its programs (``FAMILIES``): "transformer" (models/transformer.py
-build_decoder_lm: ``kv_cache.*`` state) or "hybrid_ssm"
+build_decoder_lm: ``kv_cache.*`` state), "hybrid_ssm"
 (models/hybrid_ssm.py: KV caches beside convolution windows and
-recurrent states, three kinds a slot).
+recurrent states, three kinds a slot) or "cca_moe" (models/cca_moe.py:
+a compressed KV cache beside three convolution windows a layer, and
+top-1 experts whose rows each step reports with its tokens).
 All programs live in one Executor compile cache — hosting N models on
 a shared executor (GenerationHost) dedupes nothing but ALSO collides
 nothing, because the cache key includes each program's uid/version.
@@ -60,7 +62,9 @@ class GenerationSpec:
         family's own description of the stack — for "hybrid_ssm" the
         keywords of models/hybrid_ssm.py build_hybrid_lm beyond the
         sizes above: ``arch`` (the published config keys), ``dtypes``,
-        ``embedding_std`` — and None for "transformer", which reads
+        ``embedding_std``; the same three for "cca_moe"
+        (models/cca_moe.py build_cca_moe_lm) — and None for
+        "transformer", which reads
         n_layer, n_head, d_model and d_inner."""
         from ... import flags
         if slots is None:
@@ -129,11 +133,23 @@ def _build_hybrid_ssm(spec: GenerationSpec) -> Dict:
         **(spec.arch or {}))
 
 
+def _build_cca_moe(spec: GenerationSpec) -> Dict:
+    from ...models.cca_moe import build_cca_moe_lm
+    return build_cca_moe_lm(
+        vocab_size=spec.vocab_size, max_seq_len=spec.max_seq_len,
+        slots=spec.slots, prompt_buckets=spec.prompt_buckets,
+        cache_buckets=spec.cache_buckets, seed=spec.seed,
+        **(spec.arch or {}))
+
+
 #: family name -> the builder of its program set; each returns what
 #: build_decoder_lm returns, and may add "state_kinds" ({kind: [names]})
-#: and "state_prefixes" where a slot owns more than KV caches
+#: and "state_prefixes" where a slot owns more than KV caches, and
+#: "observed" ((mode, bucket) -> {what: (offset, shape)}) where a
+#: program's fetch carries more than its tokens
 FAMILIES = {"transformer": _build_transformer,
-            "hybrid_ssm": _build_hybrid_ssm}
+            "hybrid_ssm": _build_hybrid_ssm,
+            "cca_moe": _build_cca_moe}
 
 
 class GenerationModel:
@@ -170,6 +186,10 @@ class GenerationModel:
                             for n in names]
         self._state_prefixes = tuple(programs.get(
             "state_prefixes", (KV_CACHE_PREFIX,)))
+        # what the last prefill / decode run reported after its tokens
+        # ({what: int array}; {} for a family that reports nothing)
+        self._observed_layout = programs.get("observed")
+        self.last_observed: Dict[str, np.ndarray] = {}
         self._check_frozen()
         self._verify()
         if init_scope:
@@ -241,7 +261,7 @@ class GenerationModel:
 
     def _full(self, bucket):
         """The re-forward program of a prompt bucket. A family may
-        build these when first asked for (models/hybrid_ssm.py _OnAsk);
+        build these when first asked for (models/served_lm.py OnAsk);
         such a program passes the two gates of the constructor here,
         before its first use."""
         held = self.programs["full"]
@@ -296,6 +316,29 @@ class GenerationModel:
                                     scope=self.scope, sync=True)
         return np.asarray(res[0])
 
+    def _tokens(self, out: np.ndarray, n: int, mode: str, bucket: int
+                ) -> np.ndarray:
+        """The first ``n`` values a program fetched are its tokens; a
+        family may append what the step observed ("observed" of its
+        program set: models/cca_moe.py's expert rows and picks), kept
+        as ``last_observed`` — host views of the one fetch, no other
+        transfer."""
+        flat = out.reshape(-1)
+        if self._observed_layout is not None:
+            tail = flat[n:]
+            self.last_observed = {
+                what: tail[at:at + int(np.prod(shape))].reshape(shape)
+                for what, (at, shape) in
+                self._observed_layout(mode, int(bucket)).items()}
+        return flat[:n]
+
+    def last_expert_rows(self) -> Optional[np.ndarray]:
+        """[experts + 1] of the last decode step: the rows each expert
+        was sent, summed over the layers, then the experts any row
+        reached, summed over the layers; None for a family without
+        experts."""
+        return self.last_observed.get("expert_rows")
+
     def run_prefill(self, prompt: List[int], slot: int) -> int:
         """Full-prompt forward for one request into `slot`'s cache
         rows; returns the first greedy token."""
@@ -310,7 +353,7 @@ class GenerationModel:
             "token_ids": ids,
             "lengths": np.asarray([len(prompt)], np.int64),
             "slot": np.asarray([slot], np.int64)})
-        return int(out.reshape(-1)[0])
+        return int(self._tokens(out, 1, "prefill", s)[0])
 
     def run_decode(self, tokens: np.ndarray, positions: np.ndarray,
                    bucket: int, lengths: Optional[np.ndarray] = None
@@ -329,7 +372,7 @@ class GenerationModel:
             "positions": positions,
             "lengths": positions + 1 if lengths is None
             else lengths.astype(np.int64)})
-        return out.reshape(-1)
+        return self._tokens(out, self.spec.slots, "decode", bucket)
 
     def run_full(self, token_matrix: np.ndarray, lengths: np.ndarray,
                  bucket: int) -> np.ndarray:

@@ -830,3 +830,87 @@ def test_decode_attention_at_four_query_heads_a_key_head_compiles(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# The CCA / expert family's programs (models/cca_moe.py) at ZAYA1-8B's
+# published widths and the zaya1-8b.serve-reasoning cell's sizes: 96
+# slots, 8 query heads over 2 key heads of 128, bfloat16 caches to 2048
+# positions, 16 experts of 2048, the 262,272-row table — at TWO of the
+# cell's 20 layers, which is what a test's minute allows; a layer more
+# adds 0.42 GB of weights and 0.2 GB of cache and nothing else (the
+# 20-layer compile's reading is in PERF.md section 4).
+@pytest.mark.parametrize("mode,bucket", [("decode", 2048),
+                                         ("prefill", 256)])
+def test_a_cca_moe_program_compiles_at_the_published_widths(
+        topo, one_chip, monkeypatch, mode, bucket):
+    """The appends and the grouped products as custom calls (and the
+    flash kernel in a 256-token prefill), every cache and window
+    aliased to its output, and no temporary as large as a slice of a
+    cache: composed over the cache with the key heads REPEATED, a decode
+    step's attention planned 0.8 GB of them."""
+    import json
+    import os
+    import re
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import cca_moe
+    from paddle_tpu.ops import cache_ops
+
+    real = cache_ops.device_lane_axis
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        cache_ops, "device_lane_axis",
+        lambda shape, dtype: real(shape, dtype, topo.devices[0]))
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "chipbench", "configs",
+                           "zaya1-8b.json")) as f:
+        cfg = json.load(f)
+    layers, slots, max_seq = 2, 96, 2048
+    arch = dict({k: cfg[k] for k in cca_moe.ARCH_KEYS},
+                layer_types=["hybrid"] * layers)
+    lm = cca_moe._build_program(
+        mode, bucket, arch, cfg["vocab_size"], max_seq, slots, 0,
+        dict(cca_moe.SERVED_DTYPES), 0.02)
+    block = lm.main.desc.block(0)
+
+    class EveryVarThere:         # shapes come from the program, not
+        def has(self, name):     # from gigabytes of arrays in a scope
+            return True
+
+    step = pt.Executor()._compile(lm.main.desc, block, None,
+                                  [lm.fetch_name], EveryVarThere())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def state(names):
+        return {n: sds(block.find_var_recursive(n).shape,
+                       jnp.dtype(block.find_var_recursive(n).dtype))
+                for n in names}
+
+    feed = {"token_ids": sds((slots, 1, 1), jnp.int32),
+            "positions": sds((slots,), jnp.int32),
+            "lengths": sds((slots,), jnp.int32)} if mode == "decode" else \
+        {"token_ids": sds((1, bucket, 1), jnp.int32),
+         "lengths": sds((1,), jnp.int32), "slot": sds((1,), jnp.int32)}
+    compiled = step.jitted.lower(
+        feed, state(step.ro_names), state(step.rw_names),
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w[\w\-]*?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    # a layer: K, V and three windows; the grouped products are three
+    # calls and their metadata
+    assert len(step.rw_names) == 5 * layers
+    assert calls.count("ragged-dot-none") == 3 * layers
+    if mode == "decode":
+        assert calls.count("kv_cache_append") == 2 * layers
+    else:
+        assert calls.count("flash_fwd") == layers
+    memory = compiled.memory_analysis()
+    cache = slots * 2 * max_seq * 128 * 2              # one K or V
+    weights = 2 * (2 * 207_566_355 - 1 + 262272 * 2048) - 2 * 659_985 * 2
+    assert memory.alias_size_in_bytes >= 2 * layers * cache
+    assert weights < memory.argument_size_in_bytes \
+        < weights + 2 * layers * cache + (64 << 20)
+    assert memory.temp_size_in_bytes < 32 << 20
