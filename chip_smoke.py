@@ -8,7 +8,9 @@ AMP bf16 — the shape at which attention routes into the Pallas flash
 kernel, forward and backward):
 
   kernels  flash_attention / fused_lstm / fused_gru, compiled, forward
-           and backward, against the plain compositions they replace
+           and backward, against the plain compositions they replace;
+           decode_attention's row-major body (128-wide keys) against
+           the composed read of the cache, on ragged lengths
   train    build_train -> Trainer.start -> Trainer.train: in-memory
            feeds, then the SAME compiled step fed by
            StreamingInputService spawn workers reading recordio shards
@@ -56,14 +58,18 @@ FULL = dict(vocab=32000, n_layer=6, n_head=8, d_model=512, d_inner=2048,
             new_tokens=32, rnn=(64, 64, 512),       # the stacked-LSTM LM's T,B,H
             flash_odd_seq=1100, mesh_batch=8, mesh_steps=3,
             # joyai-llm-flash.train-ep32's attention site: B,H,S,d,d_v
-            latent_site=(1, 32, 4096, 192, 128))
+            latent_site=(1, 32, 4096, 192, 128),
+            # zaya1-8b.serve-reasoning's decode site: slots, key heads,
+            # query heads a key head, max_seq, d_key
+            decode_site=(96, 2, 4, 2048, 128))
 TINY = dict(vocab=96, n_layer=1, n_head=4, d_model=256, d_inner=512,
             batch=2, seq=16, steps=4, stream_steps=2,
             prompt_buckets=[8, 16], cache_buckets=[16, 32],
             prompt_lens=[3, 5, 9, 12], deep_prompt=14,
             new_tokens=4, rnn=(6, 4, 8),
             flash_odd_seq=20, mesh_batch=4, mesh_steps=2,
-            latent_site=(1, 2, 24, 24, 16))
+            latent_site=(1, 2, 24, 24, 16),
+            decode_site=(5, 2, 4, 512, 128))
 
 # Stated tolerances. Losses are means over thousands of tokens, so bf16
 # rounding (eps 2^-8) mostly averages out. Kernel outputs and gradients
@@ -293,6 +299,48 @@ def phase_kernels(sm, cfg, interpret):
              f"kernel fused_gru T{t_max} B{bsz} H{hid} f32 ragged agrees "
              "with the masked scan", fwd_reldiff=fwd, grad_reldiff=bwd,
              rtol=RNN_FWD_RTOL, grad_rtol=RNN_GRAD_RTOL)
+
+    # the decode step's cached attention at 128-wide keys: the kernel's
+    # row-major body (live blocks only, products on the MXU at the
+    # cache's width) against the rule's composition over the whole
+    # cache, on lengths 0, 1, a block's edges, the bound and in between
+    from paddle_tpu.ops.nn_ops import _grouped_cached_attention
+    from paddle_tpu.ops.pallas.decode_attention import (block_rows,
+                                                        decode_attention)
+    slots, key_heads, group, max_seq, d_key = cfg["decode_site"]
+    q = f32(slots, key_heads * group, 1, d_key,
+            scale=1.0).astype(jnp.bfloat16)
+    k, v = (f32(slots, key_heads, max_seq, d_key,
+                scale=1.0).astype(jnp.bfloat16) for _ in range(2))
+    edge = block_rows(max_seq)
+    lens = rng.randint(1, max_seq + 1, slots)
+    lens[:5] = [0, 1, edge, edge + 1, max_seq]
+    kv_len = jnp.asarray(lens, jnp.int32)
+
+    @jax.jit
+    def composed(q, k, v, kv_len):
+        mask = jnp.where(jnp.arange(max_seq)[None, :] < kv_len[:, None],
+                         0.0, -1e9).astype(jnp.float32)[:, None, None, :]
+        return _grouped_cached_attention(q, k, v, mask, group,
+                                         1.0 / np.sqrt(d_key))
+
+    got = decode_attention(q, k, v, kv_len, bound=max_seq, lane_axis=3,
+                           interpret=interpret)
+    if jax.default_backend() == "cpu":
+        # XLA's CPU backend multiplies no batched bfloat16 pair into
+        # float32 inside a program: the rehearsal widens the operands
+        want = composed(*(x.astype(jnp.float32) for x in (q, k, v)),
+                        kv_len).astype(jnp.bfloat16)
+    else:
+        want = composed(q, k, v, kv_len)
+    live = lens > 0
+    diff = reldiff(got[live], want[live])
+    sm.check(diff < FLASH_RTOL and not np.asarray(
+        got[~live].astype(jnp.float32)).any(),
+             f"kernel decode_attention row-major {slots}x{key_heads}x"
+             f"{group} heads of {d_key}, {max_seq} positions bf16 ragged "
+             "agrees with the composed read; an empty slot gets zeros",
+             reldiff=diff, rtol=FLASH_RTOL, lengths=lens[:8].tolist())
 
 
 # -- train ------------------------------------------------------------------

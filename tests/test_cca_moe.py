@@ -119,6 +119,36 @@ def test_logits_past_the_first_cache_bucket(model):
     np.testing.assert_allclose(got, want[29:], **TOL)
 
 
+def test_decode_through_the_row_major_kernel_gives_the_references_logits(
+        monkeypatch):
+    """The family at 128-wide key heads, as ZAYA1's, with its decode
+    sites steered to the row-major body of the decode_attention kernel
+    (interpret mode here; on a TPU the rule picks it by itself: a
+    128-wide key is held row-major) and its appends to their kernel:
+    two query heads a key head, one block of 128 positions."""
+    from paddle_tpu.ops import cache_ops, nn_ops
+    from tests.test_decode_attention_kernel import _sdpa_sites
+
+    arch = dict(ARCH, head_dim=128)
+    m = GenerationModel.build(_spec(arch=arch, max_seq_len=128,
+                                    prompt_buckets=[8],
+                                    cache_buckets=[128]))
+    monkeypatch.setattr(nn_ops, "_decode_kernel_lane_axis",
+                        lambda ctx, q, cache, bound: 3)
+    monkeypatch.setattr(cache_ops, "_append_kernel_lane_axis",
+                        lambda ctx, cache: 3)
+    seq = np.random.default_rng(11).integers(1, VOCAB, 16)
+    before = _sdpa_sites()
+    got = _through_the_server(m, seq, 5, 1, bucket=128)
+    decode_sites = {labels: n for labels, n in
+                    (_sdpa_sites() - before).items() if labels[1] == "kv_len"}
+    assert decode_sites == {
+        ("decode_kernel", "kv_len", "0", "0", "2", "bhsd"):
+        len(arch["layer_types"])}
+    want = ref.logits(_tape(m), seq[None], arch)[0]
+    np.testing.assert_allclose(got, want[4:], **TOL)
+
+
 def test_full_program_gives_the_references_last_row(model):
     seq = np.random.default_rng(2).integers(1, VOCAB, 6)
     ids = np.zeros((SLOTS, 8, 1), np.int64)

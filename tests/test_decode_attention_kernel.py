@@ -21,6 +21,13 @@ from paddle_tpu.serving.generation import (GenerationConfig,
 
 HEADS, D_KEY, MAX_SEQ = 2, 64, 512
 
+# the kernel's two bodies, by the axis of the cache a v5e holds on its
+# lanes: (lane_axis, d_key, query heads a key head). A 64-wide key is
+# held position-minor, a 128-wide one row-major, where a key head's
+# query heads are the rows of the body's two products
+FORMS = [(2, 64, 1), (3, 128, 1), (3, 128, 4)]
+FORM_IDS = ["lane_minor", "row_major", "row_major_group4"]
+
 
 def _sdpa_sites():
     fam = default_registry().get("paddle_tpu_sdpa_sites_total")
@@ -51,11 +58,12 @@ def _masked_slice(q, k, v, kv_len, bound):
                        "m": mask}, {})["o"]
 
 
-def _operands(dtype, slots, seed=0):
+def _operands(dtype, slots, seed=0, d_key=D_KEY, group=1,
+              q_dtype=jnp.float32):
     rng = np.random.RandomState(seed)
-    q = jnp.asarray(rng.randn(slots, HEADS, 1, D_KEY), jnp.float32)
-    k = jnp.asarray(rng.randn(slots, HEADS, MAX_SEQ, D_KEY), dtype)
-    v = jnp.asarray(rng.randn(slots, HEADS, MAX_SEQ, D_KEY), dtype)
+    q = jnp.asarray(rng.randn(slots, HEADS * group, 1, d_key), q_dtype)
+    k = jnp.asarray(rng.randn(slots, HEADS, MAX_SEQ, d_key), dtype)
+    v = jnp.asarray(rng.randn(slots, HEADS, MAX_SEQ, d_key), dtype)
     return q, k, v
 
 
@@ -67,26 +75,61 @@ def _operands(dtype, slots, seed=0):
 @pytest.mark.parametrize("bound", [128, 256, 384, 512])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_kernel_matches_the_composed_rule_on_ragged_lengths(dtype, bound):
+@pytest.mark.parametrize("lane_axis,d_key,group", FORMS, ids=FORM_IDS)
+def test_kernel_matches_the_composed_rule_on_ragged_lengths(
+        lane_axis, d_key, group, dtype, bound):
     rows = kernel.block_rows(bound)
     lens = [1, rows - 1, rows, rows + 1, 0, bound - 1, bound, 77]
     lens = np.asarray([n for n in lens if n <= bound])
-    q, k, v = _operands(dtype, len(lens), seed=bound)
+    q, k, v = _operands(dtype, len(lens), seed=bound, d_key=d_key,
+                        group=group)
     kv_len = jnp.asarray(lens, jnp.int64)
     before = _sdpa_sites()
     want = _rule(q, k, v, kv_len, bound)
-    assert dict(_sdpa_sites() - before) == \
-        {("composed", "kv_len", "0", "0", "1", "bhsd"): 1}  # the CPU's path
-    got = kernel.decode_attention(q, k, v, kv_len, bound=bound)
+    assert dict(_sdpa_sites() - before) == {                # the CPU's path
+        ("composed", "kv_len", "0", "0", str(group), "bhsd"): 1}
+    got = kernel.decode_attention(q, k, v, kv_len, bound=bound,
+                                  lane_axis=lane_axis)
     assert got.shape == q.shape and got.dtype == q.dtype
     live = lens > 0
     # f32 round-off: the sums run in another order, nothing is rounded
-    # to 16 bits on the way (a bf16 pass would show 1e-2)
+    # to 16 bits on the way (a bf16 pass would show 1e-2). Float32
+    # queries against a bfloat16 cache multiply at float32 in the
+    # composed rule, and so in both bodies
     np.testing.assert_allclose(np.asarray(got)[live],
                                np.asarray(want)[live],
                                rtol=2e-5, atol=2e-6)
     # a slot of length 0 attends to nothing: zeros, not a mean of junk
     assert not np.asarray(got)[~live].any()
+
+
+@pytest.mark.parametrize("bound", [256, 512])
+@pytest.mark.parametrize("group", [1, 4])
+def test_row_major_body_multiplies_at_the_composed_rules_widths(group,
+                                                                bound):
+    """bfloat16 queries against a bfloat16 cache, as the
+    zaya1-8b.serve-reasoning cell's sites: both products take bfloat16
+    operands (p rounded to bfloat16 for the second) and accumulate in
+    float32, in the kernel as in ``_grouped_cached_attention``. The two
+    round p against another maximum (a block's running one, the row's)
+    and the context once more to bfloat16: within two roundings of a
+    bfloat16 result, 2 x 2**-8, of the rule at the same widths, and no
+    farther from the float32 answer than the rule itself is."""
+    rows = kernel.block_rows(bound)
+    lens = np.asarray([1, rows - 1, rows, rows + 1, bound - 1, bound, 77])
+    q, k, v = _operands(jnp.bfloat16, len(lens), seed=bound, d_key=128,
+                        group=group, q_dtype=jnp.bfloat16)
+    kv_len = jnp.asarray(lens)
+    # XLA's CPU backend multiplies no bfloat16 x bfloat16 -> float32
+    # batch inside a program; op by op it does
+    want = np.asarray(_rule(q, k, v, kv_len, bound), np.float32)
+    got = kernel.decode_attention(q, k, v, kv_len, bound=bound, lane_axis=3)
+    assert got.dtype == jnp.bfloat16
+    got = np.asarray(got, np.float32)
+    np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -9)
+    exact = np.asarray(_rule(*(x.astype(jnp.float32) for x in (q, k, v)),
+                             kv_len, bound))
+    assert np.abs(got - exact).max() <= 1.5 * np.abs(want - exact).max()
 
 
 def test_composed_rule_gives_the_bits_of_the_mask_over_a_slice():
@@ -99,29 +142,37 @@ def test_composed_rule_gives_the_bits_of_the_mask_over_a_slice():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_lengths_outside_the_bound_are_clipped():
-    q, k, v = _operands(jnp.float32, 4)
+@pytest.mark.parametrize("lane_axis,d_key,group", FORMS, ids=FORM_IDS)
+def test_lengths_outside_the_bound_are_clipped(lane_axis, d_key, group):
+    q, k, v = _operands(jnp.float32, 4, d_key=d_key, group=group)
     got = kernel.decode_attention(q, k, v, jnp.asarray([-3, 999, 256, 5]),
-                                  bound=256)
+                                  bound=256, lane_axis=lane_axis)
     want = kernel.decode_attention(q, k, v, jnp.asarray([0, 256, 256, 5]),
-                                   bound=256)
+                                   bound=256, lane_axis=lane_axis)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_only_rows_under_the_length_reach_the_result():
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("lane_axis,d_key,group", FORMS, ids=FORM_IDS)
+def test_only_rows_under_the_length_reach_the_result(lane_axis, d_key,
+                                                     group, dtype):
     """Junk past a slot's length changes nothing: in the slot's last
     block a dead key's score is replaced (a NaN there too) and its
     value weighs 0 (finite junk, as on the composed path: a cache row
     holds an earlier request's values), beyond it nothing is read."""
-    q, k, v = _operands(jnp.float32, 3)
+    q, k, v = _operands(dtype, 3, d_key=d_key, group=group, q_dtype=dtype)
     lens = np.asarray([5, 256, 300])
-    clean = kernel.decode_attention(q, k, v, jnp.asarray(lens), bound=512)
+    clean = kernel.decode_attention(q, k, v, jnp.asarray(lens), bound=512,
+                                    lane_axis=lane_axis)
     dead = np.arange(MAX_SEQ)[None, None, :, None] >= \
         lens[:, None, None, None]
     k2 = jnp.where(dead, jnp.nan, k)
     v2 = jnp.where(dead, 1e30, v)
-    dirty = kernel.decode_attention(q, k2, v2, jnp.asarray(lens), bound=512)
-    np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+    dirty = kernel.decode_attention(q, k2, v2, jnp.asarray(lens), bound=512,
+                                    lane_axis=lane_axis)
+    np.testing.assert_array_equal(np.asarray(clean, np.float32),
+                                  np.asarray(dirty, np.float32))
 
 
 @pytest.mark.parametrize("lens,bound,want", [
@@ -164,10 +215,26 @@ class _Ctx:
     ("tpu", (4, 2, 512, 64), jnp.int8, None, 1, 512, None),
     ("tpu", (4, 2, 512, 64), jnp.float32, "a mesh", 1, 512, None),
     ("tpu", (4, 2, 512, 64), jnp.float32, None, 3, 512, None),
-    ("tpu", (4, 2, 512, 128), jnp.float32, None, 1, 512, None),
+    ("tpu", (4, 2, 512, 128), jnp.float32, None, 1, 512, 3),
+    ("tpu", (96, 2, 2048, 128), jnp.bfloat16, None, 1, 1024, 3),
+    ("tpu", (4, 2, 512, 256), jnp.bfloat16, None, 1, 384, 3),
+    ("cpu", (4, 2, 512, 128), jnp.bfloat16, None, 1, 512, None),
+    ("tpu", (4, 2, 512, 192), jnp.bfloat16, None, 1, 512, None),
+    ("tpu", (4, 2, 512, 128), jnp.bfloat16, None, 1, 200, None),
+    ("tpu", (4, 2, 520, 128), jnp.bfloat16, None, 1, 512, None),
+    ("tpu", (4, 2, 512, 128), jnp.bfloat16, None, 1, 640, None),
+    ("tpu", (4, 2, 512, 128), jnp.bfloat16, "a mesh", 1, 512, None),
+    ("tpu", (4, 2, 512, 128), jnp.bfloat16, None, 3, 512, None),
+    ("tpu", (4, 2, 512, 128), jnp.int8, None, 1, 512, None),
+    ("tpu", (4, 64, 512, 128), jnp.float32, None, 1, 512, None),
 ], ids=["off_tpu", "f32", "bf16", "bound_not_lane_blocks",
         "seq_not_lane_blocks", "bound_past_cache", "int8", "under_mesh",
-        "query_longer_than_1", "row_major_cache"])
+        "query_longer_than_1", "row_major_cache", "row_major_cell",
+        "row_major_two_lane_tiles", "row_major_off_tpu",
+        "row_major_d_key_not_a_lane_tile", "row_major_bound_not_blocks",
+        "row_major_seq_not_blocks", "row_major_bound_past_cache",
+        "row_major_under_mesh", "row_major_query_longer_than_1",
+        "row_major_int8", "row_major_blocks_past_vmem"])
 def test_rule_takes_the_kernel_only_where_it_can_serve(
         monkeypatch, backend, shape, dtype, mesh, q_len, bound, want):
     """The choice reads the backend, the mesh, the shapes and how the
@@ -187,8 +254,10 @@ def test_rule_takes_the_kernel_only_where_it_can_serve(
 
 def test_a_cache_the_kernel_cannot_serve_is_refused_and_composed(
         monkeypatch):
-    """On a (pretended) TPU a row-major cache is left to the composed
-    path, counted as such; the kernel itself refuses it."""
+    """On a (pretended) TPU a row-major cache whose keys are no whole
+    128-lane tile (the CPU holds every cache row-major, this one at a
+    d_key of 64) is left to the composed path, counted as such; the
+    kernel itself refuses it."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     q, k, v = _operands(jnp.float32, 3)
     kv_len = jnp.asarray([3, 256, 400])
@@ -204,16 +273,18 @@ def test_a_cache_the_kernel_cannot_serve_is_refused_and_composed(
         kernel.decode_attention(q, k, v, kv_len, bound=100)
 
 
-def test_rule_runs_the_kernel_where_it_is_chosen(monkeypatch):
-    q, k, v = _operands(jnp.float32, 4)
+@pytest.mark.parametrize("lane_axis,d_key,group", FORMS, ids=FORM_IDS)
+def test_rule_runs_the_kernel_where_it_is_chosen(monkeypatch, lane_axis,
+                                                 d_key, group):
+    q, k, v = _operands(jnp.float32, 4, d_key=d_key, group=group)
     kv_len = jnp.asarray([9, 256, 0, 511])
     want = _rule(q, k, v, kv_len, 512)
     monkeypatch.setattr(nn_ops, "_decode_kernel_lane_axis",
-                        lambda ctx, q, cache, bound: 2)
+                        lambda ctx, q, cache, bound: lane_axis)
     before = _sdpa_sites()
     got = _rule(q, k, v, kv_len, 512)
-    assert dict(_sdpa_sites() - before) == \
-        {("decode_kernel", "kv_len", "0", "0", "1", "bhsd"): 1}
+    assert dict(_sdpa_sites() - before) == {
+        ("decode_kernel", "kv_len", "0", "0", str(group), "bhsd"): 1}
     live = np.asarray(kv_len) > 0
     np.testing.assert_allclose(np.asarray(got)[live],
                                np.asarray(want)[live],
@@ -250,14 +321,17 @@ def _generate_all(model, prompts, mode, max_new_tokens):
     return out, eng.stats()
 
 
+@pytest.mark.parametrize("lane_axis,d_model", [(2, 32), (3, 256)],
+                         ids=["lane_minor", "row_major"])
 def test_decode_through_the_kernel_matches_composed_and_reforward(
-        monkeypatch):
+        monkeypatch, lane_axis, d_model):
     """The decode programs with their attention steered to the Pallas
-    kernel (interpret mode here; on a TPU the rule picks it by itself),
-    and the append to its kernel as on the chip, emit the token streams
-    of the composed path and of the full re-forward; every attention
-    site of a decode program is counted on the path taken; the engine
-    counts the blocks read and skipped from its lengths."""
+    kernel (interpret mode here; on a TPU the rule picks it by itself,
+    the position-minor body for two heads of 16, the row-major one for
+    two of 128), and the append to its kernel as on the chip, emit the
+    token streams of the composed path and of the full re-forward; every
+    attention site of a decode program is counted on the path taken; the
+    engine counts the blocks read and skipped from its lengths."""
     rng = np.random.RandomState(3)
     # one request leaves the first cache bucket, one stays short and
     # the third slot idles throughout
@@ -265,7 +339,8 @@ def test_decode_through_the_kernel_matches_composed_and_reforward(
 
     def streams(mode):
         before = _sdpa_sites()
-        model = GenerationModel.build(GenerationSpec(**SPEC_KW))
+        model = GenerationModel.build(
+            GenerationSpec(**dict(SPEC_KW, d_model=d_model)))
         out, stats = _generate_all(model, prompts, mode, 14)
         sites = collections.Counter()
         for (path, mask, *_rest), n in (_sdpa_sites() - before).items():
@@ -276,9 +351,9 @@ def test_decode_through_the_kernel_matches_composed_and_reforward(
     composed, composed_sites, composed_stats, spec = streams("cached")
     reforward, no_sites, _, _ = streams("reforward")
     monkeypatch.setattr(nn_ops, "_decode_kernel_lane_axis",
-                        lambda ctx, q, cache, bound: 2)
+                        lambda ctx, q, cache, bound: lane_axis)
     monkeypatch.setattr(cache_ops, "_append_kernel_lane_axis",
-                        lambda ctx, cache: 2)
+                        lambda ctx, cache: lane_axis)
     through, kernel_sites, kernel_stats, _ = streams("cached")
     for t, c, r in zip(through, composed, reforward):
         assert t.tokens == c.tokens == r.tokens

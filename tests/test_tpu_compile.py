@@ -832,6 +832,46 @@ def test_decode_attention_at_four_query_heads_a_key_head_compiles(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+# The zaya1-8b.serve-reasoning cell's decode sites (chipbench/configs/
+# zaya1-8b.json: 96 slots, 8 query heads over 2 key heads of 128,
+# bfloat16 caches to 2048 positions, cache buckets 1024 and 2048). A
+# 128-wide key is held row-major, and the kernel's row-major body takes
+# the caches as they lie: blocks (2, 256, 128), no transposed view.
+@pytest.mark.parametrize("bound", [1024, 2048])
+def test_row_major_decode_attention_compiles_at_the_cells_shape(
+        topo, one_chip, bound):
+    import re
+
+    from paddle_tpu.ops.cache_ops import device_lane_axis
+    from paddle_tpu.ops.pallas.decode_attention import (decode_attention,
+                                                        fits)
+
+    slots, key_heads, group, max_seq, d_key = 96, 2, 4, 2048, 128
+    shape = (slots, key_heads, max_seq, d_key)
+    assert device_lane_axis(shape, jnp.bfloat16, topo.devices[0]) == 3
+    assert fits(shape, jnp.bfloat16, 3, bound)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, n: decode_attention(q, k, v, n, bound=bound,
+                                            lane_axis=3, interpret=False)
+    ).lower(sds((slots, key_heads * group, 1, d_key), jnp.bfloat16),
+            sds(shape, jnp.bfloat16), sds(shape, jnp.bfloat16),
+            sds((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"%decode_attention_row_major\S* = ", text)
+    assert not re.findall(r"= \S.* while\(", text)
+    # the caches go in as they are: nothing cache-shaped is copied or
+    # sliced to the bound (one slot's slice to 1024 positions is 0.5 MB,
+    # a cache's 50 MB), the work list and the queries are all XLA holds
+    assert not re.findall(r"= bf16\[%d,%d,\d{3,},%d\]\S* (?:copy|slice)\("
+                          % (slots, key_heads, d_key), text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # The CCA / expert family's programs (models/cca_moe.py) at ZAYA1-8B's
 # published widths and the zaya1-8b.serve-reasoning cell's sizes: 96
 # slots, 8 query heads over 2 key heads of 128, bfloat16 caches to 2048
@@ -843,11 +883,13 @@ def test_decode_attention_at_four_query_heads_a_key_head_compiles(
                                          ("prefill", 256)])
 def test_a_cca_moe_program_compiles_at_the_published_widths(
         topo, one_chip, monkeypatch, mode, bucket):
-    """The appends and the grouped products as custom calls (and the
-    flash kernel in a 256-token prefill), every cache and window
-    aliased to its output, and no temporary as large as a slice of a
-    cache: composed over the cache with the key heads REPEATED, a decode
-    step's attention planned 0.8 GB of them."""
+    """The appends, the length-bounded attention reads and the grouped
+    products as custom calls (the flash kernel in a 256-token prefill),
+    every cache and window aliased to its output, and no temporary as
+    large as a slice of a cache: composed over the cache with the key
+    heads REPEATED, a decode step's attention planned 0.8 GB of them;
+    composed over a slice to the bucket (until PR 54) it read 0.2 GB a
+    layer whatever the slots held."""
     import json
     import os
     import re
@@ -905,6 +947,8 @@ def test_a_cca_moe_program_compiles_at_the_published_widths(
     assert calls.count("ragged-dot-none") == 3 * layers
     if mode == "decode":
         assert calls.count("kv_cache_append") == 2 * layers
+        assert calls.count("decode_attention_row_major") == layers
+        assert len(calls) == (2 + 1 + 4) * layers
     else:
         assert calls.count("flash_fwd") == layers
     memory = compiled.memory_analysis()
