@@ -22,6 +22,16 @@ grows with the square of sequence length instead of linearly). The
 token stream is greedy either way, so cached-vs-reforward outputs are
 bit-comparable — tests/test_generation.py pins that identity.
 
+The driver thread accounts for every instant of a pass: three spans
+tile its timeline (``generation::idle_wait`` / ``collect`` /
+``iteration``), and its own CPU clock is read beside the wall clock a
+stretch of passes at a time, at most ``_STALL_S`` apart
+(``stats()["loop"]``, ``paddle_tpu_decode_loop_*``): wall, less the
+waits for the device, less CPU is the time the thread was neither
+waiting for the device nor running, and a stretch with more than
+``_STALL_S`` of it is a ``generation::stall`` span that carries the
+evidence.
+
 Failure containment mirrors the batch-serving engine: a step failure
 records into the HealthMonitor (consecutive failures trip the breaker
 OPEN → submit() sheds), and every in-flight request is retired with the
@@ -40,6 +50,7 @@ import numpy as np
 from ... import profiler
 from ...analysis.memory import publish_peak
 from ...observability import attribution as obs_attr
+from ...observability import thread_clock
 from ...resilience import faults
 from ...resilience import health as health_mod
 from ...resilience.health import CircuitOpenError, HealthMonitor
@@ -49,6 +60,16 @@ from .model import bucket_for
 
 __all__ = ["GenerationConfig", "GenerationResult", "GenerationFuture",
            "GenerationEngine"]
+
+#: the driver thread's own clocks (its CPU seconds, the process's, its
+#: context switches: a system call each, 6 us and a reschedule on a
+#: sandboxed kernel such as the chip's host) are read where a pass ends
+#: once this much time has gone by since the last reading — a STRETCH of
+#: passes, at most twenty readings a second whatever the pass takes —
+#: and a stretch whose thread was neither running nor waiting for the
+#: device for longer than this is a generation::stall span (ten decode
+#: steps of the benchmark's fastest cell)
+_STALL_S = 0.05
 
 
 class GenerationConfig:
@@ -177,6 +198,12 @@ class GenerationEngine:
                else self.spec.prompt_buckets[-1])
         self._max_len = min(self.spec.max_seq_len, top)
         self.metrics.slots_total.set(self.spec.slots)
+        # the passes the driver thread made and the seconds it waited
+        # for the device since its clocks were last read (_account,
+        # _hear_device_wait)
+        self._loop_ident = None
+        self._passes = 0
+        self._waited = 0.0
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
@@ -269,19 +296,56 @@ class GenerationEngine:
         return out
 
     # -- driver ------------------------------------------------------------
+    # The loop thread's timeline is tiled by three top-level spans:
+    # generation::idle_wait (nothing queued, no live slot),
+    # generation::collect (the rest of a pass's head: the queue's lock,
+    # the drain, the stop checks) and generation::iteration (admit and
+    # step). Its own clocks are read where a pass ends, a stretch of
+    # passes at most _STALL_S long at a time (_account).
     def _driver_loop(self):
-        while True:
+        self._loop_ident = threading.get_ident()
+        profiler.add_event_listener(self._hear_device_wait)
+        try:
+            read = None     # the thread's clocks as last read
+            while True:
+                pending, read = self._collect(read)
+                if pending is None:
+                    self._account(read, done=0, flush=True)
+                    return
+                self._iteration(pending)
+        finally:
+            profiler.remove_event_listener(self._hear_device_wait)
+
+    def _collect(self, read):
+        """The head of a pass: the last pass into the account, the wait
+        while there is nothing to do, the drain of the queue. Returns
+        (the requests to admit, the thread's clocks as last read), or
+        (None, ...) when the loop is over."""
+        span = profiler.RecordEvent("generation::collect",
+                                    cat=profiler.CAT_SERVING)
+        span.__enter__()
+        try:
+            read = thread_clock.read() if read is None \
+                else self._account(read)
             abort_now = False
             with self._wake:
-                while (not self._stopping and not self._queue
-                       and not any(s is not None for s in self._slots)):
-                    self._wake.wait(timeout=self.config.idle_wait_s)
+                if self._idle():
+                    # a pass that only waits counts nowhere: the account
+                    # is closed before the wait and opened after it
+                    self._account(read, done=0, flush=True)
+                    span.__exit__()
+                    with profiler.RecordEvent("generation::idle_wait",
+                                              cat=profiler.CAT_SERVING):
+                        while self._idle():
+                            self._wake.wait(
+                                timeout=self.config.idle_wait_s)
+                    span.__enter__()
+                    read = thread_clock.read()
                 if self._stopping:
                     if not self._drain:
                         abort_now = True
-                    elif (not self._queue and
-                          not any(s is not None for s in self._slots)):
-                        return  # drained
+                    elif not self._has_work():
+                        return None, read  # drained
                 pending = deque()
                 while self._queue:
                     pending.append(self._queue.popleft())
@@ -294,43 +358,97 @@ class GenerationEngine:
                         "generation engine stopped without drain"))
                 self._abort_all(ServingStopped(
                     "generation engine stopped without drain"))
-                return
-            # every pass that gets here admits or steps
-            with profiler.RecordEvent("generation::iteration",
-                                      cat=profiler.CAT_SERVING):
-                try:
-                    self._admit(pending)
-                    if any(s is not None for s in self._slots):
-                        self._step()
-                except BaseException as e:
-                    # device/step failure: the cache state of every
-                    # active slot is now suspect — retire them all with
-                    # the tokens they already completed, count the
-                    # failure toward the breaker, and keep the driver
-                    # alive (the breaker, not a dead thread, decides
-                    # whether to shed)
-                    self.health.record_failure(e)
-                    self._abort_all(e, reason="error", keep_tokens=True)
-                with profiler.RecordEvent("generation::telemetry",
-                                          cat=profiler.CAT_SERVING):
-                    self.metrics.slots_active.set(
-                        sum(1 for s in self._slots if s is not None))
+                return None, read
+            return pending, read
+        finally:
+            span.__exit__()
 
-    def _abort_all(self, exc: BaseException, reason: str = "aborted",
-                   keep_tokens: bool = True):
-        """Retire every in-flight slot (delivering completed tokens —
-        a trip/stop never drops delivered work) and fail the queue."""
+    def _has_work(self) -> bool:
+        """A queued request or a live slot (under _wake)."""
+        return bool(self._queue) or any(s is not None for s in self._slots)
+
+    def _idle(self) -> bool:
+        return not self._stopping and not self._has_work()
+
+    def _iteration(self, pending: deque):
+        # every pass that gets here admits or steps
+        with profiler.RecordEvent("generation::iteration",
+                                  cat=profiler.CAT_SERVING):
+            try:
+                if pending:
+                    with profiler.RecordEvent("generation::admit",
+                                              cat=profiler.CAT_SERVING):
+                        self._admit(pending)
+                if any(s is not None for s in self._slots):
+                    self._step()
+            except BaseException as e:
+                # device/step failure: the cache state of every
+                # active slot is now suspect — retire them all with
+                # the tokens they already completed, count the
+                # failure toward the breaker, and keep the driver
+                # alive (the breaker, not a dead thread, decides
+                # whether to shed)
+                self.health.record_failure(e)
+                self._abort_all(e, reason="error")
+
+    def _hear_device_wait(self, ev):
+        """profiler listener: the loop thread's waits for the device,
+        the executor's pipeline::fetch_sync spans as they close (a
+        prefill's and a step's alike)."""
+        if ev["tid"] == self._loop_ident and \
+                ev["name"] == "pipeline::fetch_sync":
+            self._waited += ev["dur"] * 1e-6
+
+    def _account(self, read, done: int = 1, flush: bool = False):
+        """``done`` passes ended since the thread's clocks were read
+        (``read``). Once _STALL_S have gone by, or to ``flush``, the
+        stretch is closed: the clocks are read again and its passes,
+        its wall seconds less the device waits', the thread's CPU
+        seconds and context switches go into the metrics; a
+        generation::stall span when the thread neither ran nor waited
+        for the device for more than _STALL_S of it. The CPU the thread
+        burns INSIDE a wait (the fetched array's conversion, the
+        runtime's own calls: 0.2 ms of a 2-ms wait on the chip's host)
+        stays in ``cpu`` while the wait's wall leaves: taking it out is
+        two more system calls a wait, 0.10 ms a pass there (PERF.md,
+        PR 55), so wall less CPU reads low by it. Returns the clocks as
+        last read."""
+        self._passes += done
+        if not flush and time.perf_counter() - read.wall < _STALL_S:
+            return read
+        end = thread_clock.read()
+        passes, waited = self._passes, self._waited
+        self._passes, self._waited = 0, 0.0
+        if not passes:
+            return end
+        # (never under 0: a counter refuses it, and this is the
+        # driver's thread)
+        wall = max(end.wall - read.wall - waited, 0.0)
+        cpu = end.cpu - read.cpu
+        voluntary = involuntary = 0
+        if end.voluntary is not None:
+            voluntary = end.voluntary - read.voluntary
+            involuntary = end.involuntary - read.involuntary
+        self.metrics.loop_passes(passes, wall, cpu, waited, voluntary,
+                                 involuntary)
+        if wall - cpu > _STALL_S:
+            # closed, so not on a device trace; the flight recorder's
+            # ring hears it with every other listener
+            profiler.emit(
+                "generation::stall", read.wall, end.wall - read.wall,
+                profiler.CAT_SERVING,
+                {"passes": passes, "wall": wall, "cpu": cpu,
+                 "device_wait": waited, "voluntary": voluntary,
+                 "involuntary": involuntary,
+                 "process_cpu": end.process_cpu - read.process_cpu})
+        return end
+
+    def _abort_all(self, exc: BaseException, reason: str = "aborted"):
+        """Retire every in-flight slot with the tokens it completed (a
+        trip/stop never drops delivered work) and fail the queue."""
         for i, req in enumerate(self._slots):
-            if req is None:
-                continue
-            self._slots[i] = None
-            self._lengths[i] = 0
-            self.metrics.retired(reason, req.future)
-            if keep_tokens:
-                req.future.set_result(GenerationResult(
-                    req.tokens, "aborted", len(req.prompt)))
-            else:
-                req.future.set_exception(exc)
+            if req is not None:
+                self._retire(i, req, reason, finish="aborted")
         with self._lock:
             queued, self._queue = list(self._queue), deque()
         for req in queued:
@@ -380,6 +498,13 @@ class GenerationEngine:
         self._history[slot, :] = 0
         self._history[slot, :p] = req.prompt
         self._lengths[slot] = p
+        self._occupancy()
+
+    def _occupancy(self):
+        # the gauge moves where the occupancy does: an admission, a
+        # retirement; a steady decode pass leaves it alone
+        self.metrics.slots_active.set(
+            sum(1 for s in self._slots if s is not None))
 
     # -- step --------------------------------------------------------------
     def _step(self):
@@ -495,8 +620,17 @@ class GenerationEngine:
         elif self._lengths[slot] >= self._max_len:
             reason = "length"
         if reason is not None:
-            self._slots[slot] = None
-            self._lengths[slot] = 0
+            self._retire(slot, req, reason)
+
+    def _retire(self, slot: int, req: _Request, reason: str,
+                finish: Optional[str] = None):
+        """Free the slot and resolve the request's future (the call
+        that wakes its client) with the tokens it completed."""
+        self._slots[slot] = None
+        self._lengths[slot] = 0
+        with profiler.RecordEvent("generation::retire",
+                                  cat=profiler.CAT_SERVING):
+            self._occupancy()
             self.metrics.retired(reason, req.future)
             req.future.set_result(GenerationResult(
-                req.tokens, reason, len(req.prompt)))
+                req.tokens, finish or reason, len(req.prompt)))

@@ -12,7 +12,6 @@ stays honest for single-token steps.
 from __future__ import annotations
 
 import itertools
-import json
 from typing import Dict, Optional
 
 from ...observability.registry import MetricsRegistry, default_registry
@@ -46,6 +45,8 @@ class GenerationMetrics:
       for a slot and time to first token, from the timestamps on its
       GenerationFuture
     - slots_active / slots_total: continuous-batching occupancy
+    - loop_*: the driver thread's own account of its passes, from its
+      thread's clocks (``loop_passes``)
     """
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
@@ -140,6 +141,43 @@ class GenerationMetrics:
         self.slots_total = gauge(
             "paddle_tpu_decode_slots_total",
             "Slot capacity of the continuous-batching engine.")
+        # the driver loop's passes that admitted or stepped, device
+        # wait taken out of wall (loop_passes)
+        self._loop = {
+            "passes": counter(
+                "paddle_tpu_decode_loop_passes_total",
+                "Passes of the engine's driver loop that admitted or "
+                "stepped (a pass that only waited for work counts "
+                "nowhere in the decode_loop families)."),
+            "wall_seconds": counter(
+                "paddle_tpu_decode_loop_wall_seconds_total",
+                "Wall seconds of the driver loop's passes, from the "
+                "head of a pass (taking the queue's lock) to the end "
+                "of its iteration, less the loop thread's waits for "
+                "the device."),
+            "cpu_seconds": counter(
+                "paddle_tpu_decode_loop_cpu_seconds_total",
+                "CPU seconds of the driver loop's thread over the same "
+                "passes, what it burns inside a wait for the device "
+                "included (the fetched array's conversion): wall less "
+                "cpu is the time the thread was neither waiting for "
+                "the device nor running (the interpreter's lock, "
+                "another lock, the scheduler), read low by that."),
+            "device_wait_seconds": counter(
+                "paddle_tpu_decode_loop_device_wait_seconds_total",
+                "Wall seconds the driver loop's thread waited for the "
+                "device: its pipeline::fetch_sync spans, a prefill's "
+                "and a step's alike."),
+            "voluntary_switches": counter(
+                "paddle_tpu_decode_loop_voluntary_switches_total",
+                "Context switches the driver loop's thread asked for "
+                "over its passes (it blocked: the device wait, a lock, "
+                "the interpreter's lock)."),
+            "involuntary_switches": counter(
+                "paddle_tpu_decode_loop_involuntary_switches_total",
+                "Context switches the driver loop's thread did not ask "
+                "for over its passes (pre-empted on its core)."),
+        }
         # lazy attribution registration, same contract as ServingMetrics
         self._attr_job = f"engine_gen_{self.engine_label}"
         self.mfu = None
@@ -160,6 +198,20 @@ class GenerationMetrics:
             if future.first_token_at is not None:
                 self.ttft_seconds.record(
                     future.first_token_at - future.enqueued_at)
+
+    def loop_passes(self, passes: int, wall: float, cpu: float,
+                    device_wait: float, voluntary: int,
+                    involuntary: int) -> None:
+        """A stretch of passes of the driver loop that admitted or
+        stepped, as its own thread's clocks read it (engine.py
+        _account): ``wall`` with the waits for the device taken out."""
+        loop = self._loop
+        loop["passes"].inc(passes)
+        loop["wall_seconds"].inc(wall)
+        loop["cpu_seconds"].inc(cpu)
+        loop["device_wait_seconds"].inc(device_wait)
+        loop["voluntary_switches"].inc(voluntary)
+        loop["involuntary_switches"].inc(involuntary)
 
     def shed(self, reason: str) -> None:
         self._shed_family.labels(engine=self.engine_label,
@@ -259,6 +311,7 @@ class GenerationMetrics:
             "kv_blocks_by_state": self._by_reason(self._kv_blocks_family),
             "state_bytes_by_kind": self._by_reason(self._state_bytes_family),
             "mfu": self.mfu.value if self.mfu is not None else 0.0,
+            "loop": {k: c.value for k, c in self._loop.items()},
         }
         if self._expert_rows_family is not None:
             out["expert_rows_by_expert"] = self._by_reason(
@@ -270,6 +323,3 @@ class GenerationMetrics:
             cs["hit_rate"] = round(cs["hits"] / total, 6) if total else 0.0
             out["compile_cache"] = cs
         return out
-
-    def stats_json(self, executor=None, **kw) -> str:
-        return json.dumps(self.stats(executor=executor), **kw)

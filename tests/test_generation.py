@@ -407,15 +407,23 @@ def test_new_decode_flags_registered():
 # ---------------------------------------------------------------------------
 # spans and each request's life (ISSUE 25)
 # ---------------------------------------------------------------------------
-def _serve_and_listen(model, prompts, mode, max_new_tokens=6):
+def _serve_and_listen(model, prompts, mode, max_new_tokens=6,
+                      idle_first=0.0, prepare=None):
     """(futures, engine stats, closed profiler events) of one engine
-    run over more prompts than slots."""
+    run over more prompts than slots. ``idle_first``: seconds the
+    started engine is left with nothing to do; ``prepare(engine)`` runs
+    before its start."""
+    import time
     from paddle_tpu import profiler
     events = []
     profiler.add_event_listener(events.append)
     eng = model.serve(config=GenerationConfig(max_new_tokens=max_new_tokens),
-                      mode=mode).start()
+                      mode=mode)
+    if prepare is not None:
+        prepare(eng)
+    eng.start()
     try:
+        time.sleep(idle_first)
         futs = [eng.submit(p) for p in prompts]
         for f in futs:
             f.result(timeout=120)
@@ -423,6 +431,21 @@ def _serve_and_listen(model, prompts, mode, max_new_tokens=6):
         eng.stop(drain=True, timeout=120)
         profiler.remove_event_listener(events.append)
     return futs, eng.stats(), events
+
+
+def _driver_spans(events):
+    """(the loop thread's events, named(prefix), inside(child, parent))"""
+    driver = [e for e in events
+              if e["args"]["thread"] == "generation-driver"]
+
+    def named(prefix):
+        return [e for e in driver if e["name"].startswith(prefix)]
+
+    def inside(child, parent):
+        return parent["ts"] <= child["ts"] and child["ts"] + \
+            child["dur"] <= parent["ts"] + parent["dur"]
+
+    return driver, named, inside
 
 
 PROMPTS = [[3, 4, 5], [6, 7], [8, 9, 10, 11], [12], [13, 14, 15]]
@@ -480,16 +503,7 @@ def test_a_request_failed_in_the_queue_has_no_slot_timestamps(model):
     ("reforward", "generation::reforward_step[")])
 def test_iteration_span_and_its_children(model, mode, step):
     futs, stats, events = _serve_and_listen(model, PROMPTS, mode)
-    driver = [e for e in events
-              if e["args"]["thread"] == "generation-driver"]
-
-    def named(prefix):
-        return [e for e in driver if e["name"].startswith(prefix)]
-
-    def inside(child, parent):
-        return parent["ts"] <= child["ts"] and child["ts"] + \
-            child["dur"] <= parent["ts"] + parent["dur"]
-
+    driver, named, inside = _driver_spans(events)
     iterations, steps = named("generation::iteration"), named(step)
     prefills = named("generation::prefill[")
     # the counts the benchmark's driver holds the engine to
@@ -504,12 +518,41 @@ def test_iteration_span_and_its_children(model, mode, step):
         assert len([s for s in steps if inside(s, it)]) <= 1
         assert [e for e in steps + prefills + named(
             "generation::deliver") if inside(e, it)]
+    admits, retires = named("generation::admit"), \
+        named("generation::retire")
+    telemetry = named("generation::telemetry")
     for child in steps + prefills + named("generation::build_step") + \
-            named("generation::deliver") + named("generation::telemetry"):
+            named("generation::deliver") + telemetry + admits + retires:
         assert len([it for it in iterations if inside(child, it)]) == 1
     assert len(named("generation::build_step")) == len(steps)
     # a deliver after every step, and in cached mode after every prefill
     assert len(named("generation::deliver")) == len(steps) + len(prefills)
+    # ONE telemetry a step and one a prefill: a steady decode pass (no
+    # admission in it) holds exactly one
+    assert len(telemetry) == len(steps) + len(prefills)
+    for it in iterations:
+        if not [a for a in admits if inside(a, it)]:
+            assert len([t for t in telemetry if inside(t, it)]) == 1
+    # an admit span only on a pass that had requests to admit, at most
+    # one a pass, and every prefill (with its telemetry and deliver)
+    # inside one; five requests on two slots: some were requeued, so
+    # there are more admits than it takes to admit each once
+    for it in iterations:
+        assert len([a for a in admits if inside(a, it)]) <= 1
+    for p in prefills:
+        assert len([a for a in admits if inside(p, a)]) == 1
+    assert admits and len(admits) <= len(iterations)
+    if mode == "cached":
+        # the first prefill of a pass starts where the admit does, give
+        # or take the slot scan: an admit without pending work is none
+        assert all(a["dur"] > 0 for a in admits)
+    # one retire a retired request, each inside a deliver (the token
+    # that finished it)
+    assert len(retires) == len(futs) == \
+        sum(stats["retired_by_reason"].values())
+    for r in retires:
+        assert len([d for d in named("generation::deliver")
+                    if inside(r, d)]) == 1
     # the executor's spans fall inside the step spans by themselves
     for s in steps + prefills:
         kinds = {e["name"] for e in named("pipeline::") if inside(e, s)}
@@ -517,3 +560,144 @@ def test_iteration_span_and_its_children(model, mode, step):
                 "pipeline::commit", "pipeline::fetch_sync"} <= kinds
     assert {e["tid"] for e in driver} == {driver[0]["tid"]}
     assert all(e["tid"] != threading.get_ident() for e in driver)
+
+
+# ---------------------------------------------------------------------------
+# the loop accounts for every instant of a pass (ISSUE 55)
+# ---------------------------------------------------------------------------
+TOP_LEVEL = ("generation::idle_wait", "generation::collect",
+             "generation::iteration")
+
+
+@pytest.mark.parametrize("mode", ["cached", "reforward"])
+def test_three_spans_tile_the_loop_threads_timeline(model, mode):
+    _futs, _stats, events = _serve_and_listen(model, PROMPTS, mode,
+                                              idle_first=0.03)
+    driver, named, inside = _driver_spans(events)
+    top = sorted((e for e in driver if e["name"] in TOP_LEVEL),
+                 key=lambda e: e["ts"])
+    assert {e["name"] for e in top} == set(TOP_LEVEL)
+    # no two overlap (a microsecond clock's rounding aside) ...
+    for a, b in zip(top, top[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 0.5, (a, b)
+    # ... and together they cover the thread's life
+    life = top[-1]["ts"] + top[-1]["dur"] - top[0]["ts"]
+    assert sum(e["dur"] for e in top) >= 0.98 * life
+    # every other span of the thread lies inside one of the three
+    for e in driver:
+        if e["name"] not in TOP_LEVEL + ("generation::stall",
+                                         "runtime::gc"):
+            assert len([t for t in top if inside(e, t)]) == 1, e
+    # a collect before every iteration; the engine idled before the
+    # first request and after the last: one span a stretch of waiting,
+    # not one a 5-ms timeout
+    for it in named("generation::iteration"):
+        before = [t for t in top if t["ts"] < it["ts"]]
+        assert before and before[-1]["name"] == "generation::collect"
+    waits = named("generation::idle_wait")
+    assert 1 <= len(waits) <= 3
+    assert max(w["dur"] for w in waits) >= 0.02 * 1e6
+
+
+@pytest.mark.parametrize("mode", ["cached", "reforward"])
+def test_the_loop_counters_are_the_passes_own(model, mode):
+    _futs, stats, events = _serve_and_listen(model, PROMPTS, mode,
+                                             idle_first=0.03)
+    driver, named, inside = _driver_spans(events)
+    loop = stats["loop"]
+    assert set(loop) == {"passes", "wall_seconds", "cpu_seconds",
+                         "device_wait_seconds", "voluntary_switches",
+                         "involuntary_switches"}
+    iterations = named("generation::iteration")
+    # a pass that only waited counts nowhere
+    assert loop["passes"] == len(iterations)
+    # a thread cannot run longer than the wall clock says; the two are
+    # different clocks of the kernel's (the monotonic one is slewed, the
+    # scheduler's is not) and agree to a part in ten thousand
+    # the CPU seconds hold what the thread burns INSIDE a wait too (at
+    # toy size a fetch is mostly the conversion of what was fetched)
+    assert 0 < loop["cpu_seconds"] <= (
+        loop["wall_seconds"] + loop["device_wait_seconds"]) * 1.001 + 1e-4
+    # the waits taken out of the wall are the thread's fetch_sync spans
+    fetch = named("pipeline::fetch_sync")
+    assert loop["device_wait_seconds"] == pytest.approx(
+        sum(e["dur"] for e in fetch) * 1e-6, rel=1e-6)
+    # wall + device wait = the passes as the spans tile them: collect
+    # and iteration, none of the idle wait (30 ms of it at least)
+    tiled = sum(e["dur"] for e in iterations
+                + named("generation::collect")) * 1e-6
+    total = loop["wall_seconds"] + loop["device_wait_seconds"]
+    assert abs(total - tiled) <= 0.05 * tiled + 1e-3, (total, tiled)
+    assert loop["voluntary_switches"] >= 0
+    assert loop["involuntary_switches"] >= 0
+    # and on /metrics, under the engine's label
+    from paddle_tpu.observability import default_registry
+    assert default_registry().get(
+        "paddle_tpu_decode_loop_passes_total") is not None
+
+
+def test_a_pass_that_did_not_run_is_a_stall_span_with_its_evidence(
+        model, tmp_path):
+    """A sleep inside a pass (the generation.step fault point's delay
+    mode): the loop's thread neither ran nor waited for the device."""
+    from paddle_tpu.observability.flight_recorder import FlightRecorder
+    rec = FlightRecorder(dump_dir=str(tmp_path), min_interval_s=0).enable()
+    try:
+        with FaultInjector(seed=0) as fi:
+            fi.on("generation.step", delay_s=0.08, times=1, after=2)
+            _futs, stats, events = _serve_and_listen(model, PROMPTS,
+                                                     "cached")
+    finally:
+        rec.disable()
+    stalls = [e for e in events if e["name"] == "generation::stall"
+              and e["args"]["wall"] >= 0.075]
+    assert len(stalls) == 1, stalls
+    stall = stalls[0]
+    assert stall["args"]["thread"] == "generation-driver"
+    args = stall["args"]
+    assert set(args) >= {"passes", "wall", "cpu", "device_wait",
+                         "voluntary", "involuntary", "process_cpu"}
+    # the stretch of passes the clocks were read over: the one that
+    # slept and at most 50 ms of its neighbours, which ran
+    assert args["passes"] >= 1
+    assert args["cpu"] < 0.06 and args["wall"] - args["cpu"] > 0.05
+    assert args["voluntary"] >= 1       # the sleep blocked
+    # over the stretch's interval: it holds the iteration that slept
+    _driver, named, inside = _driver_spans(events)
+    held = [it for it in named("generation::iteration")
+            if inside(it, stall)]
+    assert len(held) == args["passes"]
+    assert len([it for it in held if it["dur"] >= 0.08 * 1e6]) == 1
+    # the flight recorder's ring holds the same record
+    assert stall in rec.events()
+    assert not rec.dumps()              # a stall is no failure
+    assert stats["loop"]["wall_seconds"] - stats["loop"]["cpu_seconds"] \
+        > 0.05
+
+
+def test_a_pass_that_ran_all_its_time_is_no_stall(model):
+    """A busy loop of the same length on the loop's thread: slow Python,
+    not a thread that does not run."""
+    import time
+    burned = []
+
+    def prepare(eng):
+        step = eng._step
+
+        def busy_step():
+            if len(burned) == 2:
+                until = time.thread_time() + 0.08
+                while time.thread_time() < until:
+                    pass
+            burned.append(1)
+            step()
+        eng._step = busy_step
+
+    _futs, stats, events = _serve_and_listen(model, PROMPTS, "cached",
+                                             prepare=prepare)
+    assert len(burned) > 2
+    stalls = [e for e in events if e["name"] == "generation::stall"]
+    # none; on a machine that took the core away meanwhile, the span
+    # says so itself
+    assert all(e["args"]["involuntary"] > 0 for e in stalls), stalls
+    assert stats["loop"]["cpu_seconds"] >= 0.08

@@ -165,7 +165,15 @@ def test_registry_names_and_help_after_smoke_run(tmp_path, monkeypatch):
                      "paddle_tpu_persistent_cache_hits_total",
                      "paddle_tpu_persistent_cache_misses_total",
                      "paddle_tpu_decode_queue_wait_seconds",
-                     "paddle_tpu_decode_ttft_seconds"):
+                     "paddle_tpu_decode_ttft_seconds",
+                     # ISSUE 55: the driver loop's own account of its
+                     # passes, from its thread clock
+                     "paddle_tpu_decode_loop_passes_total",
+                     "paddle_tpu_decode_loop_wall_seconds_total",
+                     "paddle_tpu_decode_loop_cpu_seconds_total",
+                     "paddle_tpu_decode_loop_device_wait_seconds_total",
+                     "paddle_tpu_decode_loop_voluntary_switches_total",
+                     "paddle_tpu_decode_loop_involuntary_switches_total"):
         assert expected in names, f"smoke run did not publish {expected}"
     # the generation smoke shed exactly through the host budget path
     gen_shed = {key for key, _ in
