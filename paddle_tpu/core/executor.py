@@ -39,6 +39,9 @@ from .registry import OpRegistry, run_op
 from .scope import Scope, global_scope
 
 STEP_VAR = "@step_counter@"
+# the named scope of the one instruction of a compiled step that is no
+# op of the program: the step counter's advance
+STEP_SCOPE = "step_counter"
 
 # JAX's persistent compilation cache lives at a FIXED path inside the
 # checkout (derived from this package's own location, like native.py):
@@ -179,6 +182,35 @@ def _to_device_value(value):
     return _maybe_cached(value)
 
 
+_DTYPE_NAMES: Dict[Tuple, str] = {}
+
+
+def _dtype_name(dtype) -> str:
+    """The name of the dtype an array of `dtype` has once on the device
+    (`jnp.asarray`'s canonical form: int64 -> int32 with x64 off)."""
+    key = (dtype, jax.config.jax_enable_x64)
+    name = _DTYPE_NAMES.get(key)
+    if name is None:
+        name = _DTYPE_NAMES[key] = str(jax.dtypes.canonicalize_dtype(dtype))
+    return name
+
+
+def _feed_arg(value):
+    """(a feed value as the jitted step takes it, its `_abstractify`).
+    A plain host array goes as it is: the jitted call's own argument
+    path uploads it, with the same canonical dtype and without
+    `jnp.asarray`'s Python layers in front. Everything else (a frozen
+    owning array, which the feed cache holds on the device; ragged and
+    LoD feeds; scalars) goes through `_to_device_value` as before."""
+    if type(value) is np.ndarray:
+        as_it_is = value.flags.writeable or not value.flags.owndata
+    else:
+        as_it_is = isinstance(value, jax.Array)
+    if not as_it_is:
+        value = _to_device_value(value)
+    return value, _abstractify(value)
+
+
 def device_feed(feed: Dict[str, Any]) -> Dict[str, Any]:
     """Upload a host feed dict to in-graph device form (idempotent:
     already-device values pass through). The shared convert+upload step
@@ -222,7 +254,7 @@ def _abstractify(value):
     if isinstance(value, RaggedTree):
         return ("raggedk", len(value.lengths), value.data.shape,
                 str(value.data.dtype))
-    return (tuple(value.shape), str(value.dtype))
+    return (tuple(value.shape), _dtype_name(value.dtype))
 
 
 def feed_signature(feed_vals) -> Tuple:
@@ -469,20 +501,30 @@ def _collect_state_names(program: Program, block: BlockDesc,
 class CompiledProgram:
     """A jitted artifact for (program, feed signature, fetch list).
 
-    `jitted`/`ro_names`/`rw_names` expose the underlying jax.jit stage for
-    AOT introspection (profiler.cost_analysis, HLO dumps); `lower_again`
-    is the one way to it, and `op_table` what is read from it most."""
+    `fn(feeds, ro, rw, step)` takes SEQUENCES: the feeds in
+    `feed_names`' order, the state `ro_names` and `rw_names` name, each
+    sorted by name (the order a dict by name flattens to), and returns
+    (fetches, {name: written state}, the step counter advanced).
+    `jitted` is the jax.jit stage under it, for AOT introspection
+    (profiler.cost_analysis, HLO dumps): it takes the same sequences
+    or, for a caller that holds names, dicts by name; `lower_again` is
+    the one way to it, and `op_table` what is read from it most."""
 
     def __init__(self, fn, read_names, write_names, fetch_names,
                  jitted=None, ro_names=(), rw_names=(), block=None,
-                 arg_shardings=None):
+                 arg_shardings=None, feed_names=()):
         self.fn = fn
         self.read_names = read_names
         self.write_names = write_names
         self.fetch_names = fetch_names
         self.jitted = jitted
+        self.feed_names = list(feed_names)
         self.ro_names = list(ro_names)
         self.rw_names = list(rw_names)
+        # the donated names the trace does not write back, which a
+        # commit erases from the scope: known from the first call's
+        # result on (the traced step's outputs are static)
+        self.unwritten: Optional[List[str]] = None
         # the block `jitted` traces (its closure holds it anyway)
         self.block = block
         # the mesh executor's in_shardings, as (feed, ro, rw, step)
@@ -499,13 +541,12 @@ class CompiledProgram:
         self.avals = None
         self._op_table = None
 
-    def record_avals(self, feed_vals, state_vals, step) -> None:
-        """Keep the abstract form of the arguments of the call about to
-        be made, so that `lower_again` needs neither a scope nor an
-        executor nor a feed. An array keeps the sharding it is committed
-        to (the mesh executor's declared ones where it compiled)."""
-        args = (feed_vals, {n: state_vals[n] for n in self.ro_names},
-                {n: state_vals[n] for n in self.rw_names}, step)
+    def record_avals(self, *args) -> None:
+        """Keep the abstract form of the arguments (feeds, ro, rw, step)
+        of the call about to be made, so that `lower_again` needs
+        neither a scope nor an executor nor a feed. An array keeps the
+        sharding it is committed to (the mesh executor's declared ones
+        where it compiled)."""
         shardings = () if self.arg_shardings is None \
             else (self.arg_shardings,)
         self.avals = jax.tree_util.tree_map(_aval, args, *shardings)
@@ -542,6 +583,13 @@ class CompiledProgram:
         """{(block idx, op index): (role, scope type)} of the program
         this entry traces (`op_table.program_ops`)."""
         return op_table.program_ops(self.block)
+
+
+def by_name(names, values) -> Dict[str, Any]:
+    """{name: value} of one argument of a jitted step: handed over as a
+    sequence in `names`' order (Executor.run) or, by a caller that holds
+    the names, as that dict already."""
+    return values if isinstance(values, dict) else dict(zip(names, values))
 
 
 def _aval(x, sharding=None):
@@ -792,6 +840,9 @@ def _listen_to_compiles() -> None:
 
 
 def _obs_instruments():
+    """(registry, hits, misses, the donation gauge, {outcome: bound
+    steps}): the series themselves, resolved once a registry — a call
+    of run() increments two and sets one."""
     global _obs_cache
     reg = default_registry()
     if _obs_cache is None or _obs_cache[0] is not reg:
@@ -800,17 +851,63 @@ def _obs_instruments():
             reg.counter(
                 "paddle_tpu_compile_cache_hits_total",
                 "Executor.run dispatches served by an already-jitted "
-                "executable (all executors in this process)."),
+                "executable (all executors in this process).").labels(),
             reg.counter(
                 "paddle_tpu_compile_cache_misses_total",
                 "Executor.run dispatches that traced + XLA-compiled a "
-                "new executable (all executors in this process)."),
+                "new executable (all executors in this process).").labels(),
             reg.gauge(
                 "paddle_tpu_executor_donate_state",
                 "1 when the most recent Executor.run dispatched with "
-                "donated (buffer-aliased) train state, else 0."),
+                "donated (buffer-aliased) train state, else 0.").labels(),
         )
+        bound = reg.counter(
+            "paddle_tpu_executor_bound_steps_total",
+            "Executor.run calls by what resolved them: hit (a record "
+            "of this program and feed signature served the call) or "
+            "bound (the record was built: a first call, a new feed "
+            "signature, a program whose version moved). A steady loop "
+            "reads hits only.", ("outcome",))
+        _obs_cache += ({"hit": bound.labels(outcome="hit"),
+                        "bound": bound.labels(outcome="bound")},)
     return _obs_cache
+
+
+class _Binding:
+    """What `Executor.run` resolved once for a program AS IT IS CALLED
+    (uid, version, block, fetch list, iterations, stacked_feed, sync,
+    donation, ambient AMP and verification) — the reference's
+    `Executor::Prepare` beside its `RunPreparedContext`
+    (executor.cc:271-360): everything a call used to recompute from the
+    block's ops. It holds NAMES and plain data, never an array: what a
+    step reads is what the scope holds when it is called."""
+
+    __slots__ = ("fetch_names", "n_user_fetches", "gated", "probe",
+                 "stateful", "steps")
+
+    def __init__(self, block: BlockDesc, user_fetches: List[str]):
+        # Auto-fetch every bounded-While exhaustion flag in this block
+        # (plain temps, not persistable state). Appended even when the
+        # user also fetches one — the checked tail must be complete.
+        # Truncation warns once per flag by default; with
+        # PADDLE_TPU_CHECK_WHILE_BOUND=1 it raises instead. Limitation:
+        # a bounded While nested inside another sub-block keeps its flag
+        # block-local; propagate it to a parent var (assign) to check it
+        # here.
+        self.fetch_names = user_fetches + [
+            op.outputs["Exhausted"][0] for op in block.ops
+            if op.type == "while" and op.outputs.get("Exhausted")]
+        self.n_user_fetches = len(user_fetches)
+        # the feed-name tuples the verifier's gate has passed with
+        self.gated: set = set()
+        # (targets, prefix) of `_dynamic_while_targets`, () where the
+        # block has none, None until a call has looked
+        self.probe: Optional[Tuple] = None
+        # `_stateful_ops_in` the block, None until iterations > 1 asks
+        self.stateful: Optional[List[str]] = None
+        # {the feeds' names, shapes and dtypes as handed over [, the
+        # probed While bounds]: the CompiledProgram that serves them}
+        self.steps: Dict[Tuple, CompiledProgram] = {}
 
 
 # Deferred bounded-While truncation flags are normally checked one run
@@ -851,10 +948,11 @@ class Executor:
         self._inflight_state: List[Any] = []
         self._cache: Dict[Tuple, CompiledProgram] = {}
         self._probe_cache: Dict[Tuple, Any] = {}
-        # stateful-op scan results for run(iterations=K), keyed by
-        # (program uid, version, block) — the walk is O(num_ops) and
-        # sits on the repeated-dispatch path
-        self._stateful_cache: Dict[Tuple, List[str]] = {}
+        # the bound steps: what run() resolved once for each program as
+        # it is called (_Binding). Like _cache, read and filled from
+        # several threads: one dict operation each, and two threads
+        # that bind one key build equal records
+        self._bindings: Dict[Tuple, _Binding] = {}
         # bounded-While truncation flags from the PREVIOUS run, checked
         # one step later so the warn-by-default path never forces a
         # device sync on the just-dispatched step
@@ -897,27 +995,16 @@ class Executor:
 
     # ------------------------------------------------------------------
     def _probe_while_bounds(self, program: Program, block: BlockDesc,
-                            feed_vals, feed_sig, scope: Scope,
-                            block_idx: int, step):
+                            targets, prefix: int, feed_vals, feed_sig,
+                            scope: Scope, block_idx: int, step):
         """Probe-and-replay WhileGrad, phase 1 (reference analog:
         while_op.cc:96 step scopes — there the forward RECORDS per-step
         state; here, XLA-native, the forward prefix RE-RUNS to measure
         each dynamic loop's trip count, and phase 2 recompiles the full
         program with the bucketed bound baked into a differentiable
         masked scan). State writes are discarded — the probe is pure.
-        Returns {while_id: bound} or None."""
-        targets, prefix = _dynamic_while_targets(block)
-        if not targets:
-            return None
-        stateful = _stateful_ops_in(program, block.ops[:prefix])
-        if stateful:
-            raise RuntimeError(
-                "cannot differentiate an unbounded While in a program "
-                f"whose forward prefix has stateful ops {sorted(set(stateful))}: "
-                "the trip-count probe re-executes that prefix, which "
-                "would fire each channel/select/go op twice per step. "
-                "Give the While an explicit max_steps, or move the CSP "
-                "ops after the last dynamic While.")
+        `targets` and `prefix` are `_dynamic_while_targets(block)`'s.
+        Returns {while_id: bound}."""
         steps_names = list(targets.values())
         pkey = (program.uid, program.version, feed_sig, block_idx,
                 "__probe__")
@@ -957,13 +1044,15 @@ class Executor:
                  donate: bool = True) -> CompiledProgram:
         read_names, write_names = _collect_state_names(program, block, scope)
         fetch_names = list(fetch_names)
+        feed_names = [k for k, _ in feed_sig or ()]
         # Donate only buffers that are overwritten (param updates); read-only
         # state (e.g. params in a forward-only program) must survive the call.
-        rw_names = [n for n in read_names if n in set(write_names)]
-        ro_names = [n for n in read_names if n not in set(write_names)]
+        written = set(write_names)
+        rw_names = sorted(n for n in read_names if n in written)
+        ro_names = sorted(n for n in read_names if n not in written)
 
-        def step_fn(feed_vals: Dict[str, Any], ro_state: Dict[str, Any],
-                    rw_state: Dict[str, Any], step: jnp.ndarray):
+        def one_step(feed_vals: Dict[str, Any], ro_state: Dict[str, Any],
+                     rw_state: Dict[str, Any], step: jnp.ndarray):
             env: Dict[str, Any] = {}
             env.update(ro_state)
             env.update(rw_state)
@@ -982,12 +1071,27 @@ class Executor:
             new_state = {n: env[n] for n in write_names if n in env}
             return fetches, new_state
 
+        # what is jitted takes its feeds and its state as sequences
+        # (by_name) and returns the step counter advanced, so a call
+        # builds no dict for the flattening to sort and launches
+        # nothing beside the step
         if iterations == 1:
+            def step_fn(feed_vals, ro_state, rw_state, step):
+                fetches, new_state = one_step(
+                    by_name(feed_names, feed_vals),
+                    by_name(ro_names, ro_state),
+                    by_name(rw_names, rw_state), step)
+                with jax.named_scope(STEP_SCOPE):
+                    return fetches, new_state, step + 1
+
             fn = step_fn
         else:
             n_flags = int(or_reduce_tail)
 
             def fn(feed_vals, ro_state, rw_state, step):
+                feed_vals = by_name(feed_names, feed_vals)
+                ro_state = by_name(ro_names, ro_state)
+                rw_state = by_name(rw_names, rw_state)
                 # K steps inside ONE compiled program (lax.scan over the
                 # traced step): per-dispatch overhead is paid once per K
                 # real steps, which is what makes ms-scale steps
@@ -1008,8 +1112,8 @@ class Executor:
                 zeros = jax.tree_util.tree_map(
                     lambda a: jnp.zeros(a.shape, a.dtype),
                     jax.eval_shape(
-                        lambda rw, st: step_fn(feed0, ro_state,
-                                               rw, st),
+                        lambda rw, st: one_step(feed0, ro_state,
+                                                rw, st),
                         rw_state, step))
                 f0, ns0 = zeros
                 e0 = {n: v for n, v in ns0.items() if n not in rw_names}
@@ -1018,12 +1122,12 @@ class Executor:
                 def body(carry, xs):
                     rw_c, st, f_c, _e_c = carry
                     step_feed = xs if stacked_feed else feed_vals
-                    fetches, new_state = step_fn(step_feed, ro_state,
-                                                 rw_c, st)
+                    fetches, new_state = one_step(step_feed, ro_state,
+                                                  rw_c, st)
                     rw_next = {n: new_state.get(n, rw_c[n])
                                for n in rw_names}
                     # e0 keys come from the eval_shape trace of this
-                    # very step_fn, so every one must be produced here
+                    # very one_step, so every one must be produced here
                     # too — index directly so a divergence fails loudly
                     # instead of silently writing the zero placeholder
                     # back to the scope
@@ -1032,15 +1136,17 @@ class Executor:
                         jnp.logical_or(f_c[i], f) if i >= first_flag
                         else f
                         for i, f in enumerate(fetches)]
-                    return (rw_next, st + 1, f_out, extra_w), None
+                    with jax.named_scope(STEP_SCOPE):
+                        st = st + 1
+                    return (rw_next, st, f_out, extra_w), None
 
-                (rw_f, _, fetches, extra_w), _ = jax.lax.scan(
+                (rw_f, step, fetches, extra_w), _ = jax.lax.scan(
                     body, (rw_state, step, f0, e0),
                     xs=feed_vals if stacked_feed else None,
                     length=iterations)
                 new_state = dict(rw_f)
                 new_state.update(extra_w)
-                return fetches, new_state
+                return fetches, new_state, step
 
         # donate=True aliases the rw state (argnum 2) in XLA: state-out
         # writes land in the state-in buffers instead of fresh
@@ -1049,15 +1155,10 @@ class Executor:
         # after the call — is enforced in run() (scope is repointed at
         # the outputs, and stragglers are erased).
         jitted = jax.jit(fn, donate_argnums=(2,) if donate else ())
-
-        def call(feed_vals, state_vals, step):
-            ro = {n: state_vals[n] for n in ro_names}
-            rw = {n: state_vals[n] for n in rw_names}
-            return jitted(feed_vals, ro, rw, step)
-
-        return CompiledProgram(call, read_names, write_names, fetch_names,
-                               jitted=jitted, ro_names=ro_names,
-                               rw_names=rw_names, block=block)
+        return CompiledProgram(jitted, read_names, write_names,
+                               fetch_names, jitted=jitted,
+                               ro_names=ro_names, rw_names=rw_names,
+                               block=block, feed_names=feed_names)
 
     # ------------------------------------------------------------------
     def run(self, program: Program, feed: Optional[Dict[str, Any]] = None,
@@ -1099,11 +1200,11 @@ class Executor:
             program = program.desc
         scope = global_scope() if scope is None else scope
         with profiler.RecordEvent("pipeline::prepare",
-                                  cat=profiler.CAT_PIPELINE):
-            (compiled, missed, feed_vals, state_vals, step, fetch_names,
-             n_user_fetches) = self._prepare(
+                                  cat=profiler.CAT_PIPELINE) as span:
+            binding, compiled, missed, outcome, args = self._prepare(
                 program, feed or {}, fetch_list, scope, block_idx,
                 iterations, stacked_feed, sync)
+            span.args = {"bound": outcome}
         # a first dispatch traces, lowers and compiles inside the
         # jitted call: JAX's compile-phase events fired there carry
         # this program's uid (_on_jax_duration)
@@ -1112,56 +1213,53 @@ class Executor:
         try:
             with profiler.RecordEvent("pipeline::dispatch",
                                       cat=profiler.CAT_PIPELINE):
-                fetches, new_state = compiled.fn(feed_vals, state_vals,
-                                                 step)
+                fetches, new_state, step = compiled.fn(*args)
         finally:
             _compiling.args = None
         with profiler.RecordEvent("pipeline::commit",
                                   cat=profiler.CAT_PIPELINE):
-            result = self._commit(program, scope, compiled, fetches,
-                                  new_state, step, iterations,
-                                  fetch_names, n_user_fetches,
-                                  return_numpy)
+            result = self._commit(program, scope, binding, compiled,
+                                  fetches, new_state, step, return_numpy)
         return result.fetches() if sync else result
 
     def _prepare(self, program, feed, fetch_list, scope, block_idx,
                  iterations, stacked_feed, sync):
-        """run()'s host work before the dispatch: the gate look-up, feed
-        conversion, the compile key, the executable (compiled on a
-        miss) and the state arrays it reads."""
+        """run()'s host work before the dispatch. A call a record
+        serves (every call after the first of its shape) looks the
+        record up from what it can observe — the program's uid and
+        version, how it is called, the feeds' names, shapes and dtypes
+        — and reads the state the record names from the scope: work in
+        the feeds and the state names, none in the program's ops.
+        Returns (the _Binding, the CompiledProgram, whether it was
+        compiled now, "hit" or "bound", the arguments of its `fn`)."""
         fetch_names = [f if isinstance(f, str) else f.name
                        for f in (fetch_list or [])]
+        from ..analysis import verifier as _verifier
+        verify = _verifier.verify_enabled()
+        bkey = (program.uid, program.version, block_idx,
+                tuple(fetch_names), iterations, stacked_feed, sync,
+                self.donate_state, amp_enabled(), verify)
         block = program.block(block_idx)
-
-        n_user_fetches = len(fetch_names)
-        # Auto-fetch every bounded-While exhaustion flag in this block
-        # (plain temps, not persistable state). Appended even when the
-        # user also fetches one — the checked tail must be complete.
-        # Truncation warns once per flag by default; with
-        # PADDLE_TPU_CHECK_WHILE_BOUND=1 it raises instead. Limitation:
-        # a bounded While nested inside another sub-block keeps its flag
-        # block-local; propagate it to a parent var (assign) to check it
-        # here.
-        exhausted = [op.outputs["Exhausted"][0] for op in block.ops
-                     if op.type == "while"
-                     and op.outputs.get("Exhausted")]
-        fetch_names = fetch_names + exhausted
+        binding = self._bindings.get(bkey)
+        if binding is None:
+            binding = self._bindings[bkey] = _Binding(block, fetch_names)
 
         # Pre-compile safety gate: structural verification (def-use,
         # build-time shape markers, dead code, donation hazards) BEFORE
         # any trace or XLA compile, so a malformed program raises a
         # VerificationError (a ValueError) naming the op and block path
-        # instead of a deep JAX trace error. Memoized per program
-        # version, so steady-state dispatch pays one dict lookup;
-        # PADDLE_TPU_VERIFY=0 opts out.
-        from ..analysis import verifier as _verifier
-        if _verifier.verify_enabled():
-            _verifier.executor_gate(program, block_idx,
-                                    fetch_names[:n_user_fetches],
-                                    feed.keys(), self.donate_state, sync)
+        # instead of a deep JAX trace error. Passed once per binding
+        # and set of feeds; PADDLE_TPU_VERIFY=0 opts out.
+        feed_names = tuple(feed)
+        if verify and feed_names not in binding.gated:
+            _verifier.executor_gate(program, block_idx, fetch_names,
+                                    feed_names, self.donate_state, sync)
+            binding.gated.add(feed_names)
 
-        feed_vals = {k: _to_device_value(v) for k, v in feed.items()}
-        feed_sig = feed_signature(feed_vals)
+        feed_vals, sig = {}, []
+        for k, v in feed.items():
+            feed_vals[k], abstract = _feed_arg(v)
+            sig.append((k, abstract))
         step = scope.find(STEP_VAR)
         if step is None:
             step = jnp.zeros((), jnp.int32)
@@ -1185,12 +1283,78 @@ class Executor:
 
         # unbounded-While gradients: measure trip counts with a forward
         # probe, then compile with the bucketed bounds baked in; with
-        # stacked feeds the probe sees one PER-STEP slice
-        probe_feed = {k_: v_[0] for k_, v_ in feed_vals.items()} \
-            if stacked_feed else feed_vals
-        while_bounds = self._probe_while_bounds(
-            program, block, probe_feed, feed_sig, scope, block_idx, step)
+        # stacked feeds the probe sees one PER-STEP slice. The counts
+        # depend on the feed's VALUES, so such a program probes on
+        # every call; any other never enters the probe.
+        probe = binding.probe
+        if probe is None:
+            probe = binding.probe = self._probe_plan(program, block)
+        skey = tuple(sig)
+        while_bounds = None
+        if probe:
+            probe_feed = {k_: v_[0] for k_, v_ in feed_vals.items()} \
+                if stacked_feed else feed_vals
+            while_bounds = self._probe_while_bounds(
+                program, block, *probe, probe_feed, tuple(sorted(sig)),
+                scope, block_idx, step)
+            skey += tuple(sorted(while_bounds.items()))
 
+        _, obs_hits, _, obs_donate, obs_bound = _obs_instruments()
+        obs_donate.set(1.0 if self.donate_state else 0.0)
+        compiled = binding.steps.get(skey)
+        missed = False
+        if compiled is None:
+            outcome = "bound"
+            compiled, missed = self._bind_step(
+                program, block, binding, feed_vals, tuple(sorted(sig)),
+                while_bounds, scope, block_idx, iterations, stacked_feed,
+                sync)
+            binding.steps[skey] = compiled
+        else:
+            outcome = "hit"
+            self.cache_stats["hits"] += 1
+            obs_hits.inc()
+        obs_bound[outcome].inc()
+        self.last_cost = compiled.cost
+        self.last_memory = compiled.memory
+
+        args = ([feed_vals[k] for k in compiled.feed_names],
+                scope.values_of(compiled.ro_names),
+                scope.values_of(compiled.rw_names), step)
+        if missed:
+            compiled.record_avals(*args)
+        # the arrays of the last feed, for a caller that traces the
+        # jitted stage itself (AOT lowering reads compiled.avals)
+        self._last_feed_vals = feed_vals
+        return binding, compiled, missed, outcome, args
+
+    @staticmethod
+    def _probe_plan(program: Program, block: BlockDesc) -> Tuple:
+        """`_dynamic_while_targets(block)` where a probe has something
+        to measure, () where the block has no such While."""
+        targets, prefix = _dynamic_while_targets(block)
+        if not targets:
+            return ()
+        stateful = _stateful_ops_in(program, block.ops[:prefix])
+        if stateful:
+            raise RuntimeError(
+                "cannot differentiate an unbounded While in a program "
+                f"whose forward prefix has stateful ops {sorted(set(stateful))}: "
+                "the trip-count probe re-executes that prefix, which "
+                "would fire each channel/select/go op twice per step. "
+                "Give the While an explicit max_steps, or move the CSP "
+                "ops after the last dynamic While.")
+        return targets, prefix
+
+    def _bind_step(self, program, block, binding, feed_vals, feed_sig,
+                   while_bounds, scope, block_idx, iterations,
+                   stacked_feed, sync):
+        """The CompiledProgram for `binding` under this feed signature
+        (and these While bounds): the compile cache's where it holds
+        one, compiled now where not. Returns (it, whether it was
+        compiled now)."""
+        fetch_names = binding.fetch_names
+        n_user_fetches = binding.n_user_fetches
         if iterations < 1:
             raise ValueError(
                 f"iterations must be >= 1, got {iterations}: a "
@@ -1203,11 +1367,10 @@ class Executor:
                     "gradients: the trip-count probe measures the initial "
                     "state only, but later scan iterations may need a "
                     "larger bound. Run steps one at a time.")
-            skey = (program.uid, program.version, block_idx)
-            stateful = self._stateful_cache.get(skey)
+            stateful = binding.stateful
             if stateful is None:
-                stateful = _stateful_ops_in(program, block.ops)
-                self._stateful_cache[skey] = stateful
+                stateful = binding.stateful = \
+                    _stateful_ops_in(program, block.ops)
             if stateful:
                 raise RuntimeError(
                     f"iterations > 1 with stateful ops "
@@ -1220,8 +1383,7 @@ class Executor:
                                iterations=iterations,
                                stacked_feed=stacked_feed,
                                donate=self.donate_state)
-        _, obs_hits, obs_misses, obs_donate = _obs_instruments()
-        obs_donate.set(1.0 if self.donate_state else 0.0)
+        _, obs_hits, obs_misses = _obs_instruments()[:3]
         compiled = self._cache.get(key)
         missed = compiled is None
         if missed:
@@ -1230,7 +1392,7 @@ class Executor:
             span_args = {"uid": program.uid, "block": block_idx}
             kw = {} if iterations == 1 else {
                 "iterations": iterations,
-                "or_reduce_tail": len(exhausted),
+                "or_reduce_tail": len(fetch_names) - n_user_fetches,
                 "stacked_feed": stacked_feed}
             # feed shapes of THIS dispatch, for the -1-dim binding of
             # the memory plan and the cost model below (stacked feeds
@@ -1250,13 +1412,14 @@ class Executor:
             # of an unattributed allocator failure deep inside
             # compilation. The plan itself is best-effort; the budget
             # check respects the PADDLE_TPU_VERIFY kill switch.
+            from ..analysis import verifier as _verifier
             mem_report = None
             try:
                 from ..analysis import memory as _memory
                 with _compile_span("memory_plan", span_args):
                     mem_report = _memory.program_memory(
                         program, block_idx, feed_shapes=fs,
-                        feed_names=feed.keys(),
+                        feed_names=feed_vals.keys(),
                         label=f"program uid={program.uid} "
                               f"block={block_idx}")
             except Exception:
@@ -1286,8 +1449,6 @@ class Executor:
         else:
             self.cache_stats["hits"] += 1
             obs_hits.inc()
-        self.last_cost = compiled.cost
-        self.last_memory = compiled.memory
 
         if not sync and self.donate_state:
             rw = set(compiled.rw_names)
@@ -1299,34 +1460,31 @@ class Executor:
                     "the next step donates (and XLA deletes). Fetch them "
                     "with sync=True, or build the Executor with "
                     "donate_state=False.")
+        return compiled, missed
 
-        state_vals = {n: scope.get(n) for n in compiled.read_names}
-        if missed:
-            compiled.record_avals(feed_vals, state_vals, step)
-        # the arrays of the last feed, for a caller that traces the
-        # jitted stage itself (AOT lowering reads compiled.avals)
-        self._last_feed_vals = feed_vals
-        return (compiled, missed, feed_vals, state_vals, step,
-                fetch_names, n_user_fetches)
-
-    def _commit(self, program, scope, compiled, fetches, new_state, step,
-                iterations, fetch_names, n_user_fetches, return_numpy):
+    def _commit(self, program, scope, binding, compiled, fetches,
+                new_state, step, return_numpy):
         """run()'s host work after the dispatch: the scope repointed at
-        the new state, the While flags, the StepResult."""
-        scope.set(STEP_VAR, step + iterations)
-        for n, v in new_state.items():
-            scope.set(n, v)
+        the new state and at the step counter the compiled step
+        advanced, the While flags, the StepResult."""
+        scope.set(STEP_VAR, step)
+        scope.update(new_state)
         if self.donate_state:
             # every donated input buffer is dead after the call; the
-            # loop above repointed scope at the outputs for vars the
+            # update above repointed scope at the outputs for vars the
             # trace produced — explicitly drop any donated name the
             # trace did NOT write back, so a later scope read fails
             # loudly (KeyError) instead of returning a deleted buffer
-            for n in compiled.rw_names:
-                if n not in new_state:
-                    scope.erase(n)
-        self._inflight_state = list(new_state.values())
+            unwritten = compiled.unwritten
+            if unwritten is None:
+                unwritten = compiled.unwritten = [
+                    n for n in compiled.rw_names if n not in new_state]
+            for n in unwritten:
+                scope.erase(n)
+        self._inflight_state = new_state
 
+        n_user_fetches = binding.n_user_fetches
+        fetch_names = binding.fetch_names
         flag_vals = list(zip(fetch_names[n_user_fetches:],
                              fetches[n_user_fetches:]))
         if CHECK_WHILE_BOUND:
@@ -1334,7 +1492,7 @@ class Executor:
             # points at the offending step
             for n, v in flag_vals:
                 _check_while_flag((program.uid, n), v, raise_=True)
-        else:
+        elif flag_vals or self._deferred_flags:
             # warn mode: consume deferred flags whose arrays are
             # already resident — reading those is free — and KEEP
             # deferring any still in flight, so back-to-back async
@@ -1420,4 +1578,4 @@ class Executor:
         self._inflight_state = []
         self._cache.clear()
         self._probe_cache.clear()
-        self._stateful_cache.clear()
+        self._bindings.clear()

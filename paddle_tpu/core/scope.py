@@ -41,6 +41,20 @@ class Scope:
             raise KeyError(f"variable {name!r} not found in scope")
         return v
 
+    def values_of(self, names) -> list:
+        """`get` of each name, in order: one pass over this scope's own
+        dict where it holds them all (a step's state, read every call),
+        name by name up the parents where it does not."""
+        held = self._vars
+        try:
+            return [held[n] for n in names]
+        except KeyError:
+            return [self.get(n) for n in names]
+
+    def update(self, values: Dict[str, Any]) -> None:
+        """`set` of every item."""
+        self._vars.update(values)
+
     def erase(self, name: str) -> None:
         self._vars.pop(name, None)
 

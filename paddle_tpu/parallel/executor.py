@@ -18,7 +18,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import profiler
-from ..core.executor import Executor, CompiledProgram, trace_block
+from ..core.executor import (Executor, CompiledProgram, STEP_SCOPE,
+                             by_name, trace_block)
 from ..core.lod import RaggedNested, RaggedPair, RaggedTree
 from ..core.scope import Scope, global_scope
 from .mesh import get_mesh, make_mesh
@@ -146,14 +147,20 @@ class ParallelExecutor(Executor):
         # (core/executor.py) keeps `fn` and must not keep this executor
         feed_axis = self.sharding.feed_axis
         fetch_names = list(fetch_names)
-        rw_names = [n for n in read_names if n in set(write_names)]
-        ro_names = [n for n in read_names if n not in set(write_names)]
+        # the jitted step takes its feeds and its state as sequences,
+        # each in its names' sorted order (core/executor.py by_name)
+        feed_names = [k for k, _ in feed_sig]
+        written = set(write_names)
+        rw_names = sorted(n for n in read_names if n in written)
+        ro_names = sorted(n for n in read_names if n not in written)
 
         def fn(feed_vals, ro_state, rw_state, step):
+            ro_state = by_name(ro_names, ro_state)
+            rw_state = by_name(rw_names, rw_state)
             env: Dict[str, Any] = {}
             env.update(ro_state)
             env.update(rw_state)
-            env.update(feed_vals)
+            env.update(by_name(feed_names, feed_vals))
             extra = {
                 "program": program,
                 "step": step,
@@ -183,7 +190,8 @@ class ParallelExecutor(Executor):
                     new_state[n] = rw_state[n]
                 else:
                     new_state[n] = ro_state[n]
-            return fetches, new_state
+            with jax.named_scope(STEP_SCOPE):
+                return fetches, new_state, step + 1
 
         feed_shardings = {}
         for name, sig in feed_sig:
@@ -268,20 +276,20 @@ class ParallelExecutor(Executor):
         state_out = {n: rw_shardings.get(
             n, NamedSharding(mesh, state_spec(n)))
             for n in returnable_names}
+        step_sh = NamedSharding(mesh, P())
+        arg_shardings = ([feed_shardings[k] for k in feed_names],
+                         [ro_shardings[n] for n in ro_names],
+                         [rw_shardings[n] for n in rw_names], step_sh)
         jitted = jax.jit(
-            fn,
-            in_shardings=(feed_shardings, ro_shardings, rw_shardings,
-                          NamedSharding(mesh, P())),
-            out_shardings=(fetch_out, state_out),
+            fn, in_shardings=arg_shardings,
+            out_shardings=(fetch_out, state_out, step_sh),
             donate_argnums=(2,) if donate else ())
 
-        multiprocess = self._multiprocess
-        step_sh = NamedSharding(mesh, P())
+        call = jitted
+        if self._multiprocess:
+            pending_ro = self._pending_ro_globals
 
-        pending_ro = self._pending_ro_globals
-
-        def call(feed_vals, state_vals, step):
-            if multiprocess:
+            def call(feed_vals, ro_vals, rw_vals, step):
                 # state a plain Executor initialized (startup) lives on
                 # local devices; lift it to the global mesh once —
                 # thereafter the written-back state is already global.
@@ -289,26 +297,21 @@ class ParallelExecutor(Executor):
                 # form is handed to run() via _pending_ro_globals, which
                 # writes it into the RUN-TIME scope (one upload, not one
                 # per step; the compile-time scope may differ).
-                ro = {}
-                for n in ro_names:
-                    g = _globalize(state_vals[n], ro_shardings[n])
-                    if g is not state_vals[n]:
+                ro = []
+                for n, v in zip(ro_names, ro_vals):
+                    g = _globalize(v, ro_shardings[n])
+                    if g is not v:
                         pending_ro[n] = g
-                    ro[n] = g
-                rw = {n: _globalize(state_vals[n], rw_shardings[n])
-                      for n in rw_names}
-                step = _globalize(step, step_sh)
-            else:
-                ro = {n: state_vals[n] for n in ro_names}
-                rw = {n: state_vals[n] for n in rw_names}
-            return jitted(feed_vals, ro, rw, step)
+                    ro.append(g)
+                rw = [_globalize(v, rw_shardings[n])
+                      for n, v in zip(rw_names, rw_vals)]
+                return jitted(feed_vals, ro, rw, _globalize(step, step_sh))
 
         return CompiledProgram(call, read_names, write_names,
                                fetch_names, jitted=jitted,
                                ro_names=ro_names, rw_names=rw_names,
-                               block=block,
-                               arg_shardings=(feed_shardings, ro_shardings,
-                                              rw_shardings, step_sh))
+                               block=block, arg_shardings=arg_shardings,
+                               feed_names=feed_names)
 
     @staticmethod
     def _state_names(program, block, scope):

@@ -199,10 +199,11 @@ def aot_compiled_hlo(pexe, program, feed_structs: Dict, fetch_list,
         a = np.asarray(x) if not hasattr(x, "shape") else x
         return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
 
-    ro = {n: struct(scope.get(n)) for n in cp.ro_names}
-    rw = {n: struct(scope.get(n)) for n in cp.rw_names}
-    lowered = cp.jitted.lower(feed_structs, ro, rw,
-                              jax.ShapeDtypeStruct((), jnp.int32))
+    # the mesh step takes sequences, in its names' order
+    ro = [struct(scope.get(n)) for n in cp.ro_names]
+    rw = [struct(scope.get(n)) for n in cp.rw_names]
+    lowered = cp.jitted.lower([feed_structs[k] for k in cp.feed_names],
+                              ro, rw, jax.ShapeDtypeStruct((), jnp.int32))
     return lowered.compile().as_text()
 
 

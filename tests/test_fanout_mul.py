@@ -177,11 +177,11 @@ def _trace_step(exe, main, fetch):
     scope = pt.global_scope()
     step = exe._compile(main.desc, main.desc.block(0), sig,
                         [fetch["loss"].name], scope)
-    sds = {n: jax.ShapeDtypeStruct(s, np.int64) for n, (s, _) in sig}
+    sds = [jax.ShapeDtypeStruct(s, np.int64) for _, (s, _) in sig]
 
     def state(names):
-        return {n: jax.ShapeDtypeStruct(scope.get(n).shape,
-                                        scope.get(n).dtype) for n in names}
+        return [jax.ShapeDtypeStruct(scope.get(n).shape,
+                                     scope.get(n).dtype) for n in names]
     step.jitted.trace(sds, state(step.ro_names), state(step.rw_names),
                       jax.ShapeDtypeStruct((), np.int32))
 

@@ -77,8 +77,8 @@ def test_every_named_instruction_maps_to_an_op_of_the_program(trained):
     for line in text.splitlines():
         m = re.match(r"^\s+(?:ROOT )?%?([^\s=]+) = .*"
                      r'op_name="(jit\(step_fn\)/[^"]*)"', line)
-        if not m:
-            continue
+        if not m or m.group(2).startswith("jit(step_fn)/step_counter/"):
+            continue   # the one instruction that is no op's: step + 1
         named += 1
         ref = table.ops[m.group(1)]
         assert ref.block_path == (0,)
@@ -184,9 +184,9 @@ def test_the_entry_keeps_shapes_and_no_arrays(trained):
     assert leaves and all(isinstance(a, jax.ShapeDtypeStruct)
                           for a in leaves)
     feed, ro, rw, _step = entry.avals
-    assert set(feed) == {"x", "y"} and feed["x"].shape == (16, 8)
-    assert set(ro) == set(entry.ro_names)
-    assert set(rw) == set(entry.rw_names)
+    assert entry.feed_names == ["x", "y"] and feed[0].shape == (16, 8)
+    assert len(ro) == len(entry.ro_names)
+    assert len(rw) == len(entry.rw_names)
 
 
 def test_the_process_lists_an_entry_after_its_executor_closed():

@@ -473,14 +473,13 @@ def test_a_mesh_layer_pair_all_reduces_one_tensor_a_shared_input(
             feeds, ro, rw, step_sh = step.arg_shardings
 
             def state(names, shardings):
-                return {n: jax.ShapeDtypeStruct(
-                    scope.get(n).shape, scope.get(n).dtype,
-                    sharding=shardings[n]) for n in names}
+                return [jax.ShapeDtypeStruct(
+                    scope.get(n).shape, scope.get(n).dtype, sharding=sh)
+                    for n, sh in zip(names, shardings)]
 
             text = step.jitted.lower(
-                {n: jax.ShapeDtypeStruct(shape, jnp.int32,
-                                         sharding=feeds[n])
-                 for n, (shape, _) in sig},
+                [jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
+                 for (_, (shape, _)), sh in zip(sig, feeds)],
                 state(step.ro_names, ro), state(step.rw_names, rw),
                 jax.ShapeDtypeStruct((), jnp.int32, sharding=step_sh)
             ).compile().as_text()

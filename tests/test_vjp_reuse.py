@@ -71,10 +71,10 @@ def _step_jaxpr(exe, program, scope=None):
     scope = pt.global_scope() if scope is None else scope
     uid = program.desc.uid
     entry = next(v for k, v in exe._cache.items() if k[0] == uid)
-    ro = {n: scope.get(n) for n in entry.ro_names}
-    rw = {n: scope.get(n) for n in entry.rw_names}
-    traced = entry.jitted.trace(exe._last_feed_vals, ro, rw,
-                                jnp.zeros((), jnp.int32))
+    traced = entry.jitted.trace(
+        [exe._last_feed_vals[k] for k in entry.feed_names],
+        scope.values_of(entry.ro_names), scope.values_of(entry.rw_names),
+        jnp.zeros((), jnp.int32))
     return traced.jaxpr
 
 
