@@ -391,6 +391,28 @@ def _flops_for(op: ir.OpDesc,
         return (None, False, None) if st is None else \
             (5 * st.numel, True, None)
 
+    if t == "gated_delta_prefill":
+        # the chunked form (ops/delta_ops.py), a head a chunk of c rows:
+        # K K^T and Q K^T, the unit-triangular solve of the d_v + d_k
+        # right-hand columns, W S_0 and Q S_0, the masked product with
+        # D, and what the chunk leaves its end
+        q, v, a = first("Q"), first("V"), first("A")
+        if q is None or v is None or a is None or len(q.shape) != 3:
+            return None, False, None
+        n, s, heads = a.shape
+        d_k, d_v = q.shape[-1] // heads, v.shape[-1] // heads
+        c = min(int(op.attrs.get("chunk", 64)), s)
+        return (n * s * heads * (4 * c * d_k + c * (d_v + d_k)
+                                 + 2 * c * d_v + 6 * d_k * d_v),
+                True, None)
+
+    if t == "gated_delta_state_update":
+        # the decay, two reads (S^T k, S^T q), the outer product's
+        # product and add: seven operations an element of the state
+        st = first("State")
+        return (None, False, None) if st is None else \
+            (7 * st.numel, True, None)
+
     if t in ("causal_conv1d", "conv_state_update"):
         x, w = first("X"), first("W")
         if x is None or w is None:

@@ -11,6 +11,7 @@ from .detection import *  # noqa: F401,F403
 from .csp import *  # noqa: F401,F403
 from .ssm import *  # noqa: F401,F403
 from .cca import *  # noqa: F401,F403
+from .delta import *  # noqa: F401,F403
 from . import math_op_patch
 from .math_op_patch import monkey_patch_variable  # noqa: F401
 

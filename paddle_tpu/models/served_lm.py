@@ -1,7 +1,8 @@
 """What the token server's program families with more than a KV cache a
-slot share (models/hybrid_ssm.py, models/cca_moe.py): the three modes'
-feeds, the creation of a slot's persistable state, the float32-scaled
-RMS norm, the row of a prompt the head reads, the tied float32 head, the
+slot share (models/hybrid_ssm.py, models/cca_moe.py,
+models/delta_hybrid.py): the three modes' feeds, the creation of a
+slot's persistable state, the float32-scaled RMS norm, the row of a
+prompt the head reads, the float32 head (tied or its own), the
 re-forward programs built when first asked for, and the assembly of the
 program set that serving/generation/model.py GenerationModel takes —
 shaped as models/transformer.py build_decoder_lm's.
@@ -14,8 +15,8 @@ holds hybrid_ssm's).
 from __future__ import annotations
 
 from .. import layers
-from ..initializer import ConstantInitializer
-from ..layer_helper import LayerHelper
+from ..initializer import ConstantInitializer, UniformInitializer
+from ..layer_helper import LayerHelper, ParamAttr
 
 
 def mode_feeds(mode, seq_len, slots):
@@ -87,6 +88,20 @@ def tied_head(x, table, eps):
     and the TIED embedding table [V, d]."""
     return layers.matmul(rms(x, eps, "final_norm"), table,
                          transpose_y=True, out_dtype="float32")
+
+
+def untied_head(x, vocab_size, eps, dtype):
+    """[n, 1, V] float32 logits of x [n, 1, d] through the final norm
+    and a head of its own, [d, V] at the weights' width (made here: the
+    last parameter of a stack)."""
+    normed = rms(x, eps, "final_norm")
+    helper = LayerHelper("lm_head")
+    d = int(x.shape[-1])
+    limit = (6.0 / (d + vocab_size)) ** 0.5        # Xavier-uniform
+    head = helper.create_parameter(
+        ParamAttr(initializer=UniformInitializer(-limit, limit)),
+        [d, vocab_size], dtype)
+    return layers.matmul(normed, head, out_dtype="float32")
 
 
 class OnAsk(dict):

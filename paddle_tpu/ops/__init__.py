@@ -28,3 +28,4 @@ from . import cache_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
 from . import cca_ops  # noqa: F401
+from . import delta_ops  # noqa: F401

@@ -113,9 +113,11 @@ class GenerationMetrics:
             "paddle_tpu_decode_state_bytes",
             "Bytes reserved on the device for per-slot state, by kind: "
             "kv (the attention layers' KV caches), conv (the "
-            "state-space layers' convolution windows) and ssm (their "
-            "recurrent states); reserved for every slot to its limit, "
-            "whatever the slots hold.", ("engine", "kind"))
+            "state-space or linear-attention layers' convolution "
+            "windows), ssm (a state-space layer's recurrent state) and "
+            "delta (a delta-rule layer's matrix state); reserved for "
+            "every slot to its limit, whatever the slots hold.",
+            ("engine", "kind"))
         self.step_seconds = histogram(
             "paddle_tpu_decode_step_seconds",
             "Wall time of one decode step (dispatch to materialized "

@@ -9,9 +9,12 @@ place via donation. A spec names the architecture family that builds
 its programs (``FAMILIES``): "transformer" (models/transformer.py
 build_decoder_lm: ``kv_cache.*`` state), "hybrid_ssm"
 (models/hybrid_ssm.py: KV caches beside convolution windows and
-recurrent states, three kinds a slot) or "cca_moe" (models/cca_moe.py:
+recurrent states, three kinds a slot), "cca_moe" (models/cca_moe.py:
 a compressed KV cache beside three convolution windows a layer, and
-top-1 experts whose rows each step reports with its tokens).
+top-1 experts whose rows each step reports with its tokens) or
+"delta_hybrid" (models/delta_hybrid.py: multi-head KV caches beside
+three convolution windows and one delta-rule matrix state a linear
+layer).
 All programs live in one Executor compile cache — hosting N models on
 a shared executor (GenerationHost) dedupes nothing but ALSO collides
 nothing, because the cache key includes each program's uid/version.
@@ -142,6 +145,15 @@ def _build_cca_moe(spec: GenerationSpec) -> Dict:
         **(spec.arch or {}))
 
 
+def _build_delta_hybrid(spec: GenerationSpec) -> Dict:
+    from ...models.delta_hybrid import build_delta_hybrid_lm
+    return build_delta_hybrid_lm(
+        vocab_size=spec.vocab_size, max_seq_len=spec.max_seq_len,
+        slots=spec.slots, prompt_buckets=spec.prompt_buckets,
+        cache_buckets=spec.cache_buckets, seed=spec.seed,
+        **(spec.arch or {}))
+
+
 #: family name -> the builder of its program set; each returns what
 #: build_decoder_lm returns, and may add "state_kinds" ({kind: [names]})
 #: and "state_prefixes" where a slot owns more than KV caches, and
@@ -149,7 +161,8 @@ def _build_cca_moe(spec: GenerationSpec) -> Dict:
 #: program's fetch carries more than its tokens
 FAMILIES = {"transformer": _build_transformer,
             "hybrid_ssm": _build_hybrid_ssm,
-            "cca_moe": _build_cca_moe}
+            "cca_moe": _build_cca_moe,
+            "delta_hybrid": _build_delta_hybrid}
 
 
 class GenerationModel:

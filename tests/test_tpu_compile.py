@@ -957,3 +957,122 @@ def test_a_cca_moe_program_compiles_at_the_published_widths(
     assert weights < memory.argument_size_in_bytes \
         < weights + 2 * layers * cache + (64 << 20)
     assert memory.temp_size_in_bytes < 32 << 20
+
+
+# The delta-rule family's decode step (models/delta_hybrid.py,
+# Olmo-Hybrid-7B's widths: 40 slots, 30 heads of a 96 x 192 float32
+# matrix state; 30 key heads of 128, bfloat16 caches to 2048 positions).
+def test_delta_state_update_compiles_in_place(one_chip):
+    """One custom call, the 88-MB state aliased to its output, nothing
+    state-sized copied or planned as a temporary."""
+    import re
+
+    from paddle_tpu.ops.pallas.delta_state_update import delta_state_update
+
+    slots, heads, d_k, d_v = 40, 30, 96, 192
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda s, q, k, v, a, b: delta_state_update(s, q, k, v, a, b,
+                                                    interpret=False),
+        donate_argnums=0).lower(
+        sds(slots, d_k, heads * d_v), sds(slots, heads, d_k),
+        sds(slots, heads, d_k), sds(slots, heads * d_v),
+        sds(slots, heads), sds(slots, heads)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"%delta_state_update\S* = ", text)  # named for the trace
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slots * d_k * heads * d_v * 4
+    assert memory.temp_size_in_bytes < 8 << 20
+    assert not re.findall(r"= f32\[40,96,5760\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("mode,bucket", [("decode", 2048),
+                                         ("prefill", 1024)])
+def test_a_delta_hybrid_program_compiles_at_the_published_widths(
+        topo, one_chip, monkeypatch, mode, bucket):
+    """ONE period of the cell's four (three linear layers and a full
+    one, which is what a test's minute allows): the state updates and
+    the appends as custom calls, every state aliased to its output, and
+    what the decode step's attention is handed — 30 key heads of 128
+    are more than ``decode_attention``'s row-major body holds in flight
+    (19.7 MB against 8), so the site is COMPOSED over a slice to the
+    bucket (PERF.md section 7)."""
+    import json
+    import os
+    import re
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import delta_hybrid
+    from paddle_tpu.ops import cache_ops
+    from paddle_tpu.ops.pallas import decode_attention
+
+    real = cache_ops.device_lane_axis
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        cache_ops, "device_lane_axis",
+        lambda shape, dtype: real(shape, dtype, topo.devices[0]))
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "chipbench", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        cfg = json.load(f)
+    layers, slots, max_seq = 4, 40, 2048
+    cache = (slots, 30, max_seq, 128)
+    assert real(cache, jnp.bfloat16, topo.devices[0]) == 3
+    assert not decode_attention.fits(cache, jnp.bfloat16, 3, bucket)
+    arch = dict({k: cfg[k] for k in delta_hybrid.ARCH_KEYS},
+                layer_types=cfg["layer_types"][:layers])
+    before = _site_counts("paddle_tpu_sdpa_sites_total")
+    lm = delta_hybrid._build_program(
+        mode, bucket, arch, cfg["vocab_size"], max_seq, slots, 0,
+        dict(delta_hybrid.SERVED_DTYPES), 4.0)
+    block = lm.main.desc.block(0)
+
+    class EveryVarThere:         # shapes come from the program, not
+        def has(self, name):     # from gigabytes of arrays in a scope
+            return True
+
+    step = pt.Executor()._compile(lm.main.desc, block, None,
+                                  [lm.fetch_name], EveryVarThere())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def state(names):
+        return {n: sds(block.find_var_recursive(n).shape,
+                       jnp.dtype(block.find_var_recursive(n).dtype))
+                for n in names}
+
+    feed = {"token_ids": sds((slots, 1, 1), jnp.int32),
+            "positions": sds((slots,), jnp.int32),
+            "lengths": sds((slots,), jnp.int32)} if mode == "decode" else \
+        {"token_ids": sds((1, bucket, 1), jnp.int32),
+         "lengths": sds((1,), jnp.int32), "slot": sds((1,), jnp.int32)}
+    compiled = step.jitted.lower(
+        feed, state(step.ro_names), state(step.rw_names),
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w[\w\-]*?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    # a period: K and V, nine windows and three matrix states
+    assert len(step.rw_names) == 2 + 9 + 3
+    sites = _site_counts("paddle_tpu_sdpa_sites_total") - before
+    if mode == "decode":
+        assert sorted(calls) == ["delta_state_update"] * 3 \
+            + ["kv_cache_append"] * 2
+        assert sites == {"composed": 1}
+    else:
+        assert calls == ["flash_fwd"] and sites == {"flash": 1}
+        # the scan over a prompt's chunks, one a linear layer
+        assert len(re.findall(r"= \S.* while\(", text)) == 3
+    memory = compiled.memory_analysis()
+    held = 2 * slots * 30 * max_seq * 128 * 2 \
+        + 3 * slots * 96 * 5760 * 4               # K, V, three states
+    assert memory.alias_size_in_bytes >= held
+    weights = 2 * (832_520_436 + 2 * 385_351_680)
+    assert weights < memory.argument_size_in_bytes \
+        < weights + held + (64 << 20)
+    assert memory.temp_size_in_bytes < 256 << 20
